@@ -909,6 +909,10 @@ def cmd_eval(args: argparse.Namespace, cfg: Config) -> int:
 
 
 def cmd_bench(args: argparse.Namespace, cfg: Config) -> int:
+    # One process per chip: bench.py is the process that holds it. Nothing
+    # on the way here (config, logging, spans) initialises a JAX backend —
+    # a parent that had touched jax.devices() would keep the chip from the
+    # child.
     import subprocess
 
     cmd = [sys.executable, "bench.py"] + args.bench_args

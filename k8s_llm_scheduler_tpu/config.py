@@ -110,8 +110,9 @@ DEFAULTS: dict[str, Any] = {
         # acceptance-rate EWMA floor: below it speculation auto-disables
         # for the request and the slot hands back to the FUSED decode path
         "spec_disable_threshold": 0.3,
-        # persistent XLA compile cache dir ("auto" = ~/.cache/...; null
-        # disables) — utils/compile_cache.py
+        # persistent XLA compile cache dir ("auto" = <checkout>/.xla_cache;
+        # null disables; a set JAX_COMPILATION_CACHE_DIR wins and nothing
+        # is set in code) — utils/compile_cache.py
         "compile_cache_dir": "auto",
         # --- fused on-device decode runtime (engine/fused/): the paged
         # decode loop as ONE lax.while_loop program with early exit —

@@ -48,7 +48,7 @@ def fused_decode_chunk_impl(
     constrained: bool,  # static
     top_k: int,        # static — 0 = full distribution
     paged_attn: str = "gather",  # static: "gather" | "pallas"
-    shmap=None,        # static ShardedAttnImpl | None
+    shmap=None,        # static AttnImpl | None
     vocab_limit: int | None = None,  # static
     shardings=None,    # engine/sharded EngineShardings | None (tp constraints)
 ):

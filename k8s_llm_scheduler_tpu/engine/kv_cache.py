@@ -82,13 +82,12 @@ class PagedKVCache:
         # keep their input sharding, so placement here is placement for
         # the cache's whole life — the host free-list/page-table
         # bookkeeping below never looks at device layout and is unchanged.
+        # `device=sharding` creates the shards in place: device_put of a
+        # jnp.zeros would first build the WHOLE pool on device 0 (8.6 GB of
+        # k+v at 8B next to that chip's 4 GB of weights).
         self.sharding = sharding
-        if sharding is not None:
-            self.k = jax.device_put(jnp.zeros(shape, dtype=dtype), sharding)
-            self.v = jax.device_put(jnp.zeros(shape, dtype=dtype), sharding)
-        else:
-            self.k = jnp.zeros(shape, dtype=dtype)
-            self.v = jnp.zeros(shape, dtype=dtype)
+        self.k = jnp.zeros(shape, dtype=dtype, device=sharding)
+        self.v = jnp.zeros(shape, dtype=dtype, device=sharding)
         # Host-side state. Page 0 is scratch — never allocated.
         self._free = list(range(num_pages - 1, 0, -1))
         self._refcount = np.zeros(num_pages, dtype=np.int32)

@@ -711,7 +711,7 @@ def forward_decode_buffered(
     prefix_len: jax.Array,  # scalar int32
     page_tables: jax.Array | None = None,  # [B, P] (own_impl="pallas" only)
     own_impl: str = "dense",  # static: "dense" pre-gathered | "pallas" kernel
-    shmap: Any = None,  # static ops.attention.ShardedAttnImpl | None —
+    shmap: Any = None,  # static ops.attention.AttnImpl | None —
     # wraps the paged kernel in shard_map over the tp kv-head axis
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step against (prefix | own tokens | chunk buffer).

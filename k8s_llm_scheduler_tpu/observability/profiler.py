@@ -64,6 +64,7 @@ re-measures the budget with the profiler on.
 from __future__ import annotations
 
 import logging
+import statistics
 import threading
 import time
 from collections import deque
@@ -197,6 +198,24 @@ def detect_peak_tflops(override: float | None = None) -> tuple[float | None, str
     if override is not None:
         return override, kind
     return PEAK_BF16_TFLOPS.get(kind), kind
+
+
+def measure_dispatch_rtt_ms(samples: int = 5) -> float:
+    """Median host time, in ms, of dispatching a trivial jitted program
+    (x + 1 on 8 floats, already compiled) and `device_get`-ing its
+    result: one dispatch plus one blocking fetch on this machine."""
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x + 1)
+    x = jnp.zeros((8,), jnp.float32)
+    jax.device_get(f(x))  # compile + warm
+    out = []
+    for _ in range(samples):
+        t0 = time.perf_counter()
+        jax.device_get(f(x))  # graftlint: ok[device-sync-in-loop] — the round trip IS what is timed
+        out.append((time.perf_counter() - t0) * 1000.0)
+    return round(statistics.median(out), 3)
 
 
 class EngineProfiler:

@@ -73,19 +73,21 @@ class EngineSampler:
 
     # ------------------------------------------------------------- sampling
     def _hbm_used_frac(self) -> float | None:
+        """The FULLEST local device's used fraction: on a tp mesh the
+        watermark that matters is the chip closest to its limit, not
+        chip 0."""
         try:
             import jax
 
-            stats = jax.local_devices()[0].memory_stats()
+            all_stats = [d.memory_stats() for d in jax.local_devices()]
         except Exception:
             return None
-        if not stats:
-            return None  # CPU backends return None/{}
-        used = stats.get("bytes_in_use")
-        limit = stats.get("bytes_limit")
-        if not used or not limit:
-            return None
-        return used / limit
+        fracs = [
+            s["bytes_in_use"] / s["bytes_limit"]
+            for s in all_stats
+            if s and s.get("bytes_in_use") and s.get("bytes_limit")
+        ]
+        return max(fracs) if fracs else None  # CPU backends report nothing
 
     def sample_once(self) -> dict[str, float | None]:
         """Take one sample and append it to every series. Public so tests

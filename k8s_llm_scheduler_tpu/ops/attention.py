@@ -17,44 +17,77 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from k8s_llm_scheduler_tpu.ops import pallas_interpret
+
 NEG_INF = -1e30  # large-negative mask value; avoids NaN from -inf * 0
 
 # Default shared-prefix attention implementation: "auto" picks the Pallas
 # flash kernel (ops/pallas_prefix_attention.py) on TPU when the shapes meet
 # its tiling constraints, else the XLA einsum path. "xla" forces the einsum
 # path; "pallas" forces the kernel (interpret-mode on CPU — parity tests).
-# On a multi-device mesh the engine passes a ShardedAttnImpl instead of a
-# string: GSPMD cannot partition a pallas_call, so the kernel is wrapped in
-# shard_map over the tp-sharded kv-head axis (per-shard it is
-# embarrassingly parallel — no collectives).
+# The engine passes an AttnImpl instead of a string: it carries the mesh
+# for tp-sharded serving (GSPMD cannot partition a pallas_call, so the
+# kernel is wrapped in shard_map over the tp-sharded kv-head axis — per
+# shard it is embarrassingly parallel, no collectives) and the record of
+# what each call site resolved to.
 PREFIX_ATTN_IMPL = "auto"
 
 
 @dataclasses.dataclass(frozen=True)
-class ShardedAttnImpl:
-    """Attention-impl choice for a tp-sharded mesh.
+class AttnImpl:
+    """One engine's attention-impl choice, bound into its jitted programs.
 
-    `kind` is the same auto/xla/pallas preference as the string form; the
-    mesh+axis let the dispatch wrap Pallas kernels in shard_map over the
-    kv-head axis instead of falling back to XLA (the round-2 behavior,
-    which cost the 70B tp=8 serving path both flash kernels)."""
+    `kind` is the same auto/xla/pallas preference as the string form. On a
+    tp-sharded mesh, `mesh`+`axis` let the dispatch wrap the Pallas
+    kernels in shard_map over the kv-head axis instead of dropping to
+    XLA. `resolved` is written at TRACE time: (call site, q shape, kv
+    length) -> the implementation the preference actually resolved to
+    there ("xla", or "pallas" with "_shard_map" on a mesh and "_interpret"
+    when the kernel runs in the interpreter rather than compiled by
+    Mosaic) — the selection is fine, being invisible is not."""
 
-    mesh: Mesh
-    axis: str = "tp"
     kind: str = "auto"
+    mesh: Mesh | None = None
+    axis: str = "tp"
+    resolved: dict[tuple, str] = dataclasses.field(
+        default_factory=dict, compare=False
+    )
+
+    def resolved_counts(self) -> dict[str, dict[str, int]]:
+        """{call site: {implementation: traced geometries}} — the form
+        get_stats carries: names from two small fixed sets, so the record
+        adds a bounded handful of series to /metrics however many wave
+        geometries are traced."""
+        out: dict[str, dict[str, int]] = {}
+        for (site, *_), how in list(self.resolved.items()):
+            by_impl = out.setdefault(site, {})
+            by_impl[how] = by_impl.get(how, 0) + 1
+        return out
 
 
-def _resolve_impl(impl) -> tuple[str, Mesh | None, str | None, int]:
-    """Normalize str | ShardedAttnImpl | None -> (kind, mesh, axis, shards)."""
+def _resolve_impl(impl) -> tuple[str, Mesh | None, str | None, int, dict | None]:
+    """Normalize str | AttnImpl | None -> (kind, mesh, axis, shards, record)."""
     if impl is None:
         impl = PREFIX_ATTN_IMPL
-    if isinstance(impl, ShardedAttnImpl):
-        shards = impl.mesh.shape.get(impl.axis, 1)
-        kind = impl.kind or PREFIX_ATTN_IMPL
+    if isinstance(impl, AttnImpl):
+        shards = impl.mesh.shape.get(impl.axis, 1) if impl.mesh is not None else 1
         if shards > 1:
-            return kind, impl.mesh, impl.axis, shards
-        return kind, None, None, 1
-    return impl, None, None, 1
+            return impl.kind, impl.mesh, impl.axis, shards, impl.resolved
+        return impl.kind, None, None, 1, impl.resolved
+    return impl, None, None, 1, None
+
+
+def _note_resolved(record, site, q_shape, kv_len, use_pallas, mesh) -> None:
+    if record is None:
+        return
+    how = "xla"
+    if use_pallas:
+        how = "pallas"
+        if mesh is not None:
+            how += "_shard_map"
+        if pallas_interpret():
+            how += "_interpret"
+    record[(site, tuple(q_shape), int(kv_len))] = how
 
 
 def set_prefix_attn_impl(impl: str) -> None:
@@ -73,7 +106,7 @@ def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None):
     overrides the module default per call site (the engine plumbs its
     per-instance setting through; None falls back to PREFIX_ATTN_IMPL).
     """
-    kind, mesh, axis, shards = _resolve_impl(impl)
+    kind, mesh, axis, shards, record = _resolve_impl(impl)
     use_pallas = False
     if kind == "pallas" or (kind == "auto" and jax.default_backend() == "tpu"):
         from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
@@ -87,6 +120,7 @@ def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None):
         use_pallas = prefix_attention_supported(
             q.shape, prefix_k.shape[1], prefix_k.shape[0], shards=shards
         )
+    _note_resolved(record, "prefix", q.shape, prefix_k.shape[0], use_pallas, mesh)
     if use_pallas:
         from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
             flash_prefix_attention_parts,
@@ -109,7 +143,7 @@ def causal_chunk_attend_parts(q, qg, k_chunk, v_chunk, chunk_lens, impl=None):
     Same dispatch contract as prefix_attend_parts: `q` [B, S, n_heads, hd]
     post-RoPE for the kernel, `qg` the pre-scaled grouped layout for the
     einsum fallback."""
-    kind, mesh, axis, shards = _resolve_impl(impl)
+    kind, mesh, axis, shards, record = _resolve_impl(impl)
     use_pallas = False
     if kind == "pallas" or (kind == "auto" and jax.default_backend() == "tpu"):
         from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
@@ -119,6 +153,7 @@ def causal_chunk_attend_parts(q, qg, k_chunk, v_chunk, chunk_lens, impl=None):
         use_pallas = causal_attention_supported(
             q.shape, k_chunk.shape[2], shards=shards
         )
+    _note_resolved(record, "causal_chunk", q.shape, q.shape[1], use_pallas, mesh)
     if use_pallas:
         from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
             flash_causal_attention_parts,

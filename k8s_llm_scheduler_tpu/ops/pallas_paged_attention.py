@@ -12,9 +12,9 @@ materializing the gathered KV.
 
 Semantics match paged_decode_attention exactly (same masking, GQA
 handling, f32 accumulation); tests/test_pallas_attention.py asserts
-equivalence against the XLA path. On non-TPU backends the kernel runs in
-interpreter mode, so the hermetic CPU test suite exercises the same code
-path the chip runs.
+equivalence against the XLA path. On the cpu backend the kernel runs in
+interpreter mode (ops.pallas_interpret), so the hermetic CPU test suite
+exercises the same kernel body Mosaic compiles on the chip.
 
 Replaces the remote attention the reference rents from the HF-hosted 70B
 (reference scheduler.py:425-433) with an in-tree kernel on the hot decode
@@ -26,14 +26,11 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from k8s_llm_scheduler_tpu.utils.jax_compat import (
-    compiler_params,
-    shard_map_compat,
-)
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from k8s_llm_scheduler_tpu.ops import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -152,17 +149,10 @@ def paged_decode_attention_pallas(  # graftlint: ok[unconstrained-sharding] — 
     and merges them with an on-chip flash accumulator — no gathered
     [B, max_pages*page_size, ...] intermediate.
     """
-    B, n_heads, head_dim = q.shape
-    num_pages, page_size, n_kv, _ = k_cache.shape
-    max_pages = page_table.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    out = _paged_call(
+    return _paged_call(
         q, k_cache, v_cache, page_table, seq_lens,
         normalize=True, interpret=interpret,
     )
-    return out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -184,8 +174,6 @@ def paged_decode_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
     B, n_heads, head_dim = q.shape
     n_kv = k_cache.shape[2]
     g = n_heads // n_kv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     acc, m, l = _paged_call(
         q, k_cache, v_cache, page_table, seq_lens,
         normalize=False, interpret=interpret,
@@ -207,7 +195,7 @@ def paged_decode_attention_parts_shmap(
     rule)."""
     P = jax.sharding.PartitionSpec
     fn = functools.partial(paged_decode_attention_parts, interpret=interpret)
-    return shard_map_compat(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -230,8 +218,7 @@ def _paged_call(q, k_cache, v_cache, page_table, seq_lens, *, normalize, interpr
     B, n_heads, head_dim = q.shape
     num_pages, page_size, n_kv, _ = k_cache.shape
     max_pages = page_table.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
 
     if normalize:
         out_shape = jax.ShapeDtypeStruct((B, n_heads, head_dim), q.dtype)
@@ -278,7 +265,7 @@ def _paged_call(q, k_cache, v_cache, page_table, seq_lens, *, normalize, interpr
         out_shape=out_shape,
         grid_spec=grid_spec,
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), q, k_cache, v_cache)

@@ -27,14 +27,11 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from k8s_llm_scheduler_tpu.utils.jax_compat import (
-    compiler_params,
-    shard_map_compat,
-)
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from k8s_llm_scheduler_tpu.ops import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -250,8 +247,7 @@ def flash_causal_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
     B, S, n_heads, hd = q.shape
     n_kv = k.shape[2]
     g = n_heads // n_kv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
     q_block = _largest_divisor(S, 1024, 8)
     k_block = _largest_divisor(S, 1024, 128)
     if q_block is None or k_block is None:
@@ -291,7 +287,7 @@ def flash_causal_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
         ),
         grid_spec=grid_spec,
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(lens.astype(jnp.int32), qr, kt, vt)
@@ -321,8 +317,7 @@ def flash_prefix_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
     B, S, n_heads, hd = q.shape
     Sp, n_kv, _ = prefix_k.shape
     g = n_heads // n_kv
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret(interpret)
 
     nq = B * g * S
     q_block = _largest_divisor(nq, 1024, 8)
@@ -371,7 +366,7 @@ def flash_prefix_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
         ),
         grid_spec=grid_spec,
         interpret=interpret,
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(
@@ -402,7 +397,7 @@ def flash_prefix_attention_parts_shmap(
     """flash_prefix_attention_parts with heads sharded over `mesh[axis]`."""
     P = jax.sharding.PartitionSpec
     fn = functools.partial(flash_prefix_attention_parts, interpret=interpret)
-    return shard_map_compat(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -427,7 +422,7 @@ def flash_causal_attention_parts_shmap(
     P = jax.sharding.PartitionSpec
     fn = functools.partial(flash_causal_attention_parts, interpret=interpret)
     head_spec = P(None, None, axis, None)  # [B, S, heads, hd]
-    return shard_map_compat(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(head_spec, head_spec, head_spec, P(None)),

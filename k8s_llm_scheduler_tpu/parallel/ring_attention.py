@@ -15,13 +15,7 @@ the ppermute with the block computation. GQA-aware like ops/attention.py.
 
 from __future__ import annotations
 
-
 import jax
-
-from k8s_llm_scheduler_tpu.utils.jax_compat import (
-    pvary_compat,
-    shard_map_compat,
-)
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -87,7 +81,7 @@ def ring_self_attention(
     axes = varying_axes if varying_axes is not None else (axis_name,)
 
     def _varying(x):
-        return pvary_compat(x, axes)
+        return jax.lax.pcast(x, axes, to="varying")
 
     num0 = _varying(jnp.zeros((B, S, n_kv, g, hd), jnp.float32))
     den0 = _varying(jnp.zeros((B, S, n_kv, g), jnp.float32))
@@ -142,11 +136,11 @@ def make_ring_prefill_attention(
             q, k, v, sp_axis, varying_axes=varying, seq_lens=lens
         )
 
-    # check_vma=True (the pre-compat default): unlike the collective-free
-    # pallas wrappers, the ring loop carries real ppermute collectives and
-    # the pvary cast exists to satisfy exactly this verifier — keep it on
-    # so a sharding bug fails loudly instead of attending garbage.
-    wrapped = shard_map_compat(
+    # check_vma=True: unlike the collective-free pallas wrappers, the ring
+    # loop carries real ppermute collectives and the pcast exists to
+    # satisfy exactly this verifier — keep it on so a sharding bug fails
+    # loudly instead of attending garbage.
+    wrapped = jax.shard_map(
         wrapped,
         mesh=mesh,
         in_specs=(spec, spec, spec, P(batch_axis)),

@@ -58,6 +58,15 @@ def kv_cache_spec(tp: str | None = "tp") -> P:
     return P(None, None, None, tp, None)
 
 
+def named_shardings(mesh: Mesh, specs: Params) -> Params:
+    """PartitionSpec pytree -> NamedSharding pytree on `mesh` (P is a
+    tuple subclass, hence the is_leaf)."""
+    return jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda s: isinstance(s, P),
+    )
+
+
 def shard_params(params: Params, mesh: Mesh, specs: Params | None = None,
                  cfg: LlamaConfig | None = None) -> Params:
     """Place a param pytree onto the mesh with NamedShardings."""
@@ -65,7 +74,7 @@ def shard_params(params: Params, mesh: Mesh, specs: Params | None = None,
         assert cfg is not None, "need cfg to derive specs"
         specs = param_specs(cfg)
     return jax.tree_util.tree_map(
-        lambda p, s: jax.device_put(p, NamedSharding(mesh, s)), params, specs
+        jax.device_put, params, named_shardings(mesh, specs)
     )
 
 

@@ -53,27 +53,35 @@ def swap_engine_params(engine, params) -> Any:
     return engine.swap_params(params)
 
 
-def _tree_bytes(params) -> int:
+def _per_device_bytes(params) -> int:
+    """Bytes ONE device holds of the tree: a tp-sharded leaf counts its
+    shard, a replicated (or single-device) leaf counts whole."""
     import jax
 
-    return sum(
-        int(getattr(leaf, "nbytes", 0))
-        for leaf in jax.tree_util.tree_leaves(params)
-    )
+    total = 0
+    for leaf in jax.tree_util.tree_leaves(params):
+        shards = getattr(leaf, "addressable_shards", None)
+        total += int(
+            shards[0].data.nbytes if shards else getattr(leaf, "nbytes", 0)
+        )
+    return total
 
 
-def _device_headroom_bytes() -> int | None:
-    """Free device memory on the first device, or None when the backend
+def _device_headroom_bytes(devices) -> int | None:
+    """Least free device memory over `devices` (the serving mesh's — a
+    second copy must fit on EVERY one of them), or None when the backend
     doesn't report it (CPU, some drivers) — callers treat None as 'room'."""
-    import jax
-
-    try:
-        stats = jax.devices()[0].memory_stats()
-    except Exception:
-        return None
-    if not stats or "bytes_limit" not in stats:
-        return None
-    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+    headroom = None
+    for dev in devices:
+        try:
+            stats = dev.memory_stats()
+        except Exception:
+            return None
+        if not stats or "bytes_limit" not in stats:
+            return None
+        free = int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+        headroom = free if headroom is None else min(headroom, free)
+    return headroom
 
 
 class HotSwapper:
@@ -132,12 +140,18 @@ class HotSwapper:
     def _choose_mode(self) -> str:
         if self.mode != "auto":
             return self.mode
-        params_bytes = _tree_bytes(self.backend.engine.params)
-        headroom = _device_headroom_bytes()
+        import jax
+
+        params_bytes = _per_device_bytes(self.backend.engine.params)
+        headroom = _device_headroom_bytes(
+            list(self.mesh.devices.flat) if self.mesh is not None
+            else jax.local_devices()[:1]
+        )
         if headroom is not None and headroom < params_bytes:
             logger.info(
-                "swap mode=donate: %.2f GB params vs %.2f GB HBM headroom "
-                "(double-buffering needs a full second copy)",
+                "swap mode=donate: %.2f GB params per device vs %.2f GB "
+                "least HBM headroom (double-buffering needs a full second "
+                "copy)",
                 params_bytes / 1e9, headroom / 1e9,
             )
             return "donate"

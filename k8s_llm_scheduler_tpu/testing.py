@@ -24,11 +24,15 @@ from __future__ import annotations
 
 import asyncio
 import threading
+from pathlib import Path
 
 from k8s_llm_scheduler_tpu.cluster.fake import FakeCluster, FakeNode
 from k8s_llm_scheduler_tpu.cluster.interface import RawPod
 
 SCHEDULER_NAME = "ai-llama-scheduler"
+# The committed 4k-vocab BPE tokenizer (a HF tokenizer.json directory):
+# what bench.py and chip_smoke.py serve with, no hub needed.
+BPE_FIXTURE = str(Path(__file__).resolve().parent / "assets" / "bpe4k")
 
 
 class _Py310Deadline:

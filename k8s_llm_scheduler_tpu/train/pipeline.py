@@ -34,12 +34,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 from k8s_llm_scheduler_tpu.models.configs import LlamaConfig
 from k8s_llm_scheduler_tpu.models.llama import (

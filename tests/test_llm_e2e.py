@@ -209,10 +209,11 @@ class TestShardedBackend:
             prefix_attn_impl="pallas",
         )
         try:
-            from k8s_llm_scheduler_tpu.ops.attention import ShardedAttnImpl
+            from k8s_llm_scheduler_tpu.ops.attention import AttnImpl
 
             impl = backend.engine.prefix_attn_impl
-            assert isinstance(impl, ShardedAttnImpl) and impl.kind == "pallas"
+            assert isinstance(impl, AttnImpl) and impl.kind == "pallas"
+            assert impl.mesh is not None
             # params actually sharded over the mesh
             leaves = jax.tree_util.tree_leaves(backend.engine.params)
             assert any(

@@ -373,6 +373,29 @@ class TestRaggedTpSeam:
         assert engine.kv.k.sharding.spec == P(None, None, None, "tp", None)
 
 
+class TestRandomInit:
+    def test_one_init_gives_every_layout_the_same_weights(self):
+        """build_local_backend random-inits through ONE jitted program:
+        born sharded on a mesh (every leaf a NamedSharding over it, never
+        whole on device 0) and bit-identical to the single-device tree —
+        the premise of comparing greedy token digests across tp."""
+        import numpy as np
+
+        from k8s_llm_scheduler_tpu.engine.local import _init_params
+
+        cfg = get_config("tiny")
+        mesh = make_mesh({"tp": 2})
+        single, sharded = _init_params(0, cfg), _init_params(0, cfg, mesh)
+        wq = sharded["layers"]["wq"]
+        assert wq.sharding.spec == P(None, None, "tp")
+        assert len(wq.sharding.device_set) == 2
+        for a, b in zip(jax.tree_util.tree_leaves(single),
+                        jax.tree_util.tree_leaves(sharded), strict=True):
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32), np.asarray(b, np.float32)
+            )
+
+
 # ----------------------------------------------------- tp identity (slow)
 @pytest.mark.slow
 class TestTpIdentity:

@@ -62,10 +62,7 @@ INDEX_VERSION = 2
 CACHE_BASENAME = ".graftlint_cache.json"
 
 _JIT_WRAPPERS = ("jax.jit", "jit", "pjit", "jax.pjit")
-_SHMAP_WRAPPERS = (
-    "shard_map", "jax.shard_map", "shard_map_compat",
-    "jax.experimental.shard_map.shard_map",
-)
+_SHMAP_WRAPPERS = ("shard_map", "jax.shard_map")
 _PARTIAL_NAMES = ("partial", "functools.partial")
 
 # Attribute-call names too generic to bare-link: every container and a

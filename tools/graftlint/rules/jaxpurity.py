@@ -43,10 +43,7 @@ from tools.graftlint.core import (
 )
 
 _JIT_WRAPPERS = ("jax.jit", "jit", "pjit", "jax.pjit")
-_SHMAP_WRAPPERS = (
-    "shard_map", "jax.shard_map", "shard_map_compat",
-    "jax.experimental.shard_map.shard_map",
-)
+_SHMAP_WRAPPERS = ("shard_map", "jax.shard_map")
 
 
 def _is_jit_call(call: ast.Call) -> bool:
