@@ -2521,7 +2521,7 @@ def cmd_complete(args: argparse.Namespace, cfg: Config) -> int:
         # recorder trace — the paged path's answer to the decision
         # traces the scheduler records.
         with spans.start_trace(
-            "completion", prompt_tokens=len(ids), spec=bool(
+            "completion", layer="engine", prompt_tokens=len(ids), spec=bool(
                 getattr(args, "spec", False)
             ),
         ) as trace:

@@ -89,12 +89,30 @@ from k8s_llm_scheduler_tpu.models.llama import (
     forward_block_decode,
     forward_decode_buffered,
     forward_prefill,
+    forward_prefill_kv,
     forward_prefill_suffix,
     forward_prefill_suffix_dense,
 )
 from k8s_llm_scheduler_tpu.ops.attention import NEG_INF, AttnImpl
 
 logger = logging.getLogger(__name__)
+
+
+def named_program(fn, *, program: str, **bound):
+    """`functools.partial(fn, **bound)` for `jax.jit`, under a program name
+    of its own: the compiled module is `jit_<program>`, which is what a
+    device trace's "XLA Modules" line shows for every run of it and what
+    a trace reader finds the program by (PERF.md §3 lists the names).
+    `jax.jit` would name a bare `functools.partial` `jit__unknown`.
+    (tools/graftlint sees through it as it does through a partial.)
+
+    The name is also part of the persistent compile cache's key, and named
+    scopes are not (the key is taken after debug info is stripped): a
+    program whose scopes change must change its name, or a cache written
+    before the change serves the old executable, without the scopes."""
+    named = functools.partial(fn, **bound)
+    named.__name__ = program
+    return named
 
 
 def _pick(masked, rng, temperature):
@@ -357,10 +375,15 @@ def _wave_impl(
     """
     if shardings is not None:
         prefix_k, prefix_v = shardings.kv4(prefix_k), shardings.kv4(prefix_v)
-    last_logits, k_sfx, v_sfx = forward_prefill_suffix_dense(
-        params, cfg, tokens, suffix_lens, prefix_k, prefix_v, prefix_len,
-        prefix_impl=prefix_impl,
-    )
+    # Named scopes (suffix_prefill, block_decode, sample_expand, model;
+    # attn / mlp / kv_writeback / lm_head inside models/llama.py) are
+    # metadata on the compiled operations: a profiler trace reads device
+    # time by step from them (observability/scopes.py), no fusion changes.
+    with jax.named_scope("suffix_prefill"):
+        last_logits, k_sfx, v_sfx = forward_prefill_suffix_dense(
+            params, cfg, tokens, suffix_lens, prefix_k, prefix_v, prefix_len,
+            prefix_impl=prefix_impl,
+        )
     R = tokens.shape[0]
     n_kv, hd = cfg.n_kv_heads, cfg.head_dim
     if shardings is not None:
@@ -381,8 +404,8 @@ def _wave_impl(
         gk, gv = shardings.kv5(gk), shardings.kv5(gv)
     jcol = jnp.arange(F)
 
-    def iteration(carry):
-        gk, gv, st, act, emitted, pos_next, logits, key = carry
+    @jax.named_scope("sample_expand")
+    def sample_expand(st, act, emitted, logits, key):
         key, sub = jax.random.split(key)
         # (a) sample the block's first token from the carried logits
         if constrained:
@@ -420,14 +443,22 @@ def _wave_impl(
         blk_tok = jnp.stack(blk, axis=1)      # [R, F]
         blk_valid = jnp.stack(valid, axis=1)  # [R, F]
         blk_len = blk_valid.sum(axis=1).astype(jnp.int32)
+        return blk_tok, blk_valid, blk_len, s_cur, alive, key
+
+    def iteration(carry):
+        gk, gv, st, act, emitted, pos_next, logits, key = carry
+        blk_tok, blk_valid, blk_len, s_cur, alive, key = sample_expand(
+            st, act, emitted, logits, key
+        )
         positions = pos_next[:, None] + jcol[None, :]
         # (c) one model call for the whole block
-        new_logits, gk, gv = forward_block_decode(
-            params, cfg, blk_tok, blk_valid, blk_len, positions,
-            k_sfx, v_sfx, suffix_lens, gk, gv, emitted,
-            prefix_k, prefix_v, prefix_len, prefix_impl=prefix_impl,
-            ragged=ragged_decode,
-        )
+        with jax.named_scope("model"):
+            new_logits, gk, gv = forward_block_decode(
+                params, cfg, blk_tok, blk_valid, blk_len, positions,
+                k_sfx, v_sfx, suffix_lens, gk, gv, emitted,
+                prefix_k, prefix_v, prefix_len, prefix_impl=prefix_impl,
+                ragged=ragged_decode,
+            )
         if shardings is not None:
             new_logits = shardings.logits2(new_logits)
             gk, gv = shardings.kv5(gk), shardings.kv5(gv)
@@ -451,9 +482,10 @@ def _wave_impl(
         out = jax.lax.dynamic_update_slice(out, blk_tok, (0, i * F))
         return i + 1, out, carry
 
-    iters_run, out, (gk, gv, st, act, emitted, pos_next, _, _) = (
-        jax.lax.while_loop(cond, body, (jnp.int32(0), out0, carry0))
-    )
+    with jax.named_scope("block_decode"):
+        iters_run, out, (gk, gv, st, act, emitted, pos_next, _, _) = (
+            jax.lax.while_loop(cond, body, (jnp.int32(0), out0, carry0))
+        )
     return out, act, iters_run
 
 
@@ -522,6 +554,14 @@ class WaveHandle:
     # estimators key on it so a 50ms half-R decision wave and a 2s
     # full-R longctx wave don't share one estimate.
     geo_key: tuple | None = None
+    # The wave's number: engine.stats["waves"] at submit, 1-based. The
+    # device runs wave programs in submit order, so a trace's k-th
+    # `jit_wave` run is the k-th `engine.submit_wave` annotation, and
+    # the `wave` stat on both names it; every item's decision trace
+    # carries the same number (engine/local.py _attach_item_spans).
+    seq: int = 0
+    bucket: int = 0       # suffix bucket (tokens per row) the wave compiled for
+    model_calls: int = 0  # block iterations the device ran; set at harvest
 
     def is_ready(self) -> bool:
         """True once the device result landed (harvest won't block)."""
@@ -678,16 +718,19 @@ class InferenceEngine:
             else None
         )
 
-        self._prefill = jax.jit(forward_prefill, static_argnums=(1,))
+        self._prefill = jax.jit(
+            named_program(forward_prefill, program="prefill"),
+            static_argnums=(1,),
+        )
         # Prefix prefill needs KV only — skipping the LM head avoids a
         # [bucket, vocab] logits tensor on the admission critical path.
         self._prefill_kv = jax.jit(
-            functools.partial(forward_prefill, return_logits=False),
+            named_program(forward_prefill_kv, program="prefix_prefill_kv"),
             static_argnums=(1,),
         )
         self._admit = jax.jit(
-            functools.partial(
-                _admit_impl,
+            named_program(
+                _admit_impl, program="admit",
                 prefix_impl=prefix_attn_impl,
                 vocab_limit=self._vocab_limit,
                 shardings=shardings,
@@ -696,8 +739,8 @@ class InferenceEngine:
             donate_argnums=(7, 8, 11, 12, 13, 14, 15, 16),
         )
         self._chunk = jax.jit(
-            functools.partial(
-                _decode_chunk_impl,
+            named_program(
+                _decode_chunk_impl, program="decode_chunk",
                 shmap=chunk_shmap,
                 vocab_limit=self._vocab_limit,
                 shardings=shardings,
@@ -726,8 +769,8 @@ class InferenceEngine:
             else DENSE_TABLE_MAX_BYTES
         )
         self._fused_chunk = jax.jit(
-            functools.partial(
-                fused_decode_chunk_impl,
+            named_program(
+                fused_decode_chunk_impl, program="fused_decode_chunk",
                 shmap=chunk_shmap,
                 vocab_limit=self._vocab_limit,
                 shardings=shardings,
@@ -743,8 +786,8 @@ class InferenceEngine:
         self._fused_unsupported = False
         self._dfa: DecisionDFA | None = None
         self._wave = jax.jit(
-            functools.partial(
-                _wave_impl,
+            named_program(
+                _wave_impl, program="wave",
                 prefix_impl=prefix_attn_impl,
                 vocab_limit=self._vocab_limit,
                 ragged_decode=(decode_matmul == "ragged"),
@@ -754,8 +797,9 @@ class InferenceEngine:
         )
         # Chunked long-prefix prefill reuses the dense cascade directly.
         self._suffix_dense = jax.jit(
-            functools.partial(
-                forward_prefill_suffix_dense, prefix_impl=prefix_attn_impl
+            named_program(
+                forward_prefill_suffix_dense, program="suffix_dense",
+                prefix_impl=prefix_attn_impl,
             ),
             static_argnums=(1,),
         )
@@ -1042,7 +1086,7 @@ class InferenceEngine:
         if not prompt_ids:
             self._prefix = self._get_empty_prefix()
             return
-        with spans.span("prefix_prefill", tokens=len(prompt_ids)) as _sp:
+        with spans.span("prefix_prefill", layer="engine", tokens=len(prompt_ids)) as _sp:
             self._set_prefix_inner(prompt_ids, _sp)
 
     def _set_prefix_inner(
@@ -1075,20 +1119,23 @@ class InferenceEngine:
         prefilled = n
         if n > min(self.prefix_chunk, self.prefill_buckets[-1]):
             seed = self._best_lcp_seed(key)
-            k, v = self._prefill_prefix_chunked(prompt_ids, seed=seed)
+            with spans.thread_span("dispatch", layer="engine"):
+                k, v = self._prefill_prefix_chunked(prompt_ids, seed=seed)
+                k, v = self._place_prefix(k, v)
             if seed is not None:
                 prefilled = n - seed[2]  # reused tokens were not re-prefilled
-            k, v = self._place_prefix(k, v)
             pfx = _PrefixKV(k=k, v=v, length=n, token_ids=key)
         else:
             bucket = self._bucket_for(n)
             pad = self.tokenizer.pad_id
             tokens = np.full((1, bucket), pad, dtype=np.int32)
             tokens[0, :n] = prompt_ids
-            _, k_all, v_all = self._prefill_kv(
-                self.params, self.cfg, jnp.asarray(tokens), jnp.asarray([n])
-            )
-            k, v = self._place_prefix(k_all[:, 0], v_all[:, 0])
+            # blocks while the device's queue is full, as in submit_wave
+            with spans.thread_span("dispatch", layer="engine"):
+                _, k_all, v_all = self._prefill_kv(
+                    self.params, self.cfg, jnp.asarray(tokens), jnp.asarray([n])
+                )
+                k, v = self._place_prefix(k_all[:, 0], v_all[:, 0])
             pfx = _PrefixKV(k=k, v=v, length=n, token_ids=key)
         self._prefix_cache[key] = pfx
 
@@ -1137,7 +1184,9 @@ class InferenceEngine:
         if not prompt_ids:
             raise ValueError("cannot pin an empty prefix")
         key = tuple(prompt_ids)
-        with spans.span("prefix_prefill", tokens=len(prompt_ids), pin=True) as _sp:
+        with spans.span(
+            "prefix_prefill", layer="engine", tokens=len(prompt_ids), pin=True
+        ) as _sp:
             self._set_prefix_inner(prompt_ids, _sp, activate=False)
         if key not in self._pinned_prefix_keys:
             self._pinned_prefix_keys.add(key)
@@ -1306,16 +1355,19 @@ class InferenceEngine:
         v_buf = jnp.zeros_like(k_buf)
         if seed is not None:
             seed_k, seed_v, reuse = seed
-            k_buf = jax.lax.dynamic_update_slice_in_dim(
-                k_buf,
-                jax.lax.slice_in_dim(seed_k, 0, reuse, axis=1).astype(k_buf.dtype),
-                0, axis=1,
-            )
-            v_buf = jax.lax.dynamic_update_slice_in_dim(
-                v_buf,
-                jax.lax.slice_in_dim(seed_v, 0, reuse, axis=1).astype(v_buf.dtype),
-                0, axis=1,
-            )
+            # eager ops: each distinct `reuse` is its own small XLA program,
+            # and the scope is how a trace tells them from anything else
+            with jax.named_scope("lcp_seed"):
+                k_buf = jax.lax.dynamic_update_slice_in_dim(
+                    k_buf,
+                    jax.lax.slice_in_dim(seed_k, 0, reuse, axis=1).astype(k_buf.dtype),
+                    0, axis=1,
+                )
+                v_buf = jax.lax.dynamic_update_slice_in_dim(
+                    v_buf,
+                    jax.lax.slice_in_dim(seed_v, 0, reuse, axis=1).astype(v_buf.dtype),
+                    0, axis=1,
+                )
             self.stats["prefix_reused_tokens"] = (
                 self.stats.get("prefix_reused_tokens", 0) + reuse
             )
@@ -1469,7 +1521,7 @@ class InferenceEngine:
 
             self._rng, sub = jax.random.split(self._rng)
             with spans.span(
-                "prefill_dispatch",
+                "prefill_dispatch", layer="engine",
                 tokens=int(suffix_lens.sum()), requests=len(prompts),
             ):
                 (
@@ -1580,8 +1632,8 @@ class InferenceEngine:
             )
 
             self._packed_admit = jax.jit(
-                functools.partial(
-                    packed_admit_step,
+                named_program(
+                    packed_admit_step, program="packed_admit",
                     prefix_impl=self.prefix_attn_impl,
                     vocab_limit=self._vocab_limit,
                     shardings=self._shardings,
@@ -1887,6 +1939,18 @@ class InferenceEngine:
             )
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
+        bucket = self._bucket_for(max(len(p) for p in prompts))
+        seq = self.stats.get("waves", 0) + 1
+        with spans.thread_span(
+            "submit_wave", layer="engine",
+            wave=seq, rows=len(prompts), bucket=bucket,
+        ):
+            return self._submit_wave(prompts, max_new_tokens, bucket, seq)
+
+    def _submit_wave(
+        self, prompts: list[list[int]], max_new_tokens: int, bucket: int,
+        seq: int,
+    ) -> WaveHandle:
         prof = self.profiler
         # Dispatch fence OPENS before prompt packing: padding/copy work is
         # part of what the host pays per dispatch boundary.
@@ -1894,7 +1958,6 @@ class InferenceEngine:
         prefix = self._prefix or self._get_empty_prefix()
         self._prefix = prefix
 
-        bucket = self._bucket_for(max(len(p) for p in prompts))
         R, n_iters, F = self._wave_geometry(len(prompts), max_new_tokens)
         self._wave_shapes_seen.add((bucket, max_new_tokens))
         geo_key = self._wave_key(R, bucket, n_iters, F, max_new_tokens)
@@ -1908,19 +1971,25 @@ class InferenceEngine:
             suffix_lens[row] = len(ids)
             max_new[row] = max_new_tokens
 
-        self._rng, sub = jax.random.split(self._rng)
-        toks_d, _, iters_d = self._wave(
-            self.params, self.cfg,
-            jnp.asarray(tokens), jnp.asarray(suffix_lens),
-            prefix.k, prefix.v, jnp.int32(prefix.length),
-            jnp.asarray(max_new),
-            self._sp_tokens, self._sp_next, self._forced, self._forced_next,
-            self._done_state,
-            jnp.int32(self.tokenizer.eos_id), jnp.int32(pad),
-            jnp.int32(self._dfa_start),
-            sub, jnp.float32(self.temperature),
-            n_iters, F, max_new_tokens, self._constrained,
-        )
+        # engine.dispatch: every call in here enqueues device work, and
+        # each BLOCKS while the device's queue is full (measured on the
+        # v5e, PERF.md §6 PR 25: with the device the bottleneck the worker
+        # spends most of a wave's time right here). It is named apart so
+        # that the rest of submit_wave reads as the host's own work.
+        with spans.thread_span("dispatch", layer="engine", wave=seq):
+            self._rng, sub = jax.random.split(self._rng)
+            toks_d, _, iters_d = self._wave(
+                self.params, self.cfg,
+                jnp.asarray(tokens), jnp.asarray(suffix_lens),
+                prefix.k, prefix.v, jnp.int32(prefix.length),
+                jnp.asarray(max_new),
+                self._sp_tokens, self._sp_next, self._forced, self._forced_next,
+                self._done_state,
+                jnp.int32(self.tokenizer.eos_id), jnp.int32(pad),
+                jnp.int32(self._dfa_start),
+                sub, jnp.float32(self.temperature),
+                n_iters, F, max_new_tokens, self._constrained,
+            )
         # Recorded only AFTER a successful dispatch: a failed first
         # dispatch must leave the geometry cold (or the retry's compile
         # would be mislabeled warm and poison the service-time EMA, and
@@ -1935,7 +2004,7 @@ class InferenceEngine:
             pass
         req_ids = list(range(self._req_counter, self._req_counter + len(prompts)))
         self._req_counter += len(prompts)
-        self.stats["waves"] = self.stats.get("waves", 0) + 1
+        self.stats["waves"] = seq
         self.stats["prefills"] += 1
         self.stats["dispatches"] += 1
         self.stats["prefill_tokens"] += int(suffix_lens.sum())
@@ -1948,6 +2017,8 @@ class InferenceEngine:
             req_ids=req_ids,
             cold_compile=cold_compile,
             geo_key=geo_key,
+            seq=seq,
+            bucket=bucket,
         )
         if prof is not None:
             # dispatch fence CLOSES here: packing + jit enqueue + D2H kick
@@ -1962,13 +2033,24 @@ class InferenceEngine:
 
     def harvest_wave(self, handle: WaveHandle) -> list[Finished]:
         """Sync one wave's results (blocks until the device program ran)."""
+        with spans.thread_span(
+            "harvest_wave", layer="engine",
+            wave=handle.seq, rows=handle.n, bucket=handle.bucket,
+        ) as ann:
+            return self._harvest_wave(handle, ann)
+
+    def _harvest_wave(self, handle: WaveHandle, ann) -> list[Finished]:
         prof = self.profiler
         if prof is not None:
             t_harvest0 = time.perf_counter()
             ready_at_entry = handle.is_ready()
         # ONE device_get for both results: each fetch is its own blocking
         # round trip, and the wave sync is the per-decision critical path.
-        toks_np, iters_np = jax.device_get((handle.toks_d, handle.iters_d))
+        with spans.thread_span("harvest_wait", layer="engine", wave=handle.seq):
+            toks_np, iters_np = jax.device_get((handle.toks_d, handle.iters_d))
+        handle.model_calls = int(iters_np)
+        if ann is not None:
+            ann.set_metadata(model_calls=handle.model_calls)
         if prof is not None:
             # the block_until_ready boundary just closed
             t_sync = time.perf_counter()
@@ -2022,7 +2104,7 @@ class InferenceEngine:
         self._pending_finished = []
         if not self._by_slot:
             return pend
-        with spans.span("decode_chunk", chunks=chunks) as sp:
+        with spans.span("decode_chunk", layer="engine", chunks=chunks) as sp:
             before = self.stats["decode_tokens"]
             finished = self._step_inner(chunks)
             if sp is not None:
@@ -2258,7 +2340,9 @@ class InferenceEngine:
             return pend + self.step(chunks)
         prof = self.profiler
         t0 = time.perf_counter() if prof is not None else 0.0
-        with spans.span("decode_chunk", chunks=chunks, fused=True) as sp:
+        with spans.span(
+            "decode_chunk", layer="engine", chunks=chunks, fused=True
+        ) as sp:
             tok_before = self.stats["decode_tokens"]
             step_before = self.stats["fused_steps"]
             finished = self._step_fused_inner(chunks, prof, t0)
@@ -2330,7 +2414,9 @@ class InferenceEngine:
             while any(not r.external for r in self._by_slot.values()):
                 out.extend(self.step())
             return out
-        with spans.span("decode_chunk", fused=True, drain=True) as sp:
+        with spans.span(
+            "decode_chunk", layer="engine", fused=True, drain=True
+        ) as sp:
             before = self.stats["decode_tokens"]
             finished = self._decode_fused_inner()
             if sp is not None:
@@ -2539,7 +2625,7 @@ class InferenceEngine:
             # /debug/export carry the forensics beside the decisions the
             # wedge stranded.
             if srv.telemetry and spans.enabled():
-                with spans.start_trace("persistent-wedge") as tr:
+                with spans.start_trace("persistent-wedge", layer="engine") as tr:
                     if tr is not None:
                         tr.set_meta(
                             blackbox=srv.blackbox_dump(),

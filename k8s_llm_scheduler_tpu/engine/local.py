@@ -286,7 +286,8 @@ class LocalLLMBackend:
         text (one group key); off, both use the plain full render."""
         if self._delta is None:
             return self.prompt_engine.cluster_part(nodes), None, 0
-        dp = self._delta.encode(nodes)
+        with spans.span("delta_encode", layer="sched"):
+            dp = self._delta.encode(nodes)
         pin_spec = None
         if dp.pin_key is not None:
             cached = self._pin_ids_cache
@@ -315,14 +316,19 @@ class LocalLLMBackend:
             raise NoFeasibleNodeError(
                 f"no feasible node for {pod.namespace}/{pod.name}"
             )
-        cluster_part, pin_spec, delta_nodes = (
-            cluster_info if cluster_info is not None
-            else self._cluster_part(nodes)
-        )
-        pod_part = pod_suffix(pod)
-        prefix_ids, suffix_ids = self.tokenizer.chat_prompt_parts(
-            self.prompt_engine.system_prompt, cluster_part, pod_part
-        )
+        # render / delta_encode / tokenize: the caller's synchronous host
+        # work per decision, on the caller's thread (the event loop under
+        # Scheduler.run) — hence layer "sched", not "engine"
+        with spans.span("render", layer="sched"):
+            cluster_part, pin_spec, delta_nodes = (
+                cluster_info if cluster_info is not None
+                else self._cluster_part(nodes)
+            )
+            pod_part = pod_suffix(pod)
+        with spans.span("tokenize", layer="sched"):
+            prefix_ids, suffix_ids = self.tokenizer.chat_prompt_parts(
+                self.prompt_engine.system_prompt, cluster_part, pod_part
+            )
         # Grammar over READY nodes of this snapshot (stable across the pods
         # of a burst); per-pod feasibility is enforced by validation upstream.
         ready_names = tuple(sorted(n.name for n in nodes if n.is_ready))
@@ -542,23 +548,24 @@ class LocalLLMBackend:
         When a kvplane client is attached, the pin may ADOPT a peer
         replica's pages instead of prefilling; the provenance lands on
         the decision trace as kv_source."""
-        if item.pin_spec is not None and self._pin_manager is not None:
-            key, pin_ids = item.pin_spec
-            try:
-                self._pin_manager.ensure(key, pin_ids)
-            except Exception:
-                # unpinned is slower, never wrong — the group install
-                # below still prefills the full prefix
-                logger.exception("snapshot prefix pin failed; continuing")
-            if item.trace is not None:
-                src = self._pin_manager.source_of(key)
-                if src is not None:
-                    item.trace[0].set_meta(kv_source=src)
-        self.engine.set_prefix(item.prefix_ids)
-        names = item.group_key[1]
-        self.engine.set_grammar(
-            self._grammar_for(names) if names is not None else None
-        )
+        with spans.thread_span("install_group", layer="engine"):
+            if item.pin_spec is not None and self._pin_manager is not None:
+                key, pin_ids = item.pin_spec
+                try:
+                    self._pin_manager.ensure(key, pin_ids)
+                except Exception:
+                    # unpinned is slower, never wrong — the group install
+                    # below still prefills the full prefix
+                    logger.exception("snapshot prefix pin failed; continuing")
+                if item.trace is not None:
+                    src = self._pin_manager.source_of(key)
+                    if src is not None:
+                        item.trace[0].set_meta(kv_source=src)
+            self.engine.set_prefix(item.prefix_ids)
+            names = item.group_key[1]
+            self.engine.set_grammar(
+                self._grammar_for(names) if names is not None else None
+            )
 
     def _submit_pack(
         self, batch: list[_WorkItem], packs: "list[dict]"
@@ -836,16 +843,22 @@ class LocalLLMBackend:
                 # if still idle after it, compile ONE sibling geometry,
                 # then re-check the queue. Arriving work always wins over
                 # starting a prewarm.
-                self._drain_queue(
-                    pending, block=True,
-                    block_timeout=self.prewarm_idle_delay_s,
-                )
+                with spans.thread_span("queue_wait", layer="engine"):
+                    self._drain_queue(
+                        pending, block=True,
+                        block_timeout=self.prewarm_idle_delay_s,
+                    )
                 if self._stopped.is_set():
                     break
                 if not pending:
-                    self._try_prewarm()
+                    with spans.thread_span("prewarm", layer="engine"):
+                        self._try_prewarm()
                 continue
-            self._drain_queue(pending, block=block)
+            if block:
+                with spans.thread_span("queue_wait", layer="engine"):
+                    self._drain_queue(pending, block=True)
+            else:
+                self._drain_queue(pending, block=False)
             if self._stopped.is_set() or (
                 not pending and not waves and not packs
                 and not self._pers_items
@@ -854,7 +867,8 @@ class LocalLLMBackend:
             # Nothing below may kill the engine-owner thread — a dead worker
             # bricks every future request.
             try:
-                pending = self._worker_tick(pending, waves, packs)
+                with spans.thread_span("tick", layer="engine"):
+                    pending = self._worker_tick(pending, waves, packs)
             except Exception as exc:  # pragma: no cover - last-resort guard
                 logger.exception("engine worker tick failed")
                 for _, items in waves:
@@ -958,22 +972,25 @@ class LocalLLMBackend:
         """Match finished engine decisions to their in-flight items —
         resident-loop admissions (_pers_items) and packed admissions
         share the paged slots, so ONE resolution seam serves both."""
+        if not fins:
+            return
         now = time.perf_counter()
-        for fin in fins:
-            entry = self._pers_items.pop(fin.req_id, None)
-            if entry is not None:
-                item, submitted_at = entry
-                handle = SimpleNamespace(submitted_at=submitted_at)
-                self._attach_item_spans(item, handle, fin, now)
-                item.resolve(fin.text)
-                continue
-            for pk in packs:
-                item = pk["items"].pop(fin.req_id, None)
-                if item is not None:
-                    handle = SimpleNamespace(submitted_at=pk["submitted_at"])
+        with spans.thread_span("resolve", layer="engine"):
+            for fin in fins:
+                entry = self._pers_items.pop(fin.req_id, None)
+                if entry is not None:
+                    item, submitted_at = entry
+                    handle = SimpleNamespace(submitted_at=submitted_at)
                     self._attach_item_spans(item, handle, fin, now)
                     item.resolve(fin.text)
-                    break
+                    continue
+                for pk in packs:
+                    item = pk["items"].pop(fin.req_id, None)
+                    if item is not None:
+                        handle = SimpleNamespace(submitted_at=pk["submitted_at"])
+                        self._attach_item_spans(item, handle, fin, now)
+                        item.resolve(fin.text)
+                        break
         packs[:] = [pk for pk in packs if pk["items"]]
 
     def _fail_paged_inflight(
@@ -1046,12 +1063,13 @@ class LocalLLMBackend:
             # keep extending the window while items are still arriving (up
             # to 5 extensions) so the whole burst lands in ONE wave instead
             # of a wide wave plus straggler waves serialized behind it.
-            for _ in range(5):
-                before = len(pending)
-                time.sleep(self.admit_wait_s)  # graftlint: ok[raw-clock] — engine-owner thread paces REAL device admission; virtual-time runs stub the backend above this layer
-                self._drain_queue(pending, block=False)
-                if len(pending) == before or len(pending) >= self.engine.max_slots:
-                    break
+            with spans.thread_span("admit_hold", layer="engine"):
+                for _ in range(5):
+                    before = len(pending)
+                    time.sleep(self.admit_wait_s)  # graftlint: ok[raw-clock] — engine-owner thread paces REAL device admission; virtual-time runs stub the backend above this layer
+                    self._drain_queue(pending, block=False)
+                    if len(pending) == before or len(pending) >= self.engine.max_slots:
+                        break
         pending = self._submit_waves(pending, waves, packs)
         if packs:
             # Packed admissions decode via the paged path: advance them
@@ -1097,25 +1115,31 @@ class LocalLLMBackend:
                 # behind the straggler poll — harvest this wave
                 # blockingly and get back to stepping them
                 deadline = 0.0
-            while (
-                not handle.is_ready()
-                and not self._stopped.is_set()
-                and time.perf_counter() < deadline
+            # ONE span around the whole poll, never one per iteration;
+            # waves submitted from inside it nest as engine.submit_wave
+            with spans.thread_span(
+                "harvest_poll", layer="engine",
+                wave=getattr(handle, "seq", 0),
             ):
-                try:
-                    got = self._queue.get(timeout=0.002)
-                except queue.Empty:
-                    if pending:
-                        # held ragged tails re-check their hold deadline
-                        # even with no new arrivals (run_group)
-                        pending = self._submit_waves(pending, waves, packs)
-                    continue
-                if got is None:
-                    self._stopped.set()
-                    break
-                pending.append(got)
-                self._drain_queue(pending, block=False)
-                pending = self._submit_waves(pending, waves, packs)
+                while (
+                    not handle.is_ready()
+                    and not self._stopped.is_set()
+                    and time.perf_counter() < deadline
+                ):
+                    try:
+                        got = self._queue.get(timeout=0.002)
+                    except queue.Empty:
+                        if pending:
+                            # held ragged tails re-check their hold deadline
+                            # even with no new arrivals (run_group)
+                            pending = self._submit_waves(pending, waves, packs)
+                        continue
+                    if got is None:
+                        self._stopped.set()
+                        break
+                    pending.append(got)
+                    self._drain_queue(pending, block=False)
+                    pending = self._submit_waves(pending, waves, packs)
             prof = getattr(self.engine, "profiler", None)
             if prof is not None and handle.is_ready():
                 # ready edge observed by the poll (or already ready when
@@ -1151,9 +1175,12 @@ class LocalLLMBackend:
                     else:
                         ema = 0.9 * ema + 0.1 * min(service, 4.0 * ema)
                     self._wave_ema[geo] = ema
-                for fin, item in zip(fins, items):
-                    self._attach_item_spans(item, handle, fin, now)
-                    item.resolve(fin.text)
+                with spans.thread_span(
+                    "resolve", layer="engine", wave=getattr(handle, "seq", 0),
+                ):
+                    for fin, item in zip(fins, items):
+                        self._attach_item_spans(item, handle, fin, now)
+                        item.resolve(fin.text)
         if (
             self._held_controls and not waves and not packs
             and not self._pers_items
@@ -1174,7 +1201,8 @@ class LocalLLMBackend:
             controls, self._held_controls = self._held_controls, []
             for ctl in controls:
                 try:
-                    result = ctl.fn()
+                    with spans.thread_span("control", layer="engine"):
+                        result = ctl.fn()
                 except Exception as exc:
                     logger.exception("quiesced control action failed")
                     ctl.fail(exc)
@@ -1205,7 +1233,16 @@ class LocalLLMBackend:
 
         - admission_wait: enqueue -> wave dispatch (queue + coalescing
           window + group-switch fairness holds);
-        - prefill / decode: the wave's wall time apportioned by token
+        - wave: submit -> harvest on the host clock, MEASURED, with the
+          wave's number (`wave`, WaveHandle.seq), its rows and model
+          calls, and this item's suffix and served token counts. The
+          number is also on admission_wait and in the trace's meta: it
+          names the `jit_wave` run of a device trace that served this
+          decision (the k-th run is the k-th engine.submit_wave). Other
+          waves are in flight beside it, so this is a latency, not the
+          device time the decision cost. Waves only: a packed or
+          resident-loop decision has no wave;
+        - prefill / decode: the same interval apportioned by token
           counts (the wave is ONE fused device program — the split is the
           same token-apportioned estimate sim/arena uses, flagged
           `apportioned`), carrying suffix/emission token counts.
@@ -1221,12 +1258,15 @@ class LocalLLMBackend:
             wall_offset = item.enqueued_wall - item.enqueued_at
             submitted = getattr(handle, "submitted_at", item.enqueued_at)
             admission_ms = max(submitted - item.enqueued_at, 0.0) * 1000.0
+            seq = getattr(handle, "seq", None)
+            wave_attr = {} if seq is None else {"wave": seq}
             # publish=False + one flush: on the late-harvest path (root
             # already recorded) each publishing add_span would pay a full
-            # trace reserialization — batch the three, re-publish once
+            # trace reserialization — batch them, re-publish once
             trace.add_span(
                 "admission_wait", start_unix=item.enqueued_wall,
                 dur_ms=admission_ms, parent_id=ctx.span_id, publish=False,
+                **wave_attr,
             )
             wave_ms = max(now - submitted, 0.0) * 1000.0
             pf = len(item.suffix_ids or ())
@@ -1234,6 +1274,14 @@ class LocalLLMBackend:
             total = pf + dc
             prefill_ms = wave_ms * pf / total if total else 0.0
             submit_wall = submitted + wall_offset
+            if seq is not None:
+                trace.set_meta(wave=seq)
+                trace.add_span(
+                    "wave", start_unix=submit_wall, dur_ms=wave_ms,
+                    parent_id=ctx.span_id, publish=False, wave=seq,
+                    rows=handle.n, suffix_tokens=pf, served_tokens=dc,
+                    model_calls=handle.model_calls,
+                )
             trace.add_span(
                 "prefill", start_unix=submit_wall, dur_ms=prefill_ms,
                 parent_id=ctx.span_id, tokens=pf, apportioned=True,

@@ -440,8 +440,8 @@ class LearnLoop:
         out_dir = str(work_dir / "candidate")
         report: dict[str, Any] = {"seed": cfg.seed}
         self.counters["cycles"] += 1
-        with spans.start_trace("learn_cycle"):
-            with spans.span("learn.mine") as sp:
+        with spans.start_trace("learn_cycle", layer="learn"):
+            with spans.span("learn.mine", layer="learn") as sp:
                 incumbent_version = self.registry.active()
                 self._cycle_base_ckpt = (
                     str(self.registry.get(incumbent_version).checkpoint_path)
@@ -464,17 +464,17 @@ class LearnLoop:
             report["corpus_digest"] = record["digest"]
             report["per_class"] = record["per_class"]
 
-            with spans.span("learn.build"):
+            with spans.span("learn.build", layer="learn"):
                 report["curriculum"] = curriculum_summary(
                     record, cfg.replay_fraction,
                     cases=self._cases_for(record),
                 )
 
-            with spans.span("learn.finetune"):
+            with spans.span("learn.finetune", layer="learn"):
                 train = self.train_fn or self._default_train
                 report["train_loss"] = train(record, out_dir)
 
-            with spans.span("learn.publish"):
+            with spans.span("learn.publish", layer="learn"):
                 manifest = self.registry.publish(
                     out_dir,
                     cfg=self.model_cfg,
@@ -493,7 +493,7 @@ class LearnLoop:
             report["incumbent_version"] = incumbent_version
 
             try:
-                with spans.span("learn.gate") as sp:
+                with spans.span("learn.gate", layer="learn") as sp:
                     weakness, gate = self._gate(record, out_dir, version)
                     if sp is not None:
                         sp.attrs.update(
@@ -508,7 +508,7 @@ class LearnLoop:
                     "pass": gate["pass"], "checks": gate["checks"],
                 }
                 promoted = weakness["pass"] and gate["pass"]
-                with spans.span("learn.swap") as sp:
+                with spans.span("learn.swap", layer="learn") as sp:
                     if promoted:
                         if self.swapper is not None:
                             report["swap"] = self.swapper.swap_to(version)

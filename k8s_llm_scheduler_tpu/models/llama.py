@@ -142,10 +142,20 @@ def init_params(
     return params
 
 
+# Named scopes (embed, attn, mlp, kv_writeback, lm_head) are set HERE, once,
+# so that every step built from these functions carries them: a device
+# trace then reads time by scope (observability/scopes.py). They are
+# metadata on the compiled operations and change no fusion.
+@jax.named_scope("embed")
+def _embed(params: Params, tokens: jax.Array) -> jax.Array:
+    return params["embed"][tokens]
+
+
 def _layer_slice(layers: Params, i: int | jax.Array) -> Params:
     return jax.tree_util.tree_map(lambda a: a[i], layers)
 
 
+@jax.named_scope("lm_head")
 def _logits(params: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     """LM head with f32 ACCUMULATION but native-dtype operands: casting a
     128k-vocab embedding to f32 materializes a multi-GB transient per model
@@ -174,6 +184,7 @@ def _dense(x: jax.Array, w, eq: str) -> jax.Array:
     return jnp.einsum(eq, x, w)
 
 
+@jax.named_scope("mlp")
 def _mlp(lp: Params, cfg: LlamaConfig, x: jax.Array) -> jax.Array:
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     gate = _dense(h, lp["w_gate"], "...d,df->...f")
@@ -197,15 +208,16 @@ def prefill_layer(
     B, S = x.shape[:2]
     hd = cfg.head_dim
     attn_impl = attn_fn if attn_fn is not None else causal_prefill_attention
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = _dense(h, lp["wq"], "bsd,dh->bsh").reshape(B, S, cfg.n_heads, hd)
-    k = _dense(h, lp["wk"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
-    v = _dense(h, lp["wv"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
-    attn = attn_impl(q, k, v, seq_lens)
-    attn = _dense(attn.reshape(B, S, cfg.n_heads * hd), lp["wo"], "bsh,hd->bsd")
-    x = x + attn
+    with jax.named_scope("attn"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q = _dense(h, lp["wq"], "bsd,dh->bsh").reshape(B, S, cfg.n_heads, hd)
+        k = _dense(h, lp["wk"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
+        v = _dense(h, lp["wv"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+        attn = attn_impl(q, k, v, seq_lens)
+        attn = _dense(attn.reshape(B, S, cfg.n_heads * hd), lp["wo"], "bsh,hd->bsd")
+        x = x + attn
     x = x + _mlp(lp, cfg, x)
     return x, (k, v)
 
@@ -248,7 +260,7 @@ def forward_prefill(
     inv_freq = rope_inv_freq(cfg)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    x = params["embed"][tokens]  # [B, S, D]
+    x = _embed(params, tokens)  # [B, S, D]
 
     def body(x, lp):
         return prefill_layer(lp, cfg, x, positions, seq_lens, inv_freq, attn_impl)
@@ -260,6 +272,13 @@ def forward_prefill(
     if return_hidden:
         return logits, k_all, v_all, x
     return logits, k_all, v_all
+
+
+def forward_prefill_kv(params: Params, cfg: LlamaConfig, tokens, seq_lens):
+    """`forward_prefill` for KV alone, under scope `prefix_prefill`: what the
+    engine's prefix prefill runs (engine/engine.py `_prefill_kv`)."""
+    with jax.named_scope("prefix_prefill"):
+        return forward_prefill(params, cfg, tokens, seq_lens, return_logits=False)
 
 
 # ------------------------------------------------- suffix prefill (cascade)
@@ -282,17 +301,18 @@ def _suffix_layer(
     Returns (x_out, k, v)."""
     B, S = x.shape[:2]
     hd = cfg.head_dim
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = _dense(h, lp["wq"], "bsd,dh->bsh").reshape(B, S, cfg.n_heads, hd)
-    k = _dense(h, lp["wk"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
-    v = _dense(h, lp["wv"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
-    q = apply_rope(q, positions, inv_freq)
-    k = apply_rope(k, positions, inv_freq)
-    attn = chunk_attention_with_prefix(
-        q, k, v, suffix_lens, pk, pv, prefix_len, prefix_impl=prefix_impl
-    )
-    attn = _dense(attn.reshape(B, S, cfg.n_heads * hd), lp["wo"], "bsh,hd->bsd")
-    x = x + attn
+    with jax.named_scope("attn"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q = _dense(h, lp["wq"], "bsd,dh->bsh").reshape(B, S, cfg.n_heads, hd)
+        k = _dense(h, lp["wk"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
+        v = _dense(h, lp["wv"], "bsd,dh->bsh").reshape(B, S, cfg.n_kv_heads, hd)
+        q = apply_rope(q, positions, inv_freq)
+        k = apply_rope(k, positions, inv_freq)
+        attn = chunk_attention_with_prefix(
+            q, k, v, suffix_lens, pk, pv, prefix_len, prefix_impl=prefix_impl
+        )
+        attn = _dense(attn.reshape(B, S, cfg.n_heads * hd), lp["wo"], "bsh,hd->bsd")
+        x = x + attn
     x = x + _mlp(lp, cfg, x)
     return x, k, v
 
@@ -337,7 +357,7 @@ def forward_prefill_suffix(
     inv_freq = rope_inv_freq(cfg)
     positions = prefix_len + jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    x = params["embed"][tokens]  # [B, S, D]
+    x = _embed(params, tokens)  # [B, S, D]
     layer_ids = jnp.arange(cfg.n_layers)
 
     def body(carry, xs):
@@ -387,7 +407,7 @@ def forward_prefill_suffix_dense(
     inv_freq = rope_inv_freq(cfg)
     positions = prefix_len + jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    x = params["embed"][tokens]  # [B, S, D]
+    x = _embed(params, tokens)  # [B, S, D]
 
     def body(x, xs):
         lp, pk, pv = xs
@@ -451,7 +471,7 @@ def forward_prefill_packed(
     hd = cfg.head_dim
     inv_freq = rope_inv_freq(cfg)
 
-    x = params["embed"][tokens][None]  # [1, C, D]
+    x = _embed(params, tokens)[None]  # [1, C, D]
     pos_b = positions[None, :]  # [1, C]
 
     # Masks are layer-independent: build once outside the scan.
@@ -467,27 +487,28 @@ def forward_prefill_packed(
     def body(carry, xs):
         x, ck, cv, kc, vc = carry
         lp, pk, pv, idx = xs
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q = _dense(h, lp["wq"], "bsd,dh->bsh").reshape(1, C, cfg.n_heads, hd)
-        k = _dense(h, lp["wk"], "bsd,dh->bsh").reshape(1, C, cfg.n_kv_heads, hd)
-        v = _dense(h, lp["wv"], "bsd,dh->bsh").reshape(1, C, cfg.n_kv_heads, hd)
-        q = apply_rope(q, pos_b, inv_freq)
-        k = apply_rope(k, pos_b, inv_freq)
-        qg = (q.astype(jnp.float32) * hd**-0.5).reshape(
-            1, C, cfg.n_kv_heads, cfg.q_per_kv, hd
-        )
-        parts = [
-            prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
-            attend_part(
-                qg, ck[idx][None], cv[idx][None], carry_mask,
-                "bqkgh,bskh->bkgqs",
-            ),
-            attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
-        ]
-        attn = merge_attention_parts(parts)  # [1, n_kv, g, C, hd]
-        attn = jnp.moveaxis(attn, 3, 1).reshape(1, C, cfg.n_heads * hd)
-        attn = _dense(attn.astype(x.dtype), lp["wo"], "bsh,hd->bsd")
-        x = x + attn
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+            q = _dense(h, lp["wq"], "bsd,dh->bsh").reshape(1, C, cfg.n_heads, hd)
+            k = _dense(h, lp["wk"], "bsd,dh->bsh").reshape(1, C, cfg.n_kv_heads, hd)
+            v = _dense(h, lp["wv"], "bsd,dh->bsh").reshape(1, C, cfg.n_kv_heads, hd)
+            q = apply_rope(q, pos_b, inv_freq)
+            k = apply_rope(k, pos_b, inv_freq)
+            qg = (q.astype(jnp.float32) * hd**-0.5).reshape(
+                1, C, cfg.n_kv_heads, cfg.q_per_kv, hd
+            )
+            parts = [
+                prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
+                attend_part(
+                    qg, ck[idx][None], cv[idx][None], carry_mask,
+                    "bqkgh,bskh->bkgqs",
+                ),
+                attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
+            ]
+            attn = merge_attention_parts(parts)  # [1, n_kv, g, C, hd]
+            attn = jnp.moveaxis(attn, 3, 1).reshape(1, C, cfg.n_heads * hd)
+            attn = _dense(attn.astype(x.dtype), lp["wo"], "bsh,hd->bsd")
+            x = x + attn
         x = x + _mlp(lp, cfg, x)
         # Scatter this chunk's K/V into the paged cache (per-token dests;
         # padding routed to the reserved scratch page 0 by the caller)...
@@ -570,7 +591,7 @@ def forward_block_decode(
     cap1 = gen_k.shape[2]  # cap + 1 (trash slot at index cap)
     inv_freq = rope_inv_freq(cfg)
 
-    x = params["embed"][blk_tok]  # [R, F, D]
+    x = _embed(params, blk_tok)  # [R, F, D]
     Ss = k_sfx.shape[2]
 
     if ragged:
@@ -607,38 +628,41 @@ def forward_block_decode(
         def body_ragged(carry, xs):
             xc, gk, gv = carry
             lp, pk, pv, ks, vs, idx = xs
-            h = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
-            q = _rdense(h, lp["wq"])[inv_perm].reshape(
-                R, F, cfg.n_heads, hd
-            )
-            k = _rdense(h, lp["wk"])[inv_perm].reshape(
-                R, F, cfg.n_kv_heads, hd
-            )
-            v = _rdense(h, lp["wv"])[inv_perm].reshape(
-                R, F, cfg.n_kv_heads, hd
-            )
-            q = apply_rope(q, positions, inv_freq)
-            k = apply_rope(k, positions, inv_freq)
-            qg = (q.astype(jnp.float32) * hd**-0.5).reshape(
-                R, F, cfg.n_kv_heads, cfg.q_per_kv, hd
-            )
-            parts = [
-                prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
-                attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
-                attend_part(qg, gk[idx], gv[idx], gen_mask, "bqkgh,bskh->bkgqs"),
-                attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
-            ]
-            attn = merge_attention_parts(parts)
-            attn = jnp.moveaxis(attn, 3, 1).reshape(R * F, cfg.n_heads * hd)
-            attn_c = attn[perm].astype(xc.dtype)
-            xc = xc + _rdense(attn_c, lp["wo"])
-            h2 = rms_norm(xc, lp["mlp_norm"], cfg.rms_eps)
-            gate = _rdense(h2, lp["w_gate"])
-            up = _rdense(h2, lp["w_up"])
-            fused = jax.nn.silu(gate.astype(jnp.float32)).astype(xc.dtype) * up
-            xc = xc + _rdense(fused, lp["w_down"])
-            gk = gk.at[idx, row, dest].set(k.astype(gk.dtype))
-            gv = gv.at[idx, row, dest].set(v.astype(gv.dtype))
+            with jax.named_scope("attn"):
+                h = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
+                q = _rdense(h, lp["wq"])[inv_perm].reshape(
+                    R, F, cfg.n_heads, hd
+                )
+                k = _rdense(h, lp["wk"])[inv_perm].reshape(
+                    R, F, cfg.n_kv_heads, hd
+                )
+                v = _rdense(h, lp["wv"])[inv_perm].reshape(
+                    R, F, cfg.n_kv_heads, hd
+                )
+                q = apply_rope(q, positions, inv_freq)
+                k = apply_rope(k, positions, inv_freq)
+                qg = (q.astype(jnp.float32) * hd**-0.5).reshape(
+                    R, F, cfg.n_kv_heads, cfg.q_per_kv, hd
+                )
+                parts = [
+                    prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
+                    attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
+                    attend_part(qg, gk[idx], gv[idx], gen_mask, "bqkgh,bskh->bkgqs"),
+                    attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
+                ]
+                attn = merge_attention_parts(parts)
+                attn = jnp.moveaxis(attn, 3, 1).reshape(R * F, cfg.n_heads * hd)
+                attn_c = attn[perm].astype(xc.dtype)
+                xc = xc + _rdense(attn_c, lp["wo"])
+            with jax.named_scope("mlp"):
+                h2 = rms_norm(xc, lp["mlp_norm"], cfg.rms_eps)
+                gate = _rdense(h2, lp["w_gate"])
+                up = _rdense(h2, lp["w_up"])
+                fused = jax.nn.silu(gate.astype(jnp.float32)).astype(xc.dtype) * up
+                xc = xc + _rdense(fused, lp["w_down"])
+            with jax.named_scope("kv_writeback"):
+                gk = gk.at[idx, row, dest].set(k.astype(gk.dtype))
+                gv = gv.at[idx, row, dest].set(v.astype(gv.dtype))
             return (xc, gk, gv), None
 
         (xc, gen_k, gen_v), _ = jax.lax.scan(
@@ -654,34 +678,36 @@ def forward_block_decode(
     def body(carry, xs):
         x, gk, gv = carry
         lp, pk, pv, ks, vs, idx = xs
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q = _dense(h, lp["wq"], "bfd,dh->bfh").reshape(R, F, cfg.n_heads, hd)
-        k = _dense(h, lp["wk"], "bfd,dh->bfh").reshape(R, F, cfg.n_kv_heads, hd)
-        v = _dense(h, lp["wv"], "bfd,dh->bfh").reshape(R, F, cfg.n_kv_heads, hd)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+            q = _dense(h, lp["wq"], "bfd,dh->bfh").reshape(R, F, cfg.n_heads, hd)
+            k = _dense(h, lp["wk"], "bfd,dh->bfh").reshape(R, F, cfg.n_kv_heads, hd)
+            v = _dense(h, lp["wv"], "bfd,dh->bfh").reshape(R, F, cfg.n_kv_heads, hd)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
 
-        qg = (q.astype(jnp.float32) * hd**-0.5).reshape(
-            R, F, cfg.n_kv_heads, cfg.q_per_kv, hd
-        )
-        # Read this layer's generated-token KV from the carry: gen_mask only
-        # exposes entries < tail (previous iterations), so the read never
-        # sees this iteration's (not yet written) block.
-        parts = [
-            prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
-            attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
-            attend_part(qg, gk[idx], gv[idx], gen_mask, "bqkgh,bskh->bkgqs"),
-            attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
-        ]
-        attn = merge_attention_parts(parts)  # [R, n_kv, g, F, hd]
-        attn = jnp.moveaxis(attn, 3, 1).reshape(R, F, cfg.n_heads * hd)
-        attn = _dense(attn.astype(x.dtype), lp["wo"], "bfh,hd->bfd")
-        x = x + attn
+            qg = (q.astype(jnp.float32) * hd**-0.5).reshape(
+                R, F, cfg.n_kv_heads, cfg.q_per_kv, hd
+            )
+            # Read this layer's generated-token KV from the carry: gen_mask only
+            # exposes entries < tail (previous iterations), so the read never
+            # sees this iteration's (not yet written) block.
+            parts = [
+                prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
+                attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
+                attend_part(qg, gk[idx], gv[idx], gen_mask, "bqkgh,bskh->bkgqs"),
+                attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
+            ]
+            attn = merge_attention_parts(parts)  # [R, n_kv, g, F, hd]
+            attn = jnp.moveaxis(attn, 3, 1).reshape(R, F, cfg.n_heads * hd)
+            attn = _dense(attn.astype(x.dtype), lp["wo"], "bfh,hd->bfd")
+            x = x + attn
         x = x + _mlp(lp, cfg, x)
         # write the block's K/V AFTER attention (in-block attention came
         # from the dense k/v just computed)
-        gk = gk.at[idx, row, dest].set(k.astype(gk.dtype))
-        gv = gv.at[idx, row, dest].set(v.astype(gv.dtype))
+        with jax.named_scope("kv_writeback"):
+            gk = gk.at[idx, row, dest].set(k.astype(gk.dtype))
+            gv = gv.at[idx, row, dest].set(v.astype(gv.dtype))
         return (x, gk, gv), None
 
     (x, gen_k, gen_v), _ = jax.lax.scan(
@@ -749,7 +775,7 @@ def forward_decode_buffered(
         else:
             paged_parts = paged_decode_attention_parts
 
-    x = params["embed"][tokens]  # [B, D]
+    x = _embed(params, tokens)  # [B, D]
     layer_ids = jnp.arange(cfg.n_layers)
     q_per_kv = cfg.q_per_kv
     row = jnp.arange(B)
@@ -765,29 +791,30 @@ def forward_decode_buffered(
     def body(carry, xs):
         x, ck, cv = carry
         lp, pk, pv, ko, vo, idx = xs
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q = _dense(h, lp["wq"], "bd,dh->bh").reshape(B, cfg.n_heads, hd)
-        k = _dense(h, lp["wk"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
-        v = _dense(h, lp["wv"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+            q = _dense(h, lp["wq"], "bd,dh->bh").reshape(B, cfg.n_heads, hd)
+            k = _dense(h, lp["wk"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
+            v = _dense(h, lp["wv"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
 
-        ck = ck.at[idx, row, tail_len].set(k.astype(ck.dtype))
-        cv = cv.at[idx, row, tail_len].set(v.astype(cv.dtype))
+            ck = ck.at[idx, row, tail_len].set(k.astype(ck.dtype))
+            cv = cv.at[idx, row, tail_len].set(v.astype(cv.dtype))
 
-        qg = (q.astype(jnp.float32) * hd**-0.5).reshape(B, cfg.n_kv_heads, q_per_kv, hd)
-        if own_impl == "pallas":
-            own_part = paged_parts(q, ko, vo, page_tables, own_lens)
-        else:
-            own_part = attend_part(qg, ko, vo, own_mask, "bkgh,blkh->bkgl")
-        parts = [
-            attend_part(qg, pk, pv, pre_mask, "bkgh,skh->bkgs"),
-            own_part,
-            attend_part(qg, ck[idx], cv[idx], tail_mask, "bkgh,blkh->bkgl"),
-        ]
-        attn = merge_attention_parts(parts).reshape(B, cfg.n_heads * hd).astype(x.dtype)
-        attn = _dense(attn, lp["wo"], "bh,hd->bd")
-        x = x + attn
+            qg = (q.astype(jnp.float32) * hd**-0.5).reshape(B, cfg.n_kv_heads, q_per_kv, hd)
+            if own_impl == "pallas":
+                own_part = paged_parts(q, ko, vo, page_tables, own_lens)
+            else:
+                own_part = attend_part(qg, ko, vo, own_mask, "bkgh,blkh->bkgl")
+            parts = [
+                attend_part(qg, pk, pv, pre_mask, "bkgh,skh->bkgs"),
+                own_part,
+                attend_part(qg, ck[idx], cv[idx], tail_mask, "bkgh,blkh->bkgl"),
+            ]
+            attn = merge_attention_parts(parts).reshape(B, cfg.n_heads * hd).astype(x.dtype)
+            attn = _dense(attn, lp["wo"], "bh,hd->bd")
+            x = x + attn
         x = x + _mlp(lp, cfg, x)
         return (x, ck, cv), None
 
@@ -887,30 +914,31 @@ def forward_decode(
     offsets = jnp.where(active, offsets, 0)
     seq_lens = positions + 1
 
-    x = params["embed"][tokens]  # [B, D]
+    x = _embed(params, tokens)  # [B, D]
 
     def body(carry, lp_with_idx):
         x, kc, vc = carry
         lp, idx = lp_with_idx
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q = _dense(h, lp["wq"], "bd,dh->bh").reshape(B, cfg.n_heads, hd)
-        k = _dense(h, lp["wk"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
-        v = _dense(h, lp["wv"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
-        q = apply_rope(q, positions, inv_freq)
-        k = apply_rope(k, positions, inv_freq)
+        with jax.named_scope("attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+            q = _dense(h, lp["wq"], "bd,dh->bh").reshape(B, cfg.n_heads, hd)
+            k = _dense(h, lp["wk"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
+            v = _dense(h, lp["wv"], "bd,dh->bh").reshape(B, cfg.n_kv_heads, hd)
+            q = apply_rope(q, positions, inv_freq)
+            k = apply_rope(k, positions, inv_freq)
 
-        # Scatter new K/V into this layer's pages (inactive slots were
-        # redirected to the reserved scratch page 0 above).
-        layer_k = kc[idx]
-        layer_v = vc[idx]
-        layer_k = layer_k.at[page_ids, offsets].set(k)
-        layer_v = layer_v.at[page_ids, offsets].set(v)
-        kc = jax.lax.dynamic_update_index_in_dim(kc, layer_k, idx, axis=0)
-        vc = jax.lax.dynamic_update_index_in_dim(vc, layer_v, idx, axis=0)
+            # Scatter new K/V into this layer's pages (inactive slots were
+            # redirected to the reserved scratch page 0 above).
+            layer_k = kc[idx]
+            layer_v = vc[idx]
+            layer_k = layer_k.at[page_ids, offsets].set(k)
+            layer_v = layer_v.at[page_ids, offsets].set(v)
+            kc = jax.lax.dynamic_update_index_in_dim(kc, layer_k, idx, axis=0)
+            vc = jax.lax.dynamic_update_index_in_dim(vc, layer_v, idx, axis=0)
 
-        attn = attn_kernel(q, layer_k, layer_v, page_tables, seq_lens)
-        attn = _dense(attn.reshape(B, cfg.n_heads * hd), lp["wo"], "bh,hd->bd")
-        x = x + attn
+            attn = attn_kernel(q, layer_k, layer_v, page_tables, seq_lens)
+            attn = _dense(attn.reshape(B, cfg.n_heads * hd), lp["wo"], "bh,hd->bd")
+            x = x + attn
         x = x + _mlp(lp, cfg, x)
         return (x, kc, vc), None
 

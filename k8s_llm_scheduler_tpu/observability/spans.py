@@ -27,11 +27,24 @@ never averages). This module adds exactly that:
   /debug/trace/<id> on MetricsServer and `cli trace` (list/show/tail/
   export — JSONL, replayable alongside sim traces).
 
-Cost discipline: tracing is ON by default but every span is a dataclass
-append + two clock reads; with tracing disabled (`configure(enabled=False)`
-or `observability.tracing: false`) `span()` is a shared no-op context
-manager and `start_trace` yields None — the knob `bench.py --preset
-obs-overhead` A/Bs (< 2% of decision p50, SCALING.md).
+- **One more sink, the profiler's own trace**: every span also enters a
+  `jax.profiler.TraceAnnotation`, so a `jax.profiler` capture
+  (observability/trace.py `device_trace`, the benchmark's `--trace 1`)
+  shows the same spans on the host threads, on the device trace's clock.
+  The annotation's name carries its layer as a prefix (`sched.decide`,
+  `engine.submit_wave`); the flight-recorder name does not (`decide`).
+  The trace id, and on the engine's spans the wave's number, ride as
+  keyword stats. `thread_span` is the same annotation without a
+  flight-recorder span, for threads that serve no single decision (the
+  engine worker's phases). With no profiler session active an annotation
+  is one inactive TraceMe.
+
+Cost discipline: tracing is ON by default; every span is a dataclass
+append, two clock reads and one inactive TraceMe. With tracing disabled
+(`configure(enabled=False)` or `observability.tracing: false`) `span()`,
+`thread_span()` and `start_trace()` return one shared no-op context
+manager and write nothing. What the spans cost on the chip, on and off and
+under the profiler, is measured in PERF.md §6 (PR 25).
 """
 
 from __future__ import annotations
@@ -451,6 +464,58 @@ class FlightRecorder:
 flight = FlightRecorder()
 _enabled = True
 
+# The layers an annotation's name may carry. Every call site in the package
+# names one of the first three (tests/test_tracing_scopes.py walks them);
+# "app" is what a caller outside them gets.
+LAYERS = ("sched", "engine", "learn", "app")
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def annotation_name(layer: str, name: str) -> str:
+    """`<layer>.<name>`: the name a span has in the profiler's trace. The
+    benchmark wraps its own host spans around the program from outside under
+    bare names (`decide`, `bind`, `submit_wave`...) and matches them by
+    exact name, so the program's are never bare."""
+    return name if name.startswith(layer + ".") else f"{layer}.{name}"
+
+
+def _annotation(layer: str, name: str, stats: dict):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(annotation_name(layer, name), **stats)
+
+
+def thread_span(name: str, layer: str = "app", **stats: Any):
+    """A span of the calling THREAD, tied to no decision: the annotation
+    alone, no flight-recorder span, no trace. The engine worker's phases
+    (engine/local.py) are these. Yields the annotation, whose
+    `set_metadata(**stats)` adds what is known only at the end, or None
+    (the shared no-op) when tracing is disabled."""
+    if not _enabled:
+        return _NULL
+    return _annotation(layer, name, stats)
+
+
+class _AnnotationOnly:
+    """`span()` outside any trace: the annotation is entered, and the block
+    gets None as it always has where no flight-recorder span exists."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann) -> None:
+        self._ann = ann
+
+    def __enter__(self) -> None:
+        self._ann.__enter__()
+        return None
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
 
 def configure(enabled: bool | None = None, capacity: int | None = None) -> None:
     """Apply the `observability.*` config block (cli wiring)."""
@@ -472,6 +537,7 @@ def start_trace(
     parent_id: str | None = None,
     start_unix: float | None = None,
     start_perf: float | None = None,
+    layer: str = "app",
     **attrs: Any,
 ):
     """Open a new trace and make it ambient for the block. On exit the
@@ -487,13 +553,14 @@ def start_trace(
     if not _enabled:
         return _NULL
     return _start_trace_cm(
-        name, recorder, trace_id, parent_id, start_unix, start_perf, attrs
+        name, recorder, trace_id, parent_id, start_unix, start_perf, layer,
+        attrs,
     )
 
 
 @contextlib.contextmanager
 def _start_trace_cm(
-    name, recorder, trace_id, parent_id, start_unix, start_perf, attrs
+    name, recorder, trace_id, parent_id, start_unix, start_perf, layer, attrs
 ) -> Iterator[Trace]:
     trace = Trace(name, trace_id=trace_id, parent_id=parent_id, **attrs)
     if start_unix is not None:
@@ -501,7 +568,10 @@ def _start_trace_cm(
     t0 = start_perf if start_perf is not None else time.perf_counter()
     token = _current.set((trace, trace.root))
     try:
-        yield trace
+        # the annotation starts now, not at a backdated start: the
+        # profiler's clock cannot be written to after the fact
+        with _annotation(layer, name, {"trace": trace.trace_id}):
+            yield trace
     except BaseException:
         trace.root.status = "error"
         raise
@@ -511,20 +581,24 @@ def _start_trace_cm(
         (recorder if recorder is not None else flight).record(trace)
 
 
-def span(name: str, **attrs: Any):
-    """Child span under the ambient trace; without one (or with tracing
-    disabled) returns the SHARED no-op context manager — the hot path
-    allocates nothing. The caller may mutate the yielded span's attrs
-    mid-block."""
-    cur = _current.get() if _enabled else None
-    if cur is None:
+def span(name: str, layer: str = "app", **attrs: Any):
+    """Child span under the ambient trace, and the same interval as
+    `<layer>.<name>` in the profiler's trace. Without an ambient trace it
+    is the annotation alone (`thread_span`); with tracing disabled it is
+    the SHARED no-op context manager — that path allocates nothing. The
+    caller may mutate the yielded span's attrs mid-block (they reach the
+    flight recorder; the annotation keeps what it was given at entry)."""
+    if not _enabled:
         return _NULL
-    return _span_cm(name, cur, attrs)
+    cur = _current.get()
+    if cur is None:
+        return _AnnotationOnly(_annotation(layer, name, attrs))
+    return _span_cm(name, layer, cur, attrs)
 
 
 @contextlib.contextmanager
 def _span_cm(
-    name: str, cur: tuple[Trace, Span], attrs: dict
+    name: str, layer: str, cur: tuple[Trace, Span], attrs: dict
 ) -> Iterator[Span]:
     trace, parent = cur
     sp = Span(
@@ -540,7 +614,8 @@ def _span_cm(
     t0 = time.perf_counter()
     token = _current.set((trace, sp))
     try:
-        yield sp
+        with _annotation(layer, name, {"trace": trace.trace_id, **attrs}):
+            yield sp
     except BaseException:
         sp.status = "error"
         raise

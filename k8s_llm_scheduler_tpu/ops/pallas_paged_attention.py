@@ -262,6 +262,10 @@ def _paged_call(q, k_cache, v_cache, page_table, seq_lens, *, normalize, interpr
     )
     return pl.pallas_call(
         functools.partial(_decode_kernel, normalize=normalize),
+        name=(
+            "paged_decode_attention_pallas" if normalize
+            else "paged_decode_attention_parts"
+        ),
         out_shape=out_shape,
         grid_spec=grid_spec,
         interpret=interpret,

@@ -280,6 +280,7 @@ def flash_causal_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
     )
     o, m, l = pl.pallas_call(
         functools.partial(_causal_kernel, S=S, q_block=q_block, k_block=k_block),
+        name="flash_causal_attention_parts",
         out_shape=(
             jax.ShapeDtypeStruct((B, n_kv, g * S, hd), jnp.float32),
             jax.ShapeDtypeStruct((B, n_kv, g * S, 128), jnp.float32),
@@ -359,6 +360,10 @@ def flash_prefix_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
     )
     o, m, l = pl.pallas_call(
         _prefix_kernel,
+        # explicit: the compiled operation, and every event of it in a
+        # device trace, is `<name>.<n>`; a trace reader finds it by that
+        # stem (benchmark/metrics/prefix_attn_roofline.py KERNEL)
+        name="flash_prefix_attention_parts",
         out_shape=(
             jax.ShapeDtypeStruct((n_kv, nq, hd), jnp.float32),
             jax.ShapeDtypeStruct((n_kv, nq, 128), jnp.float32),

@@ -108,6 +108,7 @@ def ragged_matmul(
     grid = (wp.shape[1] // bn, wp.shape[0] // bk)
     out = pl.pallas_call(
         functools.partial(_kernel, bm=bm),
+        name="ragged_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

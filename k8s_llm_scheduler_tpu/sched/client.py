@@ -214,8 +214,9 @@ class DecisionClient:
         """
         if self.cache is None:
             return None, None
-        key = decision_cache_key(pod, nodes)
-        cached = self.cache.get(pod, nodes, key=key)
+        with spans.span("cache_lookup", layer="sched"):
+            key = decision_cache_key(pod, nodes)
+            cached = self.cache.get(pod, nodes, key=key)
         if cached is not None:
             self.stats["total_requests"] += 1
             self.stats["cached_requests"] += 1
@@ -262,16 +263,16 @@ class DecisionClient:
             # decision computed under pre-swap weights that resolves after
             # a hot swap's bump_generation must file under the OLD epoch
             # (unreachable), not the new one (rollout/hotswap.py).
-            key = decision_cache_key(pod, nodes)
-            generation = self.cache.generation
+            with spans.span("cache_lookup", layer="sched"):
+                key = decision_cache_key(pod, nodes)
+                generation = self.cache.generation
+                cached = self.cache.get(pod, nodes, key=key)
             trace = spans.current_trace()
             if trace is not None:
                 # prompt/decision identity for the flight recorder: the
                 # cache key digests (pod shape, cluster snapshot) — the
                 # same equivalence class the prompt prefix is keyed by
                 trace.set_meta(cache_key=key[:16], cache_generation=generation)
-            cached = self.cache.get(pod, nodes, key=key)
-            if trace is not None:
                 # which tier answered (or "miss"): l1_hit / l2_hit come
                 # from the cache's thread-local lookup record — the fleet
                 # tiering attribute (fleet/cache.TieredDecisionCache); a
@@ -284,7 +285,7 @@ class DecisionClient:
                 return dataclasses.replace(cached, source=DecisionSource.CACHE)
             existing = self._inflight.get(key)
             if existing is not None:
-                with spans.span("coalesce_wait"):
+                with spans.span("coalesce_wait", layer="sched"):
                     try:
                         leader = await asyncio.shield(existing)
                     except Exception:
@@ -352,7 +353,7 @@ class DecisionClient:
         for attempt in range(self.max_retries):
             start = time.perf_counter()  # per attempt: excludes backoff sleeps
             try:
-                with spans.span("backend", attempt=attempt):
+                with spans.span("backend", layer="sched", attempt=attempt):
                     if budget is None:
                         decision = await self._call_backend_async(pod, nodes)
                     else:

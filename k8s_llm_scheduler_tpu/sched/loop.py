@@ -172,7 +172,8 @@ class Scheduler:
             if pod is None:
                 pod = raw_pod_to_spec(raw)
             with spans.start_trace(
-                "decision", pod=f"{pod.namespace}/{pod.name}", path="full"
+                "decision", layer="sched",
+                pod=f"{pod.namespace}/{pod.name}", path="full",
             ) as trace:
                 self._stamp_shard(trace, pod)
                 return await self._schedule_pod_inner(pod, trace)
@@ -209,7 +210,7 @@ class Scheduler:
         )
 
     async def _schedule_pod_inner(self, pod, trace) -> bool:
-        with self.phases.phase("snapshot"), spans.span("snapshot"):
+        with self.phases.phase("snapshot"), spans.span("snapshot", layer="sched"):
             nodes = await self._node_snapshot()
         if not nodes:
             logger.warning("no nodes in cluster, leaving %s pending", pod.name)
@@ -217,7 +218,7 @@ class Scheduler:
             _stamp_outcome(trace, "unschedulable")
             return False
 
-        with self.phases.phase("decide"), spans.span("decide"):
+        with self.phases.phase("decide"), spans.span("decide", layer="sched"):
             # The semaphore is passed THROUGH: the client acquires it only
             # around real backend work. Cache hits and single-flight
             # follower waits never hold a slot (during a burst, followers
@@ -261,7 +262,7 @@ class Scheduler:
             # flood of cache-hit binds can't saturate the executor and
             # starve _node_snapshot's to_thread behind it.
             async with self._bind_sem:
-                with self.phases.phase("bind"), spans.span("bind"):
+                with self.phases.phase("bind"), spans.span("bind", layer="sched"):
                     ok = await asyncio.to_thread(
                         self.binder.bind_pod_to_node,
                         pod.name, pod.namespace, decision.selected_node,
@@ -329,7 +330,8 @@ class Scheduler:
             # backdated to the watch event: the trace opens after the
             # cache hit resolved, but its root must cover decide + bind
             with spans.start_trace(
-                "decision", pod=f"{pod.namespace}/{pod.name}", path="fast",
+                "decision", layer="sched",
+                pod=f"{pod.namespace}/{pod.name}", path="fast",
                 start_unix=t0_wall, start_perf=t0,
             ) as trace:
                 if trace is not None:
@@ -373,10 +375,14 @@ class Scheduler:
 
     def _bind_now(self, pod, decision) -> bool:
         """Synchronous bind + bookkeeping (nonblocking binders only)."""
-        with self.phases.phase("bind"), spans.span("bind"):
-            ok = self.binder.bind_pod_to_node(
-                pod.name, pod.namespace, decision.selected_node
-            )
+        with self.phases.phase("bind"), spans.span("bind", layer="sched"):
+            # bind_call: the same interval under a name that is ONLY ever
+            # synchronous (the executor path's "bind" covers an await), so
+            # a trace reader can sum it as the loop thread's own time
+            with spans.thread_span("bind_call", layer="sched"):
+                ok = self.binder.bind_pod_to_node(
+                    pod.name, pod.namespace, decision.selected_node
+                )
         self._note_bind(ok, pod, decision)
         return ok
 
@@ -416,8 +422,8 @@ class Scheduler:
                     # backdated to the park time: the root covers the
                     # whole park -> leader -> bind interval, not just bind
                     with spans.start_trace(
-                        "decision", pod=f"{pod.namespace}/{pod.name}",
-                        path="follower",
+                        "decision", layer="sched",
+                        pod=f"{pod.namespace}/{pod.name}", path="follower",
                         start_unix=parked_wall, start_perf=parked_at,
                     ) as trace:
                         if trace is not None:

@@ -415,7 +415,7 @@ class ReplicaServer:
                     # the client to graft (ReplicaClient._resolve); the
                     # worker's own flight recorder keeps a copy too.
                     with spans.start_trace(
-                        "replica.decide",
+                        "replica.decide", layer="sched",
                         trace_id=str(wire_trace.get("trace_id")),
                         parent_id=str(wire_trace.get("span_id")),
                         pod=f"{pod.namespace}/{pod.name}",

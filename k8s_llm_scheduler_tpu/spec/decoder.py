@@ -694,7 +694,7 @@ class SpeculativeDecoder:
         before = (s0.proposed, s0.accepted, s0.rounds, s0.disables)
         s = self.start(prompt_ids, max_new_tokens)
         try:
-            with spans.span("spec_decode") as sp:
+            with spans.span("spec_decode", layer="engine") as sp:
                 fin = None
                 while fin is None and not s.handed_off:
                     fin = self.advance(s)

@@ -63,7 +63,8 @@ CACHE_BASENAME = ".graftlint_cache.json"
 
 _JIT_WRAPPERS = ("jax.jit", "jit", "pjit", "jax.pjit")
 _SHMAP_WRAPPERS = ("shard_map", "jax.shard_map")
-_PARTIAL_NAMES = ("partial", "functools.partial")
+# `named_program` (engine/engine.py) is a partial that names the program
+_PARTIAL_NAMES = ("partial", "functools.partial", "named_program")
 
 # Attribute-call names too generic to bare-link: every container and a
 # handful of repo-wide conventions (start/stop/close/run appear on dozens

@@ -56,7 +56,7 @@ def _wrapped_bare_name_of(node: ast.AST) -> str:
     `functools.partial(fn, ...)` (the engine's idiom for binding closure
     constants: `jax.jit(functools.partial(_wave_impl, ...))`)."""
     if isinstance(node, ast.Call) and dotted_name(node.func) in (
-        "partial", "functools.partial",
+        "partial", "functools.partial", "named_program",
     ) and node.args:
         node = node.args[0]
     name = dotted_name(node)
@@ -264,7 +264,7 @@ def _jit_wrap_info(
     wrapped = call.args[0]
     offset = 0
     if isinstance(wrapped, ast.Call) and dotted_name(wrapped.func) in (
-        "partial", "functools.partial",
+        "partial", "functools.partial", "named_program",
     ) and wrapped.args:
         offset = len(wrapped.args) - 1
         wrapped = wrapped.args[0]

@@ -152,7 +152,7 @@ class UnconstrainedSharding(LintRule):
             return True
         wrapped = call.args[0]
         if isinstance(wrapped, ast.Call) and dotted_name(wrapped.func) in (
-            "partial", "functools.partial",
+            "partial", "functools.partial", "named_program",
         ):
             return any(
                 kw.arg and "shard" in kw.arg for kw in wrapped.keywords
@@ -322,7 +322,7 @@ class DonatedBufferEscape(LintRule):
             return True
         wrapped = call.args[0]
         if isinstance(wrapped, ast.Call) and dotted_name(wrapped.func) in (
-            "partial", "functools.partial",
+            "partial", "functools.partial", "named_program",
         ):
             return any(kw.arg and "shard" in kw.arg for kw in wrapped.keywords)
         return False
