@@ -665,15 +665,24 @@ class LocalLLMBackend:
                     )
                 waves.append((handle, batch))
 
-        def run_group(items: list[_WorkItem]) -> None:
-            """Full waves submit; a ragged tail holds BRIEFLY while the
-            pipeline is busy. While a wave executes, more of the burst's
-            leaders keep arriving — holding the partial turns seven ragged
-            waves into two full ones. But the hold must be deadline-bounded:
-            waves pipeline on device, so once the tail has waited
-            ~hold_max_s it ships as-is — an unbounded hold parks the tail
-            for a FULL wave round trip (~230ms measured), pushing its
-            followers past every other pod in the burst.
+        def run_group(items: list[_WorkItem], leaving: bool = False) -> None:
+            """Full waves submit; a ragged tail holds while the pipeline
+            is busy. While a wave executes, more of the burst's leaders
+            keep arriving — holding the partial turns seven ragged waves
+            into two full ones. Behind ONE wave in flight the hold is
+            deadline-bounded: once the tail has waited ~partial_hold_s it
+            ships as-is — an unbounded hold parks the tail for a FULL
+            wave round trip (~230ms measured), pushing its followers past
+            every other pod in the burst. Behind TWO or more it holds
+            until it fills or the pipeline runs down to one: the device
+            runs waves one after another, so a wave submitted now starts
+            no sooner than one submitted when only the executing wave is
+            left, and a ragged wave costs the device what a full one does.
+            (A standing backlog hands each harvest's rows back as the
+            next wave's: a 7+1 split shipped on a deadline came round
+            again as 7+1 for good, an eighth of the device's time on one
+            row.) `leaving` = a group switch is due this tick: nothing
+            more will batch with this tail, so it ships.
 
             Pack-marked items (a decide_batch admission batch) route
             through engine.admit_packed instead: one packed
@@ -726,7 +735,10 @@ class LocalLLMBackend:
             if batch:
                 oldest = min(i.enqueued_at for i in batch)
                 held_s = time.perf_counter() - oldest
-                if waves and held_s < self.partial_hold_s:
+                if not leaving and (
+                    len(waves) >= 2
+                    or (waves and held_s < self.partial_hold_s)
+                ):
                     rest.extend(batch)
                 else:
                     submit(batch)
@@ -750,7 +762,15 @@ class LocalLLMBackend:
             else:
                 others.append(item)
 
-        run_group(current)
+        # ONE reading decides both: a switch whose fairness wait is over
+        # happens below, so the current group's tail must not hold for it
+        oldest = min(others, key=lambda i: i.enqueued_at, default=None)
+        waited = time.perf_counter() - oldest.enqueued_at if others else 0.0
+        run_group(
+            current,
+            leaving=bool(others) and not packs and not self._pers_items
+            and waited >= self.group_switch_after_s,
+        )
         if not others:
             return rest
 
@@ -762,8 +782,6 @@ class LocalLLMBackend:
             # budget guarantees completion).
             rest.extend(others)
             return rest
-        oldest = min(others, key=lambda i: i.enqueued_at)
-        waited = time.perf_counter() - oldest.enqueued_at
         if waves and waited < self.group_switch_after_s:
             rest.extend(others)
             return rest

@@ -421,13 +421,20 @@ def _validate_stored_shapes(ckptr, path: Path, cfg: LlamaConfig, shapes) -> None
         # metadata unreadable on this orbax version/layout: fall through to
         # restore, whose failures are wrapped in CheckpointError anyway
         return
+    # orbax >= 0.11 returns a StepMetadata with the tree under
+    # .item_metadata (a TreeMetadata: subscriptable, not a dict); older
+    # versions returned the tree itself. None = no tree was stored.
+    tree = getattr(meta, "item_metadata", meta)
+    if tree is None:
+        return
 
     def lookup(name: str):
-        node = meta
+        node = tree
         for part in name.split("."):
-            if not isinstance(node, dict) or part not in node:
+            try:
+                node = node[part]
+            except (KeyError, IndexError, TypeError):
                 return None
-            node = node[part]
         return node
 
     for name in sorted(shapes):

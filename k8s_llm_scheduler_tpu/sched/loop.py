@@ -604,9 +604,23 @@ class Scheduler:
         await self.drain()
 
     async def drain(self) -> None:
-        """Wait for all in-flight scheduling tasks."""
+        """Wait for all in-flight scheduling tasks, and for those their
+        completion spawns (a failed leader's followers re-decide as new
+        tasks from a done-callback)."""
         while self._tasks:
-            await asyncio.gather(*list(self._tasks), return_exceptions=True)
+            batch = list(self._tasks)
+            await asyncio.gather(*batch, return_exceptions=True)
+            # gather() over tasks that have ALL finished returns without
+            # yielding, and a finished task leaves `_tasks` only in its
+            # done-callback: a task that finishes in the loop pass that
+            # wakes run() (a fleet rebind landing as stop() arrives) is
+            # here with its discard queued BEHIND us. Yield once, so what
+            # the batch queued ahead of us runs (discard, a failed
+            # leader's _flush_followers respawning), then drop the batch
+            # ourselves: emptiness must not ride on a callback, or this
+            # loop spins without ever letting the callback run.
+            await asyncio.sleep(0)
+            self._tasks.difference_update(batch)
 
     def stop(self) -> None:
         """Request loop termination; safe to call before or during run()."""

@@ -26,7 +26,10 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import asyncio  # noqa: E402
+import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
 import inspect  # noqa: E402
+import signal  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -72,6 +75,53 @@ def _lock_sanitizer_everywhere(request):
     with san:
         yield
     san.assert_clean()
+
+
+# Seconds a test's call phase may take. pytest-timeout is not in the image,
+# and without a limit of its own ONE hanging test holds its xdist worker
+# until the whole run's clock cuts it (tier 1, until PR 26). With it a hang
+# costs that worker two minutes and one failure that names the test and
+# shows every thread's stack. One constant: no marker or variable moves it.
+TEST_LIMIT_S = 120.0
+
+
+@contextlib.contextmanager
+def time_limit(nodeid: str):
+    """Fail the enclosed block with a TimeoutError naming `nodeid` once it
+    has run TEST_LIMIT_S: SIGALRM, so main thread only — where pytest and
+    every xdist worker run test bodies. All threads' stacks go to stderr
+    first. After it first fires the alarm repeats each second: code under
+    test that swallows the error (`except Exception` around one pod,
+    `except TimeoutError` around a wait_for) meets it again. The previous
+    timer and handler come back on exit, so blocks nest."""
+    limit = TEST_LIMIT_S
+    fired = False
+
+    def expired(signum, frame):
+        nonlocal fired
+        if not fired:
+            fired = True
+            # __stderr__: capsys swaps sys.stderr for an object with no
+            # fileno; fd 2 still lands in pytest's captured stderr
+            faulthandler.dump_traceback(file=sys.__stderr__)
+        raise TimeoutError(
+            f"{nodeid} still running after {limit:g} s "
+            f"(TEST_LIMIT_S, tests/conftest.py)"
+        )
+
+    old_handler = signal.signal(signal.SIGALRM, expired)
+    old_timer = signal.setitimer(signal.ITIMER_REAL, limit, 1.0)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *old_timer)
+        signal.signal(signal.SIGALRM, old_handler)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    with time_limit(item.nodeid):
+        return (yield)
 
 
 @pytest.hookimpl(tryfirst=True)

@@ -6,6 +6,7 @@ import json
 import time
 from types import SimpleNamespace
 
+import pytest
 
 from k8s_llm_scheduler_tpu.engine.local import LocalLLMBackend
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer
@@ -214,26 +215,37 @@ class TestPartialHoldDeadline:
             import concurrent.futures as cf
 
             with cf.ThreadPoolExecutor(8) as pool:
-                # full wave of 4 -> submits immediately
-                first = [
-                    pool.submit(backend.get_scheduling_decision, make_pod(i), nodes)
-                    for i in range(4)
-                ]
-                time.sleep(0.1)  # wave 1 in flight (0.4s long)
+                # full wave of 4 -> submits immediately. One batch call
+                # (all four enqueued before any is awaited), not four pool
+                # threads: on a loaded machine a late thread's row ships
+                # as a held partial of its own, and behind TWO waves in
+                # flight the tail rightly holds (TestTailHoldPolicy) — this
+                # test is about the deadline behind ONE
+                first = pool.submit(
+                    backend.get_scheduling_decisions_batch,
+                    [make_pod(i) for i in range(4)], nodes,
+                )
+                deadline = time.perf_counter() + 5
+                while not eng.submits:
+                    assert time.perf_counter() < deadline
+                    time.sleep(0.002)
+                assert eng.submits[0][1] == 4, eng.submits
+                n_first = len(eng.submits)
                 t_tail = time.perf_counter()
                 tail = [
                     pool.submit(backend.get_scheduling_decision, make_pod(10 + i), nodes)
                     for i in range(2)
                 ]
-                for f in first + tail:
-                    assert f.result(timeout=10).selected_node == "node-1"
-            assert len(eng.submits) >= 2
+                for d in first.result(timeout=10) + [
+                    f.result(timeout=10) for f in tail
+                ]:
+                    assert d.selected_node == "node-1"
             # the 2-row tail shipped after ~hold (0.05s), NOT after wave 1
             # finished (0.4s)
-            tail_submit_t = eng.submits[1][0] + eng._t0  # absolute
-            waited = tail_submit_t - t_tail
+            tail_submits = eng.submits[n_first:]
+            assert sum(n for _, n in tail_submits) == 2, eng.submits
+            waited = tail_submits[-1][0] + eng._t0 - t_tail
             assert waited < 0.3, f"tail held {waited:.3f}s (deadline 0.05s)"
-            assert eng.submits[1][1] == 2
         finally:
             backend.close()
 
@@ -266,6 +278,89 @@ class TestPartialHoldDeadline:
             first_done_at = eng.submits[0][0] + eng.wave_s
             rows_before = sum(n for t, n in eng.submits if t < first_done_at)
             assert rows_before == 8, eng.submits
+        finally:
+            backend.close()
+
+
+class TestTailHoldPolicy:
+    """run_group's hold rule, driven through _submit_waves directly with
+    the in-flight deque planted (no timing): a ragged tail behind TWO or
+    more waves holds however old it is — the device serves waves one
+    after another, so it loses nothing, and a standing backlog otherwise
+    recycles a 7+1 split for good; behind ONE wave the deadline rules;
+    and a tail whose group the engine is about to leave ships."""
+
+    @staticmethod
+    def _items(backend, n, nodes, first=0, age_s=0.0):
+        items = [
+            backend._prepare_item(make_pod(first + i), nodes) for i in range(n)
+        ]
+        for item in items:
+            item.enqueued_at -= age_s
+        return items
+
+    @pytest.mark.parametrize(
+        "in_flight,age_s,ships",
+        [
+            (2, 5.0, False),   # old tail, deep pipeline: holds for company
+            (5, 5.0, False),
+            (1, 5.0, True),    # one wave left: past its deadline, ships
+            (1, 0.0, False),   # one wave left, young: the 30 ms hold
+            (0, 0.0, True),    # idle engine never holds
+        ],
+    )
+    def test_ragged_tail_holds_behind_two_waves(self, in_flight, age_s, ships):
+        from collections import deque
+
+        eng = FakeEngine()
+        backend = LocalLLMBackend(
+            eng, tokenizer=ByteTokenizer(), partial_hold_s=0.5,
+        )
+        try:
+            nodes = make_nodes()
+            tail = self._items(backend, 3, nodes, age_s=age_s)
+            backend._current_group = tail[0].group_key
+            waves = deque((object(), []) for _ in range(in_flight))
+            rest = backend._submit_waves(list(tail), waves, [])
+            if ships:
+                assert rest == [] and [n for _, n in eng.submits] == [3]
+            else:
+                assert rest == tail and eng.submits == []
+                # company arrives: the held rows lead the full wave, the
+                # newest row is the new tail (FIFO)
+                more = self._items(backend, 2, nodes, first=10)
+                rest = backend._submit_waves(rest + more, waves, [])
+                assert [n for _, n in eng.submits] == [4]
+                assert waves[-1][1] == tail + more[:1] and rest == more[1:]
+        finally:
+            backend.close()
+
+    def test_tail_ships_when_a_group_switch_is_due(self):
+        """Behind a deep pipeline the current group's tail holds while
+        another group's items are still inside their fairness wait, and
+        ships in the tick that switches: nothing more will batch with it."""
+        from collections import deque
+
+        eng = FakeEngine()
+        backend = LocalLLMBackend(
+            eng, tokenizer=ByteTokenizer(), group_switch_after_s=0.25,
+        )
+        try:
+            tail = self._items(backend, 3, make_nodes(3), age_s=5.0)
+            other = self._items(backend, 2, make_nodes(4), first=10)
+            assert other[0].group_key != tail[0].group_key
+            backend._current_group = tail[0].group_key
+            waves = deque((object(), []) for _ in range(3))
+            rest = backend._submit_waves(tail + other, waves, [])
+            assert rest == tail + other and eng.submits == []
+            for item in other:
+                item.enqueued_at -= 0.3  # fairness wait over
+            rest = backend._submit_waves(rest, waves, [])
+            # the old group's three rows shipped, the engine switched, and
+            # the new group's tail holds behind the (now four) waves
+            assert [n for _, n in eng.submits] == [3]
+            assert backend._current_group == other[0].group_key
+            assert rest == other
         finally:
             backend.close()
 

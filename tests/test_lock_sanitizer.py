@@ -5,13 +5,21 @@ wraps threading.Lock creation, records the cross-thread acquisition-order
 graph, and flags (a) order cycles — latent ABBA deadlocks that a given
 run only hits under exact interleaving — and (b) threading locks held
 across an event-loop hop (the runtime shape of lock-across-await).
+
+Also here, as the harness's other self-test: the per-test time limit of
+tests/conftest.py (TEST_LIMIT_S, time_limit).
 """
 
 import asyncio
 import queue
+import signal
+import subprocess
+import sys
 import threading
 import time
+from pathlib import Path
 
+import conftest
 import pytest
 
 from k8s_llm_scheduler_tpu.testing import (
@@ -267,3 +275,65 @@ class TestFixture:
         with lock:
             pass
         assert lock_sanitizer.locks_created >= 1
+
+
+class TestPerTestLimit:
+    def test_sleeping_body_fails_by_name_and_the_outer_limit_returns(
+        self, monkeypatch, capfd
+    ):
+        """time_limit() on its own, nested inside this test's limit."""
+        monkeypatch.setattr(conftest, "TEST_LIMIT_S", 0.2)
+        t0 = time.monotonic()
+        with pytest.raises(TimeoutError, match=r"tests/x\.py::test_hangs .* 0\.2 s"):
+            with conftest.time_limit("tests/x.py::test_hangs"):
+                time.sleep(30)
+        assert time.monotonic() - t0 < 5
+        # every thread's stack went to stderr before the error was raised
+        assert "most recent call first" in capfd.readouterr().err
+        # this test's own limit, armed by the hook, is running again:
+        # less than the 120 s it began with, repeating once fired
+        left, interval = signal.getitimer(signal.ITIMER_REAL)
+        assert 0 < left < 120.0 and interval == 1.0
+        assert signal.getsignal(signal.SIGALRM) is not signal.SIG_DFL
+
+    def test_swallowed_error_comes_again(self, monkeypatch):
+        """Code under test that catches the TimeoutError does not get to
+        hang after it: the alarm repeats until the block is left."""
+        monkeypatch.setattr(conftest, "TEST_LIMIT_S", 0.2)
+        with pytest.raises(TimeoutError):
+            with conftest.time_limit("tests/x.py::test_swallows"):
+                try:
+                    time.sleep(30)
+                except TimeoutError:
+                    pass
+                time.sleep(30)
+
+    def test_hook_fails_the_hanging_test_and_the_next_one_runs(self, tmp_path):
+        """The wiring, in a pytest of its own: with the constant cut to
+        half a second a sleeping test fails with the TimeoutError that
+        names it, and the test after it on the same worker passes — the
+        timer was disarmed, or its next alarm would land in pytest."""
+        (tmp_path / "test_limit_probe.py").write_text(
+            "import time\n"
+            "def test_hangs():\n    time.sleep(30)\n"
+            "def test_next_runs():\n    time.sleep(0.1)\n"
+        )
+        proc = subprocess.run(
+            [
+                sys.executable, "-c",
+                "import sys, pytest, conftest\n"
+                "conftest.TEST_LIMIT_S = 0.5\n"
+                "sys.exit(pytest.main(sys.argv[1:]))",
+                str(tmp_path), "-q", "-p", "conftest", "-p", "no:cacheprovider",
+                "-p", "no:xdist", "-p", "no:randomly",
+            ],
+            cwd=Path(conftest.__file__).parent,
+            capture_output=True, text=True, timeout=100,
+        )
+        out = proc.stdout + proc.stderr
+        assert proc.returncode == 1, out
+        assert "1 failed, 1 passed" in out, out
+        assert (
+            "TimeoutError: test_limit_probe.py::test_hangs still running "
+            "after 0.5 s" in out
+        ), out

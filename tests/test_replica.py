@@ -756,8 +756,15 @@ class TestHealthAwareDispatch:
         self._run_burst(fan_fast, n=8)  # warmup: prime EMAs
         self._run_burst(fan, n=8)
         routed_before = list(fan.routed)
-        wall_fast = self._run_burst(fan_fast)
-        wall_mixed = self._run_burst(fan)
+        # fastest of three bursts an arm, in turn: a burst lasts ~0.15 s,
+        # and on a loaded host (tier 1 runs six workers) one descheduled
+        # pool thread is worth 20% of that — it read "29% degraded" with
+        # all 48 pods routed to the fast replica (PR 26). A policy that
+        # queues pods behind the slow host is slow in every burst.
+        wall_fast = wall_mixed = float("inf")
+        for _ in range(3):
+            wall_fast = min(wall_fast, self._run_burst(fan_fast))
+            wall_mixed = min(wall_mixed, self._run_burst(fan))
         timed_routing = [a - b for a, b in zip(fan.routed, routed_before)]
         # routing skew is the mechanism: the fast replica carries (nearly)
         # the whole steady-state burst

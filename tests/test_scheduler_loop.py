@@ -277,6 +277,92 @@ class TestStopWhileIdle:
         await asyncio.wait_for(task, timeout=2)
 
 
+class _SpinGuard(set):
+    """`Scheduler._tasks` that fails a test instead of hanging it:
+    drain() asks for its length every round, and a drain that has gone
+    round a thousand times is spinning (it cannot be timed out from
+    outside: a spin never yields to the loop)."""
+
+    rounds = 0
+
+    def __len__(self):
+        self.rounds += 1
+        assert self.rounds < 1000, "drain() spins on a finished task"
+        return super().__len__()
+
+
+class _RebindAtTeardown:
+    """ClusterState whose watch stream, as stop() tears it down, starts
+    a task in `scheduler._tasks` the way FleetReplica._on_gain starts a
+    rebind. The task finishes in the very loop pass that wakes run()
+    into drain(): finished, still in the set, its discard callback
+    queued behind run(). That is the state tier 1 hung in (PR 26);
+    planting it from the teardown needs no loaded machine to hit it."""
+
+    def __init__(self, cluster, scheduler):
+        self._cluster = cluster
+        self._scheduler = scheduler
+        self.get_node_metrics = cluster.get_node_metrics
+
+    async def watch_pending_pods(self, scheduler_name):
+        try:
+            async for raw in self._cluster.watch_pending_pods(scheduler_name):
+                yield raw
+        finally:
+            tasks = self._scheduler._tasks
+            task = asyncio.ensure_future(self._rebind_nothing())
+            tasks.add(task)
+            task.add_done_callback(tasks.discard)
+
+    @staticmethod
+    async def _rebind_nothing():
+        return None
+
+
+class TestStopDrains:
+    @pytest.mark.asyncio
+    @pytest.mark.parametrize("pods_in_flight", [0, 1])
+    async def test_run_returns_after_stop(self, pods_in_flight):
+        """run() comes back from stop() promptly, with every bind that
+        was in flight landed and counted. With nothing in flight the
+        only task is one that finishes as drain() begins — `while
+        self._tasks: await gather(...)` spun there forever, since
+        gather() over finished tasks never yields to the discard
+        callback. With a decision parked on the backend the same drain
+        must still wait for it and record its bind."""
+        cluster = synthetic_cluster(3)
+        backend = StubBackend(latency_s=0.3)
+        # prewarm off, as in the fleet: its cancel-and-await would
+        # yield once between the stream's teardown and drain()
+        scheduler = make_scheduler(cluster, backend, prefix_prewarm_s=0.0)
+        scheduler.cluster = _RebindAtTeardown(cluster, scheduler)
+        scheduler._tasks = _SpinGuard()
+        at_drain = []
+        drain = scheduler.drain
+
+        async def spy():
+            at_drain.append(sorted(t.done() for t in scheduler._tasks))
+            await drain()
+
+        scheduler.drain = spy
+        for pod in fixture_pods()[:pods_in_flight]:
+            cluster.add_pod(pod)
+        task = asyncio.create_task(scheduler.run())
+        async with async_deadline(5):
+            while backend.calls < pods_in_flight:
+                await asyncio.sleep(0.01)
+        await asyncio.sleep(0.05)  # idle on the stream / parked on the backend
+        scheduler.stop()
+        await asyncio.wait_for(task, timeout=5)
+        # the state the hang's stack showed: a finished task still in
+        # the set as drain() begins (beside the pending bind, if any)
+        assert at_drain == [[False] * pods_in_flight + [True]]
+        assert not scheduler._tasks
+        assert cluster.bind_count == pods_in_flight
+        assert scheduler.stats["total_scheduled"] == pods_in_flight
+        assert scheduler.stats["failed_bindings"] == 0
+
+
 class TestBurstFastPath:
     """The watch-loop fast path: cache hits bind inline, followers park on
     the leader's future and flush as a batch (no per-pod task)."""
