@@ -1,5 +1,6 @@
 """The comparison that decides `correct`: what the timed path served in the
-window against the plain reference.
+window against the plain reference of the configuration's architecture
+(reference/<architecture>.py, through harness/seam.py).
 
 After the window has closed, the peak has been read and the program's state
 is freed, a sample of the window's waves goes through the reference once:
@@ -42,8 +43,9 @@ BENCH = Path(__file__).resolve().parents[1]
 REPO = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
+from harness import seam  # noqa: E402
 from harness.traffic import rng_for  # noqa: E402
-from reference import dense_gqa as ref  # noqa: E402
+from reference.grammar import Grammar  # noqa: E402
 
 SAMPLE_WAVES = 12  # about 2,000 choices: enough that int8 moves dozens of them
 GROUP_WAVES = 6    # waves of one group that share a forward pass
@@ -102,6 +104,7 @@ def gaps_for_group(conf, weights, group, grammar, vocab_rows, control: bool = Fa
     group: every row of every wave is a tail behind the shared prefix. With
     `control`, a second list judges at each choice not the served token but
     the one the int8 forward puts first among the allowed."""
+    ref = seam.reference(conf)
     tails, spans, allowed_all, served_all = [], [], [], []
     for wave in group:
         for suffix, served in zip(wave["prompts"], wave["served"]):
@@ -177,10 +180,10 @@ def compare(conf: dict, seed: int, waves: list[dict], plans: dict, node_names: l
     limits = limits_for(conf)
     tok = reference_tokenizer()
     encode = lambda s: tok.encode(s, add_special_tokens=False)  # noqa: E731
-    grammar = ref.Grammar(encode, tok.eos_token_id, sorted(node_names), max_reason)
+    grammar = Grammar(encode, tok.eos_token_id, sorted(node_names), max_reason)
     sample = sample_groups(waves, seed)
     if weights is None:
-        weights = ref.init_weights(conf, conf["weights_seed"])
+        weights = seam.reference(conf).init_weights(conf, conf["weights_seed"])
     gaps, low_gaps, violations, unfinished, prompt_bad, bind_bad, tokens = [], [], 0, 0, 0, 0, 0
     for group in sample:
         g, lg, v, u = gaps_for_group(conf, weights, group, grammar, len(tok), control)

@@ -15,44 +15,24 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
+from harness import seam
 from harness import traffic as T
 
 HOST_SPANS = ("snapshot", "decide", "submit_wave", "harvest_wave", "prefix_prefill", "bind")
 
 
 # ------------------------------------------------------------- configuration
-def register_config(conf: dict) -> str:
-    """Register the configuration file's sizes with the program's model
-    registry (models/configs.py is not edited) and return its name."""
-    import jax.numpy as jnp
-
-    from k8s_llm_scheduler_tpu.models import configs
-
-    if conf["torch_dtype"] != "bfloat16" or conf["hidden_act"] != "silu" or conf["bias"]:
-        raise ValueError(f"{conf['name']}: only bias-free bf16 SwiGLU models run through LlamaConfig")
-    if conf["head_dim"] * conf["num_attention_heads"] != conf["hidden_size"]:
-        raise ValueError(f"{conf['name']}: head_dim x heads != hidden_size")
-    cfg = configs.LlamaConfig(
-        name=conf["name"], vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
-        n_layers=conf["num_hidden_layers"], n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"], d_ff=conf["intermediate_size"],
-        max_seq_len=conf["max_position_embeddings"], rope_theta=conf["rope_theta"],
-        rope_scaling=None, rms_eps=conf["rms_norm_eps"], dtype=jnp.bfloat16,
-        tie_embeddings=conf["tie_word_embeddings"],
-    )
-    configs._REGISTRY[cfg.name] = cfg
-    return cfg.name
-
-
 def program_config(conf: dict, mix: dict):
     """config.py DEFAULTS (not the CWD's config.yaml, not the environment)
-    with the model, the committed tokenizer fixture, and the departures the
-    configuration and traffic files state under `serve`."""
+    with the model, which the configuration's architecture registers with
+    the program by code of its own (arch/<architecture>.py `register`), the
+    committed tokenizer fixture, and the departures the configuration and
+    traffic files state under `serve`."""
     from k8s_llm_scheduler_tpu.config import DEFAULTS, Config
     from k8s_llm_scheduler_tpu.testing import BPE_FIXTURE
 
     cfg = Config(copy.deepcopy(DEFAULTS))
-    cfg.data["llm"]["model"] = conf["name"]
+    cfg.data["llm"]["model"] = seam.program(conf).register(conf)
     cfg.data["llm"]["tokenizer_path"] = BPE_FIXTURE
     for dotted, value in {**conf.get("serve", {}), **mix.get("serve", {})}.items():
         node = cfg.data
@@ -209,7 +189,6 @@ def build(conf: dict, mix: dict, seed: int):
 
     if conf["weights_seed"] != 0:
         raise ValueError("cli._backend_kwargs passes no init seed: the program always inits from 0")
-    register_config(conf)
     cfg = program_config(conf, mix)
     cluster = make_cluster(mix, seed)
     scheduler, backend = _build_stack(cfg, cluster)

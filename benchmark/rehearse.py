@@ -25,7 +25,7 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent))
 
 TOY = {
-    "name": "rehearsal-toy", "hidden_size": 256, "num_hidden_layers": 2,
+    "name": "rehearsal-toy", "architecture": "dense_gqa", "hidden_size": 256, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 64,
     "intermediate_size": 512, "vocab_size": 1280, "rope_theta": 10000.0,
     "max_position_embeddings": 8192, "rms_norm_eps": 1e-5, "tie_word_embeddings": False,

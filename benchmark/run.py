@@ -19,6 +19,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -86,6 +87,21 @@ class Tracer:
 
         jax.profiler.stop_trace()
         self.probes.tracing = False
+
+    @contextlib.contextmanager
+    def capture(self):
+        """(path of the .xplane.pb, its ProfileData). The file stays on
+        disk for as long as the block runs: an operation's scope is in the
+        file's event metadata, which ProfileData does not surface, so the
+        readers of metrics/_scope_trace.py open it themselves. Then the
+        capture directory goes, also when a reader raises."""
+        from harness import xplane
+
+        try:
+            path = xplane.find_xplane(str(self.log_dir))
+            yield path, xplane.load(path)
+        finally:
+            shutil.rmtree(self.log_dir, ignore_errors=True)
 
 
 class Ctx:
@@ -205,25 +221,25 @@ def run_cell(cell: dict, conf: dict, bench: dict, seed: int, seconds: float, tra
     if trace:
         from harness import xplane
 
-        profile = xplane.load(xplane.find_xplane(str(tracer.log_dir)))
-        reduced = xplane.reduce(profile)
-        shutil.rmtree(tracer.log_dir, ignore_errors=True)
-        ta, tb = out.trace_span
-        device_block["busy_s"] = reduced["busy_s"]
-        device_block["window_s"] = reduced["window_s"]
-        breakdown = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
         from k8s_llm_scheduler_tpu.observability import spans
 
-        waits = [s["dur_ms"] for entry in spans.flight.export_slices()[0]
-                 for s in entry["spans"] if s.get("name") == "admission_wait"]
-        ctx = Ctx(conf=conf, mix=mix, cell=cell, outcome=out, seconds=seconds, waves=waves,
-                  trace_waves=probes.waves_between(ta, tb), cluster=cluster, trace=reduced,
-                  profile=profile, peaks=device.peaks(dev["kind"]), chips=cell["chips"],
-                  prefix_prefills=probes.prefix_prefills, admission_waits_ms=waits)
-        for m in metrics_for(bench, cell["name"], "per_layer"):
-            value = reader_for(m["name"])(ctx)
-            if value is not None:
-                per_layer[m["name"]] = value
+        with tracer.capture() as (xplane_path, profile):
+            reduced = xplane.reduce(profile)
+            ta, tb = out.trace_span
+            device_block["busy_s"] = reduced["busy_s"]
+            device_block["window_s"] = reduced["window_s"]
+            breakdown = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
+            waits = [s["dur_ms"] for entry in spans.flight.export_slices()[0]
+                     for s in entry["spans"] if s.get("name") == "admission_wait"]
+            ctx = Ctx(conf=conf, mix=mix, cell=cell, outcome=out, seconds=seconds, waves=waves,
+                      trace_waves=probes.waves_between(ta, tb), cluster=cluster, trace=reduced,
+                      profile=profile, xplane_path=xplane_path, peaks=device.peaks(dev["kind"]),
+                      chips=cell["chips"], prefix_prefills=probes.prefix_prefills,
+                      admission_waits_ms=waits)
+            for m in metrics_for(bench, cell["name"], "per_layer"):
+                value = reader_for(m["name"])(ctx)
+                if value is not None:
+                    per_layer[m["name"]] = value
 
     # ---- free the program, then the reference
     node_names = [n.name for n in cluster.get_node_metrics()]
@@ -301,8 +317,10 @@ def main() -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args()
+    from harness import seam
+
     bench, cell, conf_entry = load_cell(args.workload)
-    conf = json.loads((REPO / conf_entry["file"]).read_text())
+    conf = seam.load_config(REPO / conf_entry["file"])
     try:
         import k8s_llm_scheduler_tpu  # noqa: F401
     except ImportError as exc:

@@ -20,16 +20,15 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from harness import system
-    from reference import dense_gqa as ref
+    from harness import seam
     from k8s_llm_scheduler_tpu.engine import local
     from k8s_llm_scheduler_tpu.models.configs import get_config
 
-    conf = json.loads((BENCH / "configs" / f"{sys.argv[1]}.json").read_text())
+    conf = seam.load_config(BENCH / "configs" / f"{sys.argv[1]}.json")
     seed = int(sys.argv[2])
-    system.register_config(conf)
-    mine = ref.init_weights(conf, seed)
-    theirs = local._init_params(seed % (2**31 - 1), get_config(conf["name"]))
+    model = seam.program(conf).register(conf)
+    mine = seam.reference(conf).init_weights(conf, seed)
+    theirs = local._init_params(seed % (2**31 - 1), get_config(model))
     flat_m = dict(jax.tree_util.tree_leaves_with_path(mine))
     out = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(theirs):

@@ -23,6 +23,7 @@ sys.path.insert(0, str(BENCH.parent))
 
 def main() -> int:
     import run as bench_run
+    from harness import seam
 
     workload, seconds = sys.argv[1], float(sys.argv[2])
     mix = None
@@ -36,7 +37,7 @@ def main() -> int:
         conf, mix = dict(rehearse.TOY), rehearse.small(T.load_traffic(traffic))
     else:
         bench, cell, entry = bench_run.load_cell(workload)
-        conf = json.loads((BENCH.parent / entry["file"]).read_text())
+        conf = seam.load_config(BENCH.parent / entry["file"])
     fault = None
     seeds = sys.argv[3:]
     if seeds and seeds[0].startswith("fault="):

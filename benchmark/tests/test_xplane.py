@@ -36,13 +36,13 @@ def test_reduction_reproduces_recorded_numbers():
 
 
 def test_flop_and_byte_functions():
-    from harness import flops
+    from harness import flops, seam
 
-    conf = json.loads((BENCH / "configs" / "internlm2_5-1_8b.json").read_text())
+    conf = seam.load_config(BENCH / "configs" / "internlm2_5-1_8b.json")
     # 2 FLOPs per weight per token, embedding row lookup excluded
     weights = conf["parameters"] - conf["vocab_size"] * conf["hidden_size"] - (
         2 * conf["num_hidden_layers"] + 1) * conf["hidden_size"]
-    assert flops.dense_flops_per_token(conf, with_head=True) == 2 * weights
+    assert seam.program(conf).flops_per_token(conf, with_head=True) == 2 * weights
     f, b = flops.prefix_kernel_cost(8, 192, 128, 8000)
     assert f == 4 * 8 * 192 * 8000 * 128
     assert b == 2 * 8 * 8000 * 128 * 2 + 8 * 192 * (2 * 128 + 256) * 4

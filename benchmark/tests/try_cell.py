@@ -22,10 +22,11 @@ sys.path.insert(0, str(BENCH.parent))
 
 def main() -> int:
     import run as bench_run
+    from harness import seam
 
     config, traffic, chips, seconds, seed = sys.argv[1:6]
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    conf = json.loads((BENCH / "configs" / f"{config}.json").read_text())
+    conf = seam.load_config(BENCH / "configs" / f"{config}.json")
     cell = {"name": f"try-{config}-{traffic}", "config": config, "traffic": traffic, "chips": int(chips)}
     r = bench_run.run_cell(cell, conf, bench, seed=int(seed), seconds=float(seconds), trace=False,
                            reference=False)
