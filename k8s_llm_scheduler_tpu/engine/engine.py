@@ -83,15 +83,13 @@ from k8s_llm_scheduler_tpu.observability.resident import (
     counters_dict,
 )
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
-from k8s_llm_scheduler_tpu.models.configs import LlamaConfig
+from k8s_llm_scheduler_tpu.models import family
+from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, MlaMoeConfig
 from k8s_llm_scheduler_tpu.models.llama import (
     Params,
-    forward_block_decode,
     forward_decode_buffered,
     forward_prefill,
-    forward_prefill_kv,
     forward_prefill_suffix,
-    forward_prefill_suffix_dense,
 )
 from k8s_llm_scheduler_tpu.ops.attention import NEG_INF, AttnImpl
 
@@ -326,10 +324,11 @@ def _decode_chunk_impl(
 
 def _wave_impl(
     params: Params,
-    cfg: LlamaConfig,  # static
+    cfg: LlamaConfig | MlaMoeConfig,  # static
     tokens,        # [R, Ss] suffix tokens, left-aligned, padded
     suffix_lens,   # [R] int32 (0 on padding rows)
-    prefix_k, prefix_v,  # [L, Sp, n_kv, hd] shared dense prefix KV
+    prefix_cache,  # the model's cache tuple of the shared prefix, each
+    # [L, Sp, *token shape]: (k, v) [.., n_kv, hd] dense, (c_kv, k_r) latent
     prefix_len,    # scalar int32
     max_new,       # [R] total emission budget per row (0 on padding rows)
     sp_tokens, sp_next, forced, forced_next, done_state, eos_id, pad_id,
@@ -370,26 +369,36 @@ def _wave_impl(
     would emit only pads. Early exit makes both the rounding padding and the
     post-completion tail free, so the bound can stay conservative.
 
+    The cache (prefix, suffix, generated) is THE MODEL'S per-token cache:
+    a tuple of arrays whose axes 0 and 1 are layer and token (row, then
+    token, for the per-row buffers). The model module the config's type
+    selects (models.family) says its shapes and brings the two forwards;
+    nothing here knows what a cached token is made of.
+
     Returns (emitted [R, n_iters*F] with pad_id holes, active [R],
-    iters_run scalar int32 — the number of model calls actually executed).
+    iters_run scalar int32 — the number of model calls actually executed)
+    and, for a model whose forwards count (family COUNTERS), those counts
+    summed over the wave's model calls, int32 [len(COUNTERS)].
     """
+    model = family(cfg)
+    counted = len(model.COUNTERS) > 0  # static: a tuple of names
     if shardings is not None:
-        prefix_k, prefix_v = shardings.kv4(prefix_k), shardings.kv4(prefix_v)
+        prefix_cache = tuple(shardings.kv4(a) for a in prefix_cache)
     # Named scopes (suffix_prefill, block_decode, sample_expand, model;
     # attn / mlp / kv_writeback / lm_head inside models/llama.py) are
     # metadata on the compiled operations: a profiler trace reads device
     # time by step from them (observability/scopes.py), no fusion changes.
     with jax.named_scope("suffix_prefill"):
-        last_logits, k_sfx, v_sfx = forward_prefill_suffix_dense(
-            params, cfg, tokens, suffix_lens, prefix_k, prefix_v, prefix_len,
+        last_logits, *sfx = model.forward_prefill_suffix_dense(
+            params, cfg, tokens, suffix_lens, *prefix_cache, prefix_len,
             prefix_impl=prefix_impl,
         )
+    counts = sfx.pop() if counted else None
     R = tokens.shape[0]
-    n_kv, hd = cfg.n_kv_heads, cfg.head_dim
     if shardings is not None:
         # Suffix KV [L, R, Ss, n_kv, hd] and (below) the generated-KV
         # buffers share the rank-5 kv-head layout with the paged cache.
-        k_sfx, v_sfx = shardings.kv5(k_sfx), shardings.kv5(v_sfx)
+        sfx = [shardings.kv5(a) for a in sfx]
         last_logits = shardings.logits2(last_logits)
     st = jnp.full((R,), dfa_start, dtype=jnp.int32)
     act = suffix_lens > 0
@@ -398,10 +407,14 @@ def _wave_impl(
     emitted = jnp.zeros(R, dtype=jnp.int32)
     pos_next = prefix_len + suffix_lens  # absolute position of next token
 
-    gk = jnp.zeros((cfg.n_layers, R, cap + 1, n_kv, hd), prefix_k.dtype)
-    gv = jnp.zeros_like(gk)
+    # generated-token cache, one buffer per array of the cache tuple; slot
+    # `cap` is the trash slot invalid block positions write to
+    gen = tuple(
+        jnp.zeros((cfg.n_layers, R, cap + 1, *shape), prefix_cache[0].dtype)
+        for shape in model.cache_token_shapes(cfg)
+    )
     if shardings is not None:
-        gk, gv = shardings.kv5(gk), shardings.kv5(gv)
+        gen = tuple(shardings.kv5(a) for a in gen)
     jcol = jnp.arange(F)
 
     @jax.named_scope("sample_expand")
@@ -446,34 +459,38 @@ def _wave_impl(
         return blk_tok, blk_valid, blk_len, s_cur, alive, key
 
     def iteration(carry):
-        gk, gv, st, act, emitted, pos_next, logits, key = carry
+        gen, st, act, emitted, pos_next, logits, key, *counts = carry
         blk_tok, blk_valid, blk_len, s_cur, alive, key = sample_expand(
             st, act, emitted, logits, key
         )
         positions = pos_next[:, None] + jcol[None, :]
         # (c) one model call for the whole block
         with jax.named_scope("model"):
-            new_logits, gk, gv = forward_block_decode(
+            new_logits, *gen = model.forward_block_decode(
                 params, cfg, blk_tok, blk_valid, blk_len, positions,
-                k_sfx, v_sfx, suffix_lens, gk, gv, emitted,
-                prefix_k, prefix_v, prefix_len, prefix_impl=prefix_impl,
+                *sfx, suffix_lens, *gen, emitted,
+                *prefix_cache, prefix_len, prefix_impl=prefix_impl,
                 ragged=ragged_decode,
             )
+        if counted:
+            counts = [counts[0] + gen.pop()]
         if shardings is not None:
             new_logits = shardings.logits2(new_logits)
-            gk, gv = shardings.kv5(gk), shardings.kv5(gv)
+            gen = [shardings.kv5(a) for a in gen]
         carry = (
-            gk, gv, s_cur, alive, emitted + blk_len,
-            pos_next + blk_len, new_logits, key,
+            tuple(gen), s_cur, alive, emitted + blk_len,
+            pos_next + blk_len, new_logits, key, *counts,
         )
         return carry, blk_tok
 
-    carry0 = (gk, gv, st, act, emitted, pos_next, last_logits, rng)
+    carry0 = (gen, st, act, emitted, pos_next, last_logits, rng)
+    if counted:
+        carry0 += (counts,)
     out0 = jnp.full((R, n_iters * F), pad_id, dtype=tokens.dtype)
 
     def cond(state):
         i, _, carry = state
-        alive = carry[3]
+        alive = carry[2]
         return (i < n_iters) & jnp.any(alive)
 
     def body(state):
@@ -483,20 +500,36 @@ def _wave_impl(
         return i + 1, out, carry
 
     with jax.named_scope("block_decode"):
-        iters_run, out, (gk, gv, st, act, emitted, pos_next, _, _) = (
-            jax.lax.while_loop(cond, body, (jnp.int32(0), out0, carry0))
+        iters_run, out, carry = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), out0, carry0)
         )
-    return out, act, iters_run
+    if counted:
+        return out, carry[2], iters_run, carry[7]
+    return out, carry[2], iters_run
 
 
 @dataclasses.dataclass
 class _PrefixKV:
-    """Dense KV of a burst-shared prompt prefix, prefilled once."""
+    """The model's cache of a burst-shared prompt prefix, prefilled once:
+    a tuple of arrays [L, Sp_bucket, *token shape] — (k, v) [.., n_kv, hd]
+    for the dense family, the latent pair (c_kv [.., dc], k_r [.., dr]) for
+    models/mla_moe.py. Axis 1 of every member is the token capacity."""
 
-    k: jax.Array  # [L, Sp_bucket, n_kv, hd]
-    v: jax.Array
+    kv: tuple[jax.Array, ...]
     length: int
     token_ids: tuple[int, ...]
+
+    @property
+    def k(self) -> jax.Array:
+        return self.kv[0]
+
+    @property
+    def v(self) -> jax.Array:
+        return self.kv[1]
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in self.kv)
 
 
 @dataclasses.dataclass
@@ -543,6 +576,10 @@ class WaveHandle:
 
     toks_d: jax.Array   # [R, n_iters*F] emitted tokens (pad_id holes)
     iters_d: jax.Array  # scalar int32 — model calls actually run (early exit)
+    # int32 [len(COUNTERS)] of the model family's device counters over the
+    # wave's model calls (models/mla_moe.py: expert load); None for a
+    # family that counts nothing
+    counts_d: jax.Array | None
     n: int              # real prompts in this wave (<= R)
     max_new_tokens: int
     req_ids: list[int]
@@ -613,6 +650,14 @@ class InferenceEngine:
     ) -> None:
         self.cfg = cfg
         self.params = params
+        # The model module the config's TYPE selects (models.family): its
+        # cache tuple's shapes and the three forwards of the decision path.
+        # `paged` = the family also has the paged-pool forwards (admit,
+        # decode chunks, the fused and resident loops, spec/): the dense
+        # family has, the latent one does not — its entry points refuse by
+        # name (_require_paged) and no pool is allocated for it.
+        self._model = family(cfg)
+        self.paged = isinstance(cfg, LlamaConfig)
         self.tokenizer = tokenizer or ByteTokenizer()
         if self.tokenizer.vocab_size > cfg.vocab_size:
             raise ValueError(
@@ -634,6 +679,24 @@ class InferenceEngine:
         # SAME placement serving booted with (rollout/hotswap.py).
         self.mesh = mesh
         tp_size = mesh.shape.get("tp", 1) if mesh is not None else 1
+        if not self.paged:
+            if tp_size > 1:
+                raise ValueError(
+                    f"{cfg.name}: llm.mesh tp={tp_size} is not served — a "
+                    f"latent cache has no head axis to shard and the experts' "
+                    f"exchange is not written (parallel/sharding.py, "
+                    f"engine/sharded/)"
+                )
+            if persistent_loop:
+                raise ValueError(
+                    f"{cfg.name}: llm.persistent_loop is not served — the "
+                    f"resident loop (engine/persistent/) runs the paged pool"
+                )
+            if decode_matmul != "dense":
+                raise ValueError(
+                    f"{cfg.name}: llm.decode_matmul {decode_matmul!r} is not "
+                    f"served by models/mla_moe.py"
+                )
         # The tp serving plane (engine/sharded/plane.py): the placement +
         # constraint authority for every device buffer this constructor
         # allocates and every jitted program it builds. None off-mesh —
@@ -647,7 +710,9 @@ class InferenceEngine:
         self._shardings = shardings
         self.kv = PagedKVCache(
             cfg,
-            num_pages=num_pages,
+            # no paged forwards, no pool: the scratch page alone (the host
+            # bookkeeping and page_size stay what callers read)
+            num_pages=num_pages if self.paged else 1,
             page_size=page_size,
             max_slots=max_slots,
             max_pages_per_seq=max_pages_per_seq,
@@ -725,7 +790,9 @@ class InferenceEngine:
         # Prefix prefill needs KV only — skipping the LM head avoids a
         # [bucket, vocab] logits tensor on the admission critical path.
         self._prefill_kv = jax.jit(
-            named_program(forward_prefill_kv, program="prefix_prefill_kv"),
+            named_program(
+                self._model.forward_prefill_kv, program="prefix_prefill_kv"
+            ),
             static_argnums=(1,),
         )
         self._admit = jax.jit(
@@ -793,12 +860,12 @@ class InferenceEngine:
                 ragged_decode=(decode_matmul == "ragged"),
                 shardings=shardings,
             ),
-            static_argnums=(1, 18, 19, 20, 21),
+            static_argnums=(1, 17, 18, 19, 20),
         )
         # Chunked long-prefix prefill reuses the dense cascade directly.
         self._suffix_dense = jax.jit(
             named_program(
-                forward_prefill_suffix_dense, program="suffix_dense",
+                self._model.forward_prefill_suffix_dense, program="suffix_dense",
                 prefix_impl=prefix_attn_impl,
             ),
             static_argnums=(1,),
@@ -944,6 +1011,10 @@ class InferenceEngine:
             # weight swap's HBM/occupancy transient).
             "waves": 0,
             "wave_model_calls": 0,
+            # the model family's device counters, summed inside the wave
+            # program and fetched with its tokens (models/mla_moe.py
+            # COUNTERS: expert load; none for the dense family)
+            **{name: 0 for name in self._model.COUNTERS},
             "wave_prewarms": 0,
             "wave_prewarm_failures": 0,
             "prefix_reused_tokens": 0,
@@ -1038,31 +1109,28 @@ class InferenceEngine:
         self._grammar_wave_iters = wave_iterations(dfa, self.wave_block)
 
     # -------------------------------------------------------------- prefix
-    def _place_prefix(self, k: jax.Array, v: jax.Array) -> tuple[jax.Array, jax.Array]:
-        """Pin a dense prefix KV stack to the tp plane's head-sharded
+    def _place_prefix(self, *arrays: jax.Array) -> tuple[jax.Array, ...]:
+        """Pin a prefix cache tuple to the tp plane's head-sharded
         layout (no-op off-mesh). Every _PrefixKV the engine caches or
         pins goes through here, so pin/evict/truncate/rollback all
         operate on mesh-resident buffers and the jitted programs'
         prefix constraints are placement-true from the first dispatch."""
         if self.plane is None:
-            return k, v
-        return self.plane.place_prefix(k), self.plane.place_prefix(v)
+            return arrays
+        return tuple(self.plane.place_prefix(a) for a in arrays)
+
+    def _prefix_buffers(self, cap: int) -> tuple[jax.Array, ...]:
+        """Zeroed cache tuple for `cap` prefix tokens: one array per member
+        of the model's per-token cache, [L, cap, *token shape]."""
+        return tuple(
+            jnp.zeros((self.cfg.n_layers, cap, *shape), dtype=self.cfg.dtype)
+            for shape in self._model.cache_token_shapes(self.cfg)
+        )
 
     def _get_empty_prefix(self) -> _PrefixKV:
         if self._empty_prefix is None:
-            shape = (
-                self.cfg.n_layers,
-                self.kv.page_size,
-                self.cfg.n_kv_heads,
-                self.cfg.head_dim,
-            )
-            k, v = self._place_prefix(
-                jnp.zeros(shape, dtype=self.cfg.dtype),
-                jnp.zeros(shape, dtype=self.cfg.dtype),
-            )
             self._empty_prefix = _PrefixKV(
-                k=k,
-                v=v,
+                kv=self._place_prefix(*self._prefix_buffers(self.kv.page_size)),
                 length=0,
                 token_ids=(),
             )
@@ -1120,11 +1188,12 @@ class InferenceEngine:
         if n > min(self.prefix_chunk, self.prefill_buckets[-1]):
             seed = self._best_lcp_seed(key)
             with spans.thread_span("dispatch", layer="engine"):
-                k, v = self._prefill_prefix_chunked(prompt_ids, seed=seed)
-                k, v = self._place_prefix(k, v)
+                kv = self._place_prefix(
+                    *self._prefill_prefix_chunked(prompt_ids, seed=seed)
+                )
             if seed is not None:
-                prefilled = n - seed[2]  # reused tokens were not re-prefilled
-            pfx = _PrefixKV(k=k, v=v, length=n, token_ids=key)
+                prefilled = n - seed[1]  # reused tokens were not re-prefilled
+            pfx = _PrefixKV(kv=kv, length=n, token_ids=key)
         else:
             bucket = self._bucket_for(n)
             pad = self.tokenizer.pad_id
@@ -1132,17 +1201,14 @@ class InferenceEngine:
             tokens[0, :n] = prompt_ids
             # blocks while the device's queue is full, as in submit_wave
             with spans.thread_span("dispatch", layer="engine"):
-                _, k_all, v_all = self._prefill_kv(
+                _, *cache = self._prefill_kv(
                     self.params, self.cfg, jnp.asarray(tokens), jnp.asarray([n])
                 )
-                k, v = self._place_prefix(k_all[:, 0], v_all[:, 0])
-            pfx = _PrefixKV(k=k, v=v, length=n, token_ids=key)
+                kv = self._place_prefix(*(a[:, 0] for a in cache))
+            pfx = _PrefixKV(kv=kv, length=n, token_ids=key)
         self._prefix_cache[key] = pfx
 
-        def nbytes(p: _PrefixKV) -> int:
-            return int(p.k.nbytes) + int(p.v.nbytes)
-
-        total = sum(nbytes(p) for p in self._prefix_cache.values())
+        total = sum(p.nbytes for p in self._prefix_cache.values())
         if total > self.PREFIX_CACHE_BYTES and len(self._prefix_cache) > 1:
             # Oldest-first, but PINNED entries are skipped: a pinned
             # snapshot's KV is what every delta-encoded prompt LCP-seeds
@@ -1156,7 +1222,7 @@ class InferenceEngine:
                 if k == key or k in self._pinned_prefix_keys:
                     continue
                 evicted = self._prefix_cache.pop(k)
-                total -= nbytes(evicted)
+                total -= evicted.nbytes
         if activate:
             self._prefix = pfx
         self.stats["prefix_prefills"] += 1
@@ -1216,7 +1282,7 @@ class InferenceEngine:
 
     def export_prefix_kv(
         self, key: tuple[int, ...]
-    ) -> tuple[jax.Array, jax.Array] | None:
+    ) -> tuple[jax.Array, ...] | None:
         """Hand out the cached KV stack for `key` (the shared prefix-KV
         plane exports pinned snapshots through here, fleet/kvplane/).
 
@@ -1227,7 +1293,7 @@ class InferenceEngine:
         pfx = self._prefix_cache.get(tuple(key))
         if pfx is None:
             return None
-        return pfx.k, pfx.v
+        return pfx.kv
 
     def adopt_prefix_pages(
         self,
@@ -1239,8 +1305,9 @@ class InferenceEngine:
         entry — pin_prefix's outcome without paying its prefill (the
         adopt-remote-pages seam of the shared prefix-KV plane).
 
-        The buffers must carry this engine's exact KV geometry
-        ([n_layers, cap >= len(prompt_ids), n_kv_heads, head_dim]);
+        The buffers must carry this engine's exact cache geometry
+        ([n_layers, cap >= len(prompt_ids), *the model's per-token shape]:
+        n_kv_heads, head_dim for the dense family);
         anything else is refused here rather than at decode time. Host
         arrays are placed through _place_prefix, so on a tp mesh the
         adopted pages land head-sharded exactly like a local prefill's.
@@ -1251,26 +1318,26 @@ class InferenceEngine:
             raise ValueError("cannot adopt an empty prefix")
         key = tuple(prompt_ids)
         n = len(key)
-        want = (self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim)
         kshape, vshape = tuple(k.shape), tuple(v.shape)
+        token_shapes = self._model.cache_token_shapes(self.cfg)
         if (
-            len(kshape) != 4
-            or kshape != vshape
-            or (kshape[0], kshape[2], kshape[3]) != want
+            kshape[1:2] != vshape[1:2]
             or kshape[1] < n
+            or any(
+                (shape[0], *shape[2:]) != (self.cfg.n_layers, *want)
+                for shape, want in zip((kshape, vshape), token_shapes)
+            )
         ):
             raise ValueError(
                 f"adopted prefix pages have shape k={kshape} v={vshape}; "
-                f"this engine needs [L={want[0]}, cap>={n}, "
-                f"n_kv={want[1]}, hd={want[2]}]"
+                f"this engine needs [L={self.cfg.n_layers}, cap>={n}, "
+                f"*{token_shapes[0]}] and [.., *{token_shapes[1]}]"
             )
-        k_d, v_d = self._place_prefix(
+        kv = self._place_prefix(
             jnp.asarray(k, dtype=self.cfg.dtype),
             jnp.asarray(v, dtype=self.cfg.dtype),
         )
-        self._prefix_cache[key] = _PrefixKV(
-            k=k_d, v=v_d, length=n, token_ids=key
-        )
+        self._prefix_cache[key] = _PrefixKV(kv=kv, length=n, token_ids=key)
         self._prefix_cache.move_to_end(key)
         if key not in self._pinned_prefix_keys:
             self._pinned_prefix_keys.add(key)
@@ -1284,9 +1351,9 @@ class InferenceEngine:
 
     def _best_lcp_seed(
         self, key: tuple[int, ...]
-    ) -> tuple[jax.Array, jax.Array, int] | None:
+    ) -> tuple[tuple[jax.Array, ...], int] | None:
         """Find the cached prefix sharing the longest common token prefix
-        with `key`.
+        with `key`: (its cache tuple, the reuse length).
 
         Cluster snapshots drift incrementally (a pod count here, a usage
         figure there), and causal attention makes the KV of every token
@@ -1312,13 +1379,13 @@ class InferenceEngine:
                 best_reuse, best = lcp, pfx
         if best is None or best_reuse < threshold:
             return None
-        return best.k, best.v, best_reuse
+        return best.kv, best_reuse
 
     def _prefill_prefix_chunked(
         self,
         prompt_ids: list[int],
-        seed: tuple[jax.Array, jax.Array, int] | None = None,
-    ) -> tuple[jax.Array, jax.Array]:
+        seed: tuple[tuple[jax.Array, ...], int] | None = None,
+    ) -> tuple[jax.Array, ...]:
         """Blockwise prefill for prefixes beyond the largest bucket.
 
         Processes the prompt in largest-bucket chunks; each chunk attends to
@@ -1328,11 +1395,13 @@ class InferenceEngine:
         layer instead of O(prefix^2), which is what makes the 256-node /
         40k-token cluster prompt feasible on one chip.
 
-        `seed` = (k, v, reuse_len) from _best_lcp_seed: the first reuse_len
-        tokens' KV copies from the cached buffer and prefill starts there —
-        incremental prefix caching for drifting cluster snapshots.
+        `seed` = (cache tuple, reuse_len) from _best_lcp_seed: the first
+        reuse_len tokens' cache copies from the cached buffers and prefill
+        starts there — incremental prefix caching for drifting cluster
+        snapshots.
 
-        Returns (k, v) of shape [L, cap, n_kv, hd], cap a chunk multiple.
+        Returns the cache tuple, each [L, cap, *token shape], cap a chunk
+        multiple.
         """
         chunk = min(self.prefix_chunk, self.prefill_buckets[-1])
         n = len(prompt_ids)
@@ -1346,54 +1415,60 @@ class InferenceEngine:
         # _suffix_dense/_wave/_admit/_chunk compile once per length bucket
         # instead of twice (a mid-burst jit-stall class).
         cap = -(-n // chunk) * chunk + chunk
-        done = 0 if seed is None else seed[2]
+        done = 0 if seed is None else seed[1]
         pad = self.tokenizer.pad_id
-        k_buf = jnp.zeros(
-            (self.cfg.n_layers, cap, self.cfg.n_kv_heads, self.cfg.head_dim),
-            dtype=self.cfg.dtype,
-        )
-        v_buf = jnp.zeros_like(k_buf)
+        bufs = self._prefix_buffers(cap)
         if seed is not None:
-            seed_k, seed_v, reuse = seed
+            seed_kv, reuse = seed
             # eager ops: each distinct `reuse` is its own small XLA program,
             # and the scope is how a trace tells them from anything else
             with jax.named_scope("lcp_seed"):
-                k_buf = jax.lax.dynamic_update_slice_in_dim(
-                    k_buf,
-                    jax.lax.slice_in_dim(seed_k, 0, reuse, axis=1).astype(k_buf.dtype),
-                    0, axis=1,
-                )
-                v_buf = jax.lax.dynamic_update_slice_in_dim(
-                    v_buf,
-                    jax.lax.slice_in_dim(seed_v, 0, reuse, axis=1).astype(v_buf.dtype),
-                    0, axis=1,
+                bufs = tuple(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        buf,
+                        jax.lax.slice_in_dim(old, 0, reuse, axis=1).astype(buf.dtype),
+                        0, axis=1,
+                    )
+                    for buf, old in zip(bufs, seed_kv)
                 )
             self.stats["prefix_reused_tokens"] = (
                 self.stats.get("prefix_reused_tokens", 0) + reuse
             )
+        n_cache = len(bufs)
         for start in range(done, n, chunk):
             piece = prompt_ids[start : start + chunk]
             m = len(piece)
             tokens = np.full((1, chunk), pad, dtype=np.int32)
             tokens[0, :m] = piece
-            _, k_c, v_c = self._suffix_dense(
+            out = self._suffix_dense(
                 self.params, self.cfg,
                 jnp.asarray(tokens), jnp.asarray([m], dtype=np.int32),
-                k_buf, v_buf, jnp.int32(done),
+                *bufs, jnp.int32(done),
             )
-            # k_c: [L, 1, chunk, n_kv, hd] -> append at `start`
-            k_buf = jax.lax.dynamic_update_slice_in_dim(
-                k_buf, k_c[:, 0].astype(k_buf.dtype), start, axis=1
-            )
-            v_buf = jax.lax.dynamic_update_slice_in_dim(
-                v_buf, v_c[:, 0].astype(v_buf.dtype), start, axis=1
+            # each [L, 1, chunk, *token shape] -> append at `start`
+            bufs = tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    buf, new[:, 0].astype(buf.dtype), start, axis=1
+                )
+                for buf, new in zip(bufs, out[1 : 1 + n_cache])
             )
             done += m
-        return k_buf, v_buf
+        return bufs
 
     @property
     def prefix_len(self) -> int:
         return self._prefix.length if self._prefix else 0
+
+    def _require_paged(self, path: str) -> None:
+        """Refuse, before anything is traced, an entry point that runs the
+        paged pool for a model family that has no paged forwards."""
+        if not self.paged:
+            raise ValueError(
+                f"{self.cfg.name}: {path} is not served — it runs the paged "
+                f"KV pool, and models/mla_moe.py brings the decision wave's "
+                f"forwards only (a latent cache in PagedKVCache / KVGeometry "
+                f"is not written)"
+            )
 
     # ------------------------------------------------------------ requests
     def _bucket_for(self, n: int) -> int:
@@ -1454,6 +1529,7 @@ class InferenceEngine:
         (set_prefix), else the whole prompt. All prompts pad to one shared
         bucket. Decoding starts at the next `step()` call.
         """
+        self._require_paged("add_requests() (chunked continuous batching)")
         if not prompts:
             return []
         if any(not p for p in prompts):
@@ -1587,6 +1663,7 @@ class InferenceEngine:
         the block-diagonal mask computes exactly the serial attention
         (test-pinned, tests/test_admission.py).
         """
+        self._require_paged("admit_packed() (packed admission)")
         if not prompts:
             return []
         if any(not p for p in prompts):
@@ -1883,7 +1960,7 @@ class InferenceEngine:
                 self._wave(
                     self.params, self.cfg,
                     jnp.asarray(tokens), jnp.asarray(suffix_lens),
-                    prefix.k, prefix.v, jnp.int32(prefix.length),
+                    prefix.kv, jnp.int32(prefix.length),
                     jnp.asarray(max_new_vec),
                     self._sp_tokens, self._sp_next, self._forced,
                     self._forced_next, self._done_state,
@@ -1978,10 +2055,10 @@ class InferenceEngine:
         # that the rest of submit_wave reads as the host's own work.
         with spans.thread_span("dispatch", layer="engine", wave=seq):
             self._rng, sub = jax.random.split(self._rng)
-            toks_d, _, iters_d = self._wave(
+            toks_d, _, iters_d, *counts_d = self._wave(
                 self.params, self.cfg,
                 jnp.asarray(tokens), jnp.asarray(suffix_lens),
-                prefix.k, prefix.v, jnp.int32(prefix.length),
+                prefix.kv, jnp.int32(prefix.length),
                 jnp.asarray(max_new),
                 self._sp_tokens, self._sp_next, self._forced, self._forced_next,
                 self._done_state,
@@ -1998,8 +2075,8 @@ class InferenceEngine:
         # Start the D2H transfer right behind the program so harvest finds
         # the results already on host instead of starting the copy then.
         try:
-            toks_d.copy_to_host_async()
-            iters_d.copy_to_host_async()
+            for arr in (toks_d, iters_d, *counts_d):
+                arr.copy_to_host_async()
         except AttributeError:  # pragma: no cover - backend without D2H async
             pass
         req_ids = list(range(self._req_counter, self._req_counter + len(prompts)))
@@ -2012,6 +2089,7 @@ class InferenceEngine:
         handle = WaveHandle(
             toks_d=toks_d,
             iters_d=iters_d,
+            counts_d=counts_d[0] if counts_d else None,
             n=len(prompts),
             max_new_tokens=max_new_tokens,
             req_ids=req_ids,
@@ -2047,10 +2125,19 @@ class InferenceEngine:
         # ONE device_get for both results: each fetch is its own blocking
         # round trip, and the wave sync is the per-decision critical path.
         with spans.thread_span("harvest_wait", layer="engine", wave=handle.seq):
-            toks_np, iters_np = jax.device_get((handle.toks_d, handle.iters_d))
+            toks_np, iters_np, counts_np = jax.device_get(
+                (handle.toks_d, handle.iters_d, handle.counts_d)
+            )
         handle.model_calls = int(iters_np)
+        # the family's device counters ride the same fetch: no extra sync
+        counts = (
+            {} if counts_np is None
+            else dict(zip(self._model.COUNTERS, map(int, counts_np)))
+        )
+        for name, value in counts.items():
+            self.stats[name] += value
         if ann is not None:
-            ann.set_metadata(model_calls=handle.model_calls)
+            ann.set_metadata(model_calls=handle.model_calls, **counts)
         if prof is not None:
             # the block_until_ready boundary just closed
             t_sync = time.perf_counter()
@@ -2098,6 +2185,7 @@ class InferenceEngine:
     def step(self, chunks: int = 1) -> list[Finished]:
         """Run `chunks` fused decode chunks back-to-back (no intermediate
         sync), then ONE host sync; returns requests that finished."""
+        self._require_paged("step() (chunked decode)")
         if self.persistent_active:
             self.exit_persistent()
         pend = self._pending_finished
@@ -2329,6 +2417,7 @@ class InferenceEngine:
         token accounting stays exact — the span and stats book tokens
         actually emitted, never chunk capacity. Falls back to step() when
         the fused runtime can't serve (_fused_ready)."""
+        self._require_paged("step_fused() (the fused decode loop)")
         if self.persistent_active:
             self.exit_persistent()
         pend = self._pending_finished
@@ -2400,6 +2489,7 @@ class InferenceEngine:
         later chunks' device execution. The device-side budget guarantees
         completion within the dispatched chunks. Falls back to a step()
         drain when the fused runtime can't serve."""
+        self._require_paged("decode_fused() (the fused decode loop)")
         if self.persistent_active:
             self.exit_persistent()
         pend = self._pending_finished
@@ -2507,6 +2597,7 @@ class InferenceEngine:
         engine's buffers. ONE dispatch; every subsequent admission/decode/
         emission until exit_persistent is ring traffic. Returns False when
         unsupported (caller stays on the dispatch path)."""
+        self._require_paged("enter_persistent() (the resident loop)")
         if self.persistent_active:
             return True
         if not self.persistent_supported():
@@ -2969,6 +3060,7 @@ class InferenceEngine:
         install. A resident persistent loop drains first: spec streams
         drive slots through their own dispatches, which cannot run beside
         the loop (persistent_supported gates on spec is None)."""
+        self._require_paged("attach_spec() (speculative decoding, spec/)")
         if decoder is not None and self.persistent_active:
             self.exit_persistent()
         self.spec = decoder
@@ -2992,6 +3084,7 @@ class InferenceEngine:
         (attach_spec) and the request fits it; True/False force the path
         (bench A/Bs pass False for the plain arm on a spec-enabled
         engine)."""
+        self._require_paged("generate() over the paged pool")
         if use_spec is None:
             use_spec = self.spec is not None
         if (

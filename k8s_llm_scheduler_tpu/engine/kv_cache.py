@@ -74,7 +74,12 @@ class PagedKVCache:
         self.max_slots = int(max_slots)
         self.max_pages_per_seq = int(max_pages_per_seq)
         dtype = dtype or cfg.dtype
-        shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        from k8s_llm_scheduler_tpu.models import family
+
+        # a page holds the model's per-token cache: (k, v) [n_kv, hd] for
+        # the dense family (a model without paged forwards is given one
+        # scratch page by the engine)
+        k_tok, v_tok = family(cfg).cache_token_shapes(cfg)
         # On a tp mesh the pages are BORN head-sharded (parallel/sharding.
         # kv_cache_spec via engine/sharded): each chip holds n_kv/tp heads
         # of every page, so KV capacity scales with the group instead of
@@ -86,8 +91,9 @@ class PagedKVCache:
         # jnp.zeros would first build the WHOLE pool on device 0 (8.6 GB of
         # k+v at 8B next to that chip's 4 GB of weights).
         self.sharding = sharding
-        self.k = jnp.zeros(shape, dtype=dtype, device=sharding)
-        self.v = jnp.zeros(shape, dtype=dtype, device=sharding)
+        pool = (cfg.n_layers, num_pages, page_size)
+        self.k = jnp.zeros(pool + k_tok, dtype=dtype, device=sharding)
+        self.v = jnp.zeros(pool + v_tok, dtype=dtype, device=sharding)
         # Host-side state. Page 0 is scratch — never allocated.
         self._free = list(range(num_pages - 1, 0, -1))
         self._refcount = np.zeros(num_pages, dtype=np.int32)

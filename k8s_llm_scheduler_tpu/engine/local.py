@@ -54,8 +54,8 @@ from k8s_llm_scheduler_tpu.engine.backend import BackendError, NoFeasibleNodeErr
 from k8s_llm_scheduler_tpu.engine.constrained import build_decision_dfa
 from k8s_llm_scheduler_tpu.engine.engine import InferenceEngine
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
-from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, get_config
-from k8s_llm_scheduler_tpu.models.llama import init_params
+from k8s_llm_scheduler_tpu.models import family
+from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, MlaMoeConfig, get_config
 from k8s_llm_scheduler_tpu.parallel.mesh import mesh_from_config
 from k8s_llm_scheduler_tpu.parallel.sharding import (
     named_shardings,
@@ -167,8 +167,12 @@ class LocalLLMBackend:
         # admit via packed chunked prefill when the engine supports it;
         # delta_prompts renders cluster prefixes as pinned snapshot +
         # drift diff (sched/delta.py) so prefill scales with what changed.
-        self._packed_admission = bool(packed_admission) and hasattr(
-            engine, "admit_packed"
+        # (an engine whose model has no paged forwards keeps the batch
+        # surface on waves, as a lone marked straggler already rides one)
+        self._packed_admission = (
+            bool(packed_admission)
+            and hasattr(engine, "admit_packed")
+            and getattr(engine, "paged", True)
         )
         # Persistent device-resident serving (engine/persistent/): when
         # on, the worker FEEDS THE LOOP'S RINGS instead of submitting
@@ -248,6 +252,11 @@ class LocalLLMBackend:
         self._queue: queue.Queue[_WorkItem | None] = queue.Queue()
         self._dfa_cache: dict[tuple[str, ...], Any] = {}
         self._current_group: tuple | None = None
+        # group key -> `enqueued_at` of the first item that carried it: the
+        # order in which snapshots first reached this backend, which is all
+        # a backend knows of their age (_submit_waves moves a tail only
+        # into a snapshot that reached it LATER)
+        self._group_first_seen: dict[tuple, float] = {}
         # Control items (run_quiesced) parked until the wave barrier; while
         # any is held, _submit_waves admits nothing (swap quiesce).
         self._held_controls: list[_ControlItem] = []
@@ -745,7 +754,12 @@ class LocalLLMBackend:
 
         current: list[_WorkItem] = []
         others: list[_WorkItem] = []
+        seen = self._group_first_seen
         for item in pending:
+            if item.group_key not in seen:
+                while len(seen) >= 64:  # insertion order: the oldest goes
+                    del seen[next(iter(seen))]
+                seen[item.group_key] = item.enqueued_at
             if len(item.suffix_ids) > self.engine.prefill_buckets[-1]:
                 # Oversized suffix can never admit (waves are bounded only by
                 # the largest prefill bucket — they never touch the paged
@@ -766,11 +780,37 @@ class LocalLLMBackend:
         # happens below, so the current group's tail must not hold for it
         oldest = min(others, key=lambda i: i.enqueued_at, default=None)
         waited = time.perf_counter() - oldest.enqueued_at if others else 0.0
-        run_group(
-            current,
-            leaving=bool(others) and not packs and not self._pers_items
-            and waited >= self.group_switch_after_s,
+        leaving = (
+            bool(others) and not packs and not self._pers_items
+            and waited >= self.group_switch_after_s
         )
+        # The ragged tail the engine would leave behind goes WITH it where
+        # the group it switches to is another snapshot of the same cluster
+        # (the same ready nodes, so the same grammar and the same valid
+        # answers: only the metrics in the prefix differ, and the pod's own
+        # part of the prompt is the suffix, which no snapshot touches). A
+        # snapshot that changed between two pods of one burst otherwise
+        # costs a wave: k rows ship ragged here and the other 8 - k lead
+        # the new group ragged (a wave's eight binds release eight pods
+        # that reach the scheduler's snapshot over some tens of
+        # milliseconds, and its TTL runs out among them about one time in
+        # three; measured on the v5e, PERF.md §6 PR 30: 13-23 extra waves
+        # of ~320 a window, by luck). Pods of ANOTHER cluster (other ready
+        # nodes) are never moved, and a tail moves only FORWARD: into a
+        # snapshot that first reached this backend after its own did, so a
+        # pod is never decided on older metrics than it was encoded under
+        # (a straggler of an earlier snapshot takes no tail with it).
+        adopted: list[_WorkItem] = []
+        ragged = len(current) % self.engine.max_slots
+        if (
+            leaving and ragged
+            and oldest.group_key[1] is not None  # a grammar names the nodes
+            and oldest.group_key[1] == current[-1].group_key[1]
+            and seen[oldest.group_key] > seen[current[-1].group_key]
+            and all(i.pack is None for i in current)
+        ):
+            current, adopted = current[:-ragged], current[-ragged:]
+        run_group(current, leaving=leaving)
         if not others:
             return rest
 
@@ -789,6 +829,10 @@ class LocalLLMBackend:
         target = oldest.group_key
         switch_items = [i for i in others if i.group_key == target]
         rest.extend(i for i in others if i.group_key != target)
+        for item in adopted:  # older than every row of the group they join
+            item.prefix_ids, item.group_key = oldest.prefix_ids, target
+            item.pin_spec = oldest.pin_spec
+        switch_items = adopted + switch_items
         # Invalidate first — a partial switch (prefix installed, grammar
         # failed) must not leave old-group items matching a half-switched
         # engine.
@@ -1496,7 +1540,7 @@ def _pin_quantized(params, cfg, mesh):
     )
 
 
-def _init_params(rng_seed: int, cfg: LlamaConfig, mesh=None):
+def _init_params(rng_seed: int, cfg: LlamaConfig | MlaMoeConfig, mesh=None):
     """Random-init the bf16 tree in ONE jitted program, for every layout.
     With a mesh the outputs are born on it (param_specs match the
     unquantized tree): each device draws only its own 1/N of every weight
@@ -1507,8 +1551,33 @@ def _init_params(rng_seed: int, cfg: LlamaConfig, mesh=None):
     compared across layouts."""
     shardings = None if mesh is None else named_shardings(mesh, param_specs(cfg))
     return jax.jit(
-        functools.partial(init_params, cfg=cfg), out_shardings=shardings
+        functools.partial(family(cfg).init_params, cfg=cfg),
+        out_shardings=shardings,
     )(jax.random.PRNGKey(rng_seed))
+
+
+def _refuse_unserved(cfg, *, multi, quantize, checkpoint_path, spec_enabled) -> None:
+    """What models/mla_moe.py does not bring refuses HERE, at build time,
+    naming the model and the path — never inside a trace: what would
+    otherwise fail before the engine exists. (InferenceEngine refuses the
+    resident loop and a tp mesh in its constructor, and the paged entry
+    points at the call: _require_paged.)"""
+    if not isinstance(cfg, MlaMoeConfig):
+        return
+    asked = {
+        "llm.mesh with tp > 1 (a latent cache has no head axis to shard; "
+        "experts over chips and their exchange: parallel/sharding.py, "
+        "engine/sharded/)": multi,
+        f"llm.quantization {quantize!r} (int8 expert weights: "
+        f"models/quant.py)": quantize is not None,
+        "llm.checkpoint_path (this family's checkpoint names: "
+        "models/loader.py)": bool(checkpoint_path),
+        "llm.spec_enabled (speculative decoding, spec/, runs the paged "
+        "pool; decision waves never speculate)": spec_enabled,
+    }
+    for path, on in asked.items():
+        if on:
+            raise ValueError(f"{cfg.name}: {path} is not served")
 
 
 def _require_named_cpu() -> None:
@@ -1634,6 +1703,10 @@ def build_local_backend(
             f"concept; the engine's continuous batching already fills the "
             f"chip with one replica)"
         )
+    _refuse_unserved(
+        cfg, multi=multi, quantize=quantize, checkpoint_path=checkpoint_path,
+        spec_enabled=spec_enabled,
+    )
     if multi:
         validate_specs_divisibility(cfg, mesh)
     if quantize is not None and quantize != "int8":

@@ -1,4 +1,6 @@
-"""Decision-model families in functional JAX (Llama 3.x dense)."""
+"""Decision-model families in functional JAX: Llama 3.x dense
+(models/llama.py) and latent attention with sparse experts
+(models/mla_moe.py)."""
 
 from k8s_llm_scheduler_tpu.models.configs import (  # noqa: F401
     LLAMA_3_1_8B,
@@ -6,5 +8,22 @@ from k8s_llm_scheduler_tpu.models.configs import (  # noqa: F401
     LLAMA_3_3_70B,
     TINY,
     LlamaConfig,
+    MlaMoeConfig,
     get_config,
 )
+
+
+def family(cfg):
+    """The model module a config's TYPE selects. Each has `init_params`,
+    `cache_token_shapes(cfg)` (the per-token trailing shapes of its cache
+    tuple: (k, v) here, (c_kv, k_r) there), `COUNTERS` (what its wave
+    forwards count on the device, may be empty) and the three forwards of
+    the decision path: `forward_prefill_kv`, `forward_prefill_suffix_dense`,
+    `forward_block_decode`."""
+    if isinstance(cfg, MlaMoeConfig):
+        from k8s_llm_scheduler_tpu.models import mla_moe
+
+        return mla_moe
+    from k8s_llm_scheduler_tpu.models import llama
+
+    return llama

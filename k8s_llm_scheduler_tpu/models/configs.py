@@ -1,4 +1,5 @@
-"""Llama model-family configurations.
+"""Model-family configurations: the Llama dense family and the latent-
+attention sparse-expert family (`MlaMoeConfig`, models/mla_moe.py).
 
 The reference consumes Llama-3.3-70B-Instruct behind the HuggingFace API
 (reference scheduler.py:425, config.yaml:8); the BASELINE ladder also names
@@ -55,6 +56,141 @@ class LlamaConfig:
     def __post_init__(self) -> None:
         assert self.d_model % self.n_heads == 0
         assert self.n_heads % self.n_kv_heads == 0
+
+    def matmul_flops_per_token(self) -> float:
+        """Dense matmul FLOPs of one token's forward pass (2 a multiply-add):
+        the books observability/profiler.py keeps its MFU against."""
+        d, hd = self.d_model, self.head_dim
+        attn_proj = (
+            d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
+            + self.n_heads * hd * d
+        )
+        return 2.0 * (
+            self.n_layers * (attn_proj + 3 * d * self.d_ff)
+            + d * self.vocab_size
+        )
+
+    def attn_flops_per_key(self) -> float:
+        """Score + value FLOPs of one token against one key, all layers."""
+        return 4.0 * self.n_layers * self.n_heads * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeConfig:
+    """Latent attention (MLA) over one leading dense stack and a stack of
+    sigmoid-routed sparse-expert layers with a shared expert: the
+    `glm4_moe_lite` / DeepSeek-V3 layer (models/mla_moe.py writes the
+    equations out). Published key names are mapped once, in `from_hf`.
+
+    `expert_first` / `expert_count` are the range of routed experts held
+    HERE: the router always scores all `n_routed_experts`, the layer
+    computes its own experts' part (an expert-parallel share; the whole
+    range on one chip)."""
+
+    name: str
+    vocab_size: int
+    d_model: int
+    n_dense_layers: int      # leading layers with a dense SwiGLU (first_k_dense_replace)
+    n_moe_layers: int
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    d_ff: int                # dense layers' SwiGLU width (intermediate_size)
+    d_ff_expert: int         # one expert's width (moe_intermediate_size)
+    n_routed_experts: int
+    n_shared_experts: int
+    n_experts_per_tok: int
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    expert_first: int = 0
+    expert_count: int | None = None  # None: every routed expert
+    max_seq_len: int = 8192
+    rope_theta: float = 1000000.0
+    rms_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+    tie_embeddings: bool = False
+
+    def __post_init__(self) -> None:
+        if self.tie_embeddings:
+            raise ValueError(f"{self.name}: MlaMoeConfig serves an untied output head only")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError(f"{self.name}: qk_rope_head_dim must be even")
+        if not 0 < self.n_experts_per_tok <= self.n_routed_experts:
+            raise ValueError(f"{self.name}: n_experts_per_tok outside 1..n_routed_experts")
+        if self.expert_first < 0 or self.expert_first + self.experts_held > self.n_routed_experts:
+            raise ValueError(f"{self.name}: held expert range outside the routed experts")
+
+    @property
+    def n_layers(self) -> int:
+        return self.n_dense_layers + self.n_moe_layers
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_routed_experts if self.expert_count is None else self.expert_count
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @classmethod
+    def from_hf(cls, name: str, conf: dict, **overrides) -> "MlaMoeConfig":
+        """From the published `config.json` keys (glm4_moe_lite)."""
+        if conf.get("n_group", 1) != 1 or conf.get("topk_group", 1) != 1:
+            raise ValueError(f"{name}: group-limited routing (n_group/topk_group > 1) is not served")
+        if conf.get("topk_method", "noaux_tc") != "noaux_tc":
+            raise ValueError(f"{name}: only topk_method noaux_tc (sigmoid scores + selection bias)")
+        kw = dict(
+            name=name, vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+            n_dense_layers=conf["first_k_dense_replace"],
+            n_moe_layers=conf["num_hidden_layers"] - conf["first_k_dense_replace"],
+            n_heads=conf["num_attention_heads"], q_lora_rank=conf["q_lora_rank"],
+            kv_lora_rank=conf["kv_lora_rank"], qk_nope_head_dim=conf["qk_nope_head_dim"],
+            qk_rope_head_dim=conf["qk_rope_head_dim"], v_head_dim=conf["v_head_dim"],
+            d_ff=conf["intermediate_size"], d_ff_expert=conf["moe_intermediate_size"],
+            n_routed_experts=conf["n_routed_experts"], n_shared_experts=conf["n_shared_experts"],
+            n_experts_per_tok=conf["num_experts_per_tok"],
+            routed_scaling_factor=conf["routed_scaling_factor"],
+            norm_topk_prob=conf["norm_topk_prob"],
+            max_seq_len=conf["max_position_embeddings"], rope_theta=float(conf["rope_theta"]),
+            rms_eps=conf["rms_norm_eps"], tie_embeddings=conf["tie_word_embeddings"],
+        )
+        kw.update(overrides)
+        return cls(**kw)
+
+    def attn_params(self) -> int:
+        """Matrix parameters of one layer's attention (norms left out)."""
+        d, h = self.d_model, self.n_heads
+        return (
+            d * self.q_lora_rank + self.q_lora_rank * h * self.qk_head_dim
+            + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+            + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
+            + h * self.v_head_dim * d
+        )
+
+    def matmul_flops_per_token(self) -> float:
+        """ACTIVE matmul FLOPs of one token: in an expert layer the router,
+        the experts a token is sent to and the shared experts."""
+        d = self.d_model
+        expert = 3 * d * self.d_ff_expert
+        moe = (d * self.n_routed_experts
+               + (self.n_experts_per_tok + self.n_shared_experts) * expert)
+        return 2.0 * (
+            self.n_layers * self.attn_params()
+            + self.n_dense_layers * 3 * d * self.d_ff
+            + self.n_moe_layers * moe
+            + d * self.vocab_size
+        )
+
+    def attn_flops_per_key(self) -> float:
+        """Absorbed form, as every forward runs it: a head's
+        query scores the latent (kv_lora_rank + rope) and sums it
+        (kv_lora_rank)."""
+        return 2.0 * self.n_layers * self.n_heads * (
+            2 * self.kv_lora_rank + self.qk_rope_head_dim
+        )
 
 
 TINY = LlamaConfig(
@@ -126,12 +262,38 @@ LLAMA_3_3_70B = LlamaConfig(
     rope_scaling=RopeScaling(factor=8.0),
 )
 
+# Toy of the latent-attention sparse-expert family for the CPU tests: every
+# mechanism present (a leading dense layer, two expert layers, a shared
+# expert, a selection bias), every width shrunk.
+TINY_MLA_MOE = MlaMoeConfig(
+    name="tiny-mla-moe",
+    vocab_size=512,
+    d_model=64,
+    n_dense_layers=1,
+    n_moe_layers=2,
+    n_heads=4,
+    q_lora_rank=32,
+    kv_lora_rank=32,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    d_ff=128,
+    d_ff_expert=32,
+    n_routed_experts=8,
+    n_shared_experts=1,
+    n_experts_per_tok=2,
+    routed_scaling_factor=1.8,
+    max_seq_len=2048,
+    rope_theta=10000.0,
+)
+
 _REGISTRY = {
-    c.name: c for c in (TINY, SMALL, LLAMA_3_2_1B, LLAMA_3_1_8B, LLAMA_3_3_70B)
+    c.name: c
+    for c in (TINY, SMALL, LLAMA_3_2_1B, LLAMA_3_1_8B, LLAMA_3_3_70B, TINY_MLA_MOE)
 }
 
 
-def get_config(name: str) -> LlamaConfig:
+def get_config(name: str) -> LlamaConfig | MlaMoeConfig:
     key = name.lower()
     if key not in _REGISTRY:
         raise KeyError(f"unknown model config {name!r}; known: {sorted(_REGISTRY)}")

@@ -38,6 +38,15 @@ from k8s_llm_scheduler_tpu.ops.attention import (
 
 Params = dict[str, Any]
 
+# What the wave forwards count on the device for engine.stats: nothing here
+# (models/mla_moe.py counts its expert load).
+COUNTERS: tuple[str, ...] = ()
+
+
+def cache_token_shapes(cfg: LlamaConfig) -> tuple[tuple[int, ...], ...]:
+    """Per-token trailing shapes of the cache tuple: (k, v)."""
+    return ((cfg.n_kv_heads, cfg.head_dim),) * 2
+
 
 # --------------------------------------------------------------------- norm
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:

@@ -169,22 +169,18 @@ PEAK_BF16_TFLOPS = {
 
 
 def matmul_flops_per_token(cfg) -> float:
-    """Dense matmul FLOPs for one token's forward pass (2*MACs).
-    Formerly bench.py's accounting — moved here so the profiler's MFU
-    decomposition and the bench headline share one set of books."""
-    d, hd = cfg.d_model, cfg.head_dim
-    attn_proj = (
-        d * cfg.n_heads * hd + 2 * d * cfg.n_kv_heads * hd
-        + cfg.n_heads * hd * d
-    )
-    mlp = 3 * d * cfg.d_ff
-    lm_head = d * cfg.vocab_size
-    return 2.0 * (cfg.n_layers * (attn_proj + mlp) + lm_head)
+    """Matmul FLOPs for one token's forward pass (2*MACs), as the model's
+    config counts them (models/configs.py: dense weights for the Llama
+    family, ACTIVE parameters — router, selected and shared experts — for
+    an expert layer). Formerly bench.py's accounting — here so the
+    profiler's MFU decomposition and the bench headline share one set of
+    books."""
+    return cfg.matmul_flops_per_token()
 
 
 def attn_flops_per_token(cfg, ctx: float) -> float:
     """Attention score+value FLOPs for one token attending to `ctx` keys."""
-    return 4.0 * cfg.n_layers * cfg.n_heads * cfg.head_dim * ctx
+    return cfg.attn_flops_per_key() * ctx
 
 
 def detect_peak_tflops(override: float | None = None) -> tuple[float | None, str]:

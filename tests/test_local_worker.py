@@ -365,6 +365,78 @@ class TestTailHoldPolicy:
             backend.close()
 
 
+    def test_tail_goes_with_the_engine_to_another_snapshot_of_its_cluster(self):
+        """The same ready nodes under other metrics (the next snapshot of
+        one cluster): the tail the engine would leave behind ragged joins
+        the group it switches to, at its head, and ships with it. Another
+        cluster's group (other nodes) never takes it: the test above."""
+        import dataclasses
+        from collections import deque
+
+        eng = FakeEngine()
+        backend = LocalLLMBackend(
+            eng, tokenizer=ByteTokenizer(), group_switch_after_s=0.25,
+        )
+        try:
+            nodes = make_nodes(3)
+            drifted = [dataclasses.replace(n, cpu_usage_percent=n.cpu_usage_percent + 7.0)
+                       for n in nodes]
+            width = eng.max_slots
+            full = self._items(backend, width, nodes, age_s=5.0)
+            tail = self._items(backend, 3, nodes, first=20, age_s=5.0)
+            other = self._items(backend, width - 3, drifted, first=10, age_s=0.3)
+            assert other[0].group_key != tail[0].group_key
+            assert other[0].group_key[1] == tail[0].group_key[1]  # the same ready nodes
+            suffixes = [list(i.suffix_ids) for i in tail]
+            backend._current_group = tail[0].group_key
+            waves = deque((object(), []) for _ in range(3))
+            rest = backend._submit_waves(full + tail + other, waves, [])
+            # the old group's full wave shipped; its three stragglers and the
+            # new group's rows make one full wave under the new prefix
+            assert [n for _, n in eng.submits] == [width, width] and rest == []
+            assert waves[-1][1] == tail + other
+            assert {i.group_key for i in tail} == {other[0].group_key}
+            assert all(i.prefix_ids == other[0].prefix_ids for i in tail)
+            assert [list(i.suffix_ids) for i in tail] == suffixes  # the pod's part is untouched
+            assert backend._current_group == other[0].group_key
+        finally:
+            backend.close()
+
+    def test_tail_never_goes_back_to_an_earlier_snapshot(self):
+        """A straggler of a snapshot that reached the backend BEFORE the
+        current one (its first pod was seen earlier) takes no tail with it:
+        a pod is never decided on older metrics than it was encoded under."""
+        import dataclasses
+        from collections import deque
+
+        eng = FakeEngine()
+        backend = LocalLLMBackend(
+            eng, tokenizer=ByteTokenizer(), group_switch_after_s=0.25,
+        )
+        try:
+            nodes = make_nodes(3)
+            drifted = [dataclasses.replace(n, cpu_usage_percent=n.cpu_usage_percent + 7.0)
+                       for n in nodes]
+            width = eng.max_slots
+            # the drifted snapshot is the EARLIER one: its first pod came first
+            early = self._items(backend, 1, drifted, first=30, age_s=9.0)
+            backend._submit_waves(early, deque(), [])
+            full = self._items(backend, width, nodes, age_s=5.0)
+            tail = self._items(backend, 3, nodes, first=20, age_s=5.0)
+            straggler = self._items(backend, 1, drifted, first=10, age_s=0.3)
+            assert straggler[0].group_key == early[0].group_key
+            assert straggler[0].group_key[1] == tail[0].group_key[1]  # the same ready nodes
+            key = tail[0].group_key
+            backend._current_group = key
+            eng.submits.clear()
+            waves = deque((object(), []) for _ in range(3))
+            backend._submit_waves(full + tail + straggler, waves, [])
+            assert {i.group_key for i in tail} == {key}  # stayed with their snapshot
+            assert [n for _, n in eng.submits][:2] == [width, 3]  # and shipped ragged under it
+        finally:
+            backend.close()
+
+
 class TestPoolRoleAndBatch:
     def test_decode_role_refuses_admission(self):
         from k8s_llm_scheduler_tpu.engine.backend import BackendError
