@@ -407,10 +407,11 @@ def _wave_impl(
     emitted = jnp.zeros(R, dtype=jnp.int32)
     pos_next = prefix_len + suffix_lens  # absolute position of next token
 
-    # generated-token cache, one buffer per array of the cache tuple; slot
-    # `cap` is the trash slot invalid block positions write to
+    # generated-token cache, one buffer per array of the cache tuple; F slots
+    # beyond `cap` so that a block's whole window fits behind any tail
+    # (ops/attention.write_block)
     gen = tuple(
-        jnp.zeros((cfg.n_layers, R, cap + 1, *shape), prefix_cache[0].dtype)
+        jnp.zeros((cfg.n_layers, R, cap + F, *shape), prefix_cache[0].dtype)
         for shape in model.cache_token_shapes(cfg)
     )
     if shardings is not None:

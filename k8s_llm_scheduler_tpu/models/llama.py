@@ -34,6 +34,7 @@ from k8s_llm_scheduler_tpu.ops.attention import (
     merge_attention_parts,
     paged_decode_attention,
     prefix_attend_parts,
+    write_block,
 )
 
 Params = dict[str, Any]
@@ -560,7 +561,7 @@ def forward_block_decode(
     k_sfx: jax.Array,  # [L, R, Ss, n_kv, hd] dense suffix KV
     v_sfx: jax.Array,
     suffix_lens: jax.Array,  # [R]
-    gen_k: jax.Array,  # [L, R, cap+1, n_kv, hd] generated-token KV (donated)
+    gen_k: jax.Array,  # [L, R, cap+F, n_kv, hd] generated-token KV (donated)
     gen_v: jax.Array,
     tail: jax.Array,  # [R] tokens already in gen_k/gen_v
     prefix_k_all: jax.Array,  # [L, Sp, n_kv, hd] shared dense prefix
@@ -576,8 +577,11 @@ def forward_block_decode(
     token run: every valid block token attends to the shared dense prefix,
     its row's dense suffix, the generated-so-far buffer, and causally within
     the block, all in one pass — so a forced JSON-skeleton span costs one
-    model call instead of one per character. Invalid block slots write their
-    K/V to the buffer's trash slot (index cap).
+    model call instead of one per character. The layers read the generated
+    buffers and hand back the block's K/V, which is written once they have
+    all run (ops/attention.write_block: row r's F-wide window at tail[r];
+    the padded positions' values land past the new tail, where nothing
+    reads them).
 
     `ragged=True` removes the F-width padding from every projection/MLP
     matmul (SCALING.md wave roofline: 62% of decode compute at the
@@ -589,15 +593,14 @@ def forward_block_decode(
     bookkeeping stay in the [R, F] layout (they are the small term and
     are row-structured); q/k/v scatter back through the inverse
     permutation. Dead compacted rows carry garbage — every consumer
-    masks by blk_valid / trash-slot dest, exactly as the dense path
-    already requires.
+    masks by blk_valid (the generated buffers by tail), exactly as the
+    dense path already requires.
 
     Returns (logits [R, V] f32 at each row's LAST VALID block position,
     gen_k, gen_v).
     """
     R, F = blk_tok.shape
     hd = cfg.head_dim
-    cap1 = gen_k.shape[2]  # cap + 1 (trash slot at index cap)
     inv_freq = rope_inv_freq(cfg)
 
     x = _embed(params, blk_tok)  # [R, F, D]
@@ -621,22 +624,29 @@ def forward_block_decode(
     sfx_mask = (jnp.arange(Ss)[None, :] < suffix_lens[:, None])[
         :, None, None, None, :
     ]
-    gen_mask = (jnp.arange(cap1)[None, :] < tail[:, None])[:, None, None, None, :]
+    gen_mask = (jnp.arange(gen_k.shape[2])[None, :] < tail[:, None])[
+        :, None, None, None, :
+    ]
     j = jnp.arange(F)
     blk_mask = (
         (j[:, None] >= j[None, :])[None, :, :] & blk_valid[:, None, :]
     )[:, None, None, :, :]  # [R, 1, 1, F_q, F_kv]
+    # The generated buffers are read like the suffix KV, a layer's slab a
+    # step; the layers' block K/V comes back stacked [L, R, F, n_kv, hd].
+    xs = (
+        params["layers"], prefix_k_all, prefix_v_all,
+        k_sfx, v_sfx, gen_k, gen_v,
+    )
 
-    # K/V scatter destinations: valid token j -> tail + j, invalid -> trash.
-    dest = jnp.where(blk_valid, tail[:, None] + j[None, :], cap1 - 1)  # [R, F]
-    row = jnp.arange(R)[:, None]
+    @jax.named_scope("kv_writeback")
+    def written(k_blk, v_blk):
+        return write_block(gen_k, tail, k_blk), write_block(gen_v, tail, v_blk)
 
     if ragged:
         xc = x.reshape(R * F, -1)[perm]  # valid tokens first
 
-        def body_ragged(carry, xs):
-            xc, gk, gv = carry
-            lp, pk, pv, ks, vs, idx = xs
+        def body_ragged(xc, xs):
+            lp, pk, pv, ks, vs, gk, gv = xs
             with jax.named_scope("attn"):
                 h = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
                 q = _rdense(h, lp["wq"])[inv_perm].reshape(
@@ -656,7 +666,7 @@ def forward_block_decode(
                 parts = [
                     prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
                     attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
-                    attend_part(qg, gk[idx], gv[idx], gen_mask, "bqkgh,bskh->bkgqs"),
+                    attend_part(qg, gk, gv, gen_mask, "bqkgh,bskh->bkgqs"),
                     attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
                 ]
                 attn = merge_attention_parts(parts)
@@ -669,24 +679,13 @@ def forward_block_decode(
                 up = _rdense(h2, lp["w_up"])
                 fused = jax.nn.silu(gate.astype(jnp.float32)).astype(xc.dtype) * up
                 xc = xc + _rdense(fused, lp["w_down"])
-            with jax.named_scope("kv_writeback"):
-                gk = gk.at[idx, row, dest].set(k.astype(gk.dtype))
-                gv = gv.at[idx, row, dest].set(v.astype(gv.dtype))
-            return (xc, gk, gv), None
+            return xc, (k, v)
 
-        (xc, gen_k, gen_v), _ = jax.lax.scan(
-            body_ragged,
-            (xc, gen_k, gen_v),
-            (
-                params["layers"], prefix_k_all, prefix_v_all,
-                k_sfx, v_sfx, jnp.arange(cfg.n_layers),
-            ),
-        )
-        return _logits(params, cfg, xc[last_c]), gen_k, gen_v
+        xc, blk_kv = jax.lax.scan(body_ragged, xc, xs)
+        return _logits(params, cfg, xc[last_c]), *written(*blk_kv)
 
-    def body(carry, xs):
-        x, gk, gv = carry
-        lp, pk, pv, ks, vs, idx = xs
+    def body(x, xs):
+        lp, pk, pv, ks, vs, gk, gv = xs
         with jax.named_scope("attn"):
             h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
             q = _dense(h, lp["wq"], "bfd,dh->bfh").reshape(R, F, cfg.n_heads, hd)
@@ -698,13 +697,12 @@ def forward_block_decode(
             qg = (q.astype(jnp.float32) * hd**-0.5).reshape(
                 R, F, cfg.n_kv_heads, cfg.q_per_kv, hd
             )
-            # Read this layer's generated-token KV from the carry: gen_mask only
-            # exposes entries < tail (previous iterations), so the read never
-            # sees this iteration's (not yet written) block.
+            # gen_mask only exposes entries < tail (previous iterations);
+            # in-block attention comes from the dense k/v just computed.
             parts = [
                 prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
                 attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
-                attend_part(qg, gk[idx], gv[idx], gen_mask, "bqkgh,bskh->bkgqs"),
+                attend_part(qg, gk, gv, gen_mask, "bqkgh,bskh->bkgqs"),
                 attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
             ]
             attn = merge_attention_parts(parts)  # [R, n_kv, g, F, hd]
@@ -712,22 +710,10 @@ def forward_block_decode(
             attn = _dense(attn.astype(x.dtype), lp["wo"], "bfh,hd->bfd")
             x = x + attn
         x = x + _mlp(lp, cfg, x)
-        # write the block's K/V AFTER attention (in-block attention came
-        # from the dense k/v just computed)
-        with jax.named_scope("kv_writeback"):
-            gk = gk.at[idx, row, dest].set(k.astype(gk.dtype))
-            gv = gv.at[idx, row, dest].set(v.astype(gv.dtype))
-        return (x, gk, gv), None
+        return x, (k, v)
 
-    (x, gen_k, gen_v), _ = jax.lax.scan(
-        body,
-        (x, gen_k, gen_v),
-        (
-            params["layers"], prefix_k_all, prefix_v_all,
-            k_sfx, v_sfx, jnp.arange(cfg.n_layers),
-        ),
-    )
-    return _last_valid_logits(params, cfg, x, blk_len), gen_k, gen_v
+    x, blk_kv = jax.lax.scan(body, x, xs)
+    return _last_valid_logits(params, cfg, x, blk_len), *written(*blk_kv)
 
 
 def forward_decode_buffered(

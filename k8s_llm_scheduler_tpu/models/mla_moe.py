@@ -69,7 +69,7 @@ from k8s_llm_scheduler_tpu.models.llama import (
     apply_rope,
     rms_norm,
 )
-from k8s_llm_scheduler_tpu.ops.attention import NEG_INF, merge_attention_parts
+from k8s_llm_scheduler_tpu.ops.attention import NEG_INF, merge_attention_parts, write_block
 from k8s_llm_scheduler_tpu.ops.grouped_matmul import grouped_matmul
 
 Params = dict[str, Any]
@@ -436,8 +436,8 @@ def forward_block_decode(
     c_sfx: jax.Array,      # [L, R, Ss, dc] latent suffix cache
     r_sfx: jax.Array,      # [L, R, Ss, dr]
     suffix_lens: jax.Array,  # [R]
-    gen_c: jax.Array,      # [L, R, cap+1, dc] generated-token latents (trash slot last)
-    gen_r: jax.Array,      # [L, R, cap+1, dr]
+    gen_c: jax.Array,      # [L, R, cap+F, dc] generated-token latents
+    gen_r: jax.Array,      # [L, R, cap+F, dr]
     tail: jax.Array,       # [R] tokens already in gen_c / gen_r
     prefix_c: jax.Array,   # [L, Sp, dc] shared latent prefix
     prefix_r: jax.Array,
@@ -448,39 +448,34 @@ def forward_block_decode(
     """One grammar-accelerated decode iteration (models/llama.py
     `forward_block_decode` says what that is) through the latent caches:
     (logits [R, V] f32 at each row's last valid position, gen_c, gen_r,
-    COUNTERS). Padding positions of the block are not routed, and their
-    latents go to the trash slot."""
+    COUNTERS). Padding positions of the block are not routed; the block's
+    latents are written once every layer has run (ops/attention.write_block)."""
     if ragged:
         raise ValueError(f"{cfg.name}: llm.decode_matmul 'ragged' is not served by models/mla_moe.py")
-    R, F = blk_tok.shape
-    cap1 = gen_c.shape[2]
     inv_freq = _inv_freq(cfg)
-    j = jnp.arange(F)
+    j = jnp.arange(blk_tok.shape[1])
     pre_mask = (jnp.arange(prefix_c.shape[1]) < prefix_len)[None, None, None, :]
     sfx_mask = (jnp.arange(c_sfx.shape[2])[None, :] < suffix_lens[:, None])[:, None, None, :]
-    gen_mask = (jnp.arange(cap1)[None, :] < tail[:, None])[:, None, None, :]
+    gen_mask = (jnp.arange(gen_c.shape[2])[None, :] < tail[:, None])[:, None, None, :]
     blk_mask = ((j[:, None] >= j[None, :])[None] & blk_valid[:, None, :])[:, None]
-    dest = jnp.where(blk_valid, tail[:, None] + j[None, :], cap1 - 1)
-    row = jnp.arange(R)[:, None]
 
     def step(lp, moe, x, carry, xs_l, idx):
-        gc, gr = carry
         pc, pr, sc, sr = xs_l
 
         def attend(lp, q_nope, q_rope, c_kv, k_r):
             # gen_mask exposes entries < tail only: never this block's own
             return attend_absorbed(lp, cfg, q_nope, q_rope, [
                 (pc, pr, pre_mask), (sc, sr, sfx_mask),
-                (gc[idx], gr[idx], gen_mask), (c_kv, k_r, blk_mask),
+                (gen_c[idx], gen_r[idx], gen_mask), (c_kv, k_r, blk_mask),
             ])
 
-        x, (c_kv, k_r), counters = _layer(lp, cfg, x, positions, blk_valid, inv_freq, moe, attend)
-        with jax.named_scope("kv_writeback"):
-            gc = gc.at[idx, row, dest].set(c_kv.astype(gc.dtype))
-            gr = gr.at[idx, row, dest].set(k_r.astype(gr.dtype))
-        return x, (gc, gr), (), counters
+        x, cache, counters = _layer(lp, cfg, x, positions, blk_valid, inv_freq, moe, attend)
+        return x, carry, cache, counters
 
-    x, (gen_c, gen_r), _, counters = _run_stacks(
-        params, cfg, _stream(params, blk_tok), (gen_c, gen_r),
+    x, _, (c_blk, r_blk), counters = _run_stacks(
+        params, cfg, _stream(params, blk_tok), (),
         (prefix_c, prefix_r, c_sfx, r_sfx), step)
+    with jax.named_scope("kv_writeback"):
+        gen_c = write_block(gen_c, tail, c_blk)
+        gen_r = write_block(gen_r, tail, r_blk)
     return _last_valid_logits(params, cfg, x.astype(cfg.dtype), blk_len), gen_c, gen_r, counters

@@ -255,6 +255,31 @@ def attend_part(q_scaled, k, v, mask, kv_eq):
     return o, m, l
 
 
+def write_block(buf: jax.Array, tail: jax.Array, block: jax.Array) -> jax.Array:
+    """Append one decode block to a wave's generated-token cache.
+
+    buf [L, R, cap + F, *token] holds row r's earlier tokens at slots
+    < tail[r]; block [L, R, F, *token] is what every layer just computed
+    for the block, valid tokens left-aligned. Row r's whole F-wide window
+    goes to slot tail[r]: one dense copy a row (R is static), once a model
+    call, where a per-token scatter in every layer cost 13% of the device
+    and held up the layer scan's weight prefetch (PERF.md §6, PR 31).
+
+    The padded positions' values land at and past the row's new tail. No
+    mask exposes a slot >= tail, the next block's window starts at the new
+    tail and overwrites them, and a finished row's tail never moves. The
+    buffer is cap + F long so that a window never overruns its end:
+    dynamic_update_slice would move such a window back, onto valid tokens.
+    Nothing here knows what a cached token is made of."""
+    block = block.astype(buf.dtype)
+    zeros = (0,) * (block.ndim - 3)
+    for r in range(block.shape[1]):
+        buf = jax.lax.dynamic_update_slice(
+            buf, block[:, r : r + 1], (0, r, tail[r], *zeros)
+        )
+    return buf
+
+
 def chunk_attention_with_prefix(
     q: jax.Array,  # [B, S, n_heads, head_dim] — suffix chunk queries
     k_chunk: jax.Array,  # [B, S, n_kv, head_dim]

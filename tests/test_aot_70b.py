@@ -143,12 +143,12 @@ class TestAOT70B:
             key_sds.shape, key_sds.dtype, sharding=NamedSharding(mesh, P())
         )
         compiled = (
-            jax.jit(_wave_impl, static_argnums=(1, 18, 19, 20, 21))
+            jax.jit(_wave_impl, static_argnums=(1, 17, 18, 19, 20))
             .lower(
                 abstract_params, CFG,
                 _repl(mesh, (R, Ss), i32),      # tokens
                 _repl(mesh, (R,), i32),         # suffix_lens
-                kv_sds, kv_sds,                 # prefix_k, prefix_v
+                (kv_sds, kv_sds),               # prefix cache tuple (k, v)
                 _repl(mesh, (), i32),           # prefix_len
                 _repl(mesh, (R,), i32),         # max_new
                 _repl(mesh, (NS, K), i32),      # sp_tokens

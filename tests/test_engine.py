@@ -30,15 +30,19 @@ ENGINE_CFG = LlamaConfig(
 )
 
 
-@pytest.fixture(scope="module")
-def engine():
+def make_engine(**kwargs):
     params = init_params(jax.random.PRNGKey(0), ENGINE_CFG)
     return InferenceEngine(
         params, ENGINE_CFG, TOK,
         num_pages=128, page_size=64, max_slots=4, max_pages_per_seq=32,
         prefill_buckets=(128, 256, 512, 1024),
-        chunk_steps=8, temperature=0.0,
+        chunk_steps=8, temperature=0.0, **kwargs,
     )
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return make_engine()
 
 
 class TestTokenizer:
@@ -265,6 +269,31 @@ class TestDecideWave:
                 assert obj["selected_node"] in names
         finally:
             engine.set_grammar(None)
+
+    # what the parent of PR 31 served (block K/V scattered token by token
+    # into a cap + 1 buffer, in every layer); every id below 257 is a byte + 1
+    PARENT_WAVE_TEXTS = (
+        '{"selected_node": "node-0", "confidence": 1.0, "reasoning": "/nnnnnnnn]n]ynnnnnnn"}',
+        '{"selected_node": "node-1", "confidence": 1.0, "reasoning": "y3nnnnnnn$nCnnnnnnnn"}',
+        '{"selected_node": "node-1", "confidence": 1.0, "reasoning": "dnnnnnnn]n]/nnnnnnnn"}',
+    )
+
+    @pytest.mark.parametrize("decode_matmul", ["dense", "ragged"])
+    def test_wave_token_ids_are_the_parents(self, decode_matmul):
+        """The generated-token cache written a window a row
+        (ops/attention.write_block) serves the ids the per-token scatter
+        served: 20 free reasoning tokens a row attend to it."""
+        eng = make_engine(decode_matmul=decode_matmul)
+        eng.set_grammar(build_decision_dfa(
+            TOK, ["node-0", "node-1", "node-2"], max_reason_tokens=20))
+        prompts = [
+            TOK.chat_prompt("pick a node", f"pod-{i} wants scheduling")
+            for i in range(3)
+        ]
+        fins = eng.decide_wave(prompts, max_new_tokens=150)
+        assert [f.token_ids for f in fins] == [
+            TOK.encode(text) + [TOK.eos_id] for text in self.PARENT_WAVE_TEXTS
+        ]
 
     def test_wave_single_prompt(self, engine):
         prompt = TOK.chat_prompt("sys", "solo")

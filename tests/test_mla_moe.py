@@ -89,7 +89,7 @@ def program_wave_logits(cfg, params, prefix, suffixes, served, junk=0):
     logits, sc, sr, counters = mla_moe.forward_prefill_suffix_dense(
         params, cfg, jnp.asarray(sfx), lens, pc, pr, jnp.int32(PREFIX_LEN))
     out = [[np.asarray(logits[r])] if len(suffixes[r]) else [] for r in range(R)]
-    gc, gr = (jnp.zeros((cfg.n_layers, R, CAP + 1, *shape), cfg.dtype)
+    gc, gr = (jnp.zeros((cfg.n_layers, R, CAP + F, *shape), cfg.dtype)
               for shape in mla_moe.cache_token_shapes(cfg))
     tail = np.zeros(R, np.int32)
     for blk in BLOCKS:
@@ -354,13 +354,15 @@ def test_the_lowered_forwards_hold_the_scopes_and_kernel_names():
     text = jax.jit(mla_moe.forward_block_decode, static_argnums=1).lower(
         params, cfg, jnp.zeros((R, F), jnp.int32), jnp.ones((R, F), bool), jnp.full((R,), F, jnp.int32),
         jnp.zeros((R, F), jnp.int32), z(L, R, SS, *c_tok), z(L, R, SS, *r_tok), jnp.ones((R,), jnp.int32),
-        z(L, R, CAP + 1, *c_tok), z(L, R, CAP + 1, *r_tok), jnp.zeros((R,), jnp.int32),
+        z(L, R, CAP + F, *c_tok), z(L, R, CAP + F, *r_tok), jnp.zeros((R,), jnp.int32),
         z(L, PREFIX_CAP, *c_tok), z(L, PREFIX_CAP, *r_tok), jnp.int32(PREFIX_LEN),
     ).as_text(debug_info=True)
     for path in ("attn/mla_down", "attn/mla_up", "attn/latent_attention", "attn/wo", "kv_writeback",
                  "mlp/moe_router", "mlp/moe_dispatch", "mlp/moe_experts", "mlp/moe_combine",
                  "mlp/moe_shared", "lm_head", "embed"):
         assert f"{path}/" in text, path
+    # the latent write is dense copies, a window a row (ops/attention.write_block)
+    assert "kv_writeback/dynamic_update_slice" in text and "kv_writeback/scatter" not in text
     for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
         assert f"mlp/moe_experts/{kernel}" in text or kernel in text, kernel
     # the dense layer's feed-forward stays bare `mlp`

@@ -97,7 +97,7 @@ class TestRaggedBlockDecode:
             positions=positions,
             k_sfx=t(L, R, Ss, kv, hd), v_sfx=t(L, R, Ss, kv, hd),
             suffix_lens=suffix_lens,
-            gen_k=t(L, R, cap + 1, kv, hd), gen_v=t(L, R, cap + 1, kv, hd),
+            gen_k=t(L, R, cap + F, kv, hd), gen_v=t(L, R, cap + F, kv, hd),
             tail=tail,
             prefix_k_all=t(L, Sp, kv, hd), prefix_v_all=t(L, Sp, kv, hd),
             prefix_len=jnp.int32(Sp),
@@ -145,11 +145,12 @@ class TestRaggedBlockDecode:
             np.asarray(logits_r)[live], np.asarray(logits_d)[live],
             rtol=2e-3, atol=2e-3,
         )
-        # exposed gen-KV entries (dest < tail + len) must be identical;
-        # the trash slot (index cap) is excluded by construction
+        # exposed gen-KV entries (slot < tail + len) must be identical; what
+        # the padded block positions left past them is never read
         tail = np.asarray(kw["tail"])
         blk_len = np.asarray(kw["blk_len"])
-        cap1 = np.asarray(kw["gen_k"]).shape[2]
+        F = np.asarray(kw["blk_tok"]).shape[1]
+        cap = np.asarray(kw["gen_k"]).shape[2] - F
         for r in range(len(tail)):
             hi = tail[r] + blk_len[r]
             np.testing.assert_allclose(
@@ -160,4 +161,4 @@ class TestRaggedBlockDecode:
                 np.asarray(gv_r)[:, r, :hi], np.asarray(gv_d)[:, r, :hi],
                 rtol=2e-3, atol=2e-3,
             )
-            assert hi <= cap1 - 1
+            assert hi <= cap
