@@ -215,15 +215,6 @@ PRESETS = {
     # token digest, which must not drift when the layout changes.
     "tp-serving": {"slots": 8, "rounds": 2, "max_new_tokens": 48,
                    "temperature": 0.0},
-    # persistent serving loop (engine/persistent/) TRUTH ROUND: the full
-    # composed stack (watch -> prompt -> grammar decode -> bind) at the
-    # burst1000 operating shape, A/B'd persistent-loop ON vs OFF on
-    # otherwise identical backends, plus one arrival-paced steady round
-    # per arm for the burst-vs-steady ratio. Headline figures: burst
-    # p50 vs the 200 ms target, burst/steady vs the
-    # 1.5x bar, the profiler's dispatches_per_decision gauge per arm
-    # (the zero-dispatch proof), and fused/persistent MFU books.
-    "serving": {"pods": 1000, "nodes": 64, "shapes": 32, "rounds": 1},
     # routed fast tier (sched/router.py): distill big + fast arms from
     # the same spread-lookahead teacher (fast = half-width student),
     # then arena-gate the routed hybrid against BOTH arms alone — the
@@ -280,12 +271,7 @@ async def run_burst(
         cluster.bind_pod_to_node = orig_bind
 
 
-def build_backend(
-    args,
-    delta_prompts: bool = False,
-    persistent_loop: bool = False,
-    request_timeout_s: float | None = None,
-):
+def build_backend(args, delta_prompts: bool = False):
     from k8s_llm_scheduler_tpu.engine.local import build_local_backend
 
     cfg = build_cfg(args.model)
@@ -312,24 +298,6 @@ def build_backend(
         max_new_tokens=args.max_new_tokens,
         quantize=getattr(args, "quantize", None),
         delta_prompts=delta_prompts,
-        persistent_loop=persistent_loop,
-        # BPE decision suffixes run ~100-150 tokens at bench shapes; the
-        # default bucket (smallest prefill bucket, 128) would route a
-        # fraction of admissions to the fallback dispatch path and the
-        # A/B would measure the fallback churn, not the resident loop.
-        persistent_suffix_bucket=256 if persistent_loop else None,
-        # Bench rounds compile sibling geometries WHILE the loop is
-        # resident; on a CPU harness a compile storm can starve the
-        # resident thread's heartbeat past the 30s production default and
-        # false-wedge the arm (latching persistent OFF mid-A/B). The bench
-        # proves serving economics, not wedge detection — the chaos
-        # persistent-wedge regime owns that — so give it headroom.
-        persistent_wedge_timeout_s=600.0,
-        **(
-            {"request_timeout_s": request_timeout_s}
-            if request_timeout_s is not None
-            else {}
-        ),
     )
 
 
@@ -645,161 +613,6 @@ async def burst_bench(args) -> dict:
     }
 
 
-# ------------------------------------------------------ persistent serving
-async def serving_bench(args) -> dict:
-    """`--preset serving`: the persistent-loop TRUTH ROUND.
-
-    Two identically configured backends, A/B'd:
-
-    - persistent ON: after the first admission the engine parks inside ONE
-      long-lived XLA program (engine/persistent/loop.py); steady-state
-      decisions ride the host->device CommandRing in and the
-      device->host TokenRing out — ZERO per-decision XLA dispatches;
-    - persistent OFF: every decision pays the dispatch path (admission
-      dispatch + fused decode dispatches), the pre-ISSUE-18 serving plane.
-
-    Per arm: the full composed stack (watch -> snapshot prompt -> grammar
-    decode -> bind) at the burst1000 operating shape, plus one
-    arrival-paced steady round for the burst-vs-steady ratio. Headlines:
-
-    - burst p50 on the persistent arm (wall clock at the scheduler) vs
-      the 200 ms target;
-    - burst p50 / steady p50 vs the ~1.5x bar;
-    - the profiler's windowed `dispatches_per_decision` gauge per arm
-      (the structural zero-dispatch proof — on a host where dispatch is
-      nearly free the LATENCY delta understates the win; the gauge does
-      not) plus the raw steady-round dispatch-counter delta per LLM
-      decision as a second, window-free measurement;
-    - `fused_mfu_decode` when a device peak is known (null on the CPU
-      harness — carried from the TPU books otherwise).
-    """
-    from k8s_llm_scheduler_tpu.observability.profiler import EngineProfiler
-
-    peak_tflops, device_kind = detect_peak_tflops(
-        getattr(args, "peak_tflops", None)
-    )
-
-    async def one_arm(persistent: bool) -> dict:
-        # A cold first decision pays the compile, and on the CPU harness
-        # compile alone outruns the 60s production request timeout —
-        # shedding it to the breaker would replace the measured model
-        # round with heuristic fallbacks. The timeout is a reliability
-        # knob, not part of the measured claim; size it to the harness.
-        backend = build_backend(
-            args, persistent_loop=persistent, request_timeout_s=300.0
-        )
-        eng = backend.engine
-        prof = EngineProfiler(build_cfg(args.model), peak_tflops=peak_tflops)
-        eng.attach_profiler(prof)
-        try:
-            burst = await bench_preset(args, backend=backend)
-            steady_args = argparse.Namespace(**vars(args))
-            steady_args.arrival_rate = 100.0
-            steady_args.perturb_idle = 0.0
-            steady_args.pods = min(args.pods, 128)
-            steady_args.rounds = 1
-            # Raw-counter A/B over the steady round: the windowed gauge
-            # answers "recently", the delta answers "this round, exactly".
-            disp_before = eng.stats["dispatches"]
-            steady = await bench_preset(steady_args, backend=backend)
-            disp_delta = eng.stats["dispatches"] - disp_before
-            gauges = prof.gauges()
-            snap = prof.snapshot()
-            stats = dict(eng.stats)
-        finally:
-            backend.close()
-        decisions = steady["extra"]["llm_decisions"] or 0
-        return {
-            "burst": burst,
-            "steady": steady,
-            "gauges": gauges,
-            "snapshot": snap,
-            "stats": stats,
-            "steady_dispatches": disp_delta,
-            "steady_llm_decisions": decisions,
-            "steady_dispatches_per_llm_decision": (
-                round(disp_delta / decisions, 3) if decisions else None
-            ),
-        }
-
-    arm_on = await one_arm(True)
-    arm_off = await one_arm(False)
-
-    def _arm_block(arm: dict) -> dict:
-        g, s = arm["gauges"], arm["stats"]
-        seg = arm["snapshot"].get("persistent")
-        if seg:
-            # the aggregates carry the story; the per-harvest window ring
-            # is thousands of entries of idle 20ms polls — not publishable
-            seg = {k: v for k, v in seg.items() if k != "ring"}
-        return {
-            "burst_p50_ms": arm["burst"]["value"],
-            "burst_p99_ms": arm["burst"]["extra"]["p99_ms"],
-            "burst_p50_cold_ms": arm["burst"]["extra"]["p50_cold_ms"],
-            "steady_p50_ms": arm["steady"]["value"],
-            "pods_per_sec": arm["burst"]["extra"]["pods_per_sec"],
-            # windowed gauge (recent completion windows): 0.0 on the ON
-            # arm is the zero-dispatch steady state, measured not asserted
-            "dispatches_per_decision_gauge": g.get("dispatches_per_decision"),
-            "steady_dispatches": arm["steady_dispatches"],
-            "steady_llm_decisions": arm["steady_llm_decisions"],
-            "steady_dispatches_per_llm_decision": arm[
-                "steady_dispatches_per_llm_decision"
-            ],
-            "fused_mfu_decode": g.get("fused_mfu_decode"),
-            "persistent_stats": {
-                k: s.get(k, 0)
-                for k in (
-                    "persistent_launches", "persistent_admissions",
-                    "persistent_fallbacks", "persistent_wedges",
-                    "persistent_steps", "persistent_chunks",
-                )
-            },
-            # ring/segment books from the profiler's persistent plane
-            # (ring_wait vs loop_resident vs harvest fractions)
-            "persistent_segments": seg,
-        }
-
-    burst_on = arm_on["burst"]["value"]
-    steady_on = arm_on["steady"]["value"]
-    ratio = round(burst_on / steady_on, 3) if steady_on else None
-    return {
-        "metric": "p50_decision_latency_ms",
-        "value": burst_on,
-        "unit": "ms",
-        "vs_baseline": round(TARGET_P50_MS / burst_on, 3),
-        "extra": {
-            "target_ms": TARGET_P50_MS,
-            "target_met": bool(burst_on < TARGET_P50_MS),
-            "latency_basis": "burst p50 at the scheduler, persistent arm",
-            "dispatch_rtt_ms": measure_dispatch_rtt_ms(),
-            "burst_over_steady": ratio,
-            "burst_over_steady_bar": "burst p50 within ~1.5x of steady p50",
-            "burst_over_steady_bar_met": bool(
-                ratio is not None and ratio <= 1.5
-            ),
-            "pods": args.pods,
-            "nodes": args.nodes,
-            "shapes": args.shapes,
-            "model": args.model,
-            "weights": "random-init",
-            "device_kind": device_kind,
-            "peak_bf16_tflops": peak_tflops,
-            "persistent_on": _arm_block(arm_on),
-            "persistent_off": _arm_block(arm_off),
-            "ab_burst_p50_delta_ms": round(
-                arm_off["burst"]["value"] - burst_on, 2
-            ),
-            "baseline_note": (
-                "reference publishes no numbers; target p50<200ms "
-                "(BASELINE.md). On a free-dispatch host the A/B latency "
-                "delta understates the persistent win — the per-arm "
-                "dispatches-per-decision figures are the structural claim."
-            ),
-        },
-    }
-
-
 # ------------------------------------------------------------- rollout swap
 async def rollout_bench(args) -> dict:
     """`--preset rollout`: hot-swap pause under active decode load.
@@ -907,129 +720,6 @@ async def rollout_bench(args) -> dict:
 
 
 # ------------------------------------------------------------- obs overhead
-def _persistent_obs_arm(rounds: int = 3, n_decisions: int = 10) -> dict:
-    """The persistent-arm A/B of the obs-overhead preset: the in-loop
-    telemetry plane (observability/resident.py — device counter block in
-    the while_loop carry, StatsRing publication off the push callback,
-    black-box recording) ON vs OFF in the RESIDENT serving loop of a
-    micro real engine. Telemetry is a static jit parameter, so each arm
-    is its own compiled program; both arms warm fully before any
-    measurement. Per-decision latency is wall clock around one
-    admit->complete cycle through the rings; OFF-then-ON pairing per
-    round, min-of-round-medians per arm — the same noise discipline as
-    the tracing A/B. Asserts the telemetry-ON arm still reports
-    dispatches_per_decision == 0.0 (the counters ride the carry and the
-    existing callback: zero extra dispatches is the design contract, not
-    an aspiration)."""
-    import jax
-    import jax.numpy as jnp
-
-    from k8s_llm_scheduler_tpu.engine.engine import InferenceEngine
-    from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer
-    from k8s_llm_scheduler_tpu.models.configs import LlamaConfig
-    from k8s_llm_scheduler_tpu.models.llama import init_params
-    from k8s_llm_scheduler_tpu.observability.profiler import EngineProfiler
-
-    cfg = LlamaConfig(
-        name="obs-persistent-micro", vocab_size=512, d_model=64,
-        n_layers=2, n_heads=2, n_kv_heads=1, d_ff=128, max_seq_len=4096,
-        rope_theta=10000.0, dtype=jnp.float32, tie_embeddings=True,
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    tok = ByteTokenizer()
-    prompts = [
-        tok.encode(f"pod-{i:03d} needs a node") for i in range(n_decisions)
-    ]
-
-    def serve_round(eng) -> list[float]:
-        lats = []
-        for prompt in prompts:
-            t0 = time.perf_counter()
-            (rid,) = eng.add_requests([prompt], max_new_tokens=8)
-            done = False
-            deadline = time.monotonic() + 120.0
-            while not done:
-                assert time.monotonic() < deadline, "persistent arm wedged"
-                for fin in eng.step_persistent(timeout_s=0.05):
-                    if fin.req_id == rid:
-                        done = True
-            lats.append((time.perf_counter() - t0) * 1000.0)
-        return lats
-
-    engines: dict[bool, InferenceEngine] = {}
-    for telemetry in (True, False):
-        eng = InferenceEngine(
-            params, cfg, tok, num_pages=128, page_size=16, max_slots=4,
-            max_pages_per_seq=16, prefill_buckets=(32, 64, 128),
-            chunk_steps=4, temperature=0.0, prefix_chunk=64,
-            persistent_loop=True, persistent_telemetry=telemetry,
-            # CPU-harness headroom: the A/B measures telemetry cost, not
-            # wedge detection, and the warm round's compile storm can
-            # starve the heartbeat past the 30s production default
-            # (same rationale as the serving preset).
-            persistent_wedge_timeout_s=600.0,
-        )
-        eng.set_prefix(tok.encode("obs overhead shared prefix"))
-        assert eng.enter_persistent()
-        serve_round(eng)  # compile + warm the arm's program, discarded
-        # ONE resident loop at a time: two concurrent while_loop programs
-        # starve each other on a single bench device (the second arm's
-        # loop never gets the device and reads as wedged). Residency is a
-        # hot swap — each round re-enters on the cached program.
-        eng.exit_persistent()
-        # Attach AFTER the warmup so the flow window holds only
-        # steady-state residency (the zero-dispatch gauge's contract;
-        # enter_persistent re-baselines the flow books each round).
-        eng.attach_profiler(EngineProfiler(cfg=cfg, window=256))
-        engines[telemetry] = eng
-
-    dpd_on: float | None = None
-    pers_gauges: dict = {}
-    p50s: dict[bool, list[float]] = {False: [], True: []}
-    for r in range(rounds):
-        for telemetry in (False, True):
-            eng = engines[telemetry]
-            assert eng.enter_persistent()
-            try:
-                p50s[telemetry].append(
-                    statistics.median(serve_round(eng))
-                )
-                if telemetry and r == rounds - 1:
-                    # Gauges read WHILE resident: the quiesce/rebind
-                    # dispatches of the exit below belong to the mode
-                    # transition, not the steady state under test.
-                    st = eng.get_stats()
-                    dpd_on = st.get("dispatches_per_decision")
-                    pers_gauges = st.get("persistent") or {}
-            finally:
-                eng.exit_persistent()
-    p50_off = min(p50s[False])
-    p50_on = min(p50s[True])
-    overhead_pct = (p50_on - p50_off) / p50_off * 100.0
-    assert overhead_pct < 2.0, (
-        f"in-loop telemetry overhead {overhead_pct:.2f}% >= 2% of "
-        f"resident decision p50 (on {p50_on:.3f}ms vs off "
-        f"{p50_off:.3f}ms)"
-    )
-    assert dpd_on == 0.0, (
-        f"telemetry-on persistent arm paid dispatches: "
-        f"dispatches_per_decision={dpd_on!r} (expected 0.0)"
-    )
-    return {
-        "overhead_pct": round(overhead_pct, 3),
-        "p50_on_ms": round(p50_on, 3),
-        "p50_off_ms": round(p50_off, 3),
-        "round_p50s_off_ms": [round(v, 3) for v in p50s[False]],
-        "round_p50s_on_ms": [round(v, 3) for v in p50s[True]],
-        "dispatches_per_decision_on": dpd_on,
-        "resident_tokens_per_s_on": pers_gauges.get(
-            "resident_tokens_per_s"
-        ),
-        "decisions_per_round": n_decisions,
-        "threshold_pct": 2.0,
-    }
-
-
 async def obs_overhead_bench(args) -> dict:
     """`--preset obs-overhead`: what does the tracing layer cost?
 
@@ -1167,12 +857,6 @@ async def obs_overhead_bench(args) -> dict:
         profiler_wave_us = (
             (time.perf_counter() - t0) / n_waves_micro * 1e6
         )
-
-        # persistent arm: the in-loop telemetry plane (counters + stats
-        # ring + black-box) A/B'd ON/OFF inside the RESIDENT loop of a
-        # micro real engine, under the same <2% bar — and the ON arm
-        # must still read dispatches_per_decision == 0.0
-        persistent_arm = _persistent_obs_arm(rounds=args.rounds)
     finally:
         spans.configure(enabled=was_enabled)
 
@@ -1194,7 +878,6 @@ async def obs_overhead_bench(args) -> dict:
             "round_p50s_on_ms": [round(v, 3) for v in p50s[True]],
             "span_overhead_us": round(span_us, 2),
             "profiler_wave_us": round(profiler_wave_us, 2),
-            "persistent_arm": persistent_arm,
             "pods": args.pods,
             "nodes": args.nodes,
             "arrival_rate": args.arrival_rate,
@@ -3480,9 +3163,6 @@ def main() -> None:
         return
     if args.preset == "burst":
         _emit(asyncio.run(burst_bench(args)))
-        return
-    if args.preset == "serving":
-        _emit(asyncio.run(serving_bench(args)))
         return
     if args.preset == "decode":
         _emit(asyncio.run(decode_bench(args)))

@@ -265,13 +265,13 @@ def run(cfg, *, nodes: int = NODES, pods: int = PODS, shapes: int = SHAPES) -> d
     served = {
         k: engine_stats[k] - engine_before.get(k, 0)
         for k in ("waves", "wave_model_calls", "fused_chunks", "fused_fallbacks",
-                  "chunks", "packed_admissions", "persistent_steps",
+                  "chunks", "packed_admissions",
                   "prefix_prefills", "requests", "completed")
     }
     drivers = [
         name for name, key in (
             ("wave_block_decode", "waves"), ("fused_while_loop", "fused_chunks"),
-            ("sparse_chunked", "chunks"), ("persistent_loop", "persistent_steps"),
+            ("sparse_chunked", "chunks"),
         ) if served[key]
     ]
     client, breaker = stats["client"], stats["client"].get("circuit_breaker", {})

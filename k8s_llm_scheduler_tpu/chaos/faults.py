@@ -61,7 +61,7 @@ from typing import Any, Callable, Sequence
 
 SEAMS = (
     "wire", "lease", "watch", "backend", "cache", "slo", "swap", "scale",
-    "process", "kvplane", "persistent",
+    "process", "kvplane",
 )
 
 FAULT_KINDS: dict[str, tuple[str, ...]] = {
@@ -96,18 +96,6 @@ FAULT_KINDS: dict[str, tuple[str, ...]] = {
     # truncated by params["bytes"] before the rebuild opens it (replay
     # must truncate the tear, never mis-parse it).
     "process": ("crash", "crash_recovery", "torn_tail"),
-    # persistent serving-loop ring plane (engine/persistent/ring.py,
-    # driven by chaos/harness._run_persistent_stack over the REAL rings
-    # with a deterministic no-JAX stub loop thread): `ring_full` makes
-    # the loop stop draining the command ring for the window (admission
-    # backpressure — feeders must fall back to the dispatch path, never
-    # queue unboundedly), `consumer_stall` pauses the host harvester so
-    # emissions pile into the bounded token ring (zero-loss emission
-    # backpressure — every token must still arrive, exactly once, after
-    # the stall), and `loop_wedge` stops the loop thread beating
-    # entirely so the Heartbeat watchdog must detect the wedge and kick
-    # a graceful drain back to the dispatch path.
-    "persistent": ("ring_full", "consumer_stall", "loop_wedge"),
     # shared prefix-KV plane (fleet/kvplane/KVPlaneStore.fault_seam):
     # `store_down` makes every store op raise (clients degrade to local
     # prefill), `fill_stall` kills the elected filler's publish
@@ -439,28 +427,6 @@ def _regime_crash_during_recovery(rng, n_waves: int, n_nodes: int):
     ], []
 
 
-def _regime_persistent_wedge(rng, n_waves: int, n_nodes: int):
-    # one-wave windows, strided two apart: ring_full first (admission
-    # backpressure), then loop_wedge (watchdog drain), then
-    # consumer_stall LAST. The stall must never be followed by a wedge
-    # while its parked work is mid-stream — WHICH emissions rode the
-    # ring before the wedge landed would be thread-timing's choice,
-    # exactly what the determinism contract forbids; with the stall
-    # last, the harvester simply resumes when the window closes and the
-    # loop finishes serving, so every stalled request completes via the
-    # ring deterministically. Narrow runs (n_waves 3-4) clamp windows
-    # onto the last wave, where the wedge dominates a co-resident stall
-    # and ring-full-parked commands (never taken by the paused loop)
-    # drain to the fallback path with zero emissions — still
-    # deterministic.
-    w = max(1, n_waves // 4)
-    events = []
-    for i, kind in enumerate(("ring_full", "loop_wedge", "consumer_stall")):
-        start = min(w + 2 * i, n_waves - 1)
-        events.append(_ev("persistent", kind, start, start + 1))
-    return events, []
-
-
 def _regime_kv_plane_outage(rng, n_waves: int, n_nodes: int):
     start, end = _mid_windows(n_waves)
     if end - start >= 3:
@@ -576,21 +542,6 @@ REGIMES: dict[str, dict[str, Any]] = {
         "describe": "a scale-down drain races a crashed replica's lease "
                     "failover: binds stay exactly-once across both "
                     "membership changes",
-    },
-    # --- persistent serving-loop regime (mode "persistent": the REAL
-    # engine/persistent rings + Heartbeat watchdog under a
-    # deterministic no-JAX stub loop thread; chaos/harness.
-    # _run_persistent_stack. Each pod is one serving request whose
-    # token stream — and therefore whose placement — must arrive
-    # exactly once through the ring plane or the dispatch-path
-    # fallback.)
-    "persistent-wedge": {
-        "build": _regime_persistent_wedge, "mode": "persistent",
-        "describe": "serving-loop rings under fire: a full command ring "
-                    "backpressures admission to the dispatch path, a "
-                    "wedged loop is watchdog-drained, and a stalled "
-                    "emission consumer blocks the loop without losing "
-                    "or double-delivering a single token",
     },
     # --- durable-state regimes (mode "crash": one journal-backed
     # replica over a file-backed lease store, dropped COLD at seeded

@@ -42,7 +42,6 @@ same seed -> same fault schedule -> byte-identical trace.
 from __future__ import annotations
 
 import asyncio
-import threading
 import time
 from typing import Any
 
@@ -1277,477 +1276,6 @@ async def _run_crash_stack(
         shutil.rmtree(workdir, ignore_errors=True)
 
 
-# --------------------------------------------------------- persistent mode
-_STUB_CHUNK = 4          # micro-chunk steps per harvest batch
-_STUB_SLOTS = 32         # resident slots (>= any wave plus parked work)
-_CMD_CAPACITY = 4        # small on purpose: ring_full must actually bite
-_TOK_CAPACITY = 8        # bounded: a stalled consumer must backpressure
-_WEDGE_TIMEOUT_S = 0.08  # heartbeat staleness the watchdog trips on
-
-
-def _stub_token(seed: int, pos: int) -> int:
-    """Pure-arithmetic token stream (cross-process stable, no RNG): the
-    whole emission stream of one serving request is a function of its
-    seed, so the harness can verify byte-exact delivery without sharing
-    any state with the loop thread."""
-    return int((seed * 1000003 + pos * 7919 + 12345) % 49999)
-
-
-def _stub_stream(seed: int) -> list[int]:
-    """The request's full expected stream; length 6..17 so every request
-    spans several micro-chunks (its budget exceeds one chunk)."""
-    return [_stub_token(seed, i) for i in range(6 + seed % 12)]
-
-
-class _ServeReq:
-    """One serving request in flight through the persistent plane."""
-
-    __slots__ = (
-        "pod", "seed", "expected", "delivered", "candidates", "slot",
-        "via_fallback",
-    )
-
-    def __init__(self, pod, seed: int, expected: list[int],
-                 candidates: list[str]) -> None:
-        self.pod = pod
-        self.seed = seed
-        self.expected = expected
-        self.delivered: list[int] = []
-        self.candidates = candidates
-        self.slot = -1
-        self.via_fallback = False
-
-
-class _StubResidentLoop:
-    """Deterministic no-JAX stand-in for the resident serving loop
-    (engine/persistent/loop.py) driving the REAL CommandRing /
-    TokenRing / Heartbeat from engine/persistent/ring.py. One thread
-    iteration = one micro-chunk, exactly like the device program: beat,
-    poll ONE command, serve up to _STUB_CHUNK tokens per active slot,
-    push one HarvestBatch — blocking when the token ring is full, the
-    same emission backpressure that stalls the real loop. Chaos flags
-    are wave-quantized by the harness while the loop is IDLE (the wave
-    barrier drained everything), so no take/flag race can change what
-    the loop observed: `pause_polls` stops command uptake (ring_full),
-    `wedged` stops the thread beating entirely (loop_wedge — the
-    Heartbeat watchdog must notice on its own)."""
-
-    def __init__(self) -> None:
-        from k8s_llm_scheduler_tpu.engine.persistent.ring import (
-            CommandRing,
-            Heartbeat,
-            TokenRing,
-        )
-        from k8s_llm_scheduler_tpu.observability.resident import BlackBox
-
-        self.commands = CommandRing(capacity=_CMD_CAPACITY)
-        self.tokens = TokenRing(capacity=_TOK_CAPACITY)
-        self.heartbeat = Heartbeat()
-        # Wedge black-box, chaos flavour: the real loop's box records
-        # full iteration snapshots (observability/resident.py), but
-        # iteration cadence here is thread timing — so this box records
-        # only PROTOCOL events (command uptake, FIFO order fixed by the
-        # plan), keeping the dump byte-identical across replay. Depth 16
-        # < the regime's ~36 admits, so boundedness is exercised, not
-        # just declared.
-        self.blackbox = BlackBox(depth=16)
-        self.pause_polls = False
-        self.wedged = False
-        self._stop = False
-        import numpy as np
-
-        self._seed = np.zeros(_STUB_SLOTS, dtype=np.int64)
-        self._pos = np.zeros(_STUB_SLOTS, dtype=np.int32)
-        self._budget = np.zeros(_STUB_SLOTS, dtype=np.int32)
-        self._act = np.zeros(_STUB_SLOTS, dtype=bool)
-        self._thread = threading.Thread(
-            target=self._run, name="chaos-persistent-loop", daemon=True
-        )
-        self._thread.start()
-
-    def _run(self) -> None:
-        import numpy as np
-
-        from k8s_llm_scheduler_tpu.engine.persistent.ring import (
-            OP_ABORT,
-            OP_ADMIT,
-            OP_QUIESCE,
-            HarvestBatch,
-        )
-
-        while not self._stop:
-            if self.wedged:
-                time.sleep(0.002)  # graftlint: ok[raw-clock] — a wedged loop must idle REAL wall time so the real Heartbeat watchdog trips on its own
-                continue
-            self.heartbeat.beat()
-            cmd = None
-            if not self.pause_polls:
-                cmd = self.commands.take()
-            if cmd is not None:
-                if cmd.op == OP_QUIESCE:
-                    self.blackbox.record({"event": "quiesce"})
-                    return
-                if cmd.op == OP_ABORT:
-                    self.blackbox.record(
-                        {"event": "abort", "slot": int(cmd.slot)}
-                    )
-                    if cmd.slot < 0:
-                        self._act[:] = False
-                    else:
-                        self._act[cmd.slot] = False
-                elif cmd.op == OP_ADMIT:
-                    self.blackbox.record({
-                        "event": "admit",
-                        "slot": int(cmd.slot),
-                        "seed": int(cmd.tokens[0, 0]),
-                        "budget": int(cmd.budget),
-                    })
-                    self._seed[cmd.slot] = int(cmd.tokens[0, 0])
-                    self._pos[cmd.slot] = 0
-                    self._budget[cmd.slot] = cmd.budget
-                    self._act[cmd.slot] = True
-            if not self._act.any():
-                if cmd is None:
-                    self.commands.wait_nonempty(0.005)
-                continue
-            emitted = np.full(
-                (_STUB_SLOTS, _STUB_CHUNK), -1, dtype=np.int32
-            )
-            for s in range(_STUB_SLOTS):
-                if not self._act[s]:
-                    continue
-                n = min(_STUB_CHUNK, int(self._budget[s]))
-                for j in range(n):
-                    emitted[s, j] = _stub_token(
-                        int(self._seed[s]), int(self._pos[s]) + j
-                    )
-                self._pos[s] += n
-                self._budget[s] -= n
-                if self._budget[s] <= 0:
-                    self._act[s] = False
-            batch = HarvestBatch(
-                seq=-1, emitted=emitted, steps_run=_STUB_CHUNK,
-                act=self._act.copy(), budget=self._budget.copy(),
-                pos=self._pos.copy(), admit_slot=-1, first_tok=0,
-            )
-            if not self.tokens.put(batch, stop_check=lambda: self._stop):
-                return                 # forced drain unblocked the push
-            self.heartbeat.beat()
-
-    def shutdown(self, timeout_s: float = 5.0) -> None:
-        from k8s_llm_scheduler_tpu.engine.persistent.ring import (
-            OP_QUIESCE,
-            Command,
-        )
-
-        try:
-            self.commands.put(Command(op=OP_QUIESCE), timeout_s=0.5)
-        except Exception:
-            pass  # graftlint: ok[swallowed-exception] — ring may be full or closed; the stop flag below ends the thread either way
-        self._stop = True
-        self._thread.join(timeout_s)
-        self.commands.close()
-        self.tokens.close()
-
-    @property
-    def alive(self) -> bool:
-        return self._thread.is_alive()
-
-
-async def _run_persistent_stack(
-    scenario, plan: FaultPlan, injector: FaultInjector,
-    monitor: InvariantMonitor, *, deadline_ms: float | None,
-    wave_timeout_s: float,
-) -> dict:
-    """The persistent serving plane under fire: the REAL ring plane
-    (CommandRing admission backpressure, TokenRing seq-verified
-    emission, Heartbeat wedge watchdog) under a deterministic stub loop
-    thread. Each pod is ONE serving request: its expected token stream
-    is a pure function of its name, its placement is decoded from the
-    DELIVERED stream over the wave-settled feasible set — so a lost,
-    duplicated, or corrupted emission moves a placement and breaks the
-    byte-identical trace, and the fallback path re-derives the same
-    stream, so a drain must never move one (the determinism contract).
-
-    Determinism: admission is sequential in wave order; chaos flags are
-    applied at wave boundaries while the loop is idle; the consumer-
-    stall window bounds ring admission to the command ring's capacity
-    (the same parked-work bound the production feeder enforces) so the
-    feeder never races the jammed loop for RingFull; and the wedge
-    window is ordered before any stall window (chaos/faults regime
-    builder) so no request is ever mid-stream when the watchdog drains
-    — every completion path (ring, reject-fallback, drain-fallback) is
-    chosen by the plan, never by thread timing. Timing-dependent ring
-    counters (token-ring stalls, heartbeats) stay report-only."""
-    import numpy as np
-
-    from k8s_llm_scheduler_tpu.engine.persistent.ring import (
-        OP_ADMIT,
-        Command,
-        RingFull,
-    )
-    from k8s_llm_scheduler_tpu.sim.scenarios import ClusterModel
-
-    model = ClusterModel(scenario)
-    placements: dict[str, str] = {}
-    unschedulable: list[str] = []
-    slot_req: dict[int, _ServeReq] = {}
-    free_slots = list(range(_STUB_SLOTS))
-    P = {
-        "admitted_ring": 0,
-        "completed_ring": 0,
-        "completed_fallback": 0,
-        "ring_full_rejects": 0,
-        "tokens_delivered": 0,
-        "tokens_lost": 0,
-        "tokens_duplicated": 0,
-        "tokens_corrupted": 0,
-        "wedges": 0,
-        "drains": 0,
-        "relaunches": 0,
-    }
-    timing = {"command_ring_stalls": 0, "token_ring_stalls": 0,
-              "heartbeats": 0}
-
-    def new_req(pod, snapshot) -> _ServeReq:
-        seed = int(stable_fraction(f"persistent:{pod.name}") * 2**31)
-        candidates = sorted(
-            n.name for n in feasible_nodes(pod.to_pod_spec(), snapshot)
-        )
-        return _ServeReq(pod, seed, _stub_stream(seed), candidates)
-
-    def complete(req: _ServeReq) -> None:
-        monitor.note_tokens(
-            "default", req.pod.name, req.expected, req.delivered
-        )
-        n_exp, n_got = len(req.expected), len(req.delivered)
-        P["tokens_delivered"] += n_got
-        if n_got < n_exp:
-            P["tokens_lost"] += n_exp - n_got
-        elif n_got > n_exp:
-            P["tokens_duplicated"] += n_got - n_exp
-        elif req.delivered != req.expected:
-            P["tokens_corrupted"] += 1
-        P["completed_fallback" if req.via_fallback
-          else "completed_ring"] += 1
-        if req.slot >= 0:
-            slot_req.pop(req.slot, None)
-            free_slots.append(req.slot)
-            free_slots.sort()
-            req.slot = -1
-        if not req.candidates:
-            unschedulable.append(req.pod.name)
-            return
-        stream = req.delivered or req.expected
-        node = req.candidates[
-            (stream[0] + sum(stream)) % len(req.candidates)
-        ]
-        placements[req.pod.name] = node
-        model.place(req.pod, node)
-        monitor.note_bind(True, "default", req.pod.name, node)
-
-    def fallback_finish(req: _ServeReq) -> None:
-        """The dispatch path finishes (or fully serves) the request:
-        deterministic continuation from wherever the ring left it."""
-        req.delivered.extend(req.expected[len(req.delivered):])
-        req.via_fallback = True
-        complete(req)
-
-    def book_batch(batch) -> None:
-        for s in range(batch.emitted.shape[0]):
-            req = slot_req.get(s)
-            row = [int(t) for t in batch.emitted[s] if int(t) >= 0]
-            if not row:
-                continue
-            if req is None:
-                # emissions for a slot nobody owns: double-delivery
-                P["tokens_duplicated"] += len(row)
-                monitor.record(
-                    "token_integrity", f"slot-{s}",
-                    f"{len(row)} emission(s) for an unowned slot",
-                )
-                continue
-            req.delivered.extend(row)
-            if len(req.delivered) >= len(req.expected):
-                complete(req)
-
-    async def settle_ring(loop, timeout_s: float) -> bool:
-        deadline = time.monotonic() + timeout_s
-        while slot_req and time.monotonic() < deadline:
-            for batch in loop.tokens.drain(0.02):
-                book_batch(batch)
-            await asyncio.sleep(0)
-        return not slot_req
-
-    def retire(loop) -> None:
-        timing["command_ring_stalls"] += loop.commands.stalls
-        timing["token_ring_stalls"] += loop.tokens.stalls
-        timing["heartbeats"] += loop.heartbeat.beats
-
-    async def watchdog_drain(loop) -> None:
-        """The wedge path: wait for the REAL Heartbeat watchdog to trip
-        (not the harness's knowledge of the schedule), then gracefully
-        drain — stop the thread, harvest every emission already in the
-        token ring, recover never-taken commands, and hand everything
-        still incomplete back to the dispatch path."""
-        t_end = time.monotonic() + 5.0
-        while (not loop.heartbeat.wedged(_WEDGE_TIMEOUT_S)
-               and time.monotonic() < t_end):
-            await asyncio.sleep(0.01)
-        P["wedges"] += 1
-        loop._stop = True
-        loop._thread.join(2.0)
-        # Black-box dump at the latch, before any drain mutates state —
-        # the same order the real server uses (force_stop dumps first).
-        # Parked work is deterministic here (wedge windows are ordered
-        # before stall windows, so the plane settled), and the dump
-        # rides report["persistent"] into the byte-replayable trace.
-        loop.blackbox.record({
-            "event": "wedge_drain",
-            "parked": sorted(
-                req.pod.name for req in slot_req.values()
-            ),
-        })
-        P["blackbox"] = loop.blackbox.dump(reason="wedge")
-        for batch in loop.tokens.drain(0.0):
-            book_batch(batch)
-        while True:
-            cmd = loop.commands.take()
-            if cmd is None:
-                break
-            req = slot_req.get(cmd.slot)
-            if req is not None:
-                fallback_finish(req)
-        for req in list(slot_req.values()):
-            fallback_finish(req)
-        loop.commands.close()
-        loop.tokens.close()
-        retire(loop)
-        P["drains"] += 1
-
-    seam = injector.seam("persistent")
-    loop: _StubResidentLoop | None = _StubResidentLoop()
-    waves_out: list[dict] = []
-    try:
-        for wave_idx, wave in enumerate(scenario.waves):
-            injector.begin_wave(wave_idx)
-            model.apply_churn(scenario.churn_for_wave(wave_idx))
-            inj_before = dict(injector.injection_counts())
-            ring_full = seam.should("ring_full") is not None
-            stall = seam.should("consumer_stall") is not None
-            wedge = seam.should("loop_wedge") is not None
-            t0 = time.perf_counter()  # graftlint: ok[wall-clock-in-replay] — wave/recovery timing rides the report only; build_chaos_trace strips wall_ms before canonicalizing
-            n_ring = n_fb = 0
-            if not wave:
-                waves_out.append({"wave": wave_idx, "n_pods": 0})
-                continue
-            if wedge:
-                if loop is not None:
-                    loop.wedged = True
-                    await watchdog_drain(loop)
-                    loop = None
-                # the loop is down for the window: the whole wave rides
-                # the dispatch path
-                snapshot = model.metrics()
-                for pod in wave:
-                    fallback_finish(new_req(pod, snapshot))
-                    n_fb += 1
-            else:
-                if loop is None:
-                    loop = _StubResidentLoop()
-                    P["relaunches"] += 1
-                loop.pause_polls = ring_full
-                # heal first: parked work from a previous window must
-                # resolve before this wave admits (serialized, so the
-                # per-wave books stay deterministic)
-                if slot_req and not stall:
-                    await settle_ring(loop, wave_timeout_s)
-                snapshot = model.metrics()
-                # stalled consumer: bound admitted-but-unharvested work
-                # to the command ring's capacity (the production
-                # feeder's parking bound) — the overflow rides the
-                # dispatch path by PLAN, not by who lost the race
-                quota = loop.commands.capacity if stall else None
-                for pod in wave:
-                    req = new_req(pod, snapshot)
-                    if (quota is not None and n_ring >= quota) \
-                            or not free_slots:
-                        fallback_finish(req)
-                        n_fb += 1
-                        continue
-                    slot = free_slots.pop(0)
-                    cmd = Command(
-                        op=OP_ADMIT,
-                        tokens=np.array([[req.seed]], dtype=np.int32),
-                        suffix_len=1, slot=slot,
-                        budget=len(req.expected),
-                    )
-                    try:
-                        loop.commands.put(
-                            cmd, timeout_s=0.05 if ring_full else 5.0
-                        )
-                    except RingFull:
-                        P["ring_full_rejects"] += 1
-                        free_slots.append(slot)
-                        free_slots.sort()
-                        fallback_finish(req)
-                        n_fb += 1
-                        continue
-                    req.slot = slot
-                    slot_req[slot] = req
-                    P["admitted_ring"] += 1
-                    n_ring += 1
-                if not stall and not ring_full:
-                    await settle_ring(loop, wave_timeout_s)
-                # ring_full / stall waves leave their admitted work
-                # parked (commands queued / emissions unharvested); the
-                # next wave's heal pass resolves it
-            waves_out.append({
-                "wave": wave_idx,
-                "n_pods": len(wave),
-                "n_bound": sum(
-                    1 for p in wave if p.name in placements
-                ),
-                "n_ring": n_ring,
-                "n_fallback": n_fb,
-                "parked": len(slot_req),
-                "wall_ms": round(
-                    (time.perf_counter() - t0) * 1000.0, 3  # graftlint: ok[wall-clock-in-replay] — wave/recovery timing rides the report only; build_chaos_trace strips wall_ms before canonicalizing
-                ),
-                "injections": _delta(
-                    dict(injector.injection_counts()), inj_before
-                ),
-            })
-        injector.end_run()
-
-        if loop is not None:
-            loop.pause_polls = False
-            await settle_ring(loop, wave_timeout_s)
-            for req in list(slot_req.values()):
-                fallback_finish(req)   # defensive: never hit on a
-                # healthy plane — the final heal drains everything
-            loop.shutdown()
-            retire(loop)
-            loop = None
-        all_pods = [p for wave in scenario.waves for p in wave]
-        monitor.finalize(
-            expected=[("default", p.name) for p in all_pods],
-            pending=[("default", n) for n in unschedulable],
-        )
-        return {
-            "placements": dict(sorted(placements.items())),
-            "unschedulable": sorted(unschedulable),
-            "waves": waves_out,
-            "client": {"serving": dict(timing)},
-            "persistent": P,
-        }
-    finally:
-        injector.end_run()
-        if loop is not None:
-            loop.shutdown()
-            retire(loop)
-
-
 # ------------------------------------------------------------------- runner
 def run_chaos(
     regime: str,
@@ -1807,11 +1335,6 @@ def run_chaos(
             scenario, plan, injector, monitor,
             deadline_ms=deadline_ms, wave_timeout_s=wave_timeout_s,
         ))
-    elif mode == "persistent":
-        stack = asyncio.run(_run_persistent_stack(
-            scenario, plan, injector, monitor,
-            deadline_ms=deadline_ms, wave_timeout_s=wave_timeout_s,
-        ))
     else:
         stack = asyncio.run(_run_wire_stack(
             scenario, plan, injector, monitor,
@@ -1866,14 +1389,6 @@ def run_chaos(
         # pins the degradation path, and kv_mismatches pins the zero-
         # correctness-loss invariant
         report["kvplane"] = stack["kvplane"]
-    if "persistent" in stack:
-        # persistent mode: the serving plane's protocol outcome
-        # (ring/fallback routing, token-integrity totals, wedge/drain/
-        # relaunch counts) is deterministic by the stack's admission
-        # discipline and rides the trace; ring stall counters and
-        # heartbeat totals are thread-timing and stay report-only
-        # (under report["client"]["serving"])
-        report["persistent"] = stack["persistent"]
     if quality:
         report["quality"] = _quality_vs_teacher(scenario, scores)
     return report
@@ -1979,11 +1494,6 @@ def build_chaos_trace(report: dict) -> dict:
         # the correctness-mismatch count); byte-identity across runs
         # pins the plane's degradation behaviour under the regime
         trace["kvplane"] = report["kvplane"]
-    if "persistent" in report:
-        # deterministic serving-plane outcome: which requests rode the
-        # rings vs the dispatch path, and the zero-loss/zero-duplicate
-        # token books; byte-identity across runs pins the ring protocol
-        trace["persistent"] = report["persistent"]
     return trace
 
 
@@ -2070,10 +1580,6 @@ def replay_chaos_trace(trace: dict) -> dict:
         # same contract: run-recorded protocol counters, carried
         # verbatim — byte-identity across RUNS pins them
         out["kvplane"] = dict(trace["kvplane"])
-    if "persistent" in trace:
-        # same contract: run-recorded ring-protocol books, carried
-        # verbatim — byte-identity across RUNS pins them
-        out["persistent"] = dict(trace["persistent"])
     return out
 
 

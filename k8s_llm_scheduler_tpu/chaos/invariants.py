@@ -68,15 +68,6 @@ INVARIANTS = (
     # monitor never saw bind. Judged at finalize_journal against the
     # STORE (the cluster lookup), not the journal's own claims.
     "journal_consistency",
-    # persistent-serving round: the token stream delivered for one
-    # serving request (through the device->host TokenRing, the
-    # dispatch-path fallback, or a watchdog drain that splits a request
-    # across both) must be byte-identical to the expected stream — a
-    # shortfall is a LOST emission, an overrun a DOUBLE-delivered one,
-    # and a value divergence is stream corruption (a slot-reuse or
-    # sequence bug). Checked per request via note_tokens as the chaos
-    # harness books completions.
-    "token_integrity",
 )
 
 # legal breaker edges (core/breaker.py state machine); reset() is
@@ -222,38 +213,6 @@ class InvariantMonitor:
                 f"fleet size {n_replicas} outside configured clamp "
                 f"[{min_replicas}, {max_replicas}]",
             )
-
-    # --------------------------------------------------------------- tokens
-    def note_tokens(
-        self, namespace: str, name: str,
-        expected: Any, delivered: Any,
-    ) -> None:
-        """Persistent-plane accounting (see the token_integrity entry in
-        INVARIANTS): called once per serving request as the chaos
-        harness books its completion, with the stream the request was
-        SUPPOSED to produce and the stream that actually arrived —
-        whether it rode the TokenRing, the dispatch-path fallback, or a
-        watchdog drain splitting it across both."""
-        self._check("token_integrity")
-        expected = list(expected)
-        delivered = list(delivered)
-        if delivered == expected:
-            return
-        n_exp, n_got = len(expected), len(delivered)
-        if n_got < n_exp:
-            detail = (
-                f"{n_exp - n_got} emission(s) lost "
-                f"({n_got}/{n_exp} delivered)"
-            )
-        elif n_got > n_exp:
-            detail = f"{n_got - n_exp} emission(s) double-delivered"
-        else:
-            diverge = next(
-                i for i, (a, b) in enumerate(zip(expected, delivered))
-                if a != b
-            )
-            detail = f"delivered stream diverges at position {diverge}"
-        self.record("token_integrity", f"{namespace}/{name}", detail)
 
     # ---------------------------------------------------------------- cache
     def wrap_cache(self, cache: Any) -> "MonitoredCache":

@@ -70,20 +70,6 @@ def _backend_kwargs(cfg: Config, **overrides) -> dict:
         # fused on-device decode runtime (engine/fused/)
         fused_decode=bool(cfg.get("llm.fused_decode", True)),
         top_k=int(cfg.get("llm.top_k", 0)),
-        # persistent device-resident serving loop (engine/persistent/)
-        persistent_loop=bool(cfg.get("llm.persistent_loop", False)),
-        persistent_suffix_bucket=cfg.get(
-            "llm.persistent_suffix_bucket", None
-        ),
-        # in-loop telemetry plane (observability/resident.py): device
-        # counters + stats ring + wedge black-box, zero extra dispatches
-        persistent_telemetry=bool(cfg.get("llm.persistent_telemetry", True)),
-        persistent_stats_every=int(
-            cfg.get("llm.persistent_stats_every", 8)
-        ),
-        persistent_blackbox_depth=int(
-            cfg.get("llm.persistent_blackbox_depth", 64)
-        ),
         # delta-prefill admission plane (engine/admission/, sched/delta.py)
         packed_admission=bool(cfg.get("admission.packed", True)),
         admission_chunk_tokens=int(cfg.get("admission.chunk_tokens", 256)),
@@ -275,13 +261,6 @@ async def _run_scheduler(
             window=int(cfg.get("observability.profiler_window", 256)),
         )
         engine.attach_profiler(profiler)
-        if getattr(engine, "persistent_loop", False):
-            # In-loop decision latency from device counters
-            # (admission-to-first-emission iteration stamps): attached by
-            # the scheduler as a synthetic loop_resident span per
-            # LLM decision, so flight-recorder traces decompose resident
-            # decisions without any host timer in the loop.
-            scheduler.resident_latency_fn = engine.resident_decision_latency
 
     # SLO burn-rate engine (observability/slo.py): declarative objectives
     # from the `slo` config block evaluated over multi-window burn rates;
@@ -299,15 +278,7 @@ async def _run_scheduler(
     slo_stats_provider = scheduler.get_stats
     if profiler is not None:
         def slo_stats_provider(_base=scheduler.get_stats, _prof=profiler):
-            # `persistent` mounts the resident-loop gauge family so
-            # config-declared objectives can reference e.g. a throughput
-            # floor on persistent.tokens_total or an error-rate on
-            # engine.persistent_wedges without a custom provider.
-            return {
-                **_base(),
-                "engine_profile": _prof.gauges(),
-                "persistent": _prof.persistent_gauges(),
-            }
+            return {**_base(), "engine_profile": _prof.gauges()}
 
     slo_engine = slo_mod.from_config(cfg.section("slo"), slo_stats_provider)
     if slo_engine is not None:
@@ -360,11 +331,6 @@ async def _run_scheduler(
             ):
                 return {**_base(), "engine_telemetry": _sampler.latest()}
 
-        blackbox_provider = None
-        if engine is not None and getattr(engine, "persistent_loop", False):
-            # /debug/blackbox: last-N resident-loop iteration snapshots,
-            # dumped on watchdog latch or quiesce (engine/persistent/).
-            blackbox_provider = engine.persistent_blackbox
         metrics_server = MetricsServer(
             stats_provider,
             port=cfg.get("metrics.port"),
@@ -372,7 +338,6 @@ async def _run_scheduler(
             engine_sampler=sampler,
             engine_profiler=profiler,
             slo_engine=slo_engine,
-            blackbox_provider=blackbox_provider,
         )
         metrics_server.start()
 

@@ -58,9 +58,8 @@ DEFAULTS: dict[str, Any] = {
         "temperature": 0.3,  # config.yaml:13
         "max_tokens": 200,  # config.yaml:14
         "constrained_json": True,
-        # --- TPU engine geometry (north star: mesh/sharding/max_batch) ---
+        # --- TPU engine geometry (north star: mesh/max_batch) ---
         "mesh": {"dp": 1, "tp": 1},
-        "sharding": "tensor_parallel",
         "max_batch": 8,
         "page_size": 128,
         "max_pages_per_seq": 64,
@@ -124,16 +123,6 @@ DEFAULTS: dict[str, Any] = {
         # top-k sampling cut applied INSIDE the fused loop (0 = full
         # distribution; greedy decode is unaffected by construction)
         "top_k": 0,
-        # --- persistent device-resident serving loop (engine/persistent/):
-        # ONE long-lived program subsumes admission prefill + fused decode
-        # micro-chunks; steady-state decisions pay zero XLA dispatches.
-        # Off by default until the truth round lands it as the default
-        # serving mode. ---
-        "persistent_loop": False,
-        # admission suffix bucket of the resident loop's fixed-shape
-        # ADMIT (None = smallest prefill bucket; must be a page-size
-        # multiple — suffixes past it fall back to the dispatch path)
-        "persistent_suffix_bucket": None,
     },
     # Delta-prefill admission plane (engine/admission/ + sched/delta.py):
     # packed chunked admission for batch surfaces, and snapshot-delta
@@ -459,7 +448,6 @@ ENV_OVERRIDES: dict[str, str] = {
     "SPEC_ARM": "llm.spec_arm",
     "FUSED_DECODE": "llm.fused_decode",
     "LLM_TOP_K": "llm.top_k",
-    "PERSISTENT_LOOP": "llm.persistent_loop",
     "SPEC_K": "llm.spec_k",
     "SPEC_DRAFT_MODEL": "llm.spec_draft_model",
     "SPEC_DRAFT_CHECKPOINT": "llm.spec_draft_checkpoint",

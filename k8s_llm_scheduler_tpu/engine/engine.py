@@ -73,15 +73,6 @@ from k8s_llm_scheduler_tpu.engine.constrained import (
 )
 from k8s_llm_scheduler_tpu.observability import spans
 from k8s_llm_scheduler_tpu.engine.kv_cache import PagedKVCache
-from k8s_llm_scheduler_tpu.engine.persistent.ring import OP_ADMIT
-from k8s_llm_scheduler_tpu.observability.resident import (
-    CTR_ADMITS,
-    CTR_IDLE_CHUNKS,
-    CTR_ITERS,
-    CTR_STEPS,
-    N_COUNTERS,
-    counters_dict,
-)
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
 from k8s_llm_scheduler_tpu.models import family
 from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, MlaMoeConfig
@@ -642,19 +633,13 @@ class InferenceEngine:
         fused_decode: bool = True,
         top_k: int = 0,
         fused_table_bytes: int | None = None,
-        persistent_loop: bool = False,
-        persistent_suffix_bucket: int | None = None,
-        persistent_wedge_timeout_s: float = 30.0,
-        persistent_telemetry: bool = True,
-        persistent_stats_every: int = 8,
-        persistent_blackbox_depth: int = 64,
     ) -> None:
         self.cfg = cfg
         self.params = params
         # The model module the config's TYPE selects (models.family): its
         # cache tuple's shapes and the three forwards of the decision path.
         # `paged` = the family also has the paged-pool forwards (admit,
-        # decode chunks, the fused and resident loops, spec/): the dense
+        # decode chunks, the fused loop, spec/): the dense
         # family has, the latent one does not — its entry points refuse by
         # name (_require_paged) and no pool is allocated for it.
         self._model = family(cfg)
@@ -687,11 +672,6 @@ class InferenceEngine:
                     f"latent cache has no head axis to shard and the experts' "
                     f"exchange is not written (parallel/sharding.py, "
                     f"engine/sharded/)"
-                )
-            if persistent_loop:
-                raise ValueError(
-                    f"{cfg.name}: llm.persistent_loop is not served — the "
-                    f"resident loop (engine/persistent/) runs the paged pool"
                 )
             if decode_matmul != "dense":
                 raise ValueError(
@@ -891,42 +871,6 @@ class InferenceEngine:
         # variant on demand if it is ever actually needed).
         self._wave_prewarm_failed: set[tuple] = set()
 
-        # Persistent device-resident serving loop (engine/persistent/):
-        # when enabled AND supported, add_requests feeds a command ring
-        # instead of dispatching _admit, and step_persistent() drains the
-        # token ring — ZERO per-decision XLA dispatches in steady state.
-        # The server is built lazily on first enter_persistent (its jit is
-        # cached across residencies); _persistent_wedged latches after a
-        # watchdog drain so a wedging workload stays on the dispatch path.
-        self.persistent_loop = bool(persistent_loop)
-        self.persistent_suffix_bucket = persistent_suffix_bucket
-        # Wedge detection is a DISPATCH-ECONOMICS knob, not a constant: on
-        # TPU a 30s heartbeat gap means the loop is dead, but on a CPU
-        # harness a sibling-geometry compile storm can starve the resident
-        # thread that long while the loop is perfectly healthy — a false
-        # wedge latches persistent OFF for the process.
-        self.persistent_wedge_timeout_s = float(persistent_wedge_timeout_s)
-        self._persistent = None  # PersistentServer | None
-        self._persistent_wedged = False
-        self._pers_tok_last = 0.0  # profiler wall anchor for step_persistent
-        # Device-resident telemetry plane (observability/resident.py): the
-        # loop carries an in-loop counter block exported through the
-        # StatsRing; step_persistent decomposes loop_resident from the
-        # counter DELTAS between windows (baselines below), books the new
-        # persistent sub-segments, and keeps an EWMA of in-loop
-        # per-decision latency for the scheduler's synthetic spans.
-        self.persistent_telemetry = bool(persistent_telemetry)
-        self.persistent_stats_every = int(persistent_stats_every)
-        self.persistent_blackbox_depth = int(persistent_blackbox_depth)
-        self._pers_ctr_last = np.zeros(N_COUNTERS, dtype=np.int64)
-        self._pers_stall_last = 0
-        self._pers_ctr_final: dict[str, int] | None = None
-        self._resident_latency_ms: float | None = None
-        # Completions recovered by an implicit drain (exit_persistent
-        # inside a dispatch-path entry point) park here until the next
-        # harvesting call returns them — never silently dropped.
-        self._pending_finished: list[Finished] = []
-
         # Grammar tables (sparse, vocab-independent; content swaps without
         # recompiling for a same-K grammar — see SparseDFATables).
         self._constrained = False
@@ -1031,18 +975,9 @@ class InferenceEngine:
             "fused_fallbacks": 0,
             # Every XLA dispatch this engine issues on a serving path
             # (admission, decode chunks, waves, prefix prefills, packed
-            # admission, persistent launch). dispatches_per_decision is
-            # THE persistent-loop proof metric: the delta over a window of
-            # completions, exported by the profiler — 0 in persistent
-            # steady state because admission/decode/emission all happen
-            # inside the one resident program.
+            # admission). The profiler exports its delta over a window
+            # of completions as dispatches_per_decision.
             "dispatches": 0,
-            "persistent_launches": 0,
-            "persistent_admissions": 0,
-            "persistent_steps": 0,
-            "persistent_chunks": 0,
-            "persistent_fallbacks": 0,
-            "persistent_wedges": 0,
         }
         # Decision-flow books for the dispatches_per_decision gauge:
         # deltas since the last completed decision were booked.
@@ -1061,11 +996,6 @@ class InferenceEngine:
         emitted pads would be dropped from output and max_new_tokens
         accounting (generate() could spin forever on a pad-argmaxing
         model)."""
-        if self.persistent_active:
-            # The resident loop pinned the OLD grammar's dense table (and
-            # dfa_start) at launch — drain before swapping tables so no
-            # admission is sampled under a stale grammar.
-            self.exit_persistent()
         # Fused-runtime table state resets with the grammar: the dense
         # table is built lazily on the first fused chunk (engine/fused/
         # tables.py caches per DFA, so reinstalls of a cached grammar
@@ -1146,10 +1076,6 @@ class InferenceEngine:
         prefill; longer ones (the 256-node cluster-state prompt is ~40k
         byte-tokens, SURVEY §5 long-context) take the CHUNKED path — see
         _prefill_prefix_chunked."""
-        if self.persistent_active:
-            # The resident loop pinned the OLD prefix KV at launch — every
-            # in-loop admission prefills against it. Drain before swapping.
-            self.exit_persistent()
         if self._by_slot:
             raise RuntimeError("cannot switch prefix with requests in flight")
         if not prompt_ids:
@@ -1542,16 +1468,6 @@ class InferenceEngine:
             )
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
-        if self.persistent_active:
-            # Resident-loop admission: slot allocation is host work and
-            # the prefill happens IN the loop — zero dispatches. Shapes
-            # the loop can't serve (suffix past its admission bucket)
-            # drain it and fall through to the dispatch path below.
-            limit = self.persistent_suffix_limit(max_new_tokens)
-            if all(len(p) <= limit for p in prompts):
-                return self._add_requests_persistent(prompts, max_new_tokens)
-            self.stats["persistent_fallbacks"] += 1
-            self.exit_persistent()
         prefix = self._prefix or self._get_empty_prefix()
         self._prefix = prefix
 
@@ -1683,11 +1599,6 @@ class InferenceEngine:
                     f"prompt of {len(ids)} tokens exceeds the paged "
                     f"admission limit {limit}"
                 )
-        if self.persistent_active:
-            # Packed admission mutates paged KV + slot state via its own
-            # dispatches — it cannot run beside the resident loop.
-            self.stats["persistent_fallbacks"] += 1
-            self.exit_persistent()
         prof = self.profiler
         t0 = time.perf_counter() if prof is not None else 0.0
         chunk_prefill_s = 0.0
@@ -2187,19 +2098,15 @@ class InferenceEngine:
         """Run `chunks` fused decode chunks back-to-back (no intermediate
         sync), then ONE host sync; returns requests that finished."""
         self._require_paged("step() (chunked decode)")
-        if self.persistent_active:
-            self.exit_persistent()
-        pend = self._pending_finished
-        self._pending_finished = []
         if not self._by_slot:
-            return pend
+            return []
         with spans.span("decode_chunk", layer="engine", chunks=chunks) as sp:
             before = self.stats["decode_tokens"]
             finished = self._step_inner(chunks)
             if sp is not None:
                 sp.attrs["finished"] = len(finished)
                 sp.attrs["tokens"] = self.stats["decode_tokens"] - before
-        return pend + finished
+        return finished
 
     def _chunk_dispatch(self, prefix: _PrefixKV) -> jax.Array:
         """Dispatch ONE fused decode chunk (no host sync); returns the
@@ -2419,15 +2326,11 @@ class InferenceEngine:
         actually emitted, never chunk capacity. Falls back to step() when
         the fused runtime can't serve (_fused_ready)."""
         self._require_paged("step_fused() (the fused decode loop)")
-        if self.persistent_active:
-            self.exit_persistent()
-        pend = self._pending_finished
-        self._pending_finished = []
         if not self._by_slot:
-            return pend
+            return []
         if not self._fused_ready():
             self.stats["fused_fallbacks"] += 1
-            return pend + self.step(chunks)
+            return self.step(chunks)
         prof = self.profiler
         t0 = time.perf_counter() if prof is not None else 0.0
         with spans.span(
@@ -2440,7 +2343,7 @@ class InferenceEngine:
                 sp.attrs["finished"] = len(finished)
                 sp.attrs["tokens"] = self.stats["decode_tokens"] - tok_before
                 sp.attrs["steps"] = self.stats["fused_steps"] - step_before
-        return pend + finished
+        return finished
 
     def _step_fused_inner(self, chunks: int, prof, t0: float) -> list[Finished]:
         prefix = self._prefix or self._get_empty_prefix()
@@ -2491,15 +2394,11 @@ class InferenceEngine:
         completion within the dispatched chunks. Falls back to a step()
         drain when the fused runtime can't serve."""
         self._require_paged("decode_fused() (the fused decode loop)")
-        if self.persistent_active:
-            self.exit_persistent()
-        pend = self._pending_finished
-        self._pending_finished = []
         if not self._by_slot:
-            return pend
+            return []
         if not self._fused_ready():
             self.stats["fused_fallbacks"] += 1
-            out: list[Finished] = list(pend)
+            out: list[Finished] = []
             # external (spec-driven) requests never finish through step()
             # — draining on them would spin forever
             while any(not r.external for r in self._by_slot.values()):
@@ -2513,7 +2412,7 @@ class InferenceEngine:
             if sp is not None:
                 sp.attrs["finished"] = len(finished)
                 sp.attrs["tokens"] = self.stats["decode_tokens"] - before
-        return pend + finished
+        return finished
 
     def _decode_fused_inner(self) -> list[Finished]:
         prof = self.profiler
@@ -2564,382 +2463,6 @@ class InferenceEngine:
             )
         return finished
 
-    # ------------------------------------------------- persistent serving
-    def persistent_supported(self) -> bool:
-        """Whether the resident loop can serve the CURRENT engine state.
-        False routes to the dispatch path: flag off, a prior wedge
-        (latched — a wedging workload must not relaunch-thrash), a
-        speculative decoder attached (spec drives slots externally and
-        composes with the dispatch path only), or the fused runtime
-        unavailable (the loop body IS the fused chunk body)."""
-        if not self.persistent_loop or self._persistent_wedged:
-            return False
-        if self.spec is not None:
-            return False
-        return self._fused_ready()
-
-    @property
-    def persistent_active(self) -> bool:
-        return self._persistent is not None and self._persistent.running
-
-    def persistent_suffix_limit(self, max_new_tokens: int) -> int:
-        """Largest suffix the resident loop's fixed-shape ADMIT can carry
-        (its static bucket, tightened by the paged budget bound). Callers
-        routing work pre-filter on this so an oversized suffix rides the
-        dispatch path instead of draining the loop mid-burst."""
-        if self._persistent is not None:
-            bucket = self._persistent.suffix_bucket
-        else:
-            bucket = self.persistent_suffix_bucket or self.prefill_buckets[0]
-        return min(bucket, self.max_suffix_tokens(max_new_tokens))
-
-    def enter_persistent(self) -> bool:
-        """Launch the resident serving loop (engine/persistent/) over this
-        engine's buffers. ONE dispatch; every subsequent admission/decode/
-        emission until exit_persistent is ring traffic. Returns False when
-        unsupported (caller stays on the dispatch path)."""
-        self._require_paged("enter_persistent() (the resident loop)")
-        if self.persistent_active:
-            return True
-        if not self.persistent_supported():
-            return False
-        if self._persistent is None:
-            from k8s_llm_scheduler_tpu.engine.persistent.server import (
-                PersistentServer,
-            )
-
-            self._persistent = PersistentServer(
-                self,
-                suffix_bucket=self.persistent_suffix_bucket,
-                wedge_timeout_s=self.persistent_wedge_timeout_s,
-                telemetry=self.persistent_telemetry,
-                stats_every=self.persistent_stats_every,
-                blackbox_depth=self.persistent_blackbox_depth,
-            )
-        self._persistent.launch()
-        # Fresh residency, fresh counter baselines: the device counter
-        # block restarts at zero each launch, so the host delta books
-        # must too.
-        self._pers_ctr_last = np.zeros(N_COUNTERS, dtype=np.int64)
-        self._pers_stall_last = self._persistent.tokens.stalls
-        self._pers_ctr_final = None
-        self.stats["persistent_launches"] += 1
-        self.stats["dispatches"] += 1
-        # Re-baseline the decision-flow books at the mode transition: the
-        # launch dispatch (and any setup dispatches since the last
-        # completion window, e.g. a prefix re-prefill) amortize over the
-        # whole residency — charging them to the first steady-state
-        # window would make the zero-dispatch gauge read >0 by setup.
-        self._flow_dispatches_last = self.stats["dispatches"]
-        self._pers_tok_last = time.perf_counter()
-        return True
-
-    def exit_persistent(self) -> None:
-        """Quiesce the resident loop and rebind every donated buffer from
-        its final carry, so the dispatch path resumes EXACTLY where the
-        loop left off (mid-stream slots keep decoding token-identically —
-        the hot-swap/run_quiesced composition). Completions recovered by
-        the final harvest park in _pending_finished for the next
-        harvesting call."""
-        if not self.persistent_active:
-            return
-        srv = self._persistent
-        final = srv.quiesce()
-        (k, v, _pages, tok, pos, act, st, budget, rng, _total,
-         ctr, _slot_tok, _admit_iter, _first_emit) = final
-        # The final carry holds the residency's EXACT device counter
-        # totals (the StatsRing only samples every stats_every pushes):
-        # book them for the reconciliation pin — emitted must equal the
-        # decode tokens harvested off the ring, token for token.
-        self._pers_ctr_final = counters_dict(np.asarray(ctr))
-        srv.stats_ring.clear_parked()
-        self.kv.k, self.kv.v = k, v
-        # The loop's carried page tables mirror the host allocator row for
-        # row (admissions wrote the same rows from the same allocation),
-        # so the host tables stay authoritative; drop the carried copy and
-        # let _padded_tables rebuild its padded mirror on demand.
-        self._tables_src = None
-        self._tables_padded = None
-        self._tok_d, self._pos_d = tok, pos
-        self._act_d, self._st_d, self._budget_d = act, st, budget
-        self._rng = rng
-        self._pending_finished.extend(
-            self._persistent_harvest(srv.harvest_steady(0.0))
-        )
-        # A force-stopped (wedged) loop can leave ADMIT commands undrained
-        # in the ring: those requests never reached the device. Free their
-        # slots and finish them truncated (no emitted token is ever lost —
-        # these never emitted) instead of leaving the caller to hang.
-        while (cmd := srv.commands.take()) is not None:
-            if cmd.op != OP_ADMIT:
-                continue
-            req = self._by_slot.pop(cmd.slot, None)
-            if req is None:
-                continue
-            self.kv.free_slot(cmd.slot)
-            self._act_np[cmd.slot] = False
-            self._budget_np[cmd.slot] = 0
-            ids = req.generated[: req.max_new_tokens]
-            self._pending_finished.append(
-                Finished(
-                    req_id=req.req_id,
-                    token_ids=ids,
-                    text=self.tokenizer.decode(ids),
-                    latency_ms=(time.perf_counter() - req.submitted_at)
-                    * 1000.0,
-                )
-            )
-            self.stats["completed"] += 1
-
-    def step_persistent(self, timeout_s: float = 0.05) -> list[Finished]:
-        """Steady-state persistent tick: drain the token ring, book the
-        emissions, return completions. ZERO XLA dispatches — pure ring
-        traffic (graftlint's dispatch-in-persistent-path rule sweeps the
-        reachable call graph). Also the wedge watchdog: a loop that stops
-        servicing its callbacks gets force-stopped and drained back to the
-        dispatch path, latching _persistent_wedged."""
-        out = list(self._pending_finished)
-        self._pending_finished = []
-        if not self.persistent_active:
-            return out
-        srv = self._persistent
-        if srv.wedged():
-            logger.warning(
-                "persistent loop wedged (no callback heartbeat for "
-                "%.0fs) — force-draining back to the dispatch path",
-                srv.wedge_timeout_s,
-            )
-            self.stats["persistent_wedges"] += 1
-            self._persistent_wedged = True
-            srv.force_stop()
-            # The wedge black-box (force_stop just dumped it) rides a
-            # synthetic flight-recorder trace so `cli trace show` and
-            # /debug/export carry the forensics beside the decisions the
-            # wedge stranded.
-            if srv.telemetry and spans.enabled():
-                with spans.start_trace("persistent-wedge", layer="engine") as tr:
-                    if tr is not None:
-                        tr.set_meta(
-                            blackbox=srv.blackbox_dump(),
-                            wedge_timeout_s=srv.wedge_timeout_s,
-                        )
-            self.exit_persistent()
-            out.extend(self._pending_finished)
-            self._pending_finished = []
-            return out
-        prof = self.profiler
-        t0 = time.perf_counter()
-        tok_before = self.stats["decode_tokens"]
-        step_before = self.stats["persistent_steps"]
-        batches = srv.harvest_steady(timeout_s)
-        t1 = time.perf_counter()
-        out.extend(self._persistent_harvest(batches))
-        if prof is not None:
-            now = time.perf_counter()
-            wall = max(now - self._pers_tok_last, 0.0)
-            ring_wait = min(t1 - t0, wall)
-            harvest = min(now - t1, wall - ring_wait)
-            loop_resident = max(wall - ring_wait - harvest, 0.0)
-            prof.on_persistent(
-                wall_s=wall,
-                ring_wait_s=ring_wait,
-                harvest_s=harvest,
-                loop_resident_s=loop_resident,
-                steps=self.stats["persistent_steps"] - step_before,
-                tokens=self.stats["decode_tokens"] - tok_before,
-                batches=len(batches),
-                loop_segments=self._decompose_loop_resident(
-                    srv, loop_resident
-                ),
-            )
-            self._pers_tok_last = now
-        return out
-
-    def _decompose_loop_resident(
-        self, srv, loop_resident_s: float
-    ) -> dict[str, float] | None:
-        """Counter-delta attribution of the opaque `loop_resident` window
-        into PERSISTENT_LOOP_SEGMENTS (admit/decode/ring_stall/idle) —
-        pure ring traffic, zero dispatches.
-
-        Drains the StatsRing and splits the window proportionally to the
-        counter DELTAS since the previous window: decode steps run,
-        admissions taken, token-ring backpressure stalls (a HOST book —
-        the device blocks inside its push callback and cannot count the
-        wait), and idle chunks (iterations whose decode ran zero steps).
-        The split telescopes by construction — the last segment is the
-        exact remainder — so sum == loop_resident holds to float
-        precision and the identity test pins it. Proportional weights
-        are the honest choice HERE: the device cannot timestamp inside
-        one XLA program without paying the dispatch boundaries this
-        subsystem exists to delete, so relative event counts are the
-        only in-loop signal that costs nothing. Also feeds the
-        resident-latency EWMA (admission-to-first-emission iterations x
-        mean iteration wall) the scheduler attaches as synthetic spans.
-        Returns None (sub-books unchanged) when telemetry is off or no
-        snapshot landed this window."""
-        if not srv.telemetry:
-            return None
-        snaps = srv.stats_ring.drain(0.0)
-        if not snaps:
-            return None
-        last = snaps[-1]
-        cur = np.asarray(last.counters, dtype=np.int64)
-        iters_start = int(self._pers_ctr_last[CTR_ITERS])
-        d = cur - self._pers_ctr_last
-        d_stalls = max(int(last.token_stalls) - self._pers_stall_last, 0)
-        self._pers_ctr_last = cur
-        self._pers_stall_last = int(last.token_stalls)
-        d_iters = int(d[CTR_ITERS])
-        weights = {
-            "admit": float(max(int(d[CTR_ADMITS]), 0)),
-            "decode": float(max(int(d[CTR_STEPS]), 0)),
-            "ring_stall": float(d_stalls),
-            "idle": float(max(int(d[CTR_IDLE_CHUNKS]), 0)),
-        }
-        total_w = sum(weights.values())
-        seg: dict[str, float] = {}
-        remaining = max(float(loop_resident_s), 0.0)
-        if total_w <= 0:
-            # A window with no counted events is a parked loop: idle.
-            seg = {"admit": 0.0, "decode": 0.0, "ring_stall": 0.0}
-        else:
-            for name in ("admit", "decode", "ring_stall"):
-                share = loop_resident_s * weights[name] / total_w
-                share = min(share, remaining)
-                seg[name] = share
-                remaining -= share
-        seg["idle"] = remaining  # exact remainder: sum == loop_resident
-        if d_iters > 0:
-            mean_iter_ms = loop_resident_s / d_iters * 1000.0
-            a_it = np.asarray(last.admit_iter)
-            f_em = np.asarray(last.first_emit)
-            fresh = (a_it >= iters_start) & (f_em >= a_it)
-            if fresh.any():
-                lat_iters = float((f_em[fresh] - a_it[fresh] + 1).mean())
-                lat_ms = lat_iters * mean_iter_ms
-                if self._resident_latency_ms is None:
-                    self._resident_latency_ms = lat_ms
-                else:
-                    self._resident_latency_ms = (
-                        0.7 * self._resident_latency_ms + 0.3 * lat_ms
-                    )
-        return seg
-
-    def resident_decision_latency(self) -> float | None:
-        """EWMA of in-loop per-decision latency (ms): admission-to-first-
-        emission iterations x mean resident iteration wall, derived from
-        the counter deltas. None until a ring-served admission completed
-        a telemetry window. sched/loop.py attaches this as a synthetic
-        `loop_resident` span so traces explain ring-served decisions."""
-        return self._resident_latency_ms
-
-    def persistent_counter_totals(self) -> dict[str, int] | None:
-        """Exact device counter totals of the last drained residency
-        (from the final carry, not the sampled StatsRing) — the
-        reconciliation pin: `emitted` equals the decode tokens harvested
-        off the token ring for that residency."""
-        return self._pers_ctr_final
-
-    def persistent_blackbox(self) -> dict | None:
-        """Latest wedge/quiesce black-box dump (what /debug/blackbox
-        serves); None before the first residency or with telemetry off."""
-        if self._persistent is None or not self._persistent.telemetry:
-            return None
-        return self._persistent.blackbox_dump()
-
-    def _persistent_harvest(self, batches) -> list[Finished]:
-        """Book a sequence of ring batches (in push order) into request
-        streams — the persistent twin of _finish_harvest. Batches are
-        processed one at a time because a slot can finish AND be re-used
-        by a later in-window admission: per-batch booking keeps each
-        occupant's tokens separate (the TokenRing seq check already
-        guarantees no batch was lost or duplicated)."""
-        finished: list[Finished] = []
-        pad = self.tokenizer.pad_id
-        for b in batches:
-            if b.admit_slot >= 0:
-                req = self._by_slot.get(b.admit_slot)
-                if req is not None and req.first_pending:
-                    req.generated.append(int(b.first_tok))
-                    req.first_pending = False
-            self._act_np = np.array(b.act)
-            self._budget_np = np.array(b.budget)
-            self.stats["persistent_steps"] += int(b.steps_run)
-            self.stats["persistent_chunks"] += 1
-            for slot, req in list(self._by_slot.items()):
-                if req.external:
-                    continue
-                if req.first_pending:
-                    # Admitted via the ring but its admission batch is
-                    # later in the stream: this batch predates the
-                    # request (its rows are a previous occupant's pads
-                    # and its act/budget books don't cover it yet).
-                    continue
-                emitted = [int(t) for t in b.emitted[slot] if t != pad]
-                req.generated.extend(emitted)
-                self.stats["decode_tokens"] += len(emitted)
-                if not self._act_np[slot] or self._budget_np[slot] <= 0:
-                    req.done = True
-                    self.kv.free_slot(slot)
-                    del self._by_slot[slot]
-                    ids = req.generated[: req.max_new_tokens]
-                    finished.append(
-                        Finished(
-                            req_id=req.req_id,
-                            token_ids=ids,
-                            text=self.tokenizer.decode(ids),
-                            latency_ms=(
-                                time.perf_counter() - req.submitted_at
-                            ) * 1000.0,
-                        )
-                    )
-                    self.stats["completed"] += 1
-        self._book_decision_flow()
-        return finished
-
-    def _add_requests_persistent(
-        self, prompts: list[list[int]], max_new_tokens: int
-    ) -> list[int]:
-        """Ring-routed admission: slot/page allocation is pure host work,
-        the suffix prefill + first-token sample happen INSIDE the resident
-        loop (OP_ADMIT). Zero dispatches."""
-        srv = self._persistent
-        reqs: list[_Request] = []
-        for ids in prompts:
-            n = len(ids)
-            slot = self.kv.allocate_slot(n, reserve_decode=max_new_tokens + 1)
-            row = np.zeros(self.kv.max_pages_per_seq, dtype=np.int32)
-            info_pages = self.kv.slot_pages(slot)
-            row[: len(info_pages)] = info_pages
-            n_blocks = srv.suffix_bucket // self.kv.page_size
-            page_ids = np.zeros((1, n_blocks), dtype=np.int32)
-            used = min(self.kv.pages_needed(n), n_blocks)
-            page_ids[0, :used] = info_pages[:used]
-            try:
-                srv.admit_steady(
-                    ids, slot, max_new_tokens - 1, page_ids, row
-                )
-            except Exception:
-                self.kv.free_slot(slot)
-                raise
-            req = _Request(
-                req_id=self._req_counter,
-                slot=slot,
-                prompt_len=n,
-                max_new_tokens=max_new_tokens,
-            )
-            self._req_counter += 1
-            self._by_slot[slot] = req
-            # Optimistic mirrors until the admission batch tells the truth.
-            self._act_np[slot] = True
-            self._budget_np[slot] = max_new_tokens - 1
-            reqs.append(req)
-        self.stats["requests"] += len(reqs)
-        self.stats["persistent_admissions"] += len(reqs)
-        self.stats["prefill_tokens"] += sum(len(p) for p in prompts)
-        return [r.req_id for r in reqs]
-
     def release_slot(self, slot: int) -> None:
         """Tear down one admitted slot out-of-band: drop its request, free
         its pages, and clear the host + device decode state. THE teardown
@@ -2956,24 +2479,6 @@ class InferenceEngine:
     def abort_all(self) -> None:
         """Free every in-flight slot and its KV pages — recovery path after a
         failed dispatch so the engine never leaks capacity."""
-        if self._persistent is not None:
-            if self.persistent_active:
-                # Deactivate every device-resident slot through the ring
-                # (slot=-1 = all); the loop stays resident for new work.
-                try:
-                    self._persistent.abort_steady(-1)
-                except Exception:
-                    logger.warning(
-                        "persistent abort command not accepted — force-"
-                        "draining the resident loop", exc_info=True,
-                    )
-                    self._persistent.force_stop()
-                    self.exit_persistent()
-            # Parked (undelivered) token-ring batches belong to the
-            # aborted work — the persistent twin of the piggybacked-
-            # emissions clear below: a request reusing a slot must never
-            # inherit the aborted occupant's emissions.
-            self._persistent.clear_parked()
         for slot in list(self._by_slot):
             self.kv.free_slot(slot)
             del self._by_slot[slot]
@@ -3014,14 +2519,6 @@ class InferenceEngine:
           round.
         The decision cache above the engine needs its own epoch bump —
         rollout/hotswap.py owns that (core/cache.bump_generation)."""
-        if self.persistent_active:
-            # The resident loop captured `params` at launch: drain it so
-            # no post-swap admission/decode runs under the old weights.
-            # In-flight slots rebind into the dispatch path and continue
-            # (same caveat as below: token-identical only for identical
-            # params). The loop relaunches lazily on the next
-            # enter_persistent.
-            self.exit_persistent()
         if self.spec is not None:
             self.spec.on_swap()
         old = self.params
@@ -3058,12 +2555,8 @@ class InferenceEngine:
         stream occupies only its own slot (_Request.external) — fused
         chunks for other slots keep dispatching — and swap_params calls
         decoder.on_swap() so open blocks roll back before new weights
-        install. A resident persistent loop drains first: spec streams
-        drive slots through their own dispatches, which cannot run beside
-        the loop (persistent_supported gates on spec is None)."""
+        install."""
         self._require_paged("attach_spec() (speculative decoding, spec/)")
-        if decoder is not None and self.persistent_active:
-            self.exit_persistent()
         self.spec = decoder
 
     def attach_profiler(self, profiler) -> None:
@@ -3095,17 +2588,6 @@ class InferenceEngine:
         ):
             return self.spec.generate(prompt_ids, max_new_tokens)
         req_id = self.add_request(prompt_ids, max_new_tokens)
-        if self.persistent_active:
-            # The request went through the command ring — drain the token
-            # ring until it completes. Zero dispatches on this path.
-            while True:
-                for fin in self.step_persistent(timeout_s=1.0):
-                    if fin.req_id == req_id:
-                        return fin
-                if not self.persistent_active and req_id not in {
-                    r.req_id for r in self._by_slot.values()
-                }:
-                    break  # wedge-drained; finish on the dispatch path
         # Plain decode rides the FUSED runtime (decode_fused: all chunks
         # enqueued back-to-back, one gating sync) — this is the baseline
         # the spec A/B is judged against; falls back internally when the
@@ -3118,12 +2600,9 @@ class InferenceEngine:
     def get_stats(self) -> dict[str, Any]:
         out = {**self.stats, "pages_free": self.kv.pages_free,
                "slots_free": self.free_slots}
-        if self._persistent is not None:
-            out.update(self._persistent.stats())
-        # THE zero-dispatch headline (sched/client nests this under
-        # "engine" -> llm_scheduler_engine_dispatches_per_decision):
-        # windowed from the profiler's flow books when attached, lifetime
-        # ratio otherwise — 0.0 in persistent steady state.
+        # sched/client nests this under "engine" ->
+        # llm_scheduler_engine_dispatches_per_decision: windowed from the
+        # profiler's flow books when attached, lifetime ratio otherwise.
         dpd = None
         if self.profiler is not None:
             dpd = self.profiler.dispatches_per_decision()
@@ -3133,15 +2612,6 @@ class InferenceEngine:
             )
         if dpd is not None:
             out["dispatches_per_decision"] = dpd
-        # Resident-loop gauge family as a subtree: flows through
-        # backend.get_stats into the fleet merge, so `cli fleet top` can
-        # read per-replica resident tok/s and the aggregator can export
-        # llm_scheduler_persistent_* without scraping each process.
-        if self.profiler is not None and (
-            self.profiler.persistent_profiled
-            or self.stats.get("persistent_launches")
-        ):
-            out["persistent"] = self.profiler.persistent_gauges()
         if self.spec is not None:
             out["spec"] = self.spec.stats.snapshot()
         if self.prefix_attn_impl.resolved:

@@ -160,7 +160,6 @@ class LocalLLMBackend:
         delta_prompts: bool = False,
         repin_fraction: float = 0.25,
         max_pins: int = 4,
-        persistent_loop: bool = False,
     ) -> None:
         self.engine = engine
         # Admission plane (engine/admission/): batch-surface decisions
@@ -174,19 +173,6 @@ class LocalLLMBackend:
             and hasattr(engine, "admit_packed")
             and getattr(engine, "paged", True)
         )
-        # Persistent device-resident serving (engine/persistent/): when
-        # on, the worker FEEDS THE LOOP'S RINGS instead of submitting
-        # waves — admissions enqueue on the CommandRing (engine.
-        # add_requests routes there while the loop is resident) and
-        # completions drain off the TokenRing via step_persistent. The
-        # backend flag is authoritative: it arms the engine gate too.
-        self._persistent_loop = bool(persistent_loop) and hasattr(
-            engine, "enter_persistent"
-        )
-        if self._persistent_loop:
-            engine.persistent_loop = True
-        # In-flight resident-loop decisions: req_id -> (item, submitted_at)
-        self._pers_items: dict[int, tuple[_WorkItem, float]] = {}
         if delta_prompts:
             from k8s_llm_scheduler_tpu.sched.delta import SnapshotDeltaEncoder
 
@@ -697,20 +683,8 @@ class LocalLLMBackend:
             through engine.admit_packed instead: one packed
             block-diagonal prefill for the whole batch, bounded by the
             engine's free paged slots (leftovers wait for slots to
-            drain). A lone marked straggler just rides a wave.
-
-            With the persistent loop on, current-group items that fit
-            its admission bucket feed the CommandRing first — zero
-            dispatches each. Leftovers (oversized, or parked on
-            backpressure) fall through; while the loop is resident the
-            packed branch is SKIPPED (admit_packed would drain the loop
-            — oversized items ride waves, which never touch the paged
-            cache and run beside the loop)."""
-            if self._persistent_loop:
-                items = self._route_persistent(items, rest, bool(packs))
-            if self._packed_admission and not getattr(
-                self.engine, "persistent_active", False
-            ):
+            drain). A lone marked straggler just rides a wave."""
+            if self._packed_admission:
                 # The paged pack path is page-table-bounded, tighter than
                 # the wave bound: an oversized suffix rides a wave rather
                 # than failing its pack (or poisoning its batchmates).
@@ -781,7 +755,7 @@ class LocalLLMBackend:
         oldest = min(others, key=lambda i: i.enqueued_at, default=None)
         waited = time.perf_counter() - oldest.enqueued_at if others else 0.0
         leaving = (
-            bool(others) and not packs and not self._pers_items
+            bool(others) and not packs
             and waited >= self.group_switch_after_s
         )
         # The ragged tail the engine would leave behind goes WITH it where
@@ -814,12 +788,11 @@ class LocalLLMBackend:
         if not others:
             return rest
 
-        if packs or self._pers_items:
+        if packs:
             # Paged slots are mid-flight against the CURRENT prefix
             # pointer — set_prefix requires a drained engine, so a group
-            # switch must wait for the packs (and resident-loop
-            # decisions) to finish decoding (bounded: the device-side
-            # budget guarantees completion).
+            # switch must wait for the packs to finish decoding
+            # (bounded: the device-side budget guarantees completion).
             rest.extend(others)
             return rest
         if waves and waited < self.group_switch_after_s:
@@ -896,10 +869,7 @@ class LocalLLMBackend:
         waves: deque[tuple[Any, list[_WorkItem]]] = deque()
         packs: list[dict] = []  # in-flight packed admissions
         while not self._stopped.is_set():
-            block = (
-                not pending and not waves and not packs
-                and not self._pers_items
-            )
+            block = not pending and not waves and not packs
             if block and self._prewarm_backlog() > 0:
                 # Idle with compiles owed: park only for the grace period;
                 # if still idle after it, compile ONE sibling geometry,
@@ -923,7 +893,6 @@ class LocalLLMBackend:
                 self._drain_queue(pending, block=False)
             if self._stopped.is_set() or (
                 not pending and not waves and not packs
-                and not self._pers_items
             ):
                 continue
             # Nothing below may kill the engine-owner thread — a dead worker
@@ -940,20 +909,12 @@ class LocalLLMBackend:
                 for pk in packs:
                     for item in pk["items"].values():
                         item.fail(BackendError(str(exc)))
-                for _item, _t in self._pers_items.values():
-                    _item.fail(BackendError(str(exc)))
-                if packs or self._pers_items:
-                    # the failed packs'/resident-loop requests still hold
-                    # _by_slot entries and KV pages — without an abort
-                    # they leak forever (nothing steps an empty packs
-                    # list) and free_slots shrinks until nothing admits
+                if packs:
+                    # the failed packs' requests still hold _by_slot
+                    # entries and KV pages — without an abort they leak
+                    # forever (nothing steps an empty packs list) and
+                    # free_slots shrinks until nothing admits
                     packs.clear()
-                    self._pers_items.clear()
-                    try:
-                        if getattr(self.engine, "persistent_active", False):
-                            self.engine.exit_persistent()
-                    except Exception:  # pragma: no cover - best effort
-                        logger.exception("persistent exit after failed tick")
                     try:
                         self.engine.abort_all()
                     except Exception:  # pragma: no cover - best effort
@@ -964,118 +925,16 @@ class LocalLLMBackend:
                     ctl.fail(BackendError(str(exc)))
                 self._held_controls = []
                 pending = []
-        # Shutdown: fail anything still queued or in flight, and retire
-        # the resident loop (its daemon thread must not outlive the
-        # backend holding a donated view of the engine's buffers).
-        try:
-            if getattr(self.engine, "persistent_active", False):
-                self.engine.exit_persistent()
-        except Exception:  # pragma: no cover - best effort
-            logger.exception("persistent loop exit at shutdown failed")
+        # Shutdown: fail anything still queued or in flight.
         self._drain_queue(pending, block=False)
         for _, items in waves:
             pending.extend(items)
         for pk in packs:
             pending.extend(pk["items"].values())
-        pending.extend(item for item, _t in self._pers_items.values())
-        self._pers_items.clear()
         pending.extend(self._held_controls)
         self._held_controls = []
         for item in pending:
             item.fail(BackendError("backend closed"))
-
-    def _route_persistent(
-        self, items: list[_WorkItem], rest: list[_WorkItem],
-        packs_busy: bool,
-    ) -> list[_WorkItem]:
-        """Feed current-group items that fit the resident loop's admission
-        bucket onto its CommandRing (entering the loop lazily); returns
-        the items that must take the dispatch path instead. Ring-full and
-        slot exhaustion PARK the item in `rest` (backpressure: retry next
-        tick) — they are flow control, not failures."""
-        eng = self.engine
-        limit = eng.persistent_suffix_limit(self.max_new_tokens)
-        if not any(len(i.suffix_ids) <= limit for i in items):
-            return items
-        if not eng.persistent_active:
-            if packs_busy:
-                # launching would donate paged buffers mid-pack; the
-                # packs drain within their decode budget — wait them out
-                return items
-            try:
-                if not eng.enter_persistent():
-                    return items  # unsupported / wedge-latched
-            except Exception:
-                logger.exception("persistent loop launch failed")
-                return items
-        from k8s_llm_scheduler_tpu.engine.persistent.ring import RingFull
-
-        leftover: list[_WorkItem] = []
-        for item in items:
-            if len(item.suffix_ids) > limit:
-                leftover.append(item)
-                continue
-            if eng.free_slots <= 0:
-                rest.append(item)
-                continue
-            try:
-                (req_id,) = eng.add_requests(
-                    [item.suffix_ids], self.max_new_tokens
-                )
-            except RingFull:
-                rest.append(item)  # admission backpressure
-            except Exception as exc:
-                item.fail(BackendError(str(exc)))
-            else:
-                self._pers_items[req_id] = (item, time.perf_counter())
-        return leftover
-
-    def _resolve_fins(self, fins, packs: "list[dict]") -> None:
-        """Match finished engine decisions to their in-flight items —
-        resident-loop admissions (_pers_items) and packed admissions
-        share the paged slots, so ONE resolution seam serves both."""
-        if not fins:
-            return
-        now = time.perf_counter()
-        with spans.thread_span("resolve", layer="engine"):
-            for fin in fins:
-                entry = self._pers_items.pop(fin.req_id, None)
-                if entry is not None:
-                    item, submitted_at = entry
-                    handle = SimpleNamespace(submitted_at=submitted_at)
-                    self._attach_item_spans(item, handle, fin, now)
-                    item.resolve(fin.text)
-                    continue
-                for pk in packs:
-                    item = pk["items"].pop(fin.req_id, None)
-                    if item is not None:
-                        handle = SimpleNamespace(submitted_at=pk["submitted_at"])
-                        self._attach_item_spans(item, handle, fin, now)
-                        item.resolve(fin.text)
-                        break
-        packs[:] = [pk for pk in packs if pk["items"]]
-
-    def _fail_paged_inflight(
-        self, packs: "list[dict]", exc: Exception
-    ) -> None:
-        """Fail every in-flight paged decision (packs + resident-loop
-        items) and abort the engine so their slots/pages don't leak."""
-        for pk in packs:
-            for item in pk["items"].values():
-                item.fail(BackendError(str(exc)))
-        packs.clear()
-        for item, _t in self._pers_items.values():
-            item.fail(BackendError(str(exc)))
-        self._pers_items.clear()
-        try:
-            if getattr(self.engine, "persistent_active", False):
-                self.engine.exit_persistent()
-        except Exception:  # pragma: no cover - best-effort cleanup
-            logger.exception("persistent exit after failed step")
-        try:
-            self.engine.abort_all()
-        except Exception:  # pragma: no cover - best-effort cleanup
-            logger.exception("engine abort after failed step")
 
     def _drive_packs(self, packs: "list[dict]") -> None:
         """Advance in-flight packed admissions by one decode step and
@@ -1084,33 +943,36 @@ class LocalLLMBackend:
         Packs admit into FUSED slots: the step routes through the fused
         while_loop runtime when the engine carries one (engine/fused/),
         which early-exits past finished slots and falls back to the
-        sparse chunked path on its own when the grammar can't fuse."""
+        sparse chunked path on its own when the grammar can't fuse. A
+        failed step fails every in-flight packed decision and aborts the
+        engine so their slots/pages don't leak."""
         try:
             step_fused = getattr(self.engine, "step_fused", None)
             fins = step_fused() if step_fused is not None else self.engine.step()
         except Exception as exc:
             logger.exception("packed decode step failed")
-            self._fail_paged_inflight(packs, exc)
+            for pk in packs:
+                for item in pk["items"].values():
+                    item.fail(BackendError(str(exc)))
+            packs.clear()
+            try:
+                self.engine.abort_all()
+            except Exception:  # pragma: no cover - best-effort cleanup
+                logger.exception("engine abort after failed step")
             return
-        self._resolve_fins(fins, packs)
-
-    def _drive_persistent(self, packs: "list[dict]") -> None:
-        """Drain the resident loop's TokenRing and resolve finished
-        decisions. After a wedge drain (or a quiesce that didn't resume)
-        the surviving slots keep decoding on the dispatch path — the
-        fused step continues them token-identically."""
-        eng = self.engine
-        try:
-            if eng.persistent_active:
-                fins = eng.step_persistent(timeout_s=0.02)
-            else:
-                step_fused = getattr(eng, "step_fused", None)
-                fins = step_fused() if step_fused is not None else eng.step()
-        except Exception as exc:
-            logger.exception("persistent serving step failed")
-            self._fail_paged_inflight(packs, exc)
+        if not fins:
             return
-        self._resolve_fins(fins, packs)
+        now = time.perf_counter()
+        with spans.thread_span("resolve", layer="engine"):
+            for fin in fins:
+                for pk in packs:
+                    item = pk["items"].pop(fin.req_id, None)
+                    if item is not None:
+                        handle = SimpleNamespace(submitted_at=pk["submitted_at"])
+                        self._attach_item_spans(item, handle, fin, now)
+                        item.resolve(fin.text)
+                        break
+        packs[:] = [pk for pk in packs if pk["items"]]
 
     def _worker_tick(
         self,
@@ -1136,14 +998,8 @@ class LocalLLMBackend:
         if packs:
             # Packed admissions decode via the paged path: advance them
             # (and harvest piggybacked emissions) every tick so their
-            # decisions resolve while waves pipeline alongside. The
-            # resolve seam covers resident-loop items too, so a single
-            # step never strands a Finished.
+            # decisions resolve while waves pipeline alongside.
             self._drive_packs(packs)
-        elif self._pers_items:
-            # Resident-loop decisions: harvest the TokenRing (or, after
-            # a drain, continue their slots on the dispatch path).
-            self._drive_persistent(packs)
         if waves:
             handle, items = waves[0]
             # While the oldest wave executes, keep feeding the pipeline:
@@ -1172,10 +1028,10 @@ class LocalLLMBackend:
             deadline = (
                 max(handle.submitted_at, self._last_harvest_t) + 0.5 * ema
             )
-            if packs or self._pers_items:
-                # in-flight packed/resident-loop decodes must not starve
-                # behind the straggler poll — harvest this wave
-                # blockingly and get back to stepping them
+            if packs:
+                # in-flight packed decodes must not starve behind the
+                # straggler poll — harvest this wave blockingly and get
+                # back to stepping them
                 deadline = 0.0
             # ONE span around the whole poll, never one per iteration;
             # waves submitted from inside it nest as engine.submit_wave
@@ -1243,23 +1099,12 @@ class LocalLLMBackend:
                     for fin, item in zip(fins, items):
                         self._attach_item_spans(item, handle, fin, now)
                         item.resolve(fin.text)
-        if (
-            self._held_controls and not waves and not packs
-            and not self._pers_items
-        ):
+        if self._held_controls and not waves and not packs:
             # Wave barrier reached (everything in flight harvested above —
-            # waves, packed admissions AND resident-loop decisions —
-            # admissions held since the control arrived): run the
-            # quiesced actions on this — the engine-owner — thread. Held
-            # work in `pending` resumes on the next tick. The resident
-            # loop exits FIRST: its donated buffers make the engine
-            # unusable to an arbitrary quiesced fn, and engine-side
-            # drains (swap_params etc.) expect the dispatch-path state.
-            if getattr(self.engine, "persistent_active", False):
-                try:
-                    self.engine.exit_persistent()
-                except Exception:
-                    logger.exception("persistent exit at control barrier")
+            # waves and packed admissions — admissions held since the
+            # control arrived): run the quiesced actions on this — the
+            # engine-owner — thread. Held work in `pending` resumes on the
+            # next tick.
             controls, self._held_controls = self._held_controls, []
             for ctl in controls:
                 try:
@@ -1302,8 +1147,8 @@ class LocalLLMBackend:
           names the `jit_wave` run of a device trace that served this
           decision (the k-th run is the k-th engine.submit_wave). Other
           waves are in flight beside it, so this is a latency, not the
-          device time the decision cost. Waves only: a packed or
-          resident-loop decision has no wave;
+          device time the decision cost. Waves only: a packed
+          decision has no wave;
         - prefill / decode: the same interval apportioned by token
           counts (the wave is ONE fused device program — the split is the
           same token-apportioned estimate sim/arena uses, flagged
@@ -1559,9 +1404,9 @@ def _init_params(rng_seed: int, cfg: LlamaConfig | MlaMoeConfig, mesh=None):
 def _refuse_unserved(cfg, *, multi, quantize, checkpoint_path, spec_enabled) -> None:
     """What models/mla_moe.py does not bring refuses HERE, at build time,
     naming the model and the path — never inside a trace: what would
-    otherwise fail before the engine exists. (InferenceEngine refuses the
-    resident loop and a tp mesh in its constructor, and the paged entry
-    points at the call: _require_paged.)"""
+    otherwise fail before the engine exists. (InferenceEngine refuses a
+    tp mesh in its constructor, and the paged entry points at the call:
+    _require_paged.)"""
     if not isinstance(cfg, MlaMoeConfig):
         return
     asked = {
@@ -1644,12 +1489,6 @@ def build_local_backend(
     max_pins: int = 4,
     fused_decode: bool = True,
     top_k: int = 0,
-    persistent_loop: bool = False,
-    persistent_suffix_bucket: int | None = None,
-    persistent_wedge_timeout_s: float = 30.0,
-    persistent_telemetry: bool = True,
-    persistent_stats_every: int = 8,
-    persistent_blackbox_depth: int = 64,
 ) -> LocalLLMBackend:
     """Construct the full local stack: params (from an HF safetensors or
     orbax checkpoint when checkpoint_path is set, random-init otherwise —
@@ -1780,12 +1619,6 @@ def build_local_backend(
         admission_chunk_tokens=admission_chunk_tokens,
         fused_decode=fused_decode,
         top_k=top_k,
-        persistent_loop=persistent_loop,
-        persistent_suffix_bucket=persistent_suffix_bucket,
-        persistent_wedge_timeout_s=persistent_wedge_timeout_s,
-        persistent_telemetry=persistent_telemetry,
-        persistent_stats_every=persistent_stats_every,
-        persistent_blackbox_depth=persistent_blackbox_depth,
     )
     if spec_enabled:
         if multi:
@@ -1817,5 +1650,4 @@ def build_local_backend(
         delta_prompts=delta_prompts,
         repin_fraction=repin_fraction,
         max_pins=max_pins,
-        persistent_loop=persistent_loop,
     )

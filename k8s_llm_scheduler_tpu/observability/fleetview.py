@@ -69,18 +69,6 @@ def build_telemetry(
 ) -> dict[str, Any]:
     """One replica's pullable telemetry payload (wire-shaped: plain JSON
     types only)."""
-    # Hoist the resident-loop gauge family (engine.get_stats nests it
-    # under "engine") to the payload top level so the fleet merge and
-    # `render_prometheus` expose it as llm_scheduler_persistent_* — the
-    # SAME family name the per-replica /metrics mounts (metrics.py), so
-    # dashboards need one query whichever endpoint they scrape.
-    eng = stats.get("engine")
-    if (
-        isinstance(eng, dict)
-        and isinstance(eng.get("persistent"), dict)
-        and "persistent" not in stats
-    ):
-        stats = {**stats, "persistent": eng["persistent"]}
     out: dict[str, Any] = {
         "stats": stats,
         "traces": [],
@@ -416,24 +404,13 @@ def render_top(agg: FleetAggregator, phases=("decide", "bind")) -> str:
         "  totals   "
         + "  ".join(f"{k}={v}" for k, v in totals.items())
     )
-    # Fleet resident-loop headline (merge sums per-replica tok/s — the
-    # fleet figure is genuine aggregate throughput, not an average).
-    pers = merged.get("persistent")
-    if pers:
-        lines.append(
-            "  resident "
-            f"tok/s={float(pers.get('resident_tokens_per_s', 0.0)):.1f}  "
-            f"tokens_total={int(pers.get('tokens_total', 0))}  "
-            f"loop_windows={int(pers.get('loop_windows', 0))}"
-        )
     with agg._lock:
         per_source = {
             name: st.stats for name, st in agg._sources.items()
         }
     lines.append(
         f"  {'source':<14} {'bound':>7} {'llm':>6} {'cache':>6} "
-        f"{'decide_p99':>11} {'ring':>5} {'res_tok/s':>10} "
-        f"{'shards':<18} state"
+        f"{'decide_p99':>11} {'shards':<18} state"
     )
     for name, stats in sorted(per_source.items()):
         st = status[name]
@@ -441,23 +418,11 @@ def render_top(agg: FleetAggregator, phases=("decide", "bind")) -> str:
         shards = stats.get("owned_shards")
         pool = stats.get("pool_role")
         tag = f"pool={pool}" if pool else ""
-        # Resident-loop columns: token-ring occupancy from the flat
-        # persistent server counters (nested under "engine" by
-        # sched/client.get_stats), resident tok/s from the hoisted
-        # profiler gauge family. "-" when the replica has no resident
-        # loop — most fleets are mixed during a persistent rollout.
-        eng = stats.get("engine") or {}
-        occ = eng.get("persistent_ring_occupancy_frac")
-        ring = f"{occ:.2f}" if isinstance(occ, (int, float)) else "-"
-        pers = stats.get("persistent") or eng.get("persistent") or {}
-        tps = pers.get("resident_tokens_per_s")
-        res = f"{tps:.1f}" if isinstance(tps, (int, float)) else "-"
         lines.append(
             f"  {name:<14} {stats.get('total_scheduled', 0):>7} "
             f"{stats.get('llm_decisions', 0):>6} "
             f"{stats.get('cache_decisions', 0):>6} "
             f"{phases_d.get('p99_ms', 0.0):>9.1f}ms "
-            f"{ring:>5} {res:>10} "
             f"{str(shards if shards is not None else '-'):<18} "
             + ("STALE" if st["stale"] else "live")
             + (f" {tag}" if tag else "")

@@ -179,7 +179,6 @@ class MetricsServer:
         engine_sampler: Any | None = None,
         engine_profiler: Any | None = None,
         slo_engine: Any | None = None,
-        blackbox_provider: Callable[[], dict[str, Any] | None] | None = None,
     ) -> None:
         from k8s_llm_scheduler_tpu.observability import spans
 
@@ -191,10 +190,6 @@ class MetricsServer:
         self.engine_sampler = engine_sampler
         self.engine_profiler = engine_profiler
         self.slo_engine = slo_engine
-        # /debug/blackbox: the persistent loop's wedge black-box dump
-        # (engine.persistent_blackbox) — None/absent when the backend has
-        # no resident loop or telemetry is off.
-        self.blackbox_provider = blackbox_provider
 
         server = self
 
@@ -263,14 +258,6 @@ class MetricsServer:
             stats["engine_telemetry"] = self.engine_sampler.latest()
         if self.engine_profiler is not None:
             stats["engine_profile"] = self.engine_profiler.gauges()
-            # Mount the llm_scheduler_persistent_* family at the top
-            # level (not under engine_profile) so the gauge names match
-            # across /metrics, the SLO provider tree, and the fleet
-            # merge; never clobber a provider-supplied subtree.
-            if "persistent" not in stats and hasattr(
-                self.engine_profiler, "persistent_gauges"
-            ):
-                stats["persistent"] = self.engine_profiler.persistent_gauges()
         if self.slo_engine is not None:
             stats["slo"] = self.slo_engine.gauges()
         return stats
@@ -396,18 +383,6 @@ class MetricsServer:
                 "application/json",
                 200,
             )
-        if path.startswith("/debug/blackbox"):
-            if self.blackbox_provider is None:
-                return b"no persistent black-box attached", "text/plain", 404
-            dump = self.blackbox_provider()
-            if dump is None:
-                return (
-                    b"no black-box dump yet (no residency, or telemetry "
-                    b"off)",
-                    "text/plain",
-                    404,
-                )
-            return json.dumps(dump).encode(), "application/json", 200
         return b"not found", "text/plain", 404
 
     def start(self) -> None:

@@ -108,36 +108,6 @@ FUSED_SEGMENTS = (
     "unattributed",
 )
 
-# Persistent-loop segments (engine/persistent/ — the device-resident
-# serving loop): telescoping over each step_persistent harvest's host
-# wall with the same sum==wall identity. ring_wait is the host blocking
-# on TokenRing.drain (waiting for the loop to push), harvest the host-
-# side booking of drained batches, loop_resident everything else — the
-# window where the device loop ran with NO host involvement at all. The
-# whole point of the subsystem is that loop_resident dominates while
-# dispatches_per_decision (the flow books below) reads zero.
-PERSISTENT_SEGMENTS = (
-    "ring_wait",
-    "loop_resident",
-    "harvest",
-    "unattributed",
-)
-
-# Sub-decomposition of `loop_resident` itself (observability/resident.py
-# + engine._decompose_loop_resident): the device-resident counter block
-# exported through the StatsRing splits the opaque in-loop window by
-# counter deltas — admissions taken, decode steps run, token-ring
-# backpressure stalls, idle chunks. Telescoping over loop_resident with
-# the same sum==wall identity (the last segment is the exact remainder),
-# pinned like every other segment family. Only booked for windows where
-# a telemetry snapshot landed (telemetry off -> sub-books untouched).
-PERSISTENT_LOOP_SEGMENTS = (
-    "admit",
-    "decode",
-    "ring_stall",
-    "idle",
-)
-
 # Speculative-decoding segments (spec/decoder.py — the async
 # propose/verify pipeline): telescoping over each spec REQUEST's host
 # wall with the same sum==wall identity. draft covers propose dispatches
@@ -295,35 +265,11 @@ class EngineProfiler:
         self._spec_overlapped = 0
         self._spec_tokens = 0
         self.spec_profiled = 0
-        # Persistent-loop books (engine/persistent/): per-harvest records
-        # with telescoping PERSISTENT_SEGMENTS — the residency proof the
-        # zero-dispatch loop is measured against.
-        self._pers_ring: deque[dict] = deque(maxlen=self.window)
-        self._pers_totals = {name: 0.0 for name in PERSISTENT_SEGMENTS}
-        self._pers_totals["wall"] = 0.0
-        self._pers_steps = 0
-        self._pers_tokens = 0
-        self.persistent_profiled = 0
-        # In-loop sub-books (PERSISTENT_LOOP_SEGMENTS): "wall" here is
-        # the loop_resident covered by windows that CARRIED a telemetry
-        # snapshot — the denominator the sub-fractions telescope over.
-        self._pers_loop_totals = {
-            name: 0.0 for name in PERSISTENT_LOOP_SEGMENTS
-        }
-        self._pers_loop_totals["wall"] = 0.0
-        self.persistent_loop_profiled = 0
-        # Monotone resident-token counter beside the windowed books — the
-        # SLO throughput floor windows THIS with its own baselines (the
-        # windowed figure is non-monotone under ring eviction; see the
-        # _cum comment above).
-        self._pers_tokens_cum = 0
         # Decision-flow books: (XLA dispatches, decisions completed)
         # deltas booked at each completion window (engine.
-        # _book_decision_flow). The windowed ratio is THE zero-dispatch
-        # headline: 0.0 in persistent steady state, >= 1 on the dispatch
-        # path. Dispatch deltas telescope exactly — every stats
-        # "dispatches" bump lands in exactly one window — so the lifetime
-        # sum of d_dispatches equals the engine's dispatch counter.
+        # _book_decision_flow). Dispatch deltas telescope exactly — every
+        # stats "dispatches" bump lands in exactly one window — so the
+        # lifetime sum of d_dispatches equals the engine's dispatch counter.
         self._flow_ring: deque[tuple[int, int]] = deque(maxlen=self.window)
         self._flow_dispatches = 0
         self._flow_decisions = 0
@@ -673,100 +619,6 @@ class EngineProfiler:
             self._spec_overlapped += int(overlapped_rounds)
             self._spec_tokens += int(tokens)
 
-    def on_persistent(
-        self,
-        *,
-        wall_s: float,
-        ring_wait_s: float,
-        harvest_s: float,
-        loop_resident_s: float,
-        steps: int,
-        tokens: int,
-        batches: int,
-        loop_segments: dict[str, float] | None = None,
-    ) -> None:
-        """One persistent-loop harvest window closed (engine.
-        step_persistent): wall is the time since the previous harvest,
-        ring_wait the TokenRing.drain block, harvest the host-side batch
-        booking, loop_resident the remainder — device-resident serving
-        with zero host involvement. The engine pre-clamps the measured
-        segments to the wall, so sum(PERSISTENT_SEGMENTS) == wall holds
-        exactly and the acceptance test pins it.
-
-        `loop_segments` (optional) is the counter-delta decomposition of
-        loop_resident into PERSISTENT_LOOP_SEGMENTS — already summing
-        exactly to loop_resident_s (engine._decompose_loop_resident
-        builds the last segment as the remainder); booked as-is, never
-        renormalized, so the sub-family identity pin is end to end."""
-        wall = max(float(wall_s), 0.0)
-        seg = {
-            "ring_wait": max(float(ring_wait_s), 0.0),
-            "harvest": max(float(harvest_s), 0.0),
-            "loop_resident": max(float(loop_resident_s), 0.0),
-        }
-        seg["unattributed"] = max(wall - sum(seg.values()), 0.0)
-        record = {
-            "harvest": 0,  # stamped under the lock below
-            "batches": int(batches),
-            "steps": int(steps),
-            "tokens": int(tokens),
-            "wall_ms": wall * 1000.0,
-            "segments_ms": {k: v * 1000.0 for k, v in seg.items()},
-        }
-        if loop_segments is not None:
-            record["loop_segments_ms"] = {
-                name: max(float(loop_segments.get(name, 0.0)), 0.0) * 1000.0
-                for name in PERSISTENT_LOOP_SEGMENTS
-            }
-        with self._lock:
-            self.persistent_profiled += 1
-            record["harvest"] = self.persistent_profiled
-            if len(self._pers_ring) == self._pers_ring.maxlen:
-                old = self._pers_ring[0]
-                for name in PERSISTENT_SEGMENTS:
-                    self._pers_totals[name] = max(
-                        self._pers_totals[name]
-                        - old["segments_ms"].get(name, 0.0) / 1000.0,
-                        0.0,
-                    )
-                self._pers_totals["wall"] = max(
-                    self._pers_totals["wall"] - old["wall_ms"] / 1000.0, 0.0
-                )
-                self._pers_steps = max(self._pers_steps - old["steps"], 0)
-                self._pers_tokens = max(
-                    self._pers_tokens - old["tokens"], 0
-                )
-                old_loop = old.get("loop_segments_ms")
-                if old_loop is not None:
-                    for name in PERSISTENT_LOOP_SEGMENTS:
-                        self._pers_loop_totals[name] = max(
-                            self._pers_loop_totals[name]
-                            - old_loop.get(name, 0.0) / 1000.0,
-                            0.0,
-                        )
-                    self._pers_loop_totals["wall"] = max(
-                        self._pers_loop_totals["wall"]
-                        - old["segments_ms"]["loop_resident"] / 1000.0,
-                        0.0,
-                    )
-                    self.persistent_loop_profiled = max(
-                        self.persistent_loop_profiled - 1, 0
-                    )
-            self._pers_ring.append(record)
-            for name in PERSISTENT_SEGMENTS:
-                self._pers_totals[name] += seg.get(name, 0.0)
-            self._pers_totals["wall"] += wall
-            self._pers_steps += int(steps)
-            self._pers_tokens += int(tokens)
-            self._pers_tokens_cum += int(tokens)
-            if loop_segments is not None:
-                for name in PERSISTENT_LOOP_SEGMENTS:
-                    self._pers_loop_totals[name] += (
-                        record["loop_segments_ms"][name] / 1000.0
-                    )
-                self._pers_loop_totals["wall"] += seg["loop_resident"]
-                self.persistent_loop_profiled += 1
-
     def on_decision_flow(self, d_dispatches: int, d_decisions: int) -> None:
         """Book one completion window's (dispatch delta, decision delta).
         The engine calls this whenever decisions complete, with the XLA
@@ -791,9 +643,8 @@ class EngineProfiler:
             self._flow_decisions += d_done
 
     def dispatches_per_decision(self) -> float | None:
-        """Windowed XLA dispatches per completed decision — 0.0 in
-        persistent steady state (the zero-dispatch pin), >= 1 on the
-        dispatch path. None until a completion window has been booked."""
+        """Windowed XLA dispatches per completed decision. None until a
+        completion window has been booked."""
         with self._lock:
             if self._flow_decisions <= 0:
                 return None
@@ -901,13 +752,6 @@ class EngineProfiler:
             spec_overlapped = self._spec_overlapped
             spec_tokens = self._spec_tokens
             spec = self.spec_profiled
-            pers_ring = list(self._pers_ring)
-            pers_totals = dict(self._pers_totals)
-            pers_steps = self._pers_steps
-            pers_tokens = self._pers_tokens
-            pers = self.persistent_profiled
-            pers_loop_totals = dict(self._pers_loop_totals)
-            pers_loop = self.persistent_loop_profiled
             flow_disp = self._flow_dispatches
             flow_done = self._flow_decisions
             tpd = self._prefill_tokens_per_decision_locked()
@@ -1031,45 +875,6 @@ class EngineProfiler:
             if spec_wall > 0:
                 spec_out["tokens_per_s"] = round(spec_tokens / spec_wall, 1)
             out["spec"] = spec_out
-        if pers:
-            pers_wall = pers_totals["wall"]
-            pers_out: dict[str, Any] = {
-                "harvests_profiled": pers,
-                "steps": pers_steps,
-                "tokens": pers_tokens,
-                "wall_ms_total": round(pers_wall * 1000.0, 3),
-                "segments_ms_total": {
-                    name: round(pers_totals[name] * 1000.0, 3)
-                    for name in PERSISTENT_SEGMENTS
-                },
-                "segment_frac": {
-                    name: (
-                        round(pers_totals[name] / pers_wall, 4)
-                        if pers_wall > 0
-                        else 0.0
-                    )
-                    for name in PERSISTENT_SEGMENTS
-                },
-                "ring": pers_ring,
-            }
-            if pers_wall > 0:
-                pers_out["tokens_per_s"] = round(pers_tokens / pers_wall, 1)
-            if pers_loop:
-                loop_wall = pers_loop_totals["wall"]
-                pers_out["loop_windows_profiled"] = pers_loop
-                pers_out["loop_segments_ms_total"] = {
-                    name: round(pers_loop_totals[name] * 1000.0, 3)
-                    for name in PERSISTENT_LOOP_SEGMENTS
-                }
-                pers_out["loop_segment_frac"] = {
-                    name: (
-                        round(pers_loop_totals[name] / loop_wall, 4)
-                        if loop_wall > 0
-                        else 0.0
-                    )
-                    for name in PERSISTENT_LOOP_SEGMENTS
-                }
-            out["persistent"] = pers_out
         if flow_done > 0:
             out["dispatches_per_decision"] = round(
                 flow_disp / flow_done, 4
@@ -1095,10 +900,6 @@ class EngineProfiler:
             spec_rounds = self._spec_rounds
             spec_overlapped = self._spec_overlapped
             spec = self.spec_profiled
-            pers_totals = dict(self._pers_totals)
-            pers = self.persistent_profiled
-            pers_loop_totals = dict(self._pers_loop_totals)
-            pers_loop = self.persistent_loop_profiled
             flow_disp = self._flow_dispatches
             flow_done = self._flow_decisions
             tpd = self._prefill_tokens_per_decision_locked()
@@ -1148,23 +949,6 @@ class EngineProfiler:
                 if spec_rounds > 0
                 else 0.0
             )
-        if pers:
-            out["persistent_profiled"] = float(pers)
-            pers_wall = pers_totals["wall"]
-            for name in PERSISTENT_SEGMENTS:
-                out[f"persistent_{name}_frac"] = (
-                    round(pers_totals[name] / pers_wall, 4)
-                    if pers_wall > 0
-                    else 0.0
-                )
-            if pers_loop:
-                loop_wall = pers_loop_totals["wall"]
-                for name in PERSISTENT_LOOP_SEGMENTS:
-                    out[f"persistent_loop_{name}_frac"] = (
-                        round(pers_loop_totals[name] / loop_wall, 4)
-                        if loop_wall > 0
-                        else 0.0
-                    )
         if flow_done > 0:
             out["dispatches_per_decision"] = round(
                 flow_disp / flow_done, 4
@@ -1183,49 +967,6 @@ class EngineProfiler:
                 out["mfu_device"] = mfu["device"]
             for name, value in (mfu.get("loss") or {}).items():
                 out[f"mfu_loss_{name}"] = value
-        return out
-
-    def persistent_gauges(self) -> dict[str, float]:
-        """The `llm_scheduler_persistent_*` gauge family: a flat numeric
-        subtree the metrics server mounts at stats["persistent"] (the
-        Prometheus renderer prefixes flattened paths with
-        llm_scheduler_). Fleet-merge aware by NAMING: `*_frac` leaves
-        average across replicas (fleetview._RATIO_SUFFIXES), plain
-        counters/rates SUM — a fleet's resident tok/s is the sum of its
-        replicas', its segment mix the mean. `tokens_total` is the
-        monotone counter the SLO throughput floor windows."""
-        with self._lock:
-            pers_totals = dict(self._pers_totals)
-            pers = self.persistent_profiled
-            pers_tokens = self._pers_tokens
-            pers_steps = self._pers_steps
-            pers_loop_totals = dict(self._pers_loop_totals)
-            pers_loop = self.persistent_loop_profiled
-            tokens_cum = self._pers_tokens_cum
-        out: dict[str, float] = {
-            "harvests": float(pers),
-            "steps": float(pers_steps),
-            "tokens": float(pers_tokens),
-            "tokens_total": float(tokens_cum),
-            "loop_windows": float(pers_loop),
-        }
-        pers_wall = pers_totals["wall"]
-        out["resident_tokens_per_s"] = (
-            round(pers_tokens / pers_wall, 1) if pers_wall > 0 else 0.0
-        )
-        for name in PERSISTENT_SEGMENTS:
-            out[f"{name}_frac"] = (
-                round(pers_totals[name] / pers_wall, 4)
-                if pers_wall > 0
-                else 0.0
-            )
-        loop_wall = pers_loop_totals["wall"]
-        for name in PERSISTENT_LOOP_SEGMENTS:
-            out[f"loop_{name}_frac"] = (
-                round(pers_loop_totals[name] / loop_wall, 4)
-                if loop_wall > 0
-                else 0.0
-            )
         return out
 
     def close(self) -> None:

@@ -118,15 +118,8 @@ class EngineSampler:
         out["hbm_used_frac"] = self._hbm_used_frac()
 
         tokens = int(stats.get("decode_tokens", 0))
-        # Harvest progress marker: dispatch-path harvests bump `syncs`,
-        # but persistent-loop harvests are RING traffic — zero dispatches,
-        # zero syncs (the whole point of engine/persistent/). Folding the
-        # chunk counter in means resident emissions advance the rate
-        # baseline too; without it steady-state serving read ~0 tok/s in
-        # /debug/engine (every window looked "no harvest landed").
-        syncs = int(stats.get("syncs", 0)) + int(
-            stats.get("persistent_chunks", 0)
-        )
+        # Harvest progress marker: every harvest bumps `syncs`.
+        syncs = int(stats.get("syncs", 0))
         # The rate baseline, clock read, and ring appends share ONE lock
         # acquisition: the background thread and /debug/engine's
         # cold-sample path (handler threads) may sample concurrently, and

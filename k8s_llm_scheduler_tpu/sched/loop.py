@@ -116,13 +116,6 @@ class Scheduler:
         # decision trace carries shard_id in its meta so /debug/decisions
         # and `cli trace` answer "which replica's shard was this?".
         self.shard_fn = None
-        # Optional in-loop latency probe (engine.resident_decision_latency,
-        # attached by the cli run wiring when the backend serves from the
-        # persistent loop): ring-served decisions have NO dispatch-fenced
-        # engine spans — the work happened inside one resident XLA program
-        # — so LLM decisions attach the probe's EWMA as a SYNTHETIC
-        # `loop_resident` span and `cli trace show` explains them again.
-        self.resident_latency_fn = None
         self.stats = {
             "total_scheduled": 0,
             "llm_decisions": 0,
@@ -186,29 +179,6 @@ class Scheduler:
         if trace is not None and self.shard_fn is not None:
             trace.set_meta(shard_id=self.shard_fn(pod.namespace, pod.name))
 
-    def _attach_resident_span(self, trace) -> None:
-        """Synthetic `loop_resident` span on an LLM decision: the
-        counter-derived EWMA of in-loop admission-to-first-emission
-        latency (probe wired by cli run). Marked synthetic=True — it is
-        an attribution estimate from device counters, not a fenced
-        measurement, and the trace viewer labels it as such. Backdated so
-        the span sits inside the decide window it explains."""
-        if trace is None or self.resident_latency_fn is None:
-            return
-        try:
-            lat_ms = self.resident_latency_fn()
-        except Exception:
-            logger.exception("resident latency probe failed")
-            return
-        if not lat_ms:
-            return
-        trace.add_span(
-            "loop_resident",
-            start_unix=time.time() - lat_ms / 1000.0,  # graftlint: ok[raw-clock] — wall ANCHOR backdating a retroactive span (duration comes from device counters)
-            dur_ms=float(lat_ms),
-            synthetic=True,
-        )
-
     async def _schedule_pod_inner(self, pod, trace) -> bool:
         with self.phases.phase("snapshot"), spans.span("snapshot", layer="sched"):
             nodes = await self._node_snapshot()
@@ -239,7 +209,6 @@ class Scheduler:
             self.stats["cache_decisions"] += 1
         else:
             self.stats["llm_decisions"] += 1
-            self._attach_resident_span(trace)
         _stamp_decision(trace, decision)
 
         if self.shadow is not None:

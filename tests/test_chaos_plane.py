@@ -120,7 +120,6 @@ class TestFaultPlan:
         for name, info in REGIMES.items():
             assert info["mode"] in (
                 "single", "wire", "fleet", "autoscale", "crash",
-                "persistent",
             ), name
 
     def test_every_regime_generates_at_minimum_waves(self):
@@ -717,84 +716,6 @@ class TestLearnSwapRegime:
             == canonical_chaos_bytes(build_chaos_trace(r2))
         )
         path = tmp_path / "learn-swap.trace"
-        save_chaos_trace(r1, path)
-        ok, detail = verify_chaos_trace(path)
-        assert ok, detail
-
-
-class TestPersistentWedgeRegime:
-    """PR-level test for the persistent serving plane's ring protocol
-    under fire: the REAL CommandRing/TokenRing/Heartbeat (the host side
-    of the resident loop's io_callbacks) driven by the chaos stub loop
-    through admission backpressure, a watchdog-drained wedge, and a
-    stalled emission consumer — with the token_integrity invariant
-    booking every request's delivered stream against its expected one."""
-
-    _KW = dict(
-        seed=3, n_waves=6, n_nodes=8, n_pods=36,
-        wave_timeout_s=15.0, quality=False,
-    )
-
-    def test_rings_under_fire_lose_nothing(self):
-        report = run_chaos("persistent-wedge", **self._KW)
-        assert report["invariants"]["clean"], report["invariants"]
-        p = report["persistent"]
-        # the zero-loss contract: every emission of every request was
-        # delivered exactly once, whichever path carried it
-        assert p["tokens_lost"] == 0
-        assert p["tokens_duplicated"] == 0
-        assert p["tokens_corrupted"] == 0
-        # all three fault windows genuinely engaged the plane
-        assert p["ring_full_rejects"] >= 1       # backpressure bit
-        assert p["wedges"] == 1                  # watchdog tripped
-        assert p["drains"] == 1                  # graceful drain ran
-        assert p["relaunches"] >= 1              # plane came back
-        # both completion paths carried real work, and nothing vanished
-        assert p["completed_ring"] > 0
-        assert p["completed_fallback"] > 0
-        assert p["completed_ring"] + p["completed_fallback"] == 36
-        assert report["injections"].get("persistent.ring_full", 0) >= 1
-        assert report["injections"].get("persistent.loop_wedge", 0) >= 1
-        assert (
-            report["injections"].get("persistent.consumer_stall", 0) >= 1
-        )
-        # every request was token-integrity-checked and bound once
-        assert report["invariants"]["checks"]["token_integrity"] == 36
-        assert report["invariants"]["checks"]["exactly_once_bind"] == 36
-        assert report["scores"]["bound_frac"] == 1.0
-
-    def test_wedge_dumps_bounded_blackbox(self):
-        report = run_chaos("persistent-wedge", **self._KW)
-        p = report["persistent"]
-        # the watchdog latch dumped the black-box (same order as the
-        # real server: dump first, then drain)
-        bb = p.get("blackbox")
-        assert bb is not None and bb["reason"] == "wedge"
-        # BOUNDED: depth 16 < the regime's admit count, so the ring
-        # genuinely evicted — recorded counts everything, snapshots
-        # hold only the last N
-        assert len(bb["snapshots"]) <= bb["depth"] == 16
-        assert bb["recorded"] > len(bb["snapshots"])
-        # the latch event is the newest snapshot, and admissions
-        # preceding the wedge are present in FIFO order
-        assert bb["snapshots"][-1]["event"] == "wedge_drain"
-        admits = [s for s in bb["snapshots"] if s["event"] == "admit"]
-        assert admits and all(
-            s["budget"] > 0 and s["slot"] >= 0 for s in admits
-        )
-
-    def test_regime_trace_replays_byte_identically(self, tmp_path):
-        r1 = run_chaos("persistent-wedge", **self._KW)
-        r2 = run_chaos("persistent-wedge", **self._KW)
-        # the trace carries the ring-protocol books: a drain that moved
-        # a placement, or a timing-dependent ring/fallback split, would
-        # break byte-identity here
-        assert (
-            canonical_chaos_bytes(build_chaos_trace(r1))
-            == canonical_chaos_bytes(build_chaos_trace(r2))
-        )
-        assert "persistent" in build_chaos_trace(r1)
-        path = tmp_path / "persistent-wedge.trace"
         save_chaos_trace(r1, path)
         ok, detail = verify_chaos_trace(path)
         assert ok, detail

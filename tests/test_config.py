@@ -15,10 +15,9 @@ class TestDefaults:
         assert cfg.get("circuit_breaker.failure_threshold") == 5
 
     def test_tpu_fields_present(self):
-        """The north-star llm block additions: mesh/sharding/max_batch."""
+        """The north-star llm block additions: mesh/max_batch."""
         cfg = load_config(yaml_path=None, env={})
         assert cfg.get("llm.mesh") == {"dp": 1, "tp": 1}
-        assert cfg.get("llm.sharding") == "tensor_parallel"
         assert cfg.get("llm.max_batch") == 8
 
     def test_formerly_dead_keys_live(self):

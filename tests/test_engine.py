@@ -17,9 +17,6 @@ from k8s_llm_scheduler_tpu.models.configs import LlamaConfig
 from k8s_llm_scheduler_tpu.models.llama import init_params
 from k8s_llm_scheduler_tpu.utils.json_extract import parse_decision_json
 
-# Everything here jit-compiles models/kernels (seconds per test):
-# full-suite only, excluded from the fast tier (TESTING.md).
-pytestmark = pytest.mark.slow
 
 TOK = ByteTokenizer()
 
@@ -516,7 +513,11 @@ class TestChunkedPrefix:
         import logging
 
         eng = self._engine((64, 128, 4096))
-        with caplog.at_level(logging.WARNING):
+        # by the logger's own name: other test modules raise the package
+        # logger's level at import, and every xdist worker imports them
+        with caplog.at_level(
+            logging.WARNING, logger="k8s_llm_scheduler_tpu.engine.engine"
+        ):
             eng.set_prefix([1] * (ENGINE_CFG.max_seq_len + 10))
         assert any("max_seq_len" in r.message for r in caplog.records)
         assert eng.prefix_len == ENGINE_CFG.max_seq_len + 10

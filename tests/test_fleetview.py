@@ -118,81 +118,6 @@ class TestHistogramMerge:
         assert agg.render_prometheus() == render_prometheus(stats)
 
 
-class TestPersistentFleetView:
-    """The resident-loop gauge family in the fleet plane: build_telemetry
-    hoists engine.persistent to the payload top level (same
-    llm_scheduler_persistent_* family the per-replica /metrics mounts),
-    the merge sums fleet throughput while averaging the _frac-suffixed
-    ring occupancy, and `cli fleet top` renders the ring/res_tok-s
-    columns with '-' for dispatch-path members of a mixed fleet."""
-
-    @staticmethod
-    def _stats(tps, occ, tokens, windows):
-        # Shape of sched/client.get_stats: backend stats nested under
-        # "engine", with the profiler gauge subtree at engine.persistent.
-        return {
-            "total_scheduled": 10,
-            "engine": {
-                "persistent_ring_occupancy_frac": occ,
-                "persistent": {
-                    "resident_tokens_per_s": tps,
-                    "tokens_total": tokens,
-                    "loop_windows": windows,
-                },
-            },
-        }
-
-    def test_build_telemetry_hoists_engine_persistent(self):
-        stats = self._stats(100.0, 0.5, 400, 4)
-        payload = build_telemetry(stats)
-        assert (
-            payload["stats"]["persistent"]["resident_tokens_per_s"] == 100.0
-        )
-        assert "persistent" not in stats  # caller's dict not mutated
-        # an already-hoisted tree passes through untouched
-        pre = {
-            "persistent": {"tokens_total": 7},
-            "engine": {"persistent": {"tokens_total": 9}},
-        }
-        assert build_telemetry(pre)["stats"]["persistent"]["tokens_total"] == 7
-
-    def test_merge_sums_throughput_and_means_occupancy(self):
-        agg = FleetAggregator()
-        agg.add_local("a", lambda: self._stats(100.0, 0.5, 400, 4))
-        agg.add_local("b", lambda: self._stats(50.0, 0.25, 200, 2))
-        agg.pull_all()
-        merged = agg.merged_stats()
-        # tok/s has no ratio suffix ON PURPOSE: summing per-replica
-        # resident throughput IS the fleet throughput...
-        assert merged["persistent"]["resident_tokens_per_s"] == 150.0
-        assert merged["persistent"]["tokens_total"] == 600
-        # ...while ring occupancy is _frac-suffixed so the merge reports
-        # the fleet mean, not a >1.0 sum.
-        assert merged["engine"][
-            "persistent_ring_occupancy_frac"
-        ] == pytest.approx(0.375)
-
-    def test_render_top_resident_columns_mixed_fleet(self):
-        agg = FleetAggregator()
-        agg.add_local("resident-0", lambda: self._stats(123.4, 0.5, 400, 4))
-        agg.add_local("dispatch-0", lambda: {"total_scheduled": 5})
-        agg.pull_all()
-        frame = render_top(agg)
-        assert "tok/s=123.4" in frame  # fleet resident headline
-        header = next(l for l in frame.splitlines() if "res_tok/s" in l)
-        assert "ring" in header
-        rows = {
-            line.split()[0]: line.split()
-            for line in frame.splitlines()
-            if line.strip().startswith(("resident-0", "dispatch-0"))
-        }
-        # name bound llm cache p99 ring res_tok/s shards state
-        assert rows["resident-0"][5] == "0.50"
-        assert rows["resident-0"][6] == "123.4"
-        assert rows["dispatch-0"][5] == "-"
-        assert rows["dispatch-0"][6] == "-"
-
-
 class TestAggregatorMembership:
     def test_replica_joins_mid_scrape(self):
         """A replica joining between rounds contributes its partial bucket

@@ -427,7 +427,6 @@ class TestRefusedPaths:
         (dict(quantize="int8"), "llm.quantization"),
         (dict(checkpoint_path="/nonexistent"), "llm.checkpoint_path"),
         (dict(spec_enabled=True), "llm.spec_enabled"),
-        (dict(persistent_loop=True), "llm.persistent_loop"),
         (dict(decode_matmul="ragged"), "llm.decode_matmul"),
     ])
     def test_build_refuses(self, kwargs, path):
@@ -446,7 +445,6 @@ class TestRefusedPaths:
         (lambda e: e.step(), "step()"),
         (lambda e: e.step_fused(), "step_fused()"),
         (lambda e: e.decode_fused(), "decode_fused()"),
-        (lambda e: e.enter_persistent(), "enter_persistent()"),
         (lambda e: e.attach_spec(object()), "attach_spec()"),
     ])
     def test_paged_entry_points_refuse(self, stack, call, path):

@@ -18,9 +18,6 @@ from k8s_llm_scheduler_tpu.models.llama import (
     rope_inv_freq,
 )
 
-# Everything here jit-compiles models/kernels (seconds per test):
-# full-suite only, excluded from the fast tier (TESTING.md).
-pytestmark = pytest.mark.slow
 
 CFG = LlamaConfig(
     name="test", vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,

@@ -506,48 +506,6 @@ class TestDebugEndpoints:
         finally:
             server.stop()
 
-    def test_debug_blackbox_endpoint(self, recorder):
-        """/debug/blackbox serves the persistent loop's black-box dump:
-        404 when no provider is mounted (non-persistent backend), 404
-        with a distinct body while the provider has nothing to dump yet
-        (no residency, or telemetry off), JSON once a dump exists."""
-        server = MetricsServer(
-            lambda: {}, port=0, host="127.0.0.1", flight_recorder=recorder,
-        )
-        server.start()
-        try:
-            with pytest.raises(urllib.error.HTTPError) as err:
-                urllib.request.urlopen(
-                    f"http://127.0.0.1:{server.port}/debug/blackbox"
-                )
-            assert err.value.code == 404
-        finally:
-            server.stop()
-
-        dump_holder = {"dump": None}
-        server = MetricsServer(
-            lambda: {}, port=0, host="127.0.0.1", flight_recorder=recorder,
-            blackbox_provider=lambda: dump_holder["dump"],
-        )
-        server.start()
-        try:
-            base = f"http://127.0.0.1:{server.port}"
-            with pytest.raises(urllib.error.HTTPError) as err:
-                urllib.request.urlopen(f"{base}/debug/blackbox")
-            assert err.value.code == 404
-            assert b"no black-box dump yet" in err.value.read()
-            dump_holder["dump"] = {
-                "reason": "wedge", "depth": 4, "recorded": 9,
-                "snapshots": [{"push": 8, "counters": {"emitted": 7}}],
-            }
-            body = json.loads(
-                urllib.request.urlopen(f"{base}/debug/blackbox").read()
-            )
-            assert body["reason"] == "wedge"
-            assert body["snapshots"][0]["counters"]["emitted"] == 7
-        finally:
-            server.stop()
-
     def test_handler_survives_client_disconnect(self, recorder):
         """A client that closes mid-exchange must not wedge or kill the
         server: the next request still answers (the handler class also
@@ -618,39 +576,6 @@ class TestEngineSampler:
         assert len(series["series"]["tokens_per_s"]) == 4
         # ages are relative to the newest sample (newest == 0)
         assert series["series"]["tokens_per_s"][-1][0] == 0.0
-
-    def test_persistent_chunks_count_as_harvest_progress(self):
-        """Resident-loop emissions land via the token ring — zero
-        dispatches, zero `syncs`. The sampler folds `persistent_chunks`
-        into its harvest-progress marker, so steady-state persistent
-        serving reports a real tok/s instead of a permanently-unknown
-        window (the pre-fix symptom: /debug/engine read ~0 under load)."""
-        eng = self.FakeEngine()
-        eng.stats.update({"syncs": 0, "persistent_chunks": 0})
-        clock = {"t": 50.0}
-        sampler = EngineSampler(
-            eng, interval_s=1.0, window=4, clock=lambda: clock["t"]
-        )
-        sampler.sample_once()  # baseline
-        # No tokens AND no harvest marker: the device may be mid-chunk —
-        # the rate is UNKNOWN, not zero.
-        clock["t"] = 51.0
-        assert sampler.sample_once()["tokens_per_s"] is None
-        # A persistent chunk lands with zero new tokens (still zero
-        # dispatch-path syncs): that IS harvest evidence, so the window
-        # is genuine idle — 0.0, and the baseline advances. Pre-fix this
-        # window read None: a quiet resident loop was indistinguishable
-        # from a mid-chunk one.
-        eng.stats["persistent_chunks"] = 1
-        clock["t"] = 52.0
-        assert sampler.sample_once()["tokens_per_s"] == 0.0
-        # Emissions over the next chunk report against the advanced
-        # baseline, not the whole residency.
-        eng.stats["decode_tokens"] = 256
-        eng.stats["persistent_chunks"] = 2
-        clock["t"] = 54.0
-        out = sampler.sample_once()
-        assert out["tokens_per_s"] == pytest.approx(128.0)
 
     def test_background_thread(self):
         eng = self.FakeEngine()

@@ -16,10 +16,6 @@ from k8s_llm_scheduler_tpu.ops.pallas_paged_attention import (
     paged_decode_attention_pallas,
 )
 
-# Everything here jit-compiles models/kernels (seconds per test):
-# full-suite only, excluded from the fast tier (TESTING.md).
-pytestmark = pytest.mark.slow
-
 
 def _random_case(
     rng,

@@ -17,9 +17,6 @@ from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer
 from k8s_llm_scheduler_tpu.models.configs import LlamaConfig
 from k8s_llm_scheduler_tpu.models.llama import init_params
 
-# Everything here jit-compiles models/kernels (seconds per test):
-# full-suite only, excluded from the fast tier (TESTING.md).
-pytestmark = pytest.mark.slow
 
 TOK = ByteTokenizer()
 

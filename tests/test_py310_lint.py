@@ -120,11 +120,6 @@ class TestRepoIsClean:
         assert "k8s_llm_scheduler_tpu/fleet/kvplane/pages.py" in files
         assert "k8s_llm_scheduler_tpu/fleet/kvplane/stub.py" in files
         assert "tests/test_kvplane.py" in files
-        # resident-telemetry round: the device-resident telemetry plane
-        # (stats ring + black-box are condition-variable/thread-heavy —
-        # the same risk class as the token ring they mirror)
-        assert "k8s_llm_scheduler_tpu/observability/resident.py" in files
-        assert "tests/test_resident_telemetry.py" in files
         # interprocedural-graftlint round: the analysis engine's test
         # file rides the normal scan; the engine's OWN tree is excluded
         # here (rule modules are pattern tables) and covered instead by

@@ -15,9 +15,6 @@ from k8s_llm_scheduler_tpu.models.quant import (
     quantize_weight,
 )
 
-# Everything here jit-compiles models/kernels (seconds per test):
-# full-suite only, excluded from the fast tier (TESTING.md).
-pytestmark = pytest.mark.slow
 
 CFG = LlamaConfig(
     name="quant-test", vocab_size=256, d_model=64, n_layers=2, n_heads=4,

@@ -255,101 +255,6 @@ class TestMultiWindow:
         assert results["e"]["fast"]["burn"] == 0.0
 
 
-class TestPersistentObjectives:
-    """The resident-loop objective pair from config.yaml's slo examples:
-    an error-rate budget on wedges per launch and a throughput floor on
-    the profiler's cumulative resident token counter — declared straight
-    from config against the stats shape cli run's provider mounts
-    (`engine.persistent_*` flat counters + `persistent` gauge family),
-    with the RISING-edge trip contract pinned."""
-
-    @staticmethod
-    def _engine(clock, state):
-        eng = from_config(
-            {
-                "enabled": True,
-                "fast_window_s": 10.0,
-                "slow_window_s": 20.0,
-                "objectives": [
-                    {
-                        "name": "persistent_wedges", "kind": "error_rate",
-                        "numerator": "engine.persistent_wedges",
-                        "denominator": "engine.persistent_launches",
-                        "budget": 0.05,
-                        "fast_burn_threshold": 2.0,
-                        "slow_burn_threshold": 2.0,
-                    },
-                    {
-                        "name": "resident_floor", "kind": "throughput",
-                        "counter": "persistent.tokens_total",
-                        "min_per_s": 10.0,
-                    },
-                ],
-            },
-            lambda: {
-                "engine": {
-                    "persistent_launches": state["launches"],
-                    "persistent_wedges": state["wedges"],
-                },
-                "persistent": {"tokens_total": state["tokens"]},
-            },
-            clock=lambda: clock["t"],
-        )
-        assert eng is not None
-        return eng
-
-    def test_wedge_error_rate_trips_on_rising_edge_only(self):
-        clock = {"t": 0.0}
-        state = {"launches": 0, "wedges": 0, "tokens": 0}
-        eng = self._engine(clock, state)
-        trips: list[str] = []
-        eng.on_trip.append(lambda name, _d: trips.append(name))
-        eng.evaluate()
-        # healthy serving: many launches, comfortable token rate, no wedge
-        state.update(launches=20, wedges=0, tokens=4000)
-        clock["t"] = 30.0
-        results = eng.evaluate()
-        assert not results["persistent_wedges"]["tripped"]
-        assert not results["resident_floor"]["tripped"]
-        # wedge storm: 5 wedges in 10 launches vs 5% budget = 10x burn
-        state.update(launches=30, wedges=5, tokens=8000)
-        clock["t"] = 60.0
-        results = eng.evaluate()
-        assert results["persistent_wedges"]["fast"]["burn"] > 2.0
-        assert results["persistent_wedges"]["tripped"]
-        assert trips == ["persistent_wedges"]
-        # still tripped on the next tick: the hook must NOT re-fire
-        state.update(launches=40, wedges=10, tokens=12000)
-        clock["t"] = 90.0
-        results = eng.evaluate()
-        assert results["persistent_wedges"]["tripped"]
-        assert trips == ["persistent_wedges"]
-
-    def test_resident_throughput_floor(self):
-        clock = {"t": 0.0}
-        state = {"launches": 1, "wedges": 0, "tokens": 0}
-        eng = self._engine(clock, state)
-        eng.evaluate()
-        # 400 tokens over 10s = 40 tok/s >> the 10 tok/s floor
-        state["tokens"] = 400
-        clock["t"] = 10.0
-        results = eng.evaluate()
-        assert results["resident_floor"]["fast"]["burn"] == pytest.approx(
-            0.25
-        )
-        assert not results["resident_floor"]["tripped"]
-        # sustained starvation: ~1 tok/s across both windows
-        state["tokens"] = 410
-        clock["t"] = 20.0
-        eng.evaluate()
-        state["tokens"] = 412
-        clock["t"] = 30.0
-        results = eng.evaluate()
-        assert results["resident_floor"]["fast"]["burn"] > 1.0
-        assert results["resident_floor"]["slow"]["burn"] > 1.0
-        assert results["resident_floor"]["tripped"]
-
-
 class TestSurfaces:
     def test_gauges_and_snapshot(self):
         clock = {"t": 0.0}

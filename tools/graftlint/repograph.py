@@ -585,20 +585,6 @@ class RepoGraph:
         self._jit_roots = frozenset(roots)
         return self._jit_roots
 
-    def steady_roots(self) -> frozenset[str]:
-        """The persistent serving plane's declared steady-path functions
-        (name contract: `*_steady`, or the ordered-io_callback bodies)."""
-        memo = getattr(self, "_steady_roots", None)
-        if memo is not None:
-            return memo
-        out = frozenset(
-            g for g, e in self.funcs.items()
-            if e.name.endswith("_steady")
-            or e.name in ("_device_poll", "_device_push")
-        )
-        self._steady_roots = out
-        return self._steady_roots
-
     # -------------------------------------------------------- resolution
     def _resolve_import(self, module_rel: str, name: str) -> list[str]:
         """Follow `name` through `module_rel`'s import table to defs."""
@@ -756,7 +742,7 @@ class RepoGraph:
                         out.append(callee)
             # a function lexically encloses its nested defs: treat the
             # closure as part of the enclosing protocol (install() runs
-            # inside swap_to's contract, feeders build their _steady body)
+            # inside swap_to's contract)
             for gq, _e in self._children_of(g):
                 if gq not in seen:
                     seen.add(gq)

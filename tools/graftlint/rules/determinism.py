@@ -1,7 +1,7 @@
 """Determinism rule family: byte-replayability of the trace plane.
 
 The repo's replay story (sim/trace.canonical_bytes, the chaos harness's
-canonical_chaos_bytes, the resident black-box, the decision journal) is
+canonical_chaos_bytes, the decision journal) is
 a BYTE contract: two runs with the same seed must serialize identical
 artifacts, and the digests in rollout/registry.py make any divergence a
 hard failure. Python offers four quiet ways to break that contract and

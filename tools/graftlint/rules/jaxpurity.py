@@ -510,100 +510,10 @@ class DonatedBufferReuse(LintRule):
             )
 
 
-class DispatchInPersistentPath(LintRule):
-    id = "dispatch-in-persistent-path"
-    family = "jax"
-    description = (
-        "an XLA dispatch (jax.*/jnp.* call, a jitted program, or "
-        ".block_until_ready) inside the persistent loop's steady-state "
-        "path — the path whose whole contract is zero per-decision "
-        "dispatches"
-    )
-
-    # The persistent serving plane's ZERO-DISPATCH steady-state contract
-    # (engine/persistent/): once the resident loop is launched, every
-    # per-decision interaction is ring traffic — numpy in, numpy out. A
-    # function is a declared steady-path function when its name ends in
-    # `_steady` (the feeder/harvester naming convention server.py
-    # established) or is one of the ordered-io_callback bodies; anything
-    # the repo graph says is reachable from one (strict dispatch, across
-    # modules now) is on the steady path too.
-
-    def check(self, ctx: FileContext) -> Iterable[Finding]:
-        if not _loop_scope(ctx.name):
-            return
-        repo = ctx.repo
-        steady = repo.steady_roots()
-        if not steady:
-            return
-        reach = repo.reachable(steady, dispatch="strict")
-        on_path = [
-            (qual, node)
-            for qual, node, _cls in ctx.graph_funcs()
-            if ctx.gqual(qual) in reach
-        ]
-        if not on_path:
-            return
-        # `name = jax.jit(...)` assignment targets anywhere in the module
-        # (`self._jitted = jax.jit(...)`): calling one re-enters the
-        # dispatch path even though the name itself is not jax.*
-        jitted_names: set[str] = set()
-        for node in ctx.all_nodes():
-            if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call) \
-                    and _is_jit_call(node.value):
-                for t in node.targets:
-                    tn = dotted_name(t)
-                    if tn:
-                        jitted_names.add(tn)
-        jit_roots = repo.jit_roots()
-        for qual, func in on_path:
-            g = ctx.gqual(qual)
-            for node in body_walk(func):
-                if not isinstance(node, ast.Call):
-                    continue
-                msg = self._classify(node, repo, g, jitted_names, jit_roots)
-                if msg:
-                    yield ctx.finding(
-                        self, node,
-                        f"{msg} inside `{qual}`, which is on the "
-                        f"persistent loop's steady-state path — steady "
-                        f"serving must be pure ring traffic (numpy + "
-                        f"threading), or the zero-dispatch-per-decision "
-                        f"contract is silently broken; route device work "
-                        f"through the launch/quiesce boundary or justify "
-                        f"via pragma",
-                    )
-
-    @staticmethod
-    def _classify(
-        call: ast.Call, repo, caller_g: str, jitted_names: set[str],
-        jit_roots: frozenset[str],
-    ) -> str | None:
-        if isinstance(call.func, ast.Attribute) \
-                and call.func.attr == "block_until_ready":
-            return "device sync `.block_until_ready()`"
-        name = dotted_name(call.func)
-        if not name:
-            return None
-        if name in jitted_names:
-            return f"call to jitted program `{name}`"
-        head = name.split(".", 1)[0]
-        if head in ("jax", "jnp"):
-            return f"XLA dispatch `{name}(...)`"
-        # a strictly-resolved callee that is itself a jit root re-enters
-        # the dispatch path by name
-        for callee in repo.resolve_call(caller_g, name, dispatch="strict"):
-            if callee in jit_roots:
-                bare = name.rsplit(".", 1)[-1]
-                return f"call to jit-rooted `{bare}`"
-        return None
-
-
 JAX_RULES: list[LintRule] = [
     HostSyncInJit(),
     ClosureMutationInJit(),
     NonHashableStatic(),
     DeviceSyncInLoop(),
     DonatedBufferReuse(),
-    DispatchInPersistentPath(),
 ]
