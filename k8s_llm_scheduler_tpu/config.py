@@ -63,7 +63,7 @@ DEFAULTS: dict[str, Any] = {
         "max_batch": 8,
         "page_size": 128,
         "max_pages_per_seq": 64,
-        "prefill_buckets": [256, 512, 1024, 2048, 4096, 8192],
+        "prefill_buckets": [128, 256, 512, 1024, 2048, 4096, 8192],
         "checkpoint_path": None,
         "quantization": None,  # None | "int8" (weight-only, models/quant.py)
         "tokenizer_path": None,

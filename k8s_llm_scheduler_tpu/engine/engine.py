@@ -942,6 +942,10 @@ class InferenceEngine:
             "requests": 0,
             "completed": 0,
             "prefill_tokens": 0,
+            # wave rows x the width the wave's suffix prefill was compiled
+            # for: what the device computes where prefill_tokens counts
+            # the real suffix tokens (padding rows and columns included)
+            "suffix_tokens_computed": 0,
             "prefix_prefills": 0,
             "prefix_hits": 0,
             "decode_tokens": 0,
@@ -1997,6 +2001,7 @@ class InferenceEngine:
         self.stats["prefills"] += 1
         self.stats["dispatches"] += 1
         self.stats["prefill_tokens"] += int(suffix_lens.sum())
+        self.stats["suffix_tokens_computed"] += R * bucket
         self.stats["requests"] += len(prompts)
         handle = WaveHandle(
             toks_d=toks_d,

@@ -29,6 +29,32 @@ class TestDefaults:
         assert cfg.get("circuit_breaker.half_open_max_calls") == 1
 
 
+class TestPrefillLadder:
+    """llm.prefill_buckets is stated four times; a wave's suffix prefill is
+    compiled at the smallest entry that holds its longest suffix, so a
+    ladder that lacks a step pads every wave to the next one (PERF.md §6,
+    PR 33: config.py began at 256 and every 64-85-token wave ran at 256)."""
+
+    @pytest.mark.parametrize("where", ["engine", "build_local_backend", "config.yaml"])
+    def test_every_statement_of_the_ladder_is_config_pys(self, where):
+        import inspect
+        from pathlib import Path
+
+        from k8s_llm_scheduler_tpu.config import DEFAULTS
+
+        if where == "config.yaml":
+            path = Path(__file__).resolve().parents[1] / "config.yaml"
+            stated = load_config(yaml_path=path, env={}).get("llm.prefill_buckets")
+        else:
+            from k8s_llm_scheduler_tpu.engine.engine import InferenceEngine
+            from k8s_llm_scheduler_tpu.engine.local import build_local_backend
+
+            fn = InferenceEngine.__init__ if where == "engine" else build_local_backend
+            stated = inspect.signature(fn).parameters["prefill_buckets"].default
+        assert list(stated) == DEFAULTS["llm"]["prefill_buckets"]
+        assert list(stated)[:2] == [128, 256]
+
+
 class TestYamlLayer:
     def test_yaml_overrides_defaults(self, tmp_path):
         path = tmp_path / "config.yaml"

@@ -335,6 +335,61 @@ class TestDecideWave:
             engine.set_grammar(None)
 
 
+def _family_engine(family_name, buckets):
+    """A toy engine of either model family whose ladder is `buckets`."""
+    from k8s_llm_scheduler_tpu.models import family
+    from k8s_llm_scheduler_tpu.models.configs import get_config
+
+    cfg = ENGINE_CFG if family_name == "dense_gqa" else get_config("tiny-mla-moe")
+    params = family(cfg).init_params(jax.random.PRNGKey(0), cfg)
+    return InferenceEngine(
+        params, cfg, TOK, num_pages=8, page_size=64, max_slots=4,
+        max_pages_per_seq=8, prefill_buckets=buckets, chunk_steps=4,
+        temperature=0.0,
+    )
+
+
+class TestWaveWidth:
+    """A wave's suffix prefill is compiled at the smallest entry of the
+    ladder that holds its longest suffix: the width changes the padding,
+    never the tokens served."""
+
+    NAMES = ["node-0", "node-1", "node-2"]
+
+    def _serve(self, eng, prompts):
+        eng.set_grammar(build_decision_dfa(TOK, self.NAMES, max_reason_tokens=12))
+        return eng.harvest_wave(eng.submit_wave(prompts, max_new_tokens=120))
+
+    @pytest.mark.parametrize("family_name", ["dense_gqa", "mla_moe"])
+    def test_a_ladder_from_128_serves_what_one_from_256_serves(self, family_name):
+        prompts = [
+            TOK.chat_prompt("pick a node", f"pod-{i} wants " + "cpu " * (3 + 4 * i))
+            for i in range(3)
+        ]
+        assert 64 <= max(map(len, prompts)) <= 128
+        served = {}
+        for first in (128, 256):
+            eng = _family_engine(family_name, (first, 512))
+            fins = self._serve(eng, prompts)
+            assert all(json.loads(f.text)["selected_node"] in self.NAMES for f in fins)
+            # 3 prompts ride the 4-row program: R x the width, padding included
+            assert eng.stats["suffix_tokens_computed"] == 4 * first
+            assert eng.stats["prefill_tokens"] == sum(map(len, prompts))
+            served[first] = [f.token_ids for f in fins]
+        assert served[128] == served[256]
+
+    def test_one_suffix_of_129_tokens_takes_the_whole_wave_to_256(self):
+        eng = _family_engine("dense_gqa", (128, 256, 512))
+        short = TOK.chat_prompt("s", "u")
+        long = (short + TOK.encode("x" * 200))[:129]
+        handle = eng.submit_wave([short, long], max_new_tokens=8)
+        assert handle.bucket == 256
+        assert eng.stats["suffix_tokens_computed"] == 2 * 256
+        eng.harvest_wave(handle)
+        eng.harvest_wave(eng.submit_wave([short, long[:128]], max_new_tokens=8))
+        assert eng.stats["suffix_tokens_computed"] == 2 * 256 + 2 * 128
+
+
 class TestGrammarBudget:
     def test_zero_reason_tokens_still_valid(self):
         dfa = build_decision_dfa(TOK, ["node-1"], max_reason_tokens=0)
