@@ -75,7 +75,7 @@ from k8s_llm_scheduler_tpu.observability import spans
 from k8s_llm_scheduler_tpu.engine.kv_cache import PagedKVCache
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
 from k8s_llm_scheduler_tpu.models import family
-from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, MlaMoeConfig
+from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, MlaMoeConfig, MlaScmoeConfig
 from k8s_llm_scheduler_tpu.models.llama import (
     Params,
     forward_decode_buffered,
@@ -315,7 +315,7 @@ def _decode_chunk_impl(
 
 def _wave_impl(
     params: Params,
-    cfg: LlamaConfig | MlaMoeConfig,  # static
+    cfg: LlamaConfig | MlaMoeConfig | MlaScmoeConfig,  # static
     tokens,        # [R, Ss] suffix tokens, left-aligned, padded
     suffix_lens,   # [R] int32 (0 on padding rows)
     prefix_cache,  # the model's cache tuple of the shared prefix, each
@@ -402,7 +402,7 @@ def _wave_impl(
     # beyond `cap` so that a block's whole window fits behind any tail
     # (ops/attention.write_block)
     gen = tuple(
-        jnp.zeros((cfg.n_layers, R, cap + F, *shape), prefix_cache[0].dtype)
+        jnp.zeros((model.cache_layers(cfg), R, cap + F, *shape), prefix_cache[0].dtype)
         for shape in model.cache_token_shapes(cfg)
     )
     if shardings is not None:
@@ -640,9 +640,11 @@ class InferenceEngine:
         # cache tuple's shapes and the three forwards of the decision path.
         # `paged` = the family also has the paged-pool forwards (admit,
         # decode chunks, the fused loop, spec/): the dense
-        # family has, the latent one does not — its entry points refuse by
-        # name (_require_paged) and no pool is allocated for it.
+        # family has, the latent ones (models/mla_moe.py, mla_scmoe.py) do
+        # not — their entry points refuse by name (_require_paged) and no
+        # pool is allocated for them.
         self._model = family(cfg)
+        self._model_file = f"models/{self._model.__name__.rsplit('.', 1)[-1]}.py"
         self.paged = isinstance(cfg, LlamaConfig)
         self.tokenizer = tokenizer or ByteTokenizer()
         if self.tokenizer.vocab_size > cfg.vocab_size:
@@ -676,7 +678,7 @@ class InferenceEngine:
             if decode_matmul != "dense":
                 raise ValueError(
                     f"{cfg.name}: llm.decode_matmul {decode_matmul!r} is not "
-                    f"served by models/mla_moe.py"
+                    f"served by {self._model_file}"
                 )
         # The tp serving plane (engine/sharded/plane.py): the placement +
         # constraint authority for every device buffer this constructor
@@ -1058,7 +1060,7 @@ class InferenceEngine:
         """Zeroed cache tuple for `cap` prefix tokens: one array per member
         of the model's per-token cache, [L, cap, *token shape]."""
         return tuple(
-            jnp.zeros((self.cfg.n_layers, cap, *shape), dtype=self.cfg.dtype)
+            jnp.zeros((self._model.cache_layers(self.cfg), cap, *shape), dtype=self.cfg.dtype)
             for shape in self._model.cache_token_shapes(self.cfg)
         )
 
@@ -1237,7 +1239,7 @@ class InferenceEngine:
         adopt-remote-pages seam of the shared prefix-KV plane).
 
         The buffers must carry this engine's exact cache geometry
-        ([n_layers, cap >= len(prompt_ids), *the model's per-token shape]:
+        ([the model's cache layers, cap >= len(prompt_ids), *its per-token shape]:
         n_kv_heads, head_dim for the dense family);
         anything else is refused here rather than at decode time. Host
         arrays are placed through _place_prefix, so on a tp mesh the
@@ -1251,17 +1253,18 @@ class InferenceEngine:
         n = len(key)
         kshape, vshape = tuple(k.shape), tuple(v.shape)
         token_shapes = self._model.cache_token_shapes(self.cfg)
+        layers = self._model.cache_layers(self.cfg)
         if (
             kshape[1:2] != vshape[1:2]
             or kshape[1] < n
             or any(
-                (shape[0], *shape[2:]) != (self.cfg.n_layers, *want)
+                (shape[0], *shape[2:]) != (layers, *want)
                 for shape, want in zip((kshape, vshape), token_shapes)
             )
         ):
             raise ValueError(
                 f"adopted prefix pages have shape k={kshape} v={vshape}; "
-                f"this engine needs [L={self.cfg.n_layers}, cap>={n}, "
+                f"this engine needs [L={layers}, cap>={n}, "
                 f"*{token_shapes[0]}] and [.., *{token_shapes[1]}]"
             )
         kv = self._place_prefix(
@@ -1396,7 +1399,7 @@ class InferenceEngine:
         if not self.paged:
             raise ValueError(
                 f"{self.cfg.name}: {path} is not served — it runs the paged "
-                f"KV pool, and models/mla_moe.py brings the decision wave's "
+                f"KV pool, and {self._model_file} brings the decision wave's "
                 f"forwards only (a latent cache in PagedKVCache / KVGeometry "
                 f"is not written)"
             )

@@ -79,7 +79,8 @@ class PagedKVCache:
         # a page holds the model's per-token cache: (k, v) [n_kv, hd] for
         # the dense family (a model without paged forwards is given one
         # scratch page by the engine)
-        k_tok, v_tok = family(cfg).cache_token_shapes(cfg)
+        model = family(cfg)
+        k_tok, v_tok = model.cache_token_shapes(cfg)
         # On a tp mesh the pages are BORN head-sharded (parallel/sharding.
         # kv_cache_spec via engine/sharded): each chip holds n_kv/tp heads
         # of every page, so KV capacity scales with the group instead of
@@ -91,7 +92,7 @@ class PagedKVCache:
         # jnp.zeros would first build the WHOLE pool on device 0 (8.6 GB of
         # k+v at 8B next to that chip's 4 GB of weights).
         self.sharding = sharding
-        pool = (cfg.n_layers, num_pages, page_size)
+        pool = (model.cache_layers(cfg), num_pages, page_size)
         self.k = jnp.zeros(pool + k_tok, dtype=dtype, device=sharding)
         self.v = jnp.zeros(pool + v_tok, dtype=dtype, device=sharding)
         # Host-side state. Page 0 is scratch — never allocated.

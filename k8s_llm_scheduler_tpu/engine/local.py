@@ -55,7 +55,12 @@ from k8s_llm_scheduler_tpu.engine.constrained import build_decision_dfa
 from k8s_llm_scheduler_tpu.engine.engine import InferenceEngine
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
 from k8s_llm_scheduler_tpu.models import family
-from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, MlaMoeConfig, get_config
+from k8s_llm_scheduler_tpu.models.configs import (
+    LlamaConfig,
+    MlaMoeConfig,
+    MlaScmoeConfig,
+    get_config,
+)
 from k8s_llm_scheduler_tpu.parallel.mesh import mesh_from_config
 from k8s_llm_scheduler_tpu.parallel.sharding import (
     named_shardings,
@@ -640,7 +645,30 @@ class LocalLLMBackend:
                     latest.resolve(False)
         rest: list[_WorkItem] = []
 
+        def owed() -> bool:
+            """The oldest wave in flight has finished and nobody has its
+            answers yet. They come first: every dispatch BLOCKS while the
+            device's queue is full (engine.submit_wave, set_prefix), for as
+            long as the wave on the device still runs, and this thread is
+            the one that harvests. A standing backlog hands a harvest's
+            rows straight back as the next wave, so four waves' rows once
+            come back together, cost four blocking dispatches in a row
+            while four more waves finish unharvested, and come back
+            together for good: measured on the v5e (PERF.md §6, PR 34,
+            second session) binds arrived 32 at a time with 1.0 and 1.6 s
+            between, every wave harvested a second after it had finished.
+            What is not dispatched now stays in `pending`, is dispatched
+            next tick behind the harvest, and the device's queue stays as
+            deep as the runtime lets it. is_ready() that flips late (see
+            _wave_ema) only leaves things as they were."""
+            # getattr: the policy tests hand in bare objects as handles
+            ready = getattr(waves[0][0], "is_ready", None) if waves else None
+            return ready is not None and ready()
+
         def submit(batch: list[_WorkItem]) -> None:
+            if owed():
+                rest.extend(batch)
+                return
             try:
                 handle = self.engine.submit_wave(
                     [i.suffix_ids for i in batch], self.max_new_tokens
@@ -757,6 +785,7 @@ class LocalLLMBackend:
         leaving = (
             bool(others) and not packs
             and waited >= self.group_switch_after_s
+            and not owed()
         )
         # The ragged tail the engine would leave behind goes WITH it where
         # the group it switches to is another snapshot of the same cluster
@@ -795,7 +824,11 @@ class LocalLLMBackend:
             # (bounded: the device-side budget guarantees completion).
             rest.extend(others)
             return rest
-        if waves and waited < self.group_switch_after_s:
+        if owed() or (waves and waited < self.group_switch_after_s):
+            # (a switch dispatches a prefix prefill: a finished wave's
+            # harvest goes before it too; a tail cut off above for the
+            # switch stays where it was)
+            rest.extend(adopted)
             rest.extend(others)
             return rest
 
@@ -1385,7 +1418,7 @@ def _pin_quantized(params, cfg, mesh):
     )
 
 
-def _init_params(rng_seed: int, cfg: LlamaConfig | MlaMoeConfig, mesh=None):
+def _init_params(rng_seed: int, cfg: LlamaConfig | MlaMoeConfig | MlaScmoeConfig, mesh=None):
     """Random-init the bf16 tree in ONE jitted program, for every layout.
     With a mesh the outputs are born on it (param_specs match the
     unquantized tree): each device draws only its own 1/N of every weight
@@ -1402,12 +1435,13 @@ def _init_params(rng_seed: int, cfg: LlamaConfig | MlaMoeConfig, mesh=None):
 
 
 def _refuse_unserved(cfg, *, multi, quantize, checkpoint_path, spec_enabled) -> None:
-    """What models/mla_moe.py does not bring refuses HERE, at build time,
-    naming the model and the path — never inside a trace: what would
-    otherwise fail before the engine exists. (InferenceEngine refuses a
-    tp mesh in its constructor, and the paged entry points at the call:
-    _require_paged.)"""
-    if not isinstance(cfg, MlaMoeConfig):
+    """What the latent-attention families (models/mla_moe.py,
+    models/mla_scmoe.py) do not bring refuses HERE, at build time, naming
+    the model and the path — never inside a trace: what would otherwise
+    fail before the engine exists. (InferenceEngine refuses a tp mesh and
+    ragged decode in its constructor, and the paged entry points at the
+    call: _require_paged.)"""
+    if not isinstance(cfg, (MlaMoeConfig, MlaScmoeConfig)):
         return
     asked = {
         "llm.mesh with tp > 1 (a latent cache has no head axis to shard; "

@@ -1,5 +1,7 @@
-"""Model-family configurations: the Llama dense family and the latent-
-attention sparse-expert family (`MlaMoeConfig`, models/mla_moe.py).
+"""Model-family configurations: the Llama dense family, the latent-
+attention sparse-expert family (`MlaMoeConfig`, models/mla_moe.py) and the
+shortcut-connected double layer over it (`MlaScmoeConfig`,
+models/mla_scmoe.py).
 
 The reference consumes Llama-3.3-70B-Instruct behind the HuggingFace API
 (reference scheduler.py:425, config.yaml:8); the BASELINE ladder also names
@@ -75,6 +77,17 @@ class LlamaConfig:
         return 4.0 * self.n_layers * self.n_heads * self.head_dim
 
 
+def _mla_attn_params(cfg) -> int:
+    """Matrix parameters of one latent-attention sublayer (norms left out)."""
+    d, h = cfg.d_model, cfg.n_heads
+    return (
+        d * cfg.q_lora_rank + cfg.q_lora_rank * h * cfg.qk_head_dim
+        + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+        + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+        + h * cfg.v_head_dim * d
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class MlaMoeConfig:
     """Latent attention (MLA) over one leading dense stack and a stack of
@@ -112,6 +125,15 @@ class MlaMoeConfig:
     rms_eps: float = 1e-5
     dtype: jnp.dtype = jnp.bfloat16
     tie_embeddings: bool = False
+
+    # What the code shared with models/mla_scmoe.py (the attention sublayer,
+    # `route`, `routed_experts`) asks of a config, as this family has it:
+    # sigmoid scores, no identity experts among the router's outputs (None:
+    # not counted either), latents at their normed scale.
+    router_score = "sigmoid"
+    n_zero_experts = None
+    q_lora_scale = 1.0
+    kv_lora_scale = 1.0
 
     def __post_init__(self) -> None:
         if self.tie_embeddings:
@@ -160,15 +182,7 @@ class MlaMoeConfig:
         kw.update(overrides)
         return cls(**kw)
 
-    def attn_params(self) -> int:
-        """Matrix parameters of one layer's attention (norms left out)."""
-        d, h = self.d_model, self.n_heads
-        return (
-            d * self.q_lora_rank + self.q_lora_rank * h * self.qk_head_dim
-            + d * (self.kv_lora_rank + self.qk_rope_head_dim)
-            + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
-            + h * self.v_head_dim * d
-        )
+    attn_params = _mla_attn_params
 
     def matmul_flops_per_token(self) -> float:
         """ACTIVE matmul FLOPs of one token: in an expert layer the router,
@@ -189,6 +203,130 @@ class MlaMoeConfig:
         query scores the latent (kv_lora_rank + rope) and sums it
         (kv_lora_rank)."""
         return 2.0 * self.n_layers * self.n_heads * (
+            2 * self.kv_lora_rank + self.qk_rope_head_dim
+        )
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaScmoeConfig:
+    """Shortcut-connected double layers over latent attention: the
+    LongCat-Flash layer (models/mla_scmoe.py writes the equations out). A
+    layer holds TWO attention sublayers and two dense feed-forwards, and one
+    routed feed-forward that reads the stream after the first attention and
+    joins it after the second dense feed-forward. The router has
+    `n_routed_experts + n_zero_experts` outputs, softmax scores that are not
+    renormalised; the last `n_zero_experts` are identity experts that return
+    their input and hold no weights.
+
+    `expert_first` / `expert_count`: the range of FEED-FORWARD experts held
+    here, as in MlaMoeConfig (an expert-parallel share); the identity
+    experts are applied wherever the token is."""
+
+    name: str
+    vocab_size: int
+    d_model: int
+    n_layers: int            # double layers
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    d_ff: int                # each dense feed-forward's width (ffn_hidden_size)
+    d_ff_expert: int         # one expert's width (expert_ffn_hidden_size)
+    n_routed_experts: int    # feed-forward experts
+    n_zero_experts: int      # identity experts behind them in the router's outputs
+    n_experts_per_tok: int
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = False
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+    expert_first: int = 0
+    expert_count: int | None = None  # None: every feed-forward expert
+    max_seq_len: int = 8192
+    rope_theta: float = 10000000.0
+    rms_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+    tie_embeddings: bool = False
+
+    router_score = "softmax"
+
+    def __post_init__(self) -> None:
+        if self.tie_embeddings:
+            raise ValueError(f"{self.name}: MlaScmoeConfig serves an untied output head only")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError(f"{self.name}: qk_rope_head_dim must be even")
+        if not 0 < self.n_experts_per_tok <= self.n_router_outputs:
+            raise ValueError(f"{self.name}: n_experts_per_tok outside 1..router outputs")
+        if self.expert_first < 0 or self.expert_first + self.experts_held > self.n_routed_experts:
+            raise ValueError(f"{self.name}: held expert range outside the feed-forward experts")
+
+    @property
+    def n_router_outputs(self) -> int:
+        return self.n_routed_experts + self.n_zero_experts
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_routed_experts if self.expert_count is None else self.expert_count
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def q_lora_scale(self) -> float:
+        """What the normed query latent is multiplied by (mla_scale_q_lora)."""
+        return (self.d_model / self.q_lora_rank) ** 0.5 if self.mla_scale_q_lora else 1.0
+
+    @property
+    def kv_lora_scale(self) -> float:
+        """What the normed key/value latent is multiplied by; the rotary
+        key is not scaled (mla_scale_kv_lora)."""
+        return (self.d_model / self.kv_lora_rank) ** 0.5 if self.mla_scale_kv_lora else 1.0
+
+    @classmethod
+    def from_hf(cls, name: str, conf: dict, **overrides) -> "MlaScmoeConfig":
+        """From the published `config.json` keys (longcat_flash)."""
+        if conf.get("attention_method", "MLA") != "MLA":
+            raise ValueError(f"{name}: only attention_method MLA")
+        if conf.get("zero_expert_type", "identity") != "identity":
+            raise ValueError(f"{name}: only identity zero-computation experts")
+        kw = dict(
+            name=name, vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+            n_layers=conf["num_layers"], n_heads=conf["num_attention_heads"],
+            q_lora_rank=conf["q_lora_rank"], kv_lora_rank=conf["kv_lora_rank"],
+            qk_nope_head_dim=conf["qk_nope_head_dim"], qk_rope_head_dim=conf["qk_rope_head_dim"],
+            v_head_dim=conf["v_head_dim"], d_ff=conf["ffn_hidden_size"],
+            d_ff_expert=conf["expert_ffn_hidden_size"],
+            n_routed_experts=conf["n_routed_experts"], n_zero_experts=conf["zero_expert_num"],
+            n_experts_per_tok=conf["moe_topk"],
+            routed_scaling_factor=float(conf["routed_scaling_factor"]),
+            mla_scale_q_lora=conf["mla_scale_q_lora"], mla_scale_kv_lora=conf["mla_scale_kv_lora"],
+            max_seq_len=conf["max_position_embeddings"], rope_theta=float(conf["rope_theta"]),
+            rms_eps=conf["rms_norm_eps"],
+        )
+        kw.update(overrides)
+        return cls(**kw)
+
+    attn_params = _mla_attn_params
+
+    def matmul_flops_per_token(self) -> float:
+        """Matmul FLOPs of one token AS THIS SHARE RUNS IT: two attention
+        sublayers and two dense feed-forwards a layer, the router over all
+        its outputs, and of the `n_experts_per_tok` picks the part that
+        falls, under even routing, on the feed-forward experts held HERE
+        (an identity expert multiplies nothing; an expert held elsewhere is
+        another chip's). The measured counterpart of that expectation is
+        the wave counter `moe_assignments`."""
+        d = self.d_model
+        held_picks = self.n_experts_per_tok * self.experts_held / self.n_router_outputs
+        layer = (2 * self.attn_params() + 2 * 3 * d * self.d_ff
+                 + d * self.n_router_outputs + held_picks * 3 * d * self.d_ff_expert)
+        return 2.0 * (self.n_layers * layer + d * self.vocab_size)
+
+    def attn_flops_per_key(self) -> float:
+        """Absorbed form, in each of the 2 x n_layers attention sublayers."""
+        return 2.0 * 2 * self.n_layers * self.n_heads * (
             2 * self.kv_lora_rank + self.qk_rope_head_dim
         )
 
@@ -287,13 +425,39 @@ TINY_MLA_MOE = MlaMoeConfig(
     rope_theta=10000.0,
 )
 
+# Toy of the shortcut-connected family for the CPU tests: two double layers,
+# a share of the feed-forward experts (4 of 8), identity experts, both scale
+# factors, every width shrunk.
+TINY_MLA_SCMOE = MlaScmoeConfig(
+    name="tiny-mla-scmoe",
+    vocab_size=512,
+    d_model=64,
+    n_layers=2,
+    n_heads=4,
+    q_lora_rank=32,
+    kv_lora_rank=16,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    d_ff=128,
+    d_ff_expert=32,
+    n_routed_experts=8,
+    n_zero_experts=4,
+    n_experts_per_tok=3,
+    routed_scaling_factor=6.0,
+    expert_count=4,
+    max_seq_len=2048,
+    rope_theta=10000.0,
+)
+
 _REGISTRY = {
     c.name: c
-    for c in (TINY, SMALL, LLAMA_3_2_1B, LLAMA_3_1_8B, LLAMA_3_3_70B, TINY_MLA_MOE)
+    for c in (TINY, SMALL, LLAMA_3_2_1B, LLAMA_3_1_8B, LLAMA_3_3_70B, TINY_MLA_MOE,
+              TINY_MLA_SCMOE)
 }
 
 
-def get_config(name: str) -> LlamaConfig | MlaMoeConfig:
+def get_config(name: str) -> LlamaConfig | MlaMoeConfig | MlaScmoeConfig:
     key = name.lower()
     if key not in _REGISTRY:
         raise KeyError(f"unknown model config {name!r}; known: {sorted(_REGISTRY)}")

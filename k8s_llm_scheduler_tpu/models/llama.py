@@ -49,6 +49,11 @@ def cache_token_shapes(cfg: LlamaConfig) -> tuple[tuple[int, ...], ...]:
     return ((cfg.n_kv_heads, cfg.head_dim),) * 2
 
 
+def cache_layers(cfg: LlamaConfig) -> int:
+    """Leading axis of the cache tuple: one attention a layer."""
+    return cfg.n_layers
+
+
 # --------------------------------------------------------------------- norm
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     """RMSNorm in f32, result back in input dtype."""

@@ -87,12 +87,17 @@ COUNTERS = ("moe_assignments", "moe_experts_hit", "moe_layer_calls", "moe_max_lo
 BIAS_SCALE = 0.02
 
 
-def cache_token_shapes(cfg: MlaMoeConfig) -> tuple[tuple[int, ...], ...]:
+def cache_token_shapes(cfg) -> tuple[tuple[int, ...], ...]:
     """Per-token trailing shapes of the cache tuple: (c_kv, k_r)."""
     return ((cfg.kv_lora_rank,), (cfg.qk_rope_head_dim,))
 
 
-def _inv_freq(cfg: MlaMoeConfig) -> jax.Array:
+def cache_layers(cfg: MlaMoeConfig) -> int:
+    """Leading axis of the cache tuple: one attention sublayer a layer."""
+    return cfg.n_layers
+
+
+def _inv_freq(cfg) -> jax.Array:
     dr = cfg.qk_rope_head_dim
     return 1.0 / (cfg.rope_theta ** (jnp.arange(0, dr, 2, dtype=jnp.float32) / dr))
 
@@ -177,18 +182,25 @@ def _swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
     return jnp.einsum("...f,fd->...d", fused, w_down, preferred_element_type=jnp.float32)
 
 
-def route(lp: Params, cfg: MlaMoeConfig, h: jax.Array, sel=None) -> tuple[jax.Array, jax.Array]:
-    """(selected experts [T, k] int32, their weights [T, k] f32) of the
-    normed tokens h [T, D]: sigmoid scores in float32, selection by score +
-    bias, weights from the scores alone, renormalised, scaled. With `sel`
-    the selection is GIVEN and only weighted (benchmark/tests/read_flips.py
-    hands the program the reference's selection, to show what a gap is
-    made of)."""
+# A router's scores of its logits [T, outputs], by `cfg.router_score`.
+SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
+# What a layer with identity experts counts besides COUNTERS.
+ZERO_COUNTERS = ("moe_zero_assignments", "moe_ffn_assignments")
+
+
+def route(lp: Params, cfg, h: jax.Array, sel=None) -> tuple[jax.Array, jax.Array]:
+    """(selected router outputs [T, k] int32, their weights [T, k] f32) of
+    the normed tokens h [T, D]: scores in float32 by `cfg.router_score`
+    (sigmoid of each logit, or a softmax over all the router's outputs),
+    selection by score + bias, weights from the scores alone, renormalised
+    where `cfg.norm_topk_prob`, scaled. With `sel` the selection is GIVEN
+    and only weighted (benchmark/tests/read_flips.py hands the program the
+    reference's selection, to show what a gap is made of)."""
     logits = jnp.einsum(
         "td,de->te", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    scores = jax.nn.sigmoid(logits)
+    scores = SCORES[cfg.router_score](logits)
     if sel is None:
         _, sel = jax.lax.top_k(scores + lp["router_bias"], cfg.n_experts_per_tok)
     w = jnp.take_along_axis(scores, sel, axis=1)
@@ -197,16 +209,30 @@ def route(lp: Params, cfg: MlaMoeConfig, h: jax.Array, sel=None) -> tuple[jax.Ar
     return sel.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
-def routed_experts(
-    lp: Params, cfg: MlaMoeConfig, h: jax.Array, valid: jax.Array
-) -> tuple[jax.Array, jax.Array]:
+def zero_experts(cfg, h: jax.Array, sel: jax.Array, w: jax.Array, valid: jax.Array):
+    """The identity experts' part for normed tokens h [T, D]: router outputs
+    `n_routed_experts ..` return their input, so a token's picks among them
+    add h times the sum of their weights (f32 [T, D]); and ZERO_COUNTERS:
+    the valid tokens' picks that fell on identity experts, and on any
+    feed-forward expert, held here or not."""
+    with jax.named_scope("moe_zero"):
+        zero = valid[:, None] & (sel >= cfg.n_routed_experts)
+        y = h.astype(jnp.float32) * jnp.sum(jnp.where(zero, w, 0.0), axis=1)[:, None]
+        ffn = valid[:, None] & (sel < cfg.n_routed_experts)
+    return y, jnp.stack([jnp.sum(zero), jnp.sum(ffn)]).astype(jnp.int32)
+
+
+def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax.Array, jax.Array]:
     """The held experts' part of the routed output for normed tokens h
     [T, D] (f32 [T, D]) and the layer's COUNTERS. Tokens that are not
     `valid` [T] are not routed. `lp["we_*"]` hold experts `expert_first ..
     + experts_held` of the `n_routed_experts` the router scores: this
     layer's [E, ..], or the whole stack's [L, E, ..] with `lp["layer"]`
     saying which layer this is (`_run_stacks`: the kernel then reads the
-    stack in place)."""
+    stack in place). Where the config has identity experts
+    (`cfg.n_zero_experts` not None: router outputs behind the feed-forward experts),
+    their part is added HERE, on every share (they hold no weights and are
+    applied where the token is), and ZERO_COUNTERS follow COUNTERS."""
     T, D = h.shape
     k, held_n = cfg.n_experts_per_tok, cfg.experts_held
     with jax.named_scope("moe_router"):
@@ -234,6 +260,9 @@ def routed_experts(
     counters = jnp.stack([
         jnp.sum(held), jnp.sum(sizes > 0), jnp.int32(1), jnp.max(sizes),
     ]).astype(jnp.int32)
+    if cfg.n_zero_experts is not None:
+        y_zero, zero_counters = zero_experts(cfg, h, sel, w, valid)
+        return y + y_zero, jnp.concatenate([counters, zero_counters])
     return y, counters
 
 
@@ -299,30 +328,48 @@ def attend_absorbed(lp, cfg, q_nope, q_rope, segments):
         return jnp.einsum("bhsc,chd->bshd", o_lat.astype(q_nope.dtype), w[:, :, dn:])
 
 
-def _layer(lp, cfg, x, positions, valid, inv_freq, moe, attend):
-    """ONE layer for every forward, over the float32 stream x: project (mla_down, mla_up), attend as
-    the caller says (`attend(lp, q_nope, q_rope, c_kv, k_r) -> [B, S, H,
-    dv]`: what the queries may see is the forward's), output projection,
-    feed-forward. Returns (x, (c_kv, k_r) of these tokens, COUNTERS): where
-    the cache is sunk is the forward's too."""
+def _scaled_norm(x, weight, eps: float, scale: float):
+    """RMSNorm times a config's fixed latent scale (`q_lora_scale`,
+    `kv_lora_scale`); the product is rounded once."""
+    if scale == 1.0:
+        return rms_norm(x, weight, eps)
+    y = rms_norm(x.astype(jnp.float32), weight.astype(jnp.float32), eps)
+    return (y * scale).astype(x.dtype)
+
+
+@jax.named_scope("attn")
+def attention_sublayer(lp, cfg, x, positions, inv_freq, attend):
+    """ONE latent-attention sublayer for every forward of both families,
+    over the float32 stream x: project (mla_down, mla_up), attend as the
+    caller says (`attend(lp, q_nope, q_rope, c_kv, k_r) -> [B, S, H, dv]`:
+    what the queries may see is the forward's), output projection. Returns
+    (x + attention, (c_kv, k_r) of these tokens): where the cache is sunk
+    is the forward's too. The cached latent carries `cfg.kv_lora_scale`."""
     B, S = x.shape[:2]
     H, dc, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    with jax.named_scope("attn"):
-        with jax.named_scope("mla_down"):
-            h = rms_norm(x, lp["attn_norm"], cfg.rms_eps).astype(cfg.dtype)
-            c_q = rms_norm(jnp.einsum("bsd,dq->bsq", h, lp["w_dq"]), lp["q_norm"], cfg.rms_eps)
-            kv = jnp.einsum("bsd,dc->bsc", h, lp["w_dkv"])
-            c_kv = rms_norm(kv[..., :dc], lp["kv_norm"], cfg.rms_eps)
-            k_r = apply_rope(kv[..., None, dc:], positions, inv_freq)[..., 0, :]
-        with jax.named_scope("mla_up"):
-            q = jnp.einsum("bsq,qh->bsh", c_q, lp["w_uq"]).reshape(B, S, H, cfg.qk_head_dim)
-            q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], positions, inv_freq)
-        o = attend(lp, q_nope, q_rope, c_kv, k_r)
-        with jax.named_scope("wo"):
-            x = x + jnp.einsum("bsh,hd->bsd", o.reshape(B, S, H * cfg.v_head_dim), lp["wo"],
-                               preferred_element_type=jnp.float32)
+    with jax.named_scope("mla_down"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps).astype(cfg.dtype)
+        c_q = _scaled_norm(jnp.einsum("bsd,dq->bsq", h, lp["w_dq"]), lp["q_norm"],
+                           cfg.rms_eps, cfg.q_lora_scale)
+        kv = jnp.einsum("bsd,dc->bsc", h, lp["w_dkv"])
+        c_kv = _scaled_norm(kv[..., :dc], lp["kv_norm"], cfg.rms_eps, cfg.kv_lora_scale)
+        k_r = apply_rope(kv[..., None, dc:], positions, inv_freq)[..., 0, :]
+    with jax.named_scope("mla_up"):
+        q = jnp.einsum("bsq,qh->bsh", c_q, lp["w_uq"]).reshape(B, S, H, cfg.qk_head_dim)
+        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], positions, inv_freq)
+    o = attend(lp, q_nope, q_rope, c_kv, k_r)
+    with jax.named_scope("wo"):
+        x = x + jnp.einsum("bsh,hd->bsd", o.reshape(B, S, H * cfg.v_head_dim), lp["wo"],
+                           preferred_element_type=jnp.float32)
+    return x, (c_kv, k_r)
+
+
+def _layer(lp, cfg, x, positions, valid, inv_freq, moe, attend):
+    """ONE layer for every forward: the attention sublayer, then the
+    feed-forward. Returns (x, (c_kv, k_r) of these tokens, COUNTERS)."""
+    x, cache = attention_sublayer(lp, cfg, x, positions, inv_freq, attend)
     y, counters = _feed_forward(lp, cfg, x, valid, moe)
-    return x + y, (c_kv, k_r), counters
+    return x + y, cache, counters
 
 
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")

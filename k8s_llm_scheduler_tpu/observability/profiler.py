@@ -142,7 +142,9 @@ def matmul_flops_per_token(cfg) -> float:
     """Matmul FLOPs for one token's forward pass (2*MACs), as the model's
     config counts them (models/configs.py: dense weights for the Llama
     family, ACTIVE parameters — router, selected and shared experts — for
-    an expert layer). Formerly bench.py's accounting — here so the
+    an expert layer; for MlaScmoeConfig's expert-parallel share, of a
+    token's picks those that fall on experts held here under even routing,
+    identity experts at nothing). Formerly bench.py's accounting — here so the
     profiler's MFU decomposition and the bench headline share one set of
     books."""
     return cfg.matmul_flops_per_token()

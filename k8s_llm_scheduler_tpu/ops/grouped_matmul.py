@@ -96,6 +96,26 @@ def _col_tile(n: int) -> int:
     return n
 
 
+# Mosaic's default scoped-VMEM limit on the chips served (v5e: 16 MiB), and
+# the part of it the pipeline's double-buffered blocks may take before the
+# call asks for more.
+_VMEM_DEFAULT = 16 << 20
+_VMEM_BLOCKS = 12 << 20
+
+
+def _vmem_limit(tm: int, k: int, tn: int, n_weights: int, x_bytes: int, out_bytes: int) -> int | None:
+    """None where the blocks fit the default limit (the [2048, 1536]
+    experts: the call is compiled as before); else a limit that holds them
+    double-buffered with the kernel's float32 temporaries. A [6144, 512]
+    bf16 weight block is 6.3 MB, two weights double-buffered 25 MB: the
+    chip's VMEM (128 MiB) holds it, the default limit does not, and a
+    narrower block reads 256-byte rows."""
+    blocks = 2 * (tm * k * x_bytes + n_weights * k * tn * x_bytes + tm * tn * out_bytes)
+    if blocks <= _VMEM_BLOCKS:
+        return None
+    return blocks + 3 * tm * tn * 4 + (_VMEM_DEFAULT - _VMEM_BLOCKS)
+
+
 @functools.partial(jax.jit, static_argnames=("swiglu", "out_dtype", "interpret"))
 def grouped_matmul(
     x: jax.Array,            # [M, K] rows sorted by group, rows of no group last
@@ -122,6 +142,8 @@ def grouped_matmul(
     item_group, item_tile, offsets, n_items = group_metadata(group_sizes, m_pad, tm)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     w_spec = pl.BlockSpec((1, 1, K, tn), lambda n, i, g, t, o, c, l: (l[0], g[i], 0, n))
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    limit = _vmem_limit(tm, K, tn, len(weights), x.dtype.itemsize, out_dtype.itemsize)
     out = pl.pallas_call(
         functools.partial(_kernel, tm=tm, swiglu=swiglu),
         name="moe_grouped_swiglu" if swiglu else "moe_grouped_matmul",
@@ -132,7 +154,8 @@ def grouped_matmul(
             + [w_spec] * len(weights),
             out_specs=pl.BlockSpec((tm, tn), lambda n, i, g, t, o, c, l: (t[i], n)),
         ),
-        out_shape=jax.ShapeDtypeStruct((m_pad, N), out_dtype or x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m_pad, N), out_dtype),
+        compiler_params=None if limit is None else pltpu.CompilerParams(vmem_limit_bytes=limit),
         interpret=interpret,
     )(item_group, item_tile, offsets, n_items, layer, x, *weights)
     return out[:M]

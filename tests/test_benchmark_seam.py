@@ -39,6 +39,7 @@ for _file in ("test_scope_trace.py", "test_arch_seam.py", "test_counter_readers.
 
 ARCH = _load(BENCH / "arch" / "mla_moe.py", "bench_arch_mla_moe_pins")
 REF = _load(BENCH / "reference" / "mla_moe.py", "bench_reference_mla_moe_pins")
+SC_REF = _load(BENCH / "reference" / "mla_scmoe.py", "bench_reference_mla_scmoe_pins")
 
 
 @pytest.fixture(scope="module")
@@ -102,6 +103,154 @@ class TestMlaMoeFlopPins:
         assert glm["reduced"] == ["num_hidden_layers", "num_nextn_predict_layers"]
         assert (glm["num_hidden_layers"], glm["num_nextn_predict_layers"]) == (7, 0)
         assert glm["published"]["num_hidden_layers"] == 47
+
+
+class TestLongcatThroughTheSeam:
+    """benchmark/configs/longcat-flash-chat.json loaded the way run.py loads
+    it: `"architecture": "mla_scmoe"` selects arch/ and reference/, `register`
+    hands the program a config of its own type, and the arch file's count of
+    what a token needs is the config type's books."""
+
+    ATTN = 6144 * 1536 + 1536 * 64 * 192 + 6144 * 576 + 512 * 64 * 256 + 64 * 128 * 6144  # 90.57 M
+    DENSE_FFN, ROUTER, EXPERT = 3 * 6144 * 12288, 6144 * 768, 3 * 6144 * 2048          # 226.5 M, 4.72 M, 37.75 M
+
+    @pytest.fixture(scope="class")
+    def loaded(self):
+        from harness import seam
+
+        conf = seam.load_config(BENCH / "configs" / "longcat-flash-chat.json")
+        return conf, seam.program(conf), seam.reference(conf)
+
+    def test_the_file_selects_its_architecture_and_registers_its_own_config_type(self, loaded):
+        from k8s_llm_scheduler_tpu.models import family, mla_scmoe
+        from k8s_llm_scheduler_tpu.models.configs import MlaScmoeConfig, get_config
+
+        conf, arch, ref = loaded
+        assert arch.__file__.endswith("arch/mla_scmoe.py") and ref.__file__.endswith("reference/mla_scmoe.py")
+        cfg = get_config(arch.register(conf))
+        assert isinstance(cfg, MlaScmoeConfig) and family(cfg) is mla_scmoe
+        assert (cfg.n_layers, mla_scmoe.cache_layers(cfg), cfg.experts_held, cfg.expert_first) == (4, 8, 16, 0)
+        assert (cfg.n_routed_experts, cfg.n_zero_experts, cfg.n_experts_per_tok) == (512, 256, 12)
+        assert (cfg.q_lora_scale, round(cfg.kv_lora_scale**2), cfg.routed_scaling_factor) == (2.0, 12, 6.0)
+        assert not cfg.norm_topk_prob and cfg.router_score == "softmax"
+
+    def test_a_token_by_hand_is_the_arch_files_count_and_the_config_types_books(self, loaded):
+        from k8s_llm_scheduler_tpu.models.configs import get_config
+        from k8s_llm_scheduler_tpu.observability.profiler import matmul_flops_per_token
+
+        conf, arch, _ = loaded
+        # 12 picks x 16 held / 768 outputs = a quarter of an expert a layer a
+        # token: identity experts multiply nothing, 496 experts are elsewhere
+        assert arch.held_picks_per_token(conf) == 0.25
+        by_hand = 2 * 4 * (2 * self.ATTN + 2 * self.DENSE_FFN + self.ROUTER + self.EXPERT // 4)
+        assert by_hand == 5_186_256_896  # 5.19 GFLOP a token through 4 double layers
+        assert arch.flops_per_token(conf, with_head=False) == by_hand
+        head = arch.flops_per_token(conf, with_head=True) - by_hand
+        assert head == 2 * 6144 * 16_384
+        cfg = get_config(arch.register(conf))
+        assert matmul_flops_per_token(cfg) == arch.flops_per_token(conf, with_head=True)
+        assert cfg.attn_params() == self.ATTN
+        # absorbed, 8 attention sublayers x 64 heads x 2 x (576 + 512) a key
+        assert arch.attention_flops(conf, 1, 1) == cfg.attn_flops_per_key() == 8 * 64 * 2 * (576 + 512)
+
+    def test_a_grouped_kernel_call_is_bound_by_the_touched_experts_bytes(self, loaded):
+        _, arch, _ = loaded
+        flops, moved = arch.grouped_kernel_cost(5, 4, 6144, 2048, 2, 2)
+        assert flops == 2.0 * 5 * 6144 * 2048 * 2
+        assert moved == 4 * 6144 * 2048 * 2 * 2 + 5 * (6144 * 2 + 2048 * 2)
+        assert moved / 819e9 > 50 * flops / 197e12
+
+    def test_the_configuration_file_holds_the_published_row(self, loaded):
+        """Every number of the catalog row under its own key; depth, experts
+        held and vocabulary the only cuts; no width touched."""
+        conf, _, _ = loaded
+        published = {
+            "hidden_size": 6144, "ffn_hidden_size": 12288, "expert_ffn_hidden_size": 2048,
+            "num_attention_heads": 64, "kv_lora_rank": 512, "q_lora_rank": 1536,
+            "qk_rope_head_dim": 64, "v_head_dim": 128, "qk_nope_head_dim": 128,
+            "mla_scale_q_lora": True, "mla_scale_kv_lora": True, "routed_scaling_factor": 6,
+            "n_routed_experts": 512, "zero_expert_num": 256, "zero_expert_type": "identity",
+            "moe_topk": 12, "max_position_embeddings": 131072, "rms_norm_eps": 1e-05,
+            "rope_theta": 10000000, "attention_method": "MLA", "attention_bias": False,
+        }
+        assert {k: conf[k] for k in published} == published
+        assert conf["reduced"] == ["num_layers", "experts_held", "vocab_size"]
+        assert (conf["num_layers"], conf["experts_held"], conf["vocab_size"]) == (4, 16, 16384)
+        assert conf["published"] == {**conf["published"], "num_layers": 28, "experts_held": 512,
+                                     "vocab_size": 131072}
+        entry = next(c for c in json.loads((BENCH.parent / "BENCHMARK.json").read_text())["configs"]
+                     if c["name"] == conf["name"])
+        assert entry["reduced"] == conf["reduced"] and entry["source"] == conf["source"]
+        by_hand = 4 * (2 * self.ATTN + 2 * self.DENSE_FFN + self.ROUTER + 16 * self.EXPERT) + 2 * 16384 * 6144
+        assert conf["parameters"] == by_hand  # 5.17 B matrix parameters, 10.35 GB bf16
+
+    def test_the_reference_imports_nothing_of_the_program_or_the_harness(self):
+        text = (BENCH / "reference" / "mla_scmoe.py").read_text()
+        imports = [ln for ln in text.splitlines() if ln.startswith(("import ", "from "))]
+        assert imports == ["from __future__ import annotations", "import functools", "import jax",
+                           "import jax.numpy as jnp", "import numpy as np"]
+
+    def test_the_cell_is_listed_where_its_readers_find_something(self):
+        bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+        cell = "longcat_flash-backlog20"
+        assert [w for w in bench["workloads"] if w["name"] == cell] == [
+            {**next(w for w in bench["workloads"] if w["name"] == cell),
+             "config": "longcat-flash-chat", "traffic": "backlog20_pool80", "chips": 1}]
+        listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
+        new = {"dense_ffn_device_ms_per_bind.tput", "moe_zero_device_ms_per_bind.tput",
+               "zero_expert_share.tput", "experts_here_share.tput",
+               "scmoe_grouped_swiglu_roofline.tput", "scmoe_grouped_matmul_roofline.tput"}
+        assert new <= listed
+        # readers that assume the second family's keys or a whole expert set
+        assert not listed & {"moe_shared_device_ms_per_bind.tput", "expert_load_max_over_mean.tput",
+                             "moe_grouped_swiglu_roofline.tput", "moe_grouped_matmul_roofline.tput",
+                             "prefix_attn_roofline.tput"}
+        for m in bench["per_layer"]:
+            if m["name"] in new:
+                assert m["workloads"] == [cell] and m["moves"] == "binds_per_s"
+        assert cell in next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"]
+
+
+@pytest.mark.parametrize("name, stats, want", [
+    ("zero_expert_share.tput", {"moe_zero_assignments": 400, "moe_ffn_assignments": 800}, 100 / 3),
+    ("zero_expert_share.tput", {}, None),  # a parent: no such counters
+    ("experts_here_share.tput", {"moe_assignments": 25, "moe_ffn_assignments": 800}, 3.125),
+    ("experts_here_share.tput", {"moe_assignments": 25}, None),
+])
+def test_the_new_counter_readers(name, stats, want):
+    import run as bench_run
+
+    got = bench_run.reader_for(name)(window_ctx({}, stats))  # noqa: F821 (from test_counter_readers.py)
+    assert got == pytest.approx(want) if want is not None else got is None
+
+
+def test_scmoe_reference_runs_in_both_modes_and_int8_differs():
+    """reference/mla_scmoe.py at a toy size: `f32` and the `int8` control see
+    the same wave and give different logits, both finite; a tail sees the
+    prefix and itself alone."""
+    toy = {
+        "hidden_size": 64, "num_layers": 2, "num_attention_heads": 4, "q_lora_rank": 32,
+        "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+        "ffn_hidden_size": 128, "expert_ffn_hidden_size": 32, "n_routed_experts": 8,
+        "zero_expert_num": 4, "moe_topk": 3, "routed_scaling_factor": 6, "norm_topk_prob": False,
+        "mla_scale_q_lora": True, "mla_scale_kv_lora": True, "vocab_size": 512,
+        "rope_theta": 10000.0, "rms_norm_eps": 1e-5, "experts_held": 4, "expert_first": 2,
+    }
+    weights = SC_REF.init_weights(toy, 3)
+    assert weights["layers"]["we_gate"].shape == (2, 4, 64, 32)  # the share's experts alone
+    assert weights["layers"]["router"].shape == (2, 64, 12)      # the router's whole width
+    rng = np.random.default_rng(5)
+    prefix = rng.integers(1, 500, 40).tolist()
+    tails = [rng.integers(1, 500, n).tolist() for n in (12, 9)]
+    spans = [(7, 5), (5, 4)]
+    f32 = SC_REF.wave_logits(toy, weights, prefix, tails, spans, "f32", 300)
+    low = SC_REF.wave_logits(toy, weights, prefix, tails, spans, "int8", 300)
+    assert f32.shape == low.shape == (9, 300)
+    assert np.isfinite(f32).all() and np.isfinite(low).all()
+    assert float(np.max(np.abs(f32 - low))) > 1e-3
+    assert float(np.mean(np.abs(f32 - low))) < 0.25 * float(np.std(f32))
+    alone = SC_REF.wave_logits(toy, weights, prefix, tails[:1], spans[:1], "f32", 300)
+    np.testing.assert_allclose(alone, f32[:5], rtol=1e-4, atol=1e-5)
 
 
 def test_reference_runs_in_both_modes_and_int8_differs():

@@ -340,7 +340,7 @@ def _family_engine(family_name, buckets):
     from k8s_llm_scheduler_tpu.models import family
     from k8s_llm_scheduler_tpu.models.configs import get_config
 
-    cfg = ENGINE_CFG if family_name == "dense_gqa" else get_config("tiny-mla-moe")
+    cfg = ENGINE_CFG if family_name == "dense_gqa" else get_config(f"tiny-{family_name.replace('_', '-')}")
     params = family(cfg).init_params(jax.random.PRNGKey(0), cfg)
     return InferenceEngine(
         params, cfg, TOK, num_pages=8, page_size=64, max_slots=4,
@@ -360,7 +360,7 @@ class TestWaveWidth:
         eng.set_grammar(build_decision_dfa(TOK, self.NAMES, max_reason_tokens=12))
         return eng.harvest_wave(eng.submit_wave(prompts, max_new_tokens=120))
 
-    @pytest.mark.parametrize("family_name", ["dense_gqa", "mla_moe"])
+    @pytest.mark.parametrize("family_name", ["dense_gqa", "mla_moe", "mla_scmoe"])
     def test_a_ladder_from_128_serves_what_one_from_256_serves(self, family_name):
         prompts = [
             TOK.chat_prompt("pick a node", f"pod-{i} wants " + "cpu " * (3 + 4 * i))

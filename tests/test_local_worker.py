@@ -437,6 +437,105 @@ class TestTailHoldPolicy:
             backend.close()
 
 
+class TestFinishedWaveGoesFirst:
+    """A wave that has finished and is not harvested yet goes before any
+    further dispatch: a dispatch blocks while the device's queue is full,
+    on the one thread that harvests (_submit_waves `owed`). Driven through
+    _submit_waves with the in-flight deque planted, as above."""
+
+    _items = staticmethod(TestTailHoldPolicy._items)
+
+    @pytest.mark.parametrize(
+        "oldest_ready,n_pending,submitted",
+        [
+            (True, 4, []),       # a full wave waits for the harvest
+            (True, 9, []),       # so do two and a tail
+            (False, 4, [4]),     # nothing finished: dispatched as before
+            (False, 9, [4, 4]),  # (the tail of one holds behind two waves)
+        ],
+    )
+    def test_no_dispatch_while_a_finished_wave_waits(
+        self, oldest_ready, n_pending, submitted
+    ):
+        from collections import deque
+
+        eng = FakeEngine()
+        backend = LocalLLMBackend(eng, tokenizer=ByteTokenizer())
+        try:
+            nodes = make_nodes()
+            items = self._items(backend, n_pending, nodes)
+            backend._current_group = items[0].group_key
+            oldest = FakeHandle(ready_at=0.0 if oldest_ready else float("inf"))
+            waves = deque([(oldest, []), (FakeHandle(float("inf")), [])])
+            rest = backend._submit_waves(list(items), waves, [])
+            assert [n for _, n in eng.submits] == submitted
+            assert rest == items[sum(submitted):]  # FIFO, nothing lost
+        finally:
+            backend.close()
+
+    def test_finishing_mid_tick_stops_the_tick_s_dispatches(self):
+        """The first dispatch returns when the wave on the device has
+        finished (that is what it blocked on): the second full wave of the
+        same tick stays pending, behind the harvest."""
+        from collections import deque
+
+        eng = FakeEngine()
+        backend = LocalLLMBackend(eng, tokenizer=ByteTokenizer())
+        try:
+            nodes = make_nodes()
+            items = self._items(backend, 2 * eng.max_slots, nodes)
+            backend._current_group = items[0].group_key
+            oldest = FakeHandle(ready_at=float("inf"))
+            inner = eng.submit_wave
+
+            def blocking_submit(prompts, max_new_tokens):
+                oldest.ready_at = 0.0  # the queue had room again: a wave ended
+                return inner(prompts, max_new_tokens)
+
+            eng.submit_wave = blocking_submit
+            waves = deque([(oldest, [])])
+            rest = backend._submit_waves(list(items), waves, [])
+            assert [n for _, n in eng.submits] == [eng.max_slots]
+            assert rest == items[eng.max_slots:]
+        finally:
+            backend.close()
+
+    @pytest.mark.parametrize("oldest_ready", [True, False])
+    def test_group_switch_waits_for_the_harvest_too(self, oldest_ready):
+        """A switch dispatches a prefix prefill; with a finished wave
+        waiting it is put off a tick, the old group's tail is not cut off
+        for it, and nothing changes group."""
+        import dataclasses
+        from collections import deque
+
+        eng = FakeEngine()
+        backend = LocalLLMBackend(
+            eng, tokenizer=ByteTokenizer(), group_switch_after_s=0.25,
+        )
+        try:
+            nodes = make_nodes(3)
+            drifted = [dataclasses.replace(n, cpu_usage_percent=n.cpu_usage_percent + 7.0)
+                       for n in nodes]
+            tail = self._items(backend, 3, nodes, first=20, age_s=5.0)
+            other = self._items(backend, 1, drifted, first=10, age_s=0.3)
+            key = tail[0].group_key
+            backend._current_group = key
+            oldest = FakeHandle(ready_at=0.0 if oldest_ready else float("inf"))
+            waves = deque([(oldest, [])] + [(FakeHandle(float("inf")), []) for _ in range(2)])
+            rest = backend._submit_waves(tail + other, waves, [])
+            if oldest_ready:
+                assert eng.submits == [] and eng.prefixes == 0
+                assert rest == tail + other
+                assert {i.group_key for i in tail} == {key}
+                assert backend._current_group == key
+            else:
+                assert eng.prefixes == 1 and rest == []
+                assert [n for _, n in eng.submits] == [4]
+                assert backend._current_group == other[0].group_key
+        finally:
+            backend.close()
+
+
 class TestPoolRoleAndBatch:
     def test_decode_role_refuses_admission(self):
         from k8s_llm_scheduler_tpu.engine.backend import BackendError

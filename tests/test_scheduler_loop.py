@@ -370,7 +370,10 @@ class TestBurstFastPath:
     @pytest.mark.asyncio
     async def test_followers_coalesce_onto_leader(self):
         cluster = synthetic_cluster(3)
-        backend = StubBackend(latency_s=0.15)
+        # the leaders have to be in flight still when the followers come:
+        # 0.6 s of decision against the 0.05 s below (with 0.15 s this test
+        # failed once in a loaded six-worker tier-1 run and passes alone)
+        backend = StubBackend(latency_s=0.6)
         scheduler = make_scheduler(cluster, backend, snapshot_ttl_s=60.0)
         task = asyncio.create_task(scheduler.run())
         try:

@@ -1,0 +1,78 @@
+"""The main path's Pallas kernels at real widths, COMPILED for a described
+TPU v5e with no chip attached (jax.experimental.topologies): what interpret
+mode cannot show. `ops/grouped_matmul.py` at LongCat-Flash's [6144, 2048]
+experts passed every interpret-mode test and was refused here for 28 MB of
+double-buffered blocks against Mosaic's 16 MiB default (PERF.md §6 PR 34).
+
+One file, and the topology described inside a fixture: only the worker that
+runs this file loads the TPU's library, and a host where it cannot be
+described skips these tests and no others. Nothing runs, so nothing here is
+a time.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from k8s_llm_scheduler_tpu.ops import grouped_matmul as gm
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever the host lacks
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described device can be written to the persistent
+    cache and never read back: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# (rows, experts held, K, N): a decode call and a suffix call of the third
+# family's share, and the second family's suffix call
+SHAPES = [(2304, 16, 6144, 2048), (12288, 16, 6144, 2048), (4096, 64, 2048, 1536)]
+
+
+@pytest.mark.parametrize("rows, experts, k, n", SHAPES)
+def test_the_grouped_kernels_compile_for_the_chip(one_chip, no_compile_cache, rows, experts, k, n):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    sizes, layer = shape((experts,), jnp.int32), shape((), jnp.int32)
+    up = jax.jit(lambda x, w0, w1, s, l: gm.grouped_matmul(x, (w0, w1), s, l, swiglu=True, interpret=False))
+    down = jax.jit(lambda x, w, s, l: gm.grouped_matmul(x, (w,), s, l, out_dtype=jnp.float32, interpret=False))
+    w_up, w_down = shape((2, experts, k, n), jnp.bfloat16), shape((2, experts, n, k), jnp.bfloat16)
+    for fn, args in ((up, (shape((rows, k), jnp.bfloat16), w_up, w_up, sizes, layer)),
+                     (down, (shape((rows, n), jnp.bfloat16), w_down, sizes, layer))):
+        compiled = fn.lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_limit_is_asked_for_only_where_the_blocks_outgrow_the_default():
+    # the second family's experts compile as they always did: no limit named
+    assert gm._vmem_limit(128, 2048, 512, 2, 2, 2) is None
+    assert gm._vmem_limit(128, 1536, 512, 1, 2, 4) is None
+    # [6144, 512] gate and up, double-buffered: 25 MB of weights alone
+    limit = gm._vmem_limit(128, 6144, 512, 2, 2, 2)
+    assert limit is not None and 28 << 20 < limit < 64 << 20
+    assert gm._vmem_limit(128, 2048, 512, 1, 2, 4) is None  # its down projection fits
