@@ -37,8 +37,10 @@ and `kv_lora_rank` (dc), head widths `qk_nope_head_dim` (dn),
   d_ff_expert) `+ SwiGLU_shared(h)` (width n_shared_experts x d_ff_expert).
   No capacity, no dropped token. The layer holds experts `expert_first ..
   + experts_held`, routes over all of them, and computes its own experts'
-  part. PADDING IS NOT ROUTED: a token that is not valid adds no load and
-  touches no expert's weights.
+  part: where it holds a share, from the assignments it holds alone while
+  they fit `held_bound`, from every token x pick row when they do not or
+  when it holds all (`routed_experts`). PADDING IS NOT ROUTED: a token that
+  is not valid adds no load and touches no expert's weights.
 - Head: final RMSNorm, untied output head.
 - Left out: the multi-token-prediction module (a draft head for
   self-speculation; the main model's logits do not depend on it).
@@ -70,7 +72,7 @@ from k8s_llm_scheduler_tpu.models.llama import (
     rms_norm,
 )
 from k8s_llm_scheduler_tpu.ops.attention import NEG_INF, merge_attention_parts, write_block
-from k8s_llm_scheduler_tpu.ops.grouped_matmul import grouped_matmul
+from k8s_llm_scheduler_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 
 Params = dict[str, Any]
 
@@ -186,6 +188,18 @@ def _swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
 SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": lambda logits: jax.nn.softmax(logits, axis=-1)}
 # What a layer with identity experts counts besides COUNTERS.
 ZERO_COUNTERS = ("moe_zero_assignments", "moe_ffn_assignments")
+# And behind those: the layer calls whose held assignments all lay within
+# `held_bound` (every call, where the bound is every row).
+BOUND_COUNTERS = ("moe_bounded_calls",)
+
+# How many times the rows a level router would send a share its short path
+# holds (`held_bound`). Rows in lockstep and repeated prompt tokens pick equal
+# experts, so a call's held count spreads well over a level draw's; at 2 every
+# call of the share cell still fitted, prefix prefills included, and at 4 the
+# layer alone took 4% (suffix call) and 13% (prefix prefill) longer for rows
+# nothing used (PERF.md §6 PR 36). A call that does not fit is exact all the
+# same: it takes every row.
+HELD_SLACK = 2
 
 
 def route(lp: Params, cfg, h: jax.Array, sel=None) -> tuple[jax.Array, jax.Array]:
@@ -222,6 +236,16 @@ def zero_experts(cfg, h: jax.Array, sel: jax.Array, w: jax.Array, valid: jax.Arr
     return y, jnp.stack([jnp.sum(zero), jnp.sum(ffn)]).astype(jnp.int32)
 
 
+def held_bound(n_rows: int, held_n: int, n_outputs: int) -> int:
+    """The most assignment rows a layer that holds `held_n` of its router's
+    `n_outputs` outputs handles on its short path, of the `n_rows` (tokens x
+    picks) a call routes: HELD_SLACK times what a level router sends it, in
+    whole row tiles of the grouped kernels, and never more than all of them.
+    A layer that holds every output gets `n_rows`: it has no short path."""
+    level_rows = -(-HELD_SLACK * n_rows * held_n // n_outputs)
+    return min(n_rows, -(-level_rows // ROW_TILE) * ROW_TILE)
+
+
 def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax.Array, jax.Array]:
     """The held experts' part of the routed output for normed tokens h
     [T, D] (f32 [T, D]) and the layer's COUNTERS. Tokens that are not
@@ -232,9 +256,17 @@ def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax
     stack in place). Where the config has identity experts
     (`cfg.n_zero_experts` not None: router outputs behind the feed-forward experts),
     their part is added HERE, on every share (they hold no weights and are
-    applied where the token is), and ZERO_COUNTERS follow COUNTERS."""
+    applied where the token is), and ZERO_COUNTERS and BOUND_COUNTERS follow
+    COUNTERS (such a layer never holds all its router's outputs).
+
+    Held assignments sort before the rows of no group, so where a call's
+    held assignments number at most `held_bound` they are the head of the
+    order, and only the head is gathered, multiplied and added back to its
+    tokens. A call with more takes every row, as a layer that holds every
+    output always does (its bound is every row): nothing is ever dropped."""
     T, D = h.shape
     k, held_n = cfg.n_experts_per_tok, cfg.experts_held
+    bound = held_bound(T * k, held_n, lp["router"].shape[-1])
     with jax.named_scope("moe_router"):
         sel, w = route(lp, cfg, h)
     with jax.named_scope("moe_dispatch"):
@@ -244,25 +276,50 @@ def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax
         group = jnp.where(held, local, held_n).reshape(T * k)
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((held_n + 1,), jnp.int32).at[group].add(1)[:held_n]
-        rows = h.astype(lp["we_gate"].dtype)[order // k]
-    with jax.named_scope("moe_experts"):
-        gate, up, down = (
-            w if w.ndim == 4 else w[None] for w in (lp["we_gate"], lp["we_up"], lp["we_down"])
-        )
-        layer = lp.get("layer", 0)
-        mid = grouped_matmul(rows, (gate, up), sizes, layer, swiglu=True)
-        out = grouped_matmul(mid, (down,), sizes, layer, out_dtype=jnp.float32)
-    with jax.named_scope("moe_combine"):
-        # rows of no group were never written: select, never scale
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
-        back = out[inverse].reshape(T, k, D)
-        y = jnp.sum(jnp.where(held[..., None], back * w[..., None], 0.0), axis=1)
+
+    def experts(head):
+        """f32 [M, D]: the grouped experts' output for the assignments
+        `head` [M], a head of the order; rows of no group are not written."""
+        with jax.named_scope("moe_dispatch"):
+            rows = h.astype(lp["we_gate"].dtype)[head // k]
+        with jax.named_scope("moe_experts"):
+            gate, up, down = (
+                w if w.ndim == 4 else w[None] for w in (lp["we_gate"], lp["we_up"], lp["we_down"])
+            )
+            layer = lp.get("layer", 0)
+            mid = grouped_matmul(rows, (gate, up), sizes, layer, swiglu=True)
+            return grouped_matmul(mid, (down,), sizes, layer, out_dtype=jnp.float32)
+
+    def every_row():
+        out = experts(order)
+        with jax.named_scope("moe_combine"):
+            # rows of no group were never written: select, never scale
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+            back = out[inverse].reshape(T, k, D)
+            return jnp.sum(jnp.where(held[..., None], back * w[..., None], 0.0), axis=1)
+
+    def held_rows():
+        head = order[:bound]
+        out = experts(head)
+        with jax.named_scope("moe_combine"):
+            live = jnp.arange(bound)[:, None] < jnp.sum(sizes)
+            weighted = jnp.where(live, out * w.reshape(T * k)[head][:, None], 0.0)
+            # each row to its token, summed on the MXU: a one-hot [T, bound]
+            to_token = (head // k)[None, :] == jnp.arange(T)[:, None]
+            return jnp.dot(to_token.astype(jnp.float32), weighted, precision=jax.lax.Precision.HIGHEST)
+
+    if bound == T * k:
+        y, fits = every_row(), True
+    else:
+        fits = jnp.sum(sizes) <= bound
+        y = jax.lax.cond(fits, held_rows, every_row)
     counters = jnp.stack([
         jnp.sum(held), jnp.sum(sizes > 0), jnp.int32(1), jnp.max(sizes),
     ]).astype(jnp.int32)
     if cfg.n_zero_experts is not None:
         y_zero, zero_counters = zero_experts(cfg, h, sel, w, valid)
-        return y + y_zero, jnp.concatenate([counters, zero_counters])
+        return y + y_zero, jnp.concatenate(
+            [counters, zero_counters, jnp.asarray(fits, jnp.int32)[None]])
     return y, counters
 
 

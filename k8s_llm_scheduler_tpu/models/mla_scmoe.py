@@ -56,6 +56,7 @@ from k8s_llm_scheduler_tpu.models.configs import MlaScmoeConfig
 from k8s_llm_scheduler_tpu.models.llama import _last_valid_logits, rms_norm
 from k8s_llm_scheduler_tpu.models.mla_moe import COUNTERS as EXPERT_COUNTERS
 from k8s_llm_scheduler_tpu.models.mla_moe import (
+    BOUND_COUNTERS,
     EXPERT_LEAVES,
     ZERO_COUNTERS,
     _inv_freq,
@@ -70,7 +71,7 @@ from k8s_llm_scheduler_tpu.ops.attention import write_block
 
 Params = dict[str, Any]
 
-COUNTERS = EXPERT_COUNTERS + ZERO_COUNTERS
+COUNTERS = EXPERT_COUNTERS + ZERO_COUNTERS + BOUND_COUNTERS
 
 # std of the drawn selection bias. The published model LEARNS the bias to
 # level the experts' load. Softmax scores over 768 outputs are small: at this

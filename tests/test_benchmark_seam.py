@@ -1,6 +1,7 @@
 """Tier 1's reach into benchmark/: the fast cases of
-benchmark/tests/test_arch_seam.py and all of benchmark/tests/test_scope_trace.py
-and test_counter_readers.py run here as they stand (loaded from their files, the way
+benchmark/tests/test_arch_seam.py and all of benchmark/tests/test_scope_trace.py,
+test_counter_readers.py and test_moe_bounded_share.py run here as they stand
+(loaded from their files, the way
 tests/test_tracing_scopes.py reaches benchmark/), so that a PR which breaks
 the architecture seam or the scope reduction fails the suite the driver
 runs and not only `pytest benchmark/tests`. Beside them: the FLOP counts of
@@ -31,7 +32,8 @@ def _load(path: Path, name: str):
 # on the CPU) stays with `pytest benchmark/tests`.
 SLOW = {"test_a_twin_architecture_runs_through_the_seam_by_files_alone"}
 
-for _file in ("test_scope_trace.py", "test_arch_seam.py", "test_counter_readers.py"):
+for _file in ("test_scope_trace.py", "test_arch_seam.py", "test_counter_readers.py",
+              "test_moe_bounded_share.py"):
     _mod = _load(BENCH / "tests" / _file, f"bench_tests_{_file[:-3]}")
     # tests and the fixtures they ask for, under their own names
     globals().update({k: v for k, v in vars(_mod).items()

@@ -427,6 +427,162 @@ def test_four_shares_of_four_experts_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(np.asarray(ref_parts), np.asarray(want), rtol=1e-4, atol=2e-5)
 
 
+# ------------------------------------------------------- the share's short path
+def routed_experts_every_row(lp, cfg, h, valid):
+    """`models/mla_moe.py` `routed_experts` as it stood before a share had a
+    short path (PR 34's, COUNTERS and the identity experts' part alone): every
+    token x pick row sorted, gathered, multiplied and un-sorted. Kept here as
+    what the short path has to equal, and as the text a layer that holds
+    every output still has to lower to."""
+    T, D = h.shape
+    k, held_n = cfg.n_experts_per_tok, cfg.experts_held
+    with jax.named_scope("moe_router"):
+        sel, w = mla_moe.route(lp, cfg, h)
+    with jax.named_scope("moe_dispatch"):
+        local = sel - cfg.expert_first
+        held = valid[:, None] & (local >= 0) & (local < held_n)
+        group = jnp.where(held, local, held_n).reshape(T * k)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((held_n + 1,), jnp.int32).at[group].add(1)[:held_n]
+        rows = h.astype(lp["we_gate"].dtype)[order // k]
+    with jax.named_scope("moe_experts"):
+        gate, up, down = (
+            w if w.ndim == 4 else w[None] for w in (lp["we_gate"], lp["we_up"], lp["we_down"])
+        )
+        layer = lp.get("layer", 0)
+        mid = mla_moe.grouped_matmul(rows, (gate, up), sizes, layer, swiglu=True)
+        out = mla_moe.grouped_matmul(mid, (down,), sizes, layer, out_dtype=jnp.float32)
+    with jax.named_scope("moe_combine"):
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
+        back = out[inverse].reshape(T, k, D)
+        y = jnp.sum(jnp.where(held[..., None], back * w[..., None], 0.0), axis=1)
+    counters = jnp.stack([
+        jnp.sum(held), jnp.sum(sizes > 0), jnp.int32(1), jnp.max(sizes),
+    ]).astype(jnp.int32)
+    if cfg.n_zero_experts is not None:
+        y_zero, zero_counters = mla_moe.zero_experts(cfg, h, sel, w, valid)
+        return y + y_zero, jnp.concatenate([counters, zero_counters])
+    return y, counters
+
+
+@pytest.mark.parametrize("n_rows, held_n, n_outputs", [
+    (2304, 16, 768), (12288, 16, 768), (24576, 16, 768),  # the third cell's decode, suffix and prefix calls
+    (4096, 64, 64), (96, 8, 64), (384, 4, 24), (3, 1, 1000), (1 << 20, 1, 3),
+])
+def test_the_bound_is_slack_times_a_level_share_in_whole_tiles_and_never_more_than_all(n_rows, held_n, n_outputs):
+    from k8s_llm_scheduler_tpu.ops.grouped_matmul import ROW_TILE
+
+    bound = mla_moe.held_bound(n_rows, held_n, n_outputs)
+    level = n_rows * held_n / n_outputs
+    assert 0 < bound <= n_rows
+    if bound < n_rows:
+        assert bound % ROW_TILE == 0
+        assert mla_moe.HELD_SLACK * level <= bound < mla_moe.HELD_SLACK * level + ROW_TILE
+    else:
+        assert mla_moe.HELD_SLACK * level > n_rows - ROW_TILE
+    if held_n == n_outputs:
+        assert bound == n_rows  # a layer that holds every output has no short path
+
+
+class TestShortPath:
+    """A share of 4 of 16 feed-forward experts among 24 router outputs, top 3,
+    128 tokens: 384 assignment rows, of which the short path handles a head
+    of `held_bound`. The router's SELECTION is forced (`route(sel=)`, the way
+    benchmark/tests/read_flips.py forces it), so that the number of held
+    assignments is the case's; weights, experts and identity picks are the
+    program's own."""
+
+    T, FIRST, HELD = 128, 5, 4
+    CONF = {**TOY, "n_routed_experts": 16, "zero_expert_num": 8, "moe_topk": 3,
+            "experts_held": HELD, "expert_first": FIRST}
+
+    @pytest.fixture(scope="class")
+    def layer(self):
+        cfg = toy_cfg(conf=self.CONF)
+        lp = {k: v[0] for k, v in toy_params(cfg)["layers"].items()}
+        rng = np.random.default_rng(23)
+        h = mla_moe.rms_norm(jnp.asarray(rng.normal(size=(self.T, cfg.d_model)), jnp.float32),
+                             lp["mlp_norm"][0], cfg.rms_eps)
+        valid = np.ones(self.T, bool)
+        valid[rng.choice(self.T, 16, replace=False)] = False  # padding tokens
+        bound = mla_moe.held_bound(self.T * 3, self.HELD, 24)
+        assert bound < valid.sum() * 3 < self.T * 3  # there is a short path, and it can overflow
+        return cfg, lp, h, valid, bound
+
+    def _selection(self, valid, n_held: int):
+        """[T, 3] router outputs, distinct within a token: `n_held` slots of
+        valid tokens on held experts, every other slot of a valid token on an
+        absent expert or an identity expert; padding tokens pick held
+        experts alone (and must not count)."""
+        t = np.arange(self.T)
+        sel = np.stack([t % self.FIRST, self.FIRST + self.HELD + t % 7, 16 + t % 8], axis=1)
+        on_held = self.FIRST + (t[:, None] + np.arange(3)[None, :]) % self.HELD
+        slots = np.argwhere(np.broadcast_to(valid[:, None], sel.shape))
+        slots = slots[np.random.default_rng(n_held).permutation(len(slots))[:n_held]]
+        sel[slots[:, 0], slots[:, 1]] = on_held[slots[:, 0], slots[:, 1]]
+        sel[~valid] = on_held[~valid]
+        return jnp.asarray(sel, jnp.int32)
+
+    def _run(self, monkeypatch, fn, layer, n_held):
+        cfg, lp, h, valid, _ = layer
+        forced, real = self._selection(valid, n_held), mla_moe.route
+        monkeypatch.setattr(mla_moe, "route", lambda lp_, cfg_, h_, sel=None: real(lp_, cfg_, h_, sel=forced))
+        y, counters = jax.jit(lambda lp_, h_, v: fn(lp_, cfg, h_, v))(lp, h, jnp.asarray(valid))
+        _, w = real(lp, cfg, h, sel=forced)
+        return np.asarray(y), np.asarray(counters), np.asarray(forced), np.asarray(w)
+
+    @pytest.mark.parametrize("case", ["none", "under", "at", "one_over", "every_pick"])
+    def test_it_equals_a_loop_over_experts_and_the_every_row_path(self, monkeypatch, layer, case):
+        cfg, lp, h, valid, bound = layer
+        n_held = {"none": 0, "under": bound - 37, "at": bound, "one_over": bound + 1,
+                  "every_pick": int(valid.sum()) * 3}[case]
+        y, counters, sel, w = self._run(monkeypatch, mla_moe.routed_experts, layer, n_held)
+        c = dict(zip(mla_scmoe.COUNTERS, map(int, counters)))
+        assert c["moe_assignments"] == n_held and c["moe_layer_calls"] == 1
+        assert c["moe_bounded_calls"] == (1 if n_held <= bound else 0)
+        assert c["moe_zero_assignments"] + c["moe_ffn_assignments"] == int(valid.sum()) * 3
+
+        want = np.zeros_like(y)
+        x = np.asarray(h)
+        for e in range(self.HELD):
+            mine = valid[:, None] & (sel == self.FIRST + e)
+            gate, up, down = (np.asarray(lp[k][e]) for k in mla_moe.EXPERT_LEAVES)
+            g = x @ gate
+            out = ((g / (1.0 + np.exp(-g))) * (x @ up)) @ down
+            want += out * (w * mine).sum(axis=1, keepdims=True)
+        want += x * (w * (valid[:, None] & (sel >= 16))).sum(axis=1, keepdims=True)
+        np.testing.assert_allclose(y, want, rtol=1e-4, atol=2e-5)
+        assert not y[~valid].any()  # padding is not routed, whatever it picked
+
+        before, counters_before, _, _ = self._run(monkeypatch, routed_experts_every_row, layer, n_held)
+        np.testing.assert_allclose(y, before, rtol=1e-5, atol=1e-6)  # float32 summation order
+        np.testing.assert_array_equal(counters[:6], counters_before)
+
+    def test_the_short_path_holds_no_operand_of_every_row_and_the_long_one_does(self, layer):
+        cfg, lp, h, valid, bound = layer
+        text = jax.jit(lambda lp_, h_, v: mla_moe.routed_experts(lp_, cfg, h_, v)).lower(
+            lp, h, jnp.asarray(valid)).as_text()
+        D, rows = cfg.d_model, self.T * 3
+        # the head's weighted rows against the one-hot, and the un-sort's gather
+        assert f"tensor<{self.T}x{bound}xf32>" in text and f"tensor<{bound}x{D}xf32>" in text
+        assert f"tensor<{rows}x{D}xf32>" in text
+
+
+@pytest.mark.parametrize("name, tokens", [("tiny-mla-moe", 4), ("tiny-mla-moe", 192), ("tiny-mla-moe", 2048)])
+def test_a_layer_that_holds_every_expert_lowers_as_it_did(name, tokens):
+    """`glm-4_7-flash` holds 64 of 64: its bound is every row, and the text
+    `routed_experts` lowers to is the text of the function as it stood."""
+    cfg = get_config(name)
+    assert mla_moe.held_bound(tokens * cfg.n_experts_per_tok, cfg.experts_held, cfg.n_routed_experts) \
+        == tokens * cfg.n_experts_per_tok
+    lp = jax.tree_util.tree_map(lambda a: a[0], jax.jit(
+        lambda k: mla_moe.init_params(k, cfg))(jax.random.PRNGKey(0))["moe_layers"])
+    h, valid = jnp.ones((tokens, cfg.d_model), jnp.float32), jnp.ones((tokens,), bool)
+    now, before = (jax.jit(lambda lp, h, v, fn=fn: fn(lp, cfg, h, v)).lower(lp, h, valid).as_text()
+                   for fn in (mla_moe.routed_experts, routed_experts_every_row))
+    assert now == before
+
+
 # ------------------------------------------------------------------ the names
 def test_the_lowered_forwards_hold_the_scopes_and_kernel_names():
     """The scopes a device trace reads this model's time by (benchmark/
