@@ -75,7 +75,7 @@ from k8s_llm_scheduler_tpu.observability import spans
 from k8s_llm_scheduler_tpu.engine.kv_cache import PagedKVCache
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
 from k8s_llm_scheduler_tpu.models import family
-from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, MlaMoeConfig, MlaScmoeConfig
+from k8s_llm_scheduler_tpu.models.configs import GdnMoeConfig, LlamaConfig, MlaMoeConfig, MlaScmoeConfig
 from k8s_llm_scheduler_tpu.models.llama import (
     Params,
     forward_decode_buffered,
@@ -315,7 +315,7 @@ def _decode_chunk_impl(
 
 def _wave_impl(
     params: Params,
-    cfg: LlamaConfig | MlaMoeConfig | MlaScmoeConfig,  # static
+    cfg: LlamaConfig | MlaMoeConfig | MlaScmoeConfig | GdnMoeConfig,  # static
     tokens,        # [R, Ss] suffix tokens, left-aligned, padded
     suffix_lens,   # [R] int32 (0 on padding rows)
     prefix_cache,  # the model's cache tuple of the shared prefix, each
@@ -333,6 +333,8 @@ def _wave_impl(
     vocab_limit: int | None = None,  # static — see _sample_unconstrained
     ragged_decode: bool = False,  # static — ragged-M decode matmuls
     shardings=None,  # engine/sharded EngineShardings | None (tp constraints)
+    prefix_state=(),  # the model's per-sequence state after the prefix, each
+    # [state layers, *shape]; () for a family that has none
 ):
     """One whole decision wave in ONE device program, with
     GRAMMAR-ACCELERATED BLOCK DECODING.
@@ -366,6 +368,13 @@ def _wave_impl(
     selects (models.family) says its shapes and brings the two forwards;
     nothing here knows what a cached token is made of.
 
+    A family with a PER-SEQUENCE STATE (`state_shapes(cfg)` not empty:
+    models/gdn_moe.py) also takes the state the prefix left: the suffix
+    call seeds every row from it and returns each row's own, which rides
+    the block loop's carry; every model call advances a row's state by the
+    tokens it holds for the row, and a row that is not alive keeps it. The
+    prefix's state is read here and never written.
+
     Returns (emitted [R, n_iters*F] with pad_id holes, active [R],
     iters_run scalar int32 — the number of model calls actually executed)
     and, for a model whose forwards count (family COUNTERS), those counts
@@ -373,6 +382,7 @@ def _wave_impl(
     """
     model = family(cfg)
     counted = len(model.COUNTERS) > 0  # static: a tuple of names
+    stateful = len(model.state_shapes(cfg)) > 0  # static too
     if shardings is not None:
         prefix_cache = tuple(shardings.kv4(a) for a in prefix_cache)
     # Named scopes (suffix_prefill, block_decode, sample_expand, model;
@@ -383,8 +393,10 @@ def _wave_impl(
         last_logits, *sfx = model.forward_prefill_suffix_dense(
             params, cfg, tokens, suffix_lens, *prefix_cache, prefix_len,
             prefix_impl=prefix_impl,
+            **({"state": prefix_state} if stateful else {}),
         )
     counts = sfx.pop() if counted else None
+    state = sfx.pop() if stateful else None  # each row's, after its suffix
     R = tokens.shape[0]
     if shardings is not None:
         # Suffix KV [L, R, Ss, n_kv, hd] and (below) the generated-KV
@@ -451,7 +463,8 @@ def _wave_impl(
         return blk_tok, blk_valid, blk_len, s_cur, alive, key
 
     def iteration(carry):
-        gen, st, act, emitted, pos_next, logits, key, *counts = carry
+        gen, st, act, emitted, pos_next, logits, key, *more = carry
+        counts, state = more[:int(counted)], more[int(counted):]
         blk_tok, blk_valid, blk_len, s_cur, alive, key = sample_expand(
             st, act, emitted, logits, key
         )
@@ -463,21 +476,26 @@ def _wave_impl(
                 *sfx, suffix_lens, *gen, emitted,
                 *prefix_cache, prefix_len, prefix_impl=prefix_impl,
                 ragged=ragged_decode,
+                **({"state": state[0]} if stateful else {}),
             )
         if counted:
             counts = [counts[0] + gen.pop()]
+        if stateful:
+            state = [gen.pop()]
         if shardings is not None:
             new_logits = shardings.logits2(new_logits)
             gen = [shardings.kv5(a) for a in gen]
         carry = (
             tuple(gen), s_cur, alive, emitted + blk_len,
-            pos_next + blk_len, new_logits, key, *counts,
+            pos_next + blk_len, new_logits, key, *counts, *state,
         )
         return carry, blk_tok
 
     carry0 = (gen, st, act, emitted, pos_next, last_logits, rng)
     if counted:
         carry0 += (counts,)
+    if stateful:
+        carry0 += (state,)
     out0 = jnp.full((R, n_iters * F), pad_id, dtype=tokens.dtype)
 
     def cond(state):
@@ -505,11 +523,18 @@ class _PrefixKV:
     """The model's cache of a burst-shared prompt prefix, prefilled once:
     a tuple of arrays [L, Sp_bucket, *token shape] — (k, v) [.., n_kv, hd]
     for the dense family, the latent pair (c_kv [.., dc], k_r [.., dr]) for
-    models/mla_moe.py. Axis 1 of every member is the token capacity."""
+    models/mla_moe.py. Axis 1 of every member is the token capacity.
+
+    `state`: what a family with a per-sequence state (models/gdn_moe.py)
+    carries AFTER the prefix's `length` tokens, each [state layers,
+    *shape]; () for the others. It is pinned, evicted and epoch-stamped
+    with its entry, and only ever read: every wave seeds its rows from
+    it."""
 
     kv: tuple[jax.Array, ...]
     length: int
     token_ids: tuple[int, ...]
+    state: tuple[jax.Array, ...] = ()
 
     @property
     def k(self) -> jax.Array:
@@ -521,7 +546,7 @@ class _PrefixKV:
 
     @property
     def nbytes(self) -> int:
-        return sum(int(a.nbytes) for a in self.kv)
+        return sum(int(a.nbytes) for a in (*self.kv, *self.state))
 
 
 @dataclasses.dataclass
@@ -646,6 +671,12 @@ class InferenceEngine:
         self._model = family(cfg)
         self._model_file = f"models/{self._model.__name__.rsplit('.', 1)[-1]}.py"
         self.paged = isinstance(cfg, LlamaConfig)
+        # A family whose sequences carry a state besides their tokens
+        # (`state_shapes`: models/gdn_moe.py): a prefix entry then holds
+        # the state its prefill left, waves seed their rows from it, and
+        # a new prefix is never seeded from a cached one's tokens (a
+        # state exists only at the lengths it was saved at).
+        self._stateful = len(self._model.state_shapes(cfg)) > 0
         self.tokenizer = tokenizer or ByteTokenizer()
         if self.tokenizer.vocab_size > cfg.vocab_size:
             raise ValueError(
@@ -669,9 +700,14 @@ class InferenceEngine:
         tp_size = mesh.shape.get("tp", 1) if mesh is not None else 1
         if not self.paged:
             if tp_size > 1:
+                what = (
+                    "a per-sequence state has no sharding rule"
+                    if self._stateful
+                    else "a latent cache has no head axis to shard"
+                )
                 raise ValueError(
-                    f"{cfg.name}: llm.mesh tp={tp_size} is not served — a "
-                    f"latent cache has no head axis to shard and the experts' "
+                    f"{cfg.name}: llm.mesh tp={tp_size} is not served — "
+                    f"{what} and the experts' "
                     f"exchange is not written (parallel/sharding.py, "
                     f"engine/sharded/)"
                 )
@@ -966,6 +1002,9 @@ class InferenceEngine:
             # program and fetched with its tokens (models/mla_moe.py
             # COUNTERS: expert load; none for the dense family)
             **{name: 0 for name in self._model.COUNTERS},
+            # wave rows seeded from a prefix's per-sequence state (a
+            # family that has one: models/gdn_moe.py)
+            **({"state_seeds": 0} if self._stateful else {}),
             "wave_prewarms": 0,
             "wave_prewarm_failures": 0,
             "prefix_reused_tokens": 0,
@@ -1064,12 +1103,29 @@ class InferenceEngine:
             for shape in self._model.cache_token_shapes(self.cfg)
         )
 
+    def _zero_state(self) -> tuple[jax.Array, ...]:
+        """The per-sequence state before any token, one array per member,
+        [state layers, *shape]; () for a family that has none."""
+        layers = self._model.state_layers(self.cfg)
+        return tuple(
+            jnp.zeros((layers, *shape), dtype)
+            for shape, dtype in self._model.state_shapes(self.cfg)
+        )
+
+    def _prefix_state_kw(self, prefix: _PrefixKV) -> dict:
+        """The wave program's `prefix_state` argument, for a family that has
+        a state alone (the others' call stays as it was): the prefix's own
+        arrays, read by every wave from this pin and never donated, each row
+        seeded with a copy."""
+        return {"prefix_state": prefix.state} if self._stateful else {}
+
     def _get_empty_prefix(self) -> _PrefixKV:
         if self._empty_prefix is None:
             self._empty_prefix = _PrefixKV(
                 kv=self._place_prefix(*self._prefix_buffers(self.kv.page_size)),
                 length=0,
                 token_ids=(),
+                state=self._zero_state(),
             )
         return self._empty_prefix
 
@@ -1118,15 +1174,17 @@ class InferenceEngine:
         # attention scores (8.6 GB at 8B scale for an 8k prompt), while the
         # chunked cascade is bounded at O(prefix_chunk x S).
         prefilled = n
+        n_cache = len(self._model.cache_token_shapes(self.cfg))
         if n > min(self.prefix_chunk, self.prefill_buckets[-1]):
-            seed = self._best_lcp_seed(key)
+            # a cached prefix's STATE exists at its own length only, not at
+            # the common length: such a family prefills the whole prefix
+            seed = None if self._stateful else self._best_lcp_seed(key)
             with spans.thread_span("dispatch", layer="engine"):
-                kv = self._place_prefix(
-                    *self._prefill_prefix_chunked(prompt_ids, seed=seed)
-                )
+                kv, state = self._prefill_prefix_chunked(prompt_ids, seed=seed)
+                kv = self._place_prefix(*kv)
             if seed is not None:
                 prefilled = n - seed[1]  # reused tokens were not re-prefilled
-            pfx = _PrefixKV(kv=kv, length=n, token_ids=key)
+            pfx = _PrefixKV(kv=kv, length=n, token_ids=key, state=state)
         else:
             bucket = self._bucket_for(n)
             pad = self.tokenizer.pad_id
@@ -1137,8 +1195,10 @@ class InferenceEngine:
                 _, *cache = self._prefill_kv(
                     self.params, self.cfg, jnp.asarray(tokens), jnp.asarray([n])
                 )
-                kv = self._place_prefix(*(a[:, 0] for a in cache))
-            pfx = _PrefixKV(kv=kv, length=n, token_ids=key)
+                kv = self._place_prefix(*(a[:, 0] for a in cache[:n_cache]))
+                # the state after the n real tokens of the padded bucket
+                state = tuple(a[:, 0] for a in cache[n_cache]) if self._stateful else ()
+            pfx = _PrefixKV(kv=kv, length=n, token_ids=key, state=state)
         self._prefix_cache[key] = pfx
 
         total = sum(p.nbytes for p in self._prefix_cache.values())
@@ -1223,6 +1283,7 @@ class InferenceEngine:
         adopting peer installs bytes identical to this engine's own
         entry and no novel pad-shape reaches its jitted programs.
         Returns None when the entry is not resident."""
+        self._require_stateless("export_prefix_kv() (the shared prefix-KV plane)")
         pfx = self._prefix_cache.get(tuple(key))
         if pfx is None:
             return None
@@ -1247,6 +1308,7 @@ class InferenceEngine:
 
         Returns (cache key, prefix_epoch) — pin_prefix's contract, and
         the same staleness rules apply (pin_alive / swap_params)."""
+        self._require_stateless("adopt_prefix_pages() (the shared prefix-KV plane)")
         if not prompt_ids:
             raise ValueError("cannot adopt an empty prefix")
         key = tuple(prompt_ids)
@@ -1319,7 +1381,7 @@ class InferenceEngine:
         self,
         prompt_ids: list[int],
         seed: tuple[tuple[jax.Array, ...], int] | None = None,
-    ) -> tuple[jax.Array, ...]:
+    ) -> tuple[tuple[jax.Array, ...], tuple[jax.Array, ...]]:
         """Blockwise prefill for prefixes beyond the largest bucket.
 
         Processes the prompt in largest-bucket chunks; each chunk attends to
@@ -1334,8 +1396,12 @@ class InferenceEngine:
         starts there — incremental prefix caching for drifting cluster
         snapshots.
 
-        Returns the cache tuple, each [L, cap, *token shape], cap a chunk
-        multiple.
+        A family with a per-sequence state is never seeded; its state is
+        carried from chunk to chunk (a chunk is a one-row suffix call
+        seeded from the state the chunks before it left).
+
+        Returns (the cache tuple, each [L, cap, *token shape], cap a chunk
+        multiple; the state after the n tokens, () for a family without).
         """
         chunk = min(self.prefix_chunk, self.prefill_buckets[-1])
         n = len(prompt_ids)
@@ -1369,6 +1435,7 @@ class InferenceEngine:
                 self.stats.get("prefix_reused_tokens", 0) + reuse
             )
         n_cache = len(bufs)
+        state = self._zero_state()
         for start in range(done, n, chunk):
             piece = prompt_ids[start : start + chunk]
             m = len(piece)
@@ -1378,7 +1445,10 @@ class InferenceEngine:
                 self.params, self.cfg,
                 jnp.asarray(tokens), jnp.asarray([m], dtype=np.int32),
                 *bufs, jnp.int32(done),
+                **({"state": state} if self._stateful else {}),
             )
+            if self._stateful:
+                state = tuple(a[:, 0] for a in out[1 + n_cache])
             # each [L, 1, chunk, *token shape] -> append at `start`
             bufs = tuple(
                 jax.lax.dynamic_update_slice_in_dim(
@@ -1387,7 +1457,7 @@ class InferenceEngine:
                 for buf, new in zip(bufs, out[1 : 1 + n_cache])
             )
             done += m
-        return bufs
+        return bufs, state
 
     @property
     def prefix_len(self) -> int:
@@ -1397,11 +1467,24 @@ class InferenceEngine:
         """Refuse, before anything is traced, an entry point that runs the
         paged pool for a model family that has no paged forwards."""
         if not self.paged:
+            what = "a per-sequence state" if self._stateful else "a latent cache"
             raise ValueError(
                 f"{self.cfg.name}: {path} is not served — it runs the paged "
                 f"KV pool, and {self._model_file} brings the decision wave's "
-                f"forwards only (a latent cache in PagedKVCache / KVGeometry "
+                f"forwards only ({what} in PagedKVCache / KVGeometry "
                 f"is not written)"
+            )
+
+    def _require_stateless(self, path: str) -> None:
+        """Refuse an entry point that hands a prefix out or takes one in as
+        its cache tuple alone, for a family whose prefix is a cache AND a
+        state."""
+        if self._stateful:
+            raise ValueError(
+                f"{self.cfg.name}: {path} is not served — a pinned prefix of "
+                f"{self._model_file} is its cache and the per-sequence state "
+                f"its prefill left, and the plane ships (k, v) only "
+                f"(fleet/kvplane/pages.py)"
             )
 
     # ------------------------------------------------------------ requests
@@ -1887,6 +1970,7 @@ class InferenceEngine:
                     jnp.int32(self._dfa_start),
                     sub, jnp.float32(self.temperature),
                     n_iters, F, max_new, self._constrained,
+                    **self._prefix_state_kw(prefix),
                 )
             except Exception:
                 # Record and move on: the backlog must drain even when a
@@ -1985,6 +2069,7 @@ class InferenceEngine:
                 jnp.int32(self._dfa_start),
                 sub, jnp.float32(self.temperature),
                 n_iters, F, max_new_tokens, self._constrained,
+                **self._prefix_state_kw(prefix),
             )
         # Recorded only AFTER a successful dispatch: a failed first
         # dispatch must leave the geometry cold (or the retry's compile
@@ -2005,6 +2090,8 @@ class InferenceEngine:
         self.stats["dispatches"] += 1
         self.stats["prefill_tokens"] += int(suffix_lens.sum())
         self.stats["suffix_tokens_computed"] += R * bucket
+        if self._stateful:
+            self.stats["state_seeds"] += R
         self.stats["requests"] += len(prompts)
         handle = WaveHandle(
             toks_d=toks_d,

@@ -1,7 +1,8 @@
 """Model-family configurations: the Llama dense family, the latent-
-attention sparse-expert family (`MlaMoeConfig`, models/mla_moe.py) and the
+attention sparse-expert family (`MlaMoeConfig`, models/mla_moe.py), the
 shortcut-connected double layer over it (`MlaScmoeConfig`,
-models/mla_scmoe.py).
+models/mla_scmoe.py) and the gated-delta-rule / gated-attention hybrid over
+sparse experts (`GdnMoeConfig`, models/gdn_moe.py).
 
 The reference consumes Llama-3.3-70B-Instruct behind the HuggingFace API
 (reference scheduler.py:425, config.yaml:8); the BASELINE ladder also names
@@ -331,6 +332,161 @@ class MlaScmoeConfig:
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class GdnMoeConfig:
+    """Gated-delta-rule linear attention and gated softmax attention in a
+    fixed period over sparse experts with a gated shared expert: the
+    `qwen3_next` layer (models/gdn_moe.py writes the equations out). Layer i
+    attends (16 query / 2 KV heads) where (i + 1) % `full_attention_interval`
+    == 0 and is a delta-rule mixer otherwise; every layer's feed-forward is
+    the sparse block. A delta-rule layer keeps no per-token cache: it keeps a
+    STATE a sequence, `gdn_value_heads` matrices [gdn_key_dim, gdn_value_dim]
+    in float32 and the last `conv_kernel - 1` inputs of its convolution.
+
+    `expert_first` / `expert_count`: the range of routed experts held here,
+    as in MlaMoeConfig (an expert-parallel share)."""
+
+    name: str
+    vocab_size: int
+    d_model: int
+    n_layers: int                 # whole periods of full_attention_interval
+    full_attention_interval: int
+    n_heads: int                  # the attention layers' query heads
+    n_kv_heads: int
+    head_dim: int
+    partial_rotary_factor: float  # the share of head_dim that is rotated
+    gdn_key_heads: int            # linear_num_key_heads
+    gdn_value_heads: int          # linear_num_value_heads
+    gdn_key_dim: int              # linear_key_head_dim
+    gdn_value_dim: int            # linear_value_head_dim
+    conv_kernel: int              # linear_conv_kernel_dim
+    d_ff_expert: int              # one expert's width (moe_intermediate_size)
+    d_ff_shared: int              # the shared expert's width
+    n_routed_experts: int
+    n_experts_per_tok: int
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    expert_first: int = 0
+    expert_count: int | None = None  # None: every routed expert
+    max_seq_len: int = 8192
+    rope_theta: float = 10000000.0
+    rms_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.bfloat16
+    tie_embeddings: bool = False
+
+    # what `route` and `routed_experts` of models/mla_moe.py ask of a config
+    router_score = "softmax"
+    n_zero_experts = None
+
+    def __post_init__(self) -> None:
+        if self.tie_embeddings:
+            raise ValueError(f"{self.name}: GdnMoeConfig serves an untied output head only")
+        if self.n_layers % self.full_attention_interval:
+            raise ValueError(f"{self.name}: n_layers must be whole periods of full_attention_interval")
+        if self.n_heads % self.n_kv_heads or self.gdn_value_heads % self.gdn_key_heads:
+            raise ValueError(f"{self.name}: query / value heads must be a multiple of their key heads")
+        if self.rotary_dim % 2:
+            raise ValueError(f"{self.name}: the rotated part of a head must be even")
+        if not 0 < self.n_experts_per_tok <= self.n_routed_experts:
+            raise ValueError(f"{self.name}: n_experts_per_tok outside 1..n_routed_experts")
+        if self.expert_first < 0 or self.expert_first + self.experts_held > self.n_routed_experts:
+            raise ValueError(f"{self.name}: held expert range outside the routed experts")
+
+    @property
+    def n_periods(self) -> int:
+        return self.n_layers // self.full_attention_interval
+
+    @property
+    def n_attn_layers(self) -> int:
+        return self.n_periods
+
+    @property
+    def n_gdn_layers(self) -> int:
+        return self.n_layers - self.n_periods
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_routed_experts if self.expert_count is None else self.expert_count
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
+    def gdn_key_width(self) -> int:
+        return self.gdn_key_heads * self.gdn_key_dim
+
+    @property
+    def gdn_value_width(self) -> int:
+        return self.gdn_value_heads * self.gdn_value_dim
+
+    @property
+    def conv_width(self) -> int:
+        """Channels the causal convolution runs over: [q | k | v]."""
+        return 2 * self.gdn_key_width + self.gdn_value_width
+
+    @classmethod
+    def from_hf(cls, name: str, conf: dict, **overrides) -> "GdnMoeConfig":
+        """From the published `config.json` keys (qwen3_next)."""
+        if conf.get("decoder_sparse_step", 1) != 1 or conf.get("mlp_only_layers"):
+            raise ValueError(f"{name}: every layer's feed-forward is served as the sparse block")
+        if conf.get("rope_scaling") is not None or conf.get("use_sliding_window", False):
+            raise ValueError(f"{name}: rope scaling and sliding windows are not served")
+        kw = dict(
+            name=name, vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+            n_layers=conf["num_hidden_layers"],
+            full_attention_interval=conf["full_attention_interval"],
+            n_heads=conf["num_attention_heads"], n_kv_heads=conf["num_key_value_heads"],
+            head_dim=conf["head_dim"], partial_rotary_factor=conf["partial_rotary_factor"],
+            gdn_key_heads=conf["linear_num_key_heads"], gdn_value_heads=conf["linear_num_value_heads"],
+            gdn_key_dim=conf["linear_key_head_dim"], gdn_value_dim=conf["linear_value_head_dim"],
+            conv_kernel=conf["linear_conv_kernel_dim"],
+            d_ff_expert=conf["moe_intermediate_size"],
+            d_ff_shared=conf["shared_expert_intermediate_size"],
+            n_routed_experts=conf["num_experts"], n_experts_per_tok=conf["num_experts_per_tok"],
+            norm_topk_prob=conf["norm_topk_prob"],
+            max_seq_len=conf["max_position_embeddings"], rope_theta=float(conf["rope_theta"]),
+            rms_eps=conf["rms_norm_eps"], tie_embeddings=conf["tie_word_embeddings"],
+        )
+        kw.update(overrides)
+        return cls(**kw)
+
+    def gdn_params(self) -> int:
+        """Matrix parameters of one delta-rule mixer (the convolution's
+        taps, the norms and the two per-head vectors left out)."""
+        d = self.d_model
+        return (d * (2 * self.gdn_key_width + 2 * self.gdn_value_width)
+                + d * 2 * self.gdn_value_heads + self.gdn_value_width * d)
+
+    def attn_params(self) -> int:
+        d, hd = self.d_model, self.head_dim
+        return d * self.n_heads * 2 * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
+
+    def gdn_state_flops_per_token(self) -> float:
+        """What a token costs a delta-rule layer beside its projections,
+        counted per token as the recurrence states it: S^T k, the rank-one
+        update and S^T q, 2 x dk x dv each a value head."""
+        return 3 * 2.0 * self.gdn_value_heads * self.gdn_key_dim * self.gdn_value_dim
+
+    def matmul_flops_per_token(self) -> float:
+        """Matmul FLOPs of one token AS THIS SHARE RUNS IT: the mixers and
+        the shared expert whole, the router over all its outputs, and of the
+        `n_experts_per_tok` picks the part that falls, under even routing,
+        on the experts held here."""
+        d = self.d_model
+        held_picks = self.n_experts_per_tok * self.experts_held / self.n_routed_experts
+        moe = (d * self.n_routed_experts + held_picks * 3 * d * self.d_ff_expert
+               + 3 * d * self.d_ff_shared + d)
+        return (2.0 * (self.n_gdn_layers * self.gdn_params() + self.n_attn_layers * self.attn_params()
+                       + self.n_layers * moe + d * self.vocab_size)
+                + self.n_gdn_layers * self.gdn_state_flops_per_token())
+
+    def attn_flops_per_key(self) -> float:
+        """Score + value FLOPs of one token against one key, in the layers
+        that attend (a delta-rule layer's cost does not grow with context)."""
+        return 4.0 * self.n_attn_layers * self.n_heads * self.head_dim
+
+
 TINY = LlamaConfig(
     name="tiny",
     vocab_size=512,          # byte tokenizer fits in 512
@@ -450,14 +606,42 @@ TINY_MLA_SCMOE = MlaScmoeConfig(
     rope_theta=10000.0,
 )
 
+# Toy of the delta-rule / attention hybrid for the CPU tests: two periods of
+# three delta-rule layers and one gated attention, a share of the routed
+# experts (4 of 16), a gated shared expert, every width shrunk (two value
+# heads a key head and a quarter of the head rotated, as published).
+TINY_GDN_MOE = GdnMoeConfig(
+    name="tiny-gdn-moe",
+    vocab_size=512,
+    d_model=64,
+    n_layers=8,
+    full_attention_interval=4,
+    n_heads=4,
+    n_kv_heads=2,
+    head_dim=32,
+    partial_rotary_factor=0.25,
+    gdn_key_heads=2,
+    gdn_value_heads=4,
+    gdn_key_dim=16,
+    gdn_value_dim=16,
+    conv_kernel=4,
+    d_ff_expert=32,
+    d_ff_shared=32,
+    n_routed_experts=16,
+    n_experts_per_tok=3,
+    expert_count=4,
+    max_seq_len=2048,
+    rope_theta=10000.0,
+)
+
 _REGISTRY = {
     c.name: c
     for c in (TINY, SMALL, LLAMA_3_2_1B, LLAMA_3_1_8B, LLAMA_3_3_70B, TINY_MLA_MOE,
-              TINY_MLA_SCMOE)
+              TINY_MLA_SCMOE, TINY_GDN_MOE)
 }
 
 
-def get_config(name: str) -> LlamaConfig | MlaMoeConfig | MlaScmoeConfig:
+def get_config(name: str) -> LlamaConfig | MlaMoeConfig | MlaScmoeConfig | GdnMoeConfig:
     key = name.lower()
     if key not in _REGISTRY:
         raise KeyError(f"unknown model config {name!r}; known: {sorted(_REGISTRY)}")

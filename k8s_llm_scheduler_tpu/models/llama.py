@@ -54,6 +54,15 @@ def cache_layers(cfg: LlamaConfig) -> int:
     return cfg.n_layers
 
 
+def state_shapes(cfg: LlamaConfig) -> tuple:
+    """What a sequence carries besides its per-token cache: nothing."""
+    return ()
+
+
+def state_layers(cfg: LlamaConfig) -> int:
+    return 0
+
+
 # --------------------------------------------------------------------- norm
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     """RMSNorm in f32, result back in input dtype."""

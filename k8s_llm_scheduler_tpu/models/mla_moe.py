@@ -99,6 +99,15 @@ def cache_layers(cfg: MlaMoeConfig) -> int:
     return cfg.n_layers
 
 
+def state_shapes(cfg) -> tuple:
+    """What a sequence carries besides its per-token cache: nothing."""
+    return ()
+
+
+def state_layers(cfg) -> int:
+    return 0
+
+
 def _inv_freq(cfg) -> jax.Array:
     dr = cfg.qk_rope_head_dim
     return 1.0 / (cfg.rope_theta ** (jnp.arange(0, dr, 2, dtype=jnp.float32) / dr))
@@ -216,7 +225,9 @@ def route(lp: Params, cfg, h: jax.Array, sel=None) -> tuple[jax.Array, jax.Array
     )
     scores = SCORES[cfg.router_score](logits)
     if sel is None:
-        _, sel = jax.lax.top_k(scores + lp["router_bias"], cfg.n_experts_per_tok)
+        # a router without a selection bias (models/gdn_moe.py) holds no such leaf
+        bias = lp.get("router_bias")
+        _, sel = jax.lax.top_k(scores if bias is None else scores + bias, cfg.n_experts_per_tok)
     w = jnp.take_along_axis(scores, sel, axis=1)
     if cfg.norm_topk_prob:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
@@ -256,8 +267,9 @@ def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax
     stack in place). Where the config has identity experts
     (`cfg.n_zero_experts` not None: router outputs behind the feed-forward experts),
     their part is added HERE, on every share (they hold no weights and are
-    applied where the token is), and ZERO_COUNTERS and BOUND_COUNTERS follow
-    COUNTERS (such a layer never holds all its router's outputs).
+    applied where the token is), and ZERO_COUNTERS follow COUNTERS. Where
+    the layer holds a share of its router's outputs (it then has the short
+    path below), BOUND_COUNTERS come last.
 
     Held assignments sort before the rows of no group, so where a call's
     held assignments number at most `held_bound` they are the head of the
@@ -316,11 +328,14 @@ def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax
     counters = jnp.stack([
         jnp.sum(held), jnp.sum(sizes > 0), jnp.int32(1), jnp.max(sizes),
     ]).astype(jnp.int32)
+    more = []
     if cfg.n_zero_experts is not None:
         y_zero, zero_counters = zero_experts(cfg, h, sel, w, valid)
-        return y + y_zero, jnp.concatenate(
-            [counters, zero_counters, jnp.asarray(fits, jnp.int32)[None]])
-    return y, counters
+        y = y + y_zero
+        more.append(zero_counters)
+    if held_n < lp["router"].shape[-1]:
+        more.append(jnp.asarray(fits, jnp.int32)[None])
+    return y, jnp.concatenate([counters, *more]) if more else counters
 
 
 def shared_experts(lp: Params, h: jax.Array) -> jax.Array:
@@ -339,7 +354,8 @@ def _feed_forward(lp: Params, cfg: MlaMoeConfig, x: jax.Array, valid: jax.Array,
                 jnp.zeros((len(COUNTERS),), jnp.int32))
     flat = h.reshape(-1, h.shape[-1])
     y, counters = routed_experts(lp, cfg, flat, valid.reshape(-1))
-    return (y + shared_experts(lp, flat)).reshape(x.shape), counters
+    # a share's BOUND_COUNTERS are not among this family's COUNTERS
+    return (y + shared_experts(lp, flat)).reshape(x.shape), counters[:len(COUNTERS)]
 
 
 # ---------------------------------------------------------------- attention

@@ -66,6 +66,8 @@ from k8s_llm_scheduler_tpu.models.mla_moe import (
     attention_sublayer,
     cache_token_shapes,  # noqa: F401  (the family's contract: models.family)
     routed_experts,
+    state_layers,  # noqa: F401
+    state_shapes,  # noqa: F401
 )
 from k8s_llm_scheduler_tpu.ops.attention import write_block
 

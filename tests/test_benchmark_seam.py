@@ -1,6 +1,7 @@
 """Tier 1's reach into benchmark/: the fast cases of
 benchmark/tests/test_arch_seam.py and all of benchmark/tests/test_scope_trace.py,
-test_counter_readers.py and test_moe_bounded_share.py run here as they stand
+test_counter_readers.py, test_moe_bounded_share.py (less the one case PR 37's
+appended entries outdate, below) and test_state_readers.py run here as they stand
 (loaded from their files, the way
 tests/test_tracing_scopes.py reaches benchmark/), so that a PR which breaks
 the architecture seam or the scope reduction fails the suite the driver
@@ -31,17 +32,32 @@ def _load(path: Path, name: str):
 # The whole run of a twin architecture through run_cell (a minute and more
 # on the CPU) stays with `pytest benchmark/tests`.
 SLOW = {"test_a_twin_architecture_runs_through_the_seam_by_files_alone"}
+# benchmark/tests/test_moe_bounded_share.py holds that `moe_bounded_share.tput`
+# is the LAST per-layer entry and lists the third cell alone. ISSUE 37 appends
+# the fourth cell to that list and five entries behind it, and a PR may edit
+# no file the benchmark has: the case stays in its file for a benchmark PR to
+# bring up to date, and what still holds of it is held below.
+OUTDATED = {"test_the_entry_is_the_third_cells_alone"}
 
 for _file in ("test_scope_trace.py", "test_arch_seam.py", "test_counter_readers.py",
-              "test_moe_bounded_share.py"):
+              "test_moe_bounded_share.py", "test_state_readers.py"):
     _mod = _load(BENCH / "tests" / _file, f"bench_tests_{_file[:-3]}")
     # tests and the fixtures they ask for, under their own names
     globals().update({k: v for k, v in vars(_mod).items()
-                      if not k.startswith("__") and k not in SLOW and k != "BENCH"})
+                      if not k.startswith("__") and k not in SLOW | OUTDATED and k != "BENCH"})
+
+
+def test_the_bounded_share_entry_keeps_its_keys_and_its_first_cell():
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    entry = next(m for m in bench["per_layer"] if m["name"] == "moe_bounded_share.tput")
+    assert entry == {"name": "moe_bounded_share.tput", "unit": "%", "better": "higher",
+                     "source": "program_counter", "layer": "model", "moves": "binds_per_s",
+                     "workloads": ["longcat_flash-backlog20", "qwen3_next-backlog20"]}
 
 ARCH = _load(BENCH / "arch" / "mla_moe.py", "bench_arch_mla_moe_pins")
 REF = _load(BENCH / "reference" / "mla_moe.py", "bench_reference_mla_moe_pins")
 SC_REF = _load(BENCH / "reference" / "mla_scmoe.py", "bench_reference_mla_scmoe_pins")
+GDN_REF = _load(BENCH / "reference" / "gdn_moe.py", "bench_reference_gdn_moe_pins")
 
 
 @pytest.fixture(scope="module")
@@ -213,6 +229,128 @@ class TestLongcatThroughTheSeam:
         assert cell in next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"]
 
 
+class TestQwen3NextThroughTheSeam:
+    """benchmark/configs/qwen3-next-80b-a3b.json loaded the way run.py loads
+    it: `"architecture": "gdn_moe"` selects arch/ and reference/, `register`
+    hands the program a config of its own type, and the arch file's count of
+    what a token needs is the config type's books."""
+
+    GDN = 2048 * 12288 + 2048 * 64 + 4096 * 2048                      # 33.69 M: W_qkvz, W_ba, W_o
+    ATTN = 2048 * 16 * 512 + 2 * 2048 * 2 * 256 + 16 * 256 * 2048     # 27.26 M: W_q with its gate, W_k, W_v, W_o
+    ROUTER, EXPERT, SHARED = 2048 * 512, 3 * 2048 * 512, 3 * 2048 * 512 + 2048
+    STATE = 3 * 2 * 32 * 128 * 128                                    # S^T k, k delta^T, S^T q a value head
+
+    @pytest.fixture(scope="class")
+    def loaded(self):
+        from harness import seam
+
+        conf = seam.load_config(BENCH / "configs" / "qwen3-next-80b-a3b.json")
+        return conf, seam.program(conf), seam.reference(conf)
+
+    def test_the_file_selects_its_architecture_and_registers_its_own_config_type(self, loaded):
+        from k8s_llm_scheduler_tpu.models import family, gdn_moe
+        from k8s_llm_scheduler_tpu.models.configs import GdnMoeConfig, get_config
+
+        conf, arch, ref = loaded
+        assert arch.__file__.endswith("arch/gdn_moe.py") and ref.__file__.endswith("reference/gdn_moe.py")
+        cfg = get_config(arch.register(conf))
+        assert isinstance(cfg, GdnMoeConfig) and family(cfg) is gdn_moe
+        assert (cfg.n_layers, cfg.n_periods, gdn_moe.cache_layers(cfg), gdn_moe.state_layers(cfg)) == (12, 3, 3, 3)
+        assert (cfg.n_routed_experts, cfg.experts_held, cfg.expert_first, cfg.n_experts_per_tok) == (512, 128, 0, 10)
+        assert cfg.norm_topk_prob and cfg.router_score == "softmax" and cfg.rotary_dim == 64
+        members = [m[0] for m in gdn_moe.state_shapes(cfg)]  # a member a delta-rule position of the period
+        assert members == [(32, 128, 128)] * 3 + [(3, 8192)] * 3  # 2.1 MB and 98 KB a sequence a layer
+        from k8s_llm_scheduler_tpu.models.mla_moe import held_bound
+        assert [held_bound(t * 10, 128, 512) for t in (8 * 24, 8 * 128, 2048)] == [1024, 5120, 10240]  # half the rows
+
+    def test_a_token_by_hand_is_the_arch_files_count_and_the_config_types_books(self, loaded):
+        from k8s_llm_scheduler_tpu.models.configs import get_config
+        from k8s_llm_scheduler_tpu.observability.profiler import matmul_flops_per_token
+
+        conf, arch, _ = loaded
+        assert arch.held_picks_per_token(conf) == 2.5  # 10 picks x 128 held / 512 outputs
+        moe = self.ROUTER + 2.5 * self.EXPERT + self.SHARED
+        by_hand = 2 * (9 * self.GDN + 3 * self.ATTN + 12 * moe) + 9 * self.STATE
+        assert by_hand == 1_087_684_608  # 1.09 GFLOP a token through 12 layers, 28 MFLOP of it state products
+        assert arch.flops_per_token(conf, with_head=False) == by_hand
+        assert arch.flops_per_token(conf, with_head=True) - by_hand == 2 * 2048 * 37_984
+        cfg = get_config(arch.register(conf))
+        assert matmul_flops_per_token(cfg) == arch.flops_per_token(conf, with_head=True)
+        assert (cfg.gdn_params(), cfg.attn_params()) == (self.GDN, self.ATTN)
+        assert cfg.gdn_state_flops_per_token() == arch.gdn_state_flops_per_token(conf) == self.STATE
+        # the three layers that attend alone: 16 heads x 2 x 2 x 256 a key
+        assert arch.attention_flops(conf, 1, 1) == cfg.attn_flops_per_key() == 3 * 16 * 4 * 256
+
+    def test_a_grouped_kernel_call_is_bound_by_the_touched_experts_bytes(self, loaded):
+        _, arch, _ = loaded
+        flops, moved = arch.grouped_kernel_cost(45, 14, 2048, 512, 2, 2)
+        assert flops == 2.0 * 45 * 2048 * 512 * 2
+        assert moved == 14 * 2048 * 512 * 2 * 2 + 45 * (2048 * 2 + 512 * 2)
+        assert moved / 819e9 > 50 * flops / 197e12
+
+    def test_the_configuration_file_holds_the_published_row(self, loaded):
+        """Every number of the catalog row under its own key; depth, experts
+        held and vocabulary the only cuts; no width touched."""
+        conf, _, _ = loaded
+        published = {
+            "decoder_sparse_step": 1, "full_attention_interval": 4, "head_dim": 256, "hidden_act": "silu",
+            "hidden_size": 2048, "intermediate_size": 5120, "linear_conv_kernel_dim": 4,
+            "linear_key_head_dim": 128, "linear_num_key_heads": 16, "linear_num_value_heads": 32,
+            "linear_value_head_dim": 128, "max_position_embeddings": 262144, "mlp_only_layers": [],
+            "model_type": "qwen3_next", "moe_intermediate_size": 512, "norm_topk_prob": True,
+            "num_attention_heads": 16, "num_experts": 512, "num_experts_per_tok": 10,
+            "num_key_value_heads": 2, "partial_rotary_factor": 0.25, "rms_norm_eps": 1e-06,
+            "rope_scaling": None, "rope_theta": 10000000, "shared_expert_intermediate_size": 512,
+            "tie_word_embeddings": False, "use_sliding_window": False,
+        }
+        assert {k: conf[k] for k in published} == published
+        assert conf["reduced"] == ["num_hidden_layers", "experts_held", "vocab_size"]
+        assert (conf["num_hidden_layers"], conf["experts_held"], conf["vocab_size"]) == (12, 128, 37984)
+        assert conf["published"] == {**conf["published"], "num_hidden_layers": 48, "experts_held": 512,
+                                     "vocab_size": 151936}
+        assert conf["vocab_size"] * 4 == 151936 and "16 chips" in conf["deployment"]
+        entry = next(c for c in json.loads((BENCH.parent / "BENCHMARK.json").read_text())["configs"]
+                     if c["name"] == conf["name"])
+        assert entry["reduced"] == conf["reduced"] and entry["source"] == conf["source"]
+        by_hand = (9 * (self.GDN + 4 * 8192 + 2 * 32 + 128) + 3 * (self.ATTN + 2 * 256)
+                   + 12 * (2 * 2048 + self.ROUTER + 128 * self.EXPERT + self.SHARED)
+                   + 2 * 37984 * 2048 + 2048)
+        assert conf["parameters"] == by_hand  # 5.42 B parameters, 10.85 GB bf16
+
+    def test_the_reference_imports_nothing_of_the_program_or_the_harness(self):
+        text = (BENCH / "reference" / "gdn_moe.py").read_text()
+        imports = [ln for ln in text.splitlines() if ln.startswith(("import ", "from "))]
+        assert imports == ["from __future__ import annotations", "import functools", "import jax",
+                           "import jax.numpy as jnp", "import numpy as np"]
+        assert "lax.scan(step, s0" in text and "solve_triangular" not in text  # the recurrence, not the chunked form
+
+    def test_the_cell_is_listed_where_its_readers_find_something(self):
+        bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+        cell = "qwen3_next-backlog20"
+        assert bench["workloads"][-1] == {**bench["workloads"][-1], "name": cell, "config": "qwen3-next-80b-a3b",
+                                          "traffic": "backlog20_pool80", "chips": 1}
+        listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
+        new = {"gdn_device_ms_per_bind.tput", "gdn_scan_device_ms_per_bind.tput",
+               "full_attn_device_ms_per_bind.tput", "state_carry_device_ms_per_bind.tput",
+               "state_valid_share.tput"}
+        shared = {"moe_experts_device_ms_per_bind.tput", "moe_router_device_ms_per_bind.tput",
+                  "moe_shared_device_ms_per_bind.tput", "experts_hit_per_layer_call.tput",
+                  "moe_bounded_share.tput", "moe_grouped_swiglu_roofline.tput",
+                  "moe_grouped_matmul_roofline.tput"}
+        assert new | shared <= listed
+        # readers of another family's scopes, keys or whole expert set
+        assert not listed & {"expert_load_max_over_mean.tput", "mla_proj_device_ms_per_bind.tput",
+                             "dense_ffn_device_ms_per_bind.tput", "moe_zero_device_ms_per_bind.tput",
+                             "zero_expert_share.tput", "experts_here_share.tput",
+                             "scmoe_grouped_swiglu_roofline.tput", "scmoe_grouped_matmul_roofline.tput",
+                             "prefix_attn_roofline.tput"}
+        for m in bench["per_layer"]:
+            if cell in m["workloads"]:
+                assert m["workloads"][-1] == cell  # appended, nothing moved
+        assert next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"][-1] == cell
+        assert len(bench["workloads"]) == 4 and all(w["chips"] == 1 for w in bench["workloads"])
+
+
 @pytest.mark.parametrize("name, stats, want", [
     ("zero_expert_share.tput", {"moe_zero_assignments": 400, "moe_ffn_assignments": 800}, 100 / 3),
     ("zero_expert_share.tput", {}, None),  # a parent: no such counters
@@ -282,3 +420,38 @@ def test_reference_runs_in_both_modes_and_int8_differs():
     # a tail sees the prefix and itself alone: the other row's tokens do not matter
     alone = REF.wave_logits(toy, weights, prefix, tails[:1], spans[:1], "f32", 300)
     np.testing.assert_allclose(alone, f32[:5], rtol=1e-4, atol=1e-5)
+
+
+def test_gdn_reference_runs_in_both_modes_and_int8_differs():
+    """reference/gdn_moe.py at a toy size: `f32` and the `int8` control see
+    the same wave and give different logits, both finite; a tail is seeded
+    from the prefix's state and sees the prefix and itself alone; and the
+    prefix's padding (its length is rounded up to few programs) is not
+    there."""
+    toy = {
+        "hidden_size": 64, "num_hidden_layers": 4, "full_attention_interval": 4, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 32, "partial_rotary_factor": 0.25, "linear_num_key_heads": 2,
+        "linear_num_value_heads": 4, "linear_key_head_dim": 16, "linear_value_head_dim": 16,
+        "linear_conv_kernel_dim": 4, "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32,
+        "num_experts": 16, "num_experts_per_tok": 3, "norm_topk_prob": True, "vocab_size": 512,
+        "rope_theta": 10000.0, "rms_norm_eps": 1e-6, "experts_held": 4, "expert_first": 4,
+    }
+    weights = GDN_REF.init_weights(toy, 3)
+    assert weights["layers"]["we_gate"].shape == (4, 4, 64, 32)   # the share's experts alone
+    assert weights["layers"]["router"].shape == (4, 64, 16)       # the router's whole width
+    assert weights["gdn"]["w_qkvz"].shape == (3, 64, 2 * 32 + 2 * 64) and weights["attn"]["wq"].shape == (1, 64, 256)
+    rng = np.random.default_rng(5)
+    prefix = rng.integers(1, 500, 40).tolist()
+    tails = [rng.integers(1, 500, n).tolist() for n in (12, 9)]
+    spans = [(7, 5), (5, 4)]
+    f32 = GDN_REF.wave_logits(toy, weights, prefix, tails, spans, "f32", 300)
+    low = GDN_REF.wave_logits(toy, weights, prefix, tails, spans, "int8", 300)
+    assert f32.shape == low.shape == (9, 300)
+    assert np.isfinite(f32).all() and np.isfinite(low).all()
+    assert float(np.max(np.abs(f32 - low))) > 1e-3
+    assert float(np.mean(np.abs(f32 - low))) < 0.25 * float(np.std(f32))
+    alone = GDN_REF.wave_logits(toy, weights, prefix, tails[:1], spans[:1], "f32", 300)
+    np.testing.assert_allclose(alone, f32[:5], rtol=1e-4, atol=1e-5)
+    # a shorter prefix is another state and another answer (the tail is seeded from it)
+    shorter = GDN_REF.wave_logits(toy, weights, prefix[:-1], tails[:1], spans[:1], "f32", 300)
+    assert float(np.max(np.abs(shorter - alone))) > 1e-2

@@ -49,8 +49,10 @@ def no_compile_cache():
 
 
 # (rows, experts held, K, N): a decode call and a suffix call of the third
-# family's share, and the second family's suffix call
-SHAPES = [(2304, 16, 6144, 2048), (12288, 16, 6144, 2048), (4096, 64, 2048, 1536)]
+# family's share, the second family's suffix call, and the short path of the
+# fourth family's share in a decode and a suffix call (half of T x 10 rows)
+SHAPES = [(2304, 16, 6144, 2048), (12288, 16, 6144, 2048), (4096, 64, 2048, 1536),
+          (1024, 128, 2048, 512), (5120, 128, 2048, 512)]
 
 
 @pytest.mark.parametrize("rows, experts, k, n", SHAPES)
@@ -76,3 +78,21 @@ def test_the_limit_is_asked_for_only_where_the_blocks_outgrow_the_default():
     limit = gm._vmem_limit(128, 6144, 512, 2, 2, 2)
     assert limit is not None and 28 << 20 < limit < 64 << 20
     assert gm._vmem_limit(128, 2048, 512, 1, 2, 4) is None  # its down projection fits
+
+
+@pytest.mark.parametrize("rows, positions, chunk", [(8, 24, 24), (8, 128, 64)])
+def test_the_chunked_delta_rule_compiles_for_the_chip(one_chip, no_compile_cache, rows, positions, chunk):
+    """models/gdn_moe.py `gated_delta_chunks` at the published head sizes (32
+    value heads of 128 x 128, float32): a decode block as one chunk, a suffix
+    call as two; the unit-lower-triangular solve is expanded by the TPU's
+    compiler (no custom call is left for it)."""
+    from k8s_llm_scheduler_tpu.models import gdn_moe
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    qk, gb = shape(rows, 32, positions, 128), shape(rows, 32, positions)
+    compiled = jax.jit(lambda q, k, v, g, b, s: gdn_moe.gated_delta_chunks(q, k, v, g, b, s, chunk)).lower(
+        qk, qk, qk, gb, gb, shape(rows, 32, 128, 128)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert "triangular-solve(" not in compiled.as_text()
