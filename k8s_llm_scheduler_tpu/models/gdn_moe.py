@@ -23,12 +23,13 @@ mixer's gated norm. D = d_model.
   `delta = beta_t (v_t - S^T k_t)`; `S <- S + k_t delta^T`; `o_t = S^T
   q_t`. Then `o <- w (o rsqrt(mean o^2 + eps)) silu(z)` a head (weight
   [dv]), `W_o`.
-  THE PROGRAM RUNS THE CHUNKED FORM of that recurrence (`gated_delta_chunks`
-  writes it out): over a chunk of C positions one unit-lower-triangular
-  solve in float32 and matrix products, the state read once and written
-  once a layer a call. A position that is not valid has g = 0 and beta = 0,
-  which leaves the state as it was BY CONSTRUCTION, and the convolution's
-  window is cut at the row's valid length.
+  THE PROGRAM RUNS THE CHUNKED FORM of that recurrence, as ONE KERNEL
+  (ops/gdn_scan.py writes the form out): over a chunk of C positions one
+  unit-lower-triangular solve in float32 and matrix products, a (row, block
+  of heads)'s state read from HBM once, held in VMEM across the call's
+  chunks and written once, WHERE IT LIES. A position that is not valid has
+  g = 0 and beta = 0, which leaves the state as it was BY CONSTRUCTION, and
+  the convolution's window is cut at the row's valid length.
 - Gated attention (H query heads, Hkv key/value heads, width hd): `W_q` D ->
   H x 2 hd, split a head into query and gate; `W_k`, `W_v` D -> Hkv x hd;
   (1 + w) RMS norm of q and of k over the head; rotary (theta
@@ -49,15 +50,21 @@ the last `conv_kernel - 1` inputs of the convolution [conv_kernel - 1,
 channels], AFTER a given number of tokens. `state_shapes` lists them ONE
 MEMBER A POSITION IN THE PERIOD (S of the period's first, second, third
 delta-rule layer, then their windows), each with the periods as its leading
-axis: the layer scan then reads a layer's state as its own slice of a member
-it is handed and writes the new one into a member it hands back, and no
-layer reads and writes one buffer (a layer that updated its entry of ONE
-[layers, ..] array in place cost two copies of the whole 151 MB state a
-period a call: PERF.md §6 PR 37). The three forwards take and return it
-beside the cache (`state=`): prefix prefill returns the state after
-`seq_lens` tokens; the suffix call seeds every row from the prefix's and
-returns each row's after its suffix; block decode advances each row's by
-`blk_len`.
+axis. THE MATRICES RIDE THE LAYER SCAN'S CARRY WHOLE: a delta-rule layer's
+kernel is handed its member and the period's index, advances that entry in
+place (the kernel's output is aliased to its input; the other entries are
+not touched) and hands the member on, so a state is read once and written
+once a layer a call and never copied: not by the scan (a layer that
+updated its entry of ONE [layers, ..] array with a `dynamic_update_slice`
+cost two copies of the whole 151 MB state a period a call, and members
+handed back as the scan's `ys` one copy of them a call into the block
+loop's carry: PERF.md §6 PRs 37 and 38), not by the block loop. The windows
+are small and are scanned over: a layer reads its own slice and hands the
+new one back. The three forwards take and return the state beside the
+cache (`state=`): prefix prefill returns the state after `seq_lens` tokens;
+the suffix call seeds every row from the prefix's (a copy of the rows' own:
+the prefix's arrays are read, never written) and returns each row's after
+its suffix; block decode advances each row's by `blk_len`.
 
 Params: `params["layers"]` holds what every layer has (norms, router,
 experts, shared expert; leading axis = layer), `params["gdn"]` the delta-rule
@@ -85,6 +92,7 @@ from k8s_llm_scheduler_tpu.models.mla_moe import (
     routed_experts,
 )
 from k8s_llm_scheduler_tpu.ops.attention import merge_attention_parts, write_block
+from k8s_llm_scheduler_tpu.ops.gdn_scan import gdn_chunk_scan
 
 Params = dict[str, Any]
 
@@ -94,21 +102,13 @@ Params = dict[str, Any]
 STATE_COUNTERS = ("state_tokens_valid", "state_tokens_computed")
 COUNTERS = EXPERT_COUNTERS + BOUND_COUNTERS + STATE_COUNTERS
 
-# Positions a chunk of the delta rule holds in prefill: the solve is C x C a
-# head, the sequential part one step a chunk. Block decode's chunk is the
-# block.
-CHUNK = 64
+# Positions a chunk of the delta rule holds in prefill: the solve's cost a
+# position grows with the chunk, the products with the state are once a
+# chunk. Block decode's chunk is the block. On the chip (PERF.md §6 PR 38) a
+# layer's scan alone read 649 us at 32 and 728 at 64 for a suffix call,
+# 1,335 and 1,574 for a prefix prefill (16: no better than 32).
+CHUNK = 32
 L2_EPS = 1e-6
-HIGHEST = jax.lax.Precision.HIGHEST
-# The three products with the state (W S_0 and q S_0, stacked; k^T Delta):
-# float32 operands in three bfloat16 passes where `highest` takes six. They
-# are the delta rule's cost in block decode (a [24, 128] operand against a
-# 128 x 128 state 256 times a layer: the MXU loads a state's block for 24
-# rows), and on the chip the error against the token-by-token recurrence read
-# the same to two digits at three passes and at six, and 40 x larger at one
-# (PERF.md §6 PR 37).
-STATE_PRODUCTS = jax.lax.Precision.HIGH
-
 
 # ------------------------------------------------------- what a sequence carries
 def cache_token_shapes(cfg: GdnMoeConfig) -> tuple[tuple[int, ...], ...]:
@@ -237,93 +237,24 @@ def _last_valid_logits(params: Params, cfg: GdnMoeConfig, x: jax.Array, lens: ja
 
 
 # ----------------------------------------------------------- the delta rule
-def gated_delta_chunks(q, k, v, g, beta, s0, chunk: int):
+def gated_delta_chunks(q, k, v, g, beta, lens, state, period, chunk: int):
     """The gated delta rule over T = n x `chunk` positions in its chunked
-    form. q, k [B, H, T, dk] (normalised, q scaled), v [B, H, T, dv], g
-    (log decay, <= 0) and beta [B, H, T], s0 [B, H, dk, dv]; all float32.
-    Returns (o [B, H, T, dv], the state after the T positions).
-
-    Within a chunk, with gamma_t = sum_{i <= t} g_i and the state S_0 it
-    starts from, the recurrence's delta_i obey
-        delta_i + beta_i sum_{j < i} e^{gamma_i - gamma_j} (k_i . k_j) delta_j
-            = beta_i (v_i - e^{gamma_i} S_0^T k_i),
-    a unit-lower-triangular system (I + A) Delta = rhs. Solved once
-    (`_unit_lower_inverse`) for both right-hand sides, U = (I + A)^-1 (beta
-    v) and W = (I + A)^-1 (beta e^gamma k), for every chunk at once; then,
-    chunk after chunk,
-        Delta = U - W S_0
-        O     = (e^gamma q) S_0 + (tril(e^{gamma_t - gamma_i}) * q k^T) Delta
-        S_C   = e^{gamma_C} S_0 + (e^{gamma_C - gamma} k)^T Delta.
-    A position with g = 0 and beta = 0 has delta = 0 and decays nothing."""
-    B, H, T, dk = k.shape
-    n = T // chunk
+    form, which ops/gdn_scan.py writes out and runs as one kernel. q, k [B,
+    Hk, T, dk] (normalised, q scaled; value head h reads key head h // (H /
+    Hk)), v [B, H, T, dv], g (log decay, <= 0) and beta [B, H, T], all
+    float32; row r's first `lens[r]` positions are valid (the others have g
+    = 0 and beta = 0: no delta, no decay); `state` [P, B, H, dk, dv] is a
+    whole member of what the sequences carry, of which these rows' is entry
+    `period`. Returns (o [B, H, T, dv], `state` with that entry after the T
+    positions, UPDATED WHERE IT LIES)."""
+    T = k.shape[2]
 
     def cut(a):
-        return a.reshape(B, H, n, chunk, *a.shape[3:])
+        return a.reshape(*a.shape[:2], T // chunk, chunk, *a.shape[3:])
 
     q, k, v, g, beta = map(cut, (q, k, v, g, beta))
-    gamma = jnp.cumsum(g, axis=-1)                               # [B, H, n, C]
-    low = jnp.tril(jnp.ones((chunk, chunk), bool))
-    # e^{gamma_i - gamma_j} for j <= i (the difference is <= 0 there), else 0
-    decay = jnp.where(low, jnp.exp(jnp.where(low, gamma[..., :, None] - gamma[..., None, :], 0.0)), 0.0)
-    kk = jnp.einsum("bhnid,bhnjd->bhnij", k, k, precision=HIGHEST)
-    a = jnp.where(jnp.tril(jnp.ones((chunk, chunk), bool), -1), beta[..., None] * decay * kk, 0.0)
-    rhs = jnp.concatenate([beta[..., None] * v, (beta * jnp.exp(gamma))[..., None] * k], axis=-1)
-    solved = jnp.einsum("...ij,...jk->...ik", _unit_lower_inverse(a), rhs, precision=HIGHEST)
-    u, w = solved[..., : v.shape[-1]], solved[..., v.shape[-1]:]
-    qk = jnp.einsum("bhnid,bhnjd->bhnij", q, k, precision=HIGHEST) * decay
-    # what reads S_0, stacked: one product with the state for both
-    reads = jnp.concatenate([w, q * jnp.exp(gamma)[..., None]], axis=-2)
-    k_out = k * jnp.exp(gamma[..., -1:] - gamma)[..., None]      # writes S_C
-    total = jnp.exp(gamma[..., -1])                              # [B, H, n]
-
-    def step(s, xs):
-        u_c, reads_c, qk_c, k_c, total_c = xs
-        read = jnp.einsum("bhik,bhkv->bhiv", reads_c, s, precision=STATE_PRODUCTS)
-        delta = u_c - read[..., :chunk, :]
-        o = read[..., chunk:, :] + jnp.einsum("bhij,bhjv->bhiv", qk_c, delta, precision=HIGHEST)
-        s = total_c[..., None, None] * s + jnp.einsum("bhik,bhiv->bhkv", k_c, delta,
-                                                     precision=STATE_PRODUCTS)
-        return s, o
-
-    xs = tuple(jnp.moveaxis(a_, 2, 0) for a_ in (u, reads, qk, k_out, total))
-    if n == 1:
-        s, o = step(s0, tuple(a_[0] for a_ in xs))
-        return o, s
-    s, o = jax.lax.scan(step, s0, xs)
-    return jnp.moveaxis(o, 0, 2).reshape(B, H, T, -1), s
-
-
-SOLVE_BLOCK = 8
-
-
-def _unit_lower_inverse(a: jax.Array) -> jax.Array:
-    """(I + a)^-1 for a strictly lower-triangular a [..., n, n], float32: THE
-    TRIANGULAR SOLVE of the chunked delta rule, by blocks. A diagonal block
-    of at most SOLVE_BLOCK rows is inverted as the finite sum of its Neumann
-    series, I - a + a^2 - .. = (I - a)(I + a^2)(I + a^4) (a^8 = 0: exact),
-    and two inverted halves are joined by the block formula
-        [[P, 0], [C, Q]]^-1 = [[P^-1, 0], [-Q^-1 C P^-1, Q^-1]],
-    which is forward substitution over blocks: as stable as the inverse
-    itself. The whole series over a chunk is NOT: equal keys in a row (a
-    prompt repeats itself) make a's powers grow like binomials before they
-    cancel. Batched products only; `jax.scipy.linalg.solve_triangular`, a
-    row at a time as the TPU's compiler expands it, took over half of the
-    scan's time at the same error (PERF.md §6 PR 37)."""
-    n = a.shape[-1]
-    mm = lambda x, y: jnp.einsum("...ij,...jk->...ik", x, y, precision=HIGHEST)  # noqa: E731
-    if n <= SOLVE_BLOCK:
-        eye = jnp.eye(n, dtype=a.dtype)
-        out, power, reach = eye - a, a, 2
-        while reach < n:
-            power = mm(power, power)
-            out, reach = mm(out, eye + power), 2 * reach
-        return out
-    h = -(-(n // 2) // SOLVE_BLOCK) * SOLVE_BLOCK
-    p, q = _unit_lower_inverse(a[..., :h, :h]), _unit_lower_inverse(a[..., h:, h:])
-    c = -mm(mm(q, a[..., h:, :h]), p)
-    top = jnp.concatenate([p, jnp.zeros((*a.shape[:-2], h, n - h), a.dtype)], axis=-1)
-    return jnp.concatenate([top, jnp.concatenate([c, q], axis=-1)], axis=-2)
+    o, state = gdn_chunk_scan(q, k, v, jnp.cumsum(g, axis=-1), beta, lens, state, period)
+    return o.reshape(*v.shape[:2], T, -1), state
 
 
 def _chunk(S: int) -> int:
@@ -340,13 +271,14 @@ def _window_at(xx: jax.Array, lens: jax.Array, width: int) -> jax.Array:
 
 
 def gdn_mixer(lp: Params, cfg: GdnMoeConfig, u: jax.Array, valid: jax.Array, lens: jax.Array,
-              s0: jax.Array, window: jax.Array):
+              state: jax.Array, period, window: jax.Array):
     """The delta-rule mixer's output [B, S, D] f32 for normed tokens u [B,
     S, D] (the weights' dtype), of which row r's first `lens[r]` are `valid`
-    [B, S]; `s0` [B, Hv, dk, dv] and `window` [B, conv_kernel - 1, channels]
-    are each row's state before the call. Returns (output, the state after
-    each row's valid tokens, the window at them). S is padded up to whole
-    chunks here; padding is not valid."""
+    [B, S]; entry `period` of `state` [periods, B, Hv, dk, dv] and `window`
+    [B, conv_kernel - 1, channels] are each row's state before the call.
+    Returns (output, `state` with that entry after each row's valid tokens,
+    the window at them). S is padded up to whole chunks here; padding is
+    not valid."""
     B, S, _ = u.shape
     Hk, Hv, dk, dv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
     kw, vw, taps = cfg.gdn_key_width, cfg.gdn_value_width, cfg.conv_kernel
@@ -368,23 +300,21 @@ def gdn_mixer(lp: Params, cfg: GdnMoeConfig, u: jax.Array, valid: jax.Array, len
         q = _l2(mixed[..., :kw].reshape(B, S, Hk, dk)) * dk**-0.5
         k = _l2(mixed[..., kw: 2 * kw].reshape(B, S, Hk, dk))
         v = mixed[..., 2 * kw:].reshape(B, S, Hv, dv)
-        rep = Hv // Hk
-        # value head h reads key head h // rep; heads lead, then positions
-        q, k = (jnp.repeat(jnp.moveaxis(a, 1, 2), rep, axis=1) for a in (q, k))
-        v, g, beta = (jnp.moveaxis(a, 1, 2) for a in (v, g, beta))
+        # heads lead, then positions
+        q, k, v, g, beta = (jnp.moveaxis(a, 1, 2) for a in (q, k, v, g, beta))
         chunk = _chunk(S)
         pad = -S % chunk
         if pad:
             q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (q, k, v))
             g, beta = (jnp.pad(a, ((0, 0), (0, 0), (0, pad))) for a in (g, beta))
-        o, s = gated_delta_chunks(q, k, v, g, beta, s0, chunk)
+        o, state = gated_delta_chunks(q, k, v, g, beta, lens, state, period, chunk)
         o = jnp.moveaxis(o[:, :, :S], 1, 2)                       # [B, S, Hv, dv]
     with jax.named_scope("gdn_out"):
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps)
         o = o * lp["o_norm"].astype(jnp.float32) * jax.nn.silu(z.reshape(B, S, Hv, dv))
         out = jnp.einsum("bsn,nd->bsd", o.reshape(B, S, vw).astype(cfg.dtype), lp["wo"],
                          preferred_element_type=jnp.float32)
-    return out, s, window
+    return out, state, window
 
 
 # ------------------------------------------------------ the gated attention
@@ -451,21 +381,23 @@ def _sparse_block(layers: Params, idx, cfg: GdnMoeConfig, x: jax.Array, valid: j
 def _run_periods(params, cfg: GdnMoeConfig, x, valid, lens, positions, state, cache_xs, segments):
     """Every layer over the float32 stream x [B, S, D]: a scan over the
     periods, `full_attention_interval - 1` delta-rule layers and one
-    attention written out in its body. `state` (`state_shapes`: a member a
-    delta-rule position of the period, [periods, B, ..]) is scanned over:
-    each delta-rule layer is handed its own entry and hands the new one
-    back, once. `cache_xs`: cache arrays [La, ..] handed to the period's
-    attention; `segments(cache_l, p, k, v)` says what its queries may see.
-    Returns (x, (k, v) of these tokens [La, B, S, Hkv, hd], state, EXPERT +
-    BOUND counters)."""
+    attention written out in its body. Of `state` (`state_shapes`: a member
+    a delta-rule position of the period, [periods, B, ..]) the matrices ride
+    the scan's CARRY whole: each delta-rule layer advances its own entry of
+    its member where it lies (ops/gdn_scan.py), once; the windows are
+    scanned over, each layer handed its own and handing the new one back.
+    `cache_xs`: cache arrays [La, ..] handed to the period's attention;
+    `segments(cache_l, p, k, v)` says what its queries may see. Returns (x,
+    (k, v) of these tokens [La, B, S, Hkv, hd], state, EXPERT + BOUND
+    counters)."""
     per = cfg.full_attention_interval
     layers, gdn, attn = params["layers"], params["gdn"], params["attn"]
     inv_freq = _rope_inv_freq(cfg)
 
     def body(carry, inp):
-        x, counters = carry
-        cache_l, state_l, p = inp
-        new_s, new_w = [], []
+        x, counters, matrices = carry
+        cache_l, windows, p = inp
+        matrices, new_w = list(matrices), []
         for j in range(per):
             idx = p * per + j
             with jax.named_scope("attn"):
@@ -473,9 +405,8 @@ def _run_periods(params, cfg: GdnMoeConfig, x, valid, lens, positions, state, ca
                 if j < per - 1:
                     at = p * (per - 1) + j
                     with jax.named_scope("gdn"):
-                        y, s, w = gdn_mixer({k_: a[at] for k_, a in gdn.items()}, cfg, u, valid, lens,
-                                            state_l[j], state_l[per - 1 + j])
-                        new_s.append(s)
+                        y, matrices[j], w = gdn_mixer({k_: a[at] for k_, a in gdn.items()}, cfg, u, valid, lens,
+                                                      matrices[j], p, windows[j])
                         new_w.append(w)
                 else:
                     with jax.named_scope("full_attn"):
@@ -486,12 +417,13 @@ def _run_periods(params, cfg: GdnMoeConfig, x, valid, lens, positions, state, ca
             y, c = _sparse_block(layers, idx, cfg, x, valid)
             x = x + y
             counters = counters + c
-        return (x, counters), (kv, (*new_s, *new_w))
+        return (x, counters, tuple(matrices)), (kv, tuple(new_w))
 
     zero = jnp.zeros((len(EXPERT_COUNTERS) + len(BOUND_COUNTERS),), jnp.int32)
-    (x, counters), (kv, state) = jax.lax.scan(
-        body, (x, zero), (cache_xs, tuple(state), jnp.arange(cfg.n_periods)))
-    return x, kv, state, counters
+    state = tuple(state)
+    (x, counters, matrices), (kv, windows) = jax.lax.scan(
+        body, (x, zero, state[: per - 1]), (cache_xs, state[per - 1:], jnp.arange(cfg.n_periods)))
+    return x, kv, (*matrices, *windows), counters
 
 
 def _with_state_counters(counters, lens, computed: int):

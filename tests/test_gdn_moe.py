@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import importlib.util
+import re
 import time
 from pathlib import Path
 
@@ -73,47 +74,70 @@ def _highest():
 
 
 # ------------------------------------------------- the chunked delta rule
-@pytest.mark.parametrize("lens, chunk", [((48, 48), 16), ((48, 17), 16), ((0, 5), 24), ((24, 1), 24)])
-def test_the_chunked_delta_rule_is_the_recurrence(lens, chunk):
-    """`gated_delta_chunks` over whole chunks, against the reference's
-    token-by-token scan: outputs at the valid positions and the state after
-    them; a position that is not valid (g = 0, beta = 0) leaves the state as
-    it was, so a row of length 0 keeps the state it came with."""
-    rng = np.random.default_rng(3)
-    B, H, dk, dv = len(lens), 4, 16, 8
+def _delta_rule_inputs(rng, lens, chunk, Hk, H, dk, dv, periods):
+    B = len(lens)
     T = -(-max(max(lens), 1) // chunk) * chunk
-    q, k = (gdn_moe._l2(jnp.asarray(rng.normal(size=(B, H, T, dk)), jnp.float32)) for _ in range(2))
+    q, k = (gdn_moe._l2(jnp.asarray(rng.normal(size=(B, Hk, T, dk)), jnp.float32)) for _ in range(2))
     v = jnp.asarray(rng.normal(size=(B, H, T, dv)), jnp.float32)
     ok = np.arange(T)[None, :] < np.asarray(lens)[:, None]
     g = jnp.where(ok[:, None], -jnp.asarray(rng.uniform(0.001, 0.5, size=(B, H, T)), jnp.float32), 0.0)
     beta = jnp.where(ok[:, None], jnp.asarray(rng.uniform(0, 1, size=(B, H, T)), jnp.float32), 0.0)
-    s0 = jnp.asarray(rng.normal(size=(B, H, dk, dv)), jnp.float32)
-    o, s = gdn_moe.gated_delta_chunks(q, k, v, g, beta, s0, chunk)
-    for b in range(B):
+    member = jnp.asarray(rng.normal(size=(periods, B, H, dk, dv)), jnp.float32)
+    return q, k, v, g, beta, ok, member
+
+
+def _assert_is_the_recurrence(q, k, v, g, beta, ok, lens, member, period, chunk, atol=1e-5):
+    """`gated_delta_chunks` (the XLA preamble and ops/gdn_scan.py's kernel,
+    interpreted) against the reference's token-by-token scan, row by row."""
+    o, new = gdn_moe.gated_delta_chunks(q, k, v, g, beta, jnp.asarray(lens, jnp.int32), member, period, chunk)
+    q, k = (jnp.repeat(a, v.shape[1] // k.shape[1], axis=1) for a in (q, k))  # value head h reads key head h // rep
+    for b, n in enumerate(lens):
         t = lambda a: jnp.moveaxis(a[b], 0, 1)  # noqa: E731  [H, T, ..] -> [T, H, ..]
-        want_o, want_s = REF.delta_rule(t(q), t(k), t(v), t(g), t(beta), jnp.asarray(ok[b]), s0[b])
-        n = lens[b]
-        np.testing.assert_allclose(np.asarray(t(o))[:n], np.asarray(want_o)[:n], rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(s[b]), np.asarray(want_s), rtol=1e-4, atol=1e-5)
-        if n == 0:
-            np.testing.assert_array_equal(np.asarray(s[b]), np.asarray(s0[b]))
+        want_o, want_s = REF.delta_rule(t(q), t(k), t(v), t(g), t(beta), jnp.asarray(ok[b]), member[period, b])
+        np.testing.assert_allclose(np.asarray(t(o))[:n], np.asarray(want_o)[:n], rtol=1e-4, atol=atol)
+        np.testing.assert_allclose(np.asarray(new[period, b]), np.asarray(want_s), rtol=1e-4, atol=atol)
+        if n == 0:  # no valid position: the row's state comes back to the bit
+            np.testing.assert_array_equal(np.asarray(new[period, b]), np.asarray(member[period, b]))
+    for p in range(member.shape[0]):  # the other periods' entries are not this call's to touch
+        if p != period:
+            np.testing.assert_array_equal(np.asarray(new[p]), np.asarray(member[p]))
 
 
-@pytest.mark.parametrize("n", [5, 8, 24, 64])
-def test_the_blockwise_inverse_is_the_triangular_solve_also_where_keys_repeat(n):
-    """`_unit_lower_inverse` against scipy's forward substitution, on a random
-    strictly lower matrix and on THE HARD ONE: every key equal and beta one
-    (a prompt that repeats itself), where (I + a)^-1 is bounded by one and
-    the powers of a, which a whole-chunk Neumann series would sum, reach
-    1e17 at n = 64."""
-    import scipy.linalg
+# the last three: the cell's call shapes in small (a decode block as one chunk
+# of 24 with ragged rows, one of them empty; a suffix call as two chunks of 64;
+# a prefix prefill as many chunks for one row), on the middle entry of a member
+@pytest.mark.parametrize("lens, chunk, key_heads, periods, period", [
+    ((48, 48), 16, 4, 1, 0), ((48, 17), 16, 4, 1, 0), ((0, 5), 24, 4, 1, 0), ((24, 1), 24, 4, 1, 0),
+    ((3, 0, 24, 1, 8), 24, 2, 3, 1), ((70, 128, 83), 64, 2, 3, 1), ((300,), 64, 2, 3, 1)])
+def test_the_chunked_delta_rule_is_the_recurrence(lens, chunk, key_heads, periods, period):
+    """`gated_delta_chunks` over whole chunks, against the reference's
+    token-by-token scan: outputs at the valid positions and the state after
+    them; a position that is not valid (g = 0, beta = 0) leaves the state as
+    it was, so a row of length 0 keeps the state it came with; of a member
+    of several periods only the entry named is advanced; a key head
+    serves one value head or two."""
+    rng = np.random.default_rng(3)
+    q, k, v, g, beta, ok, member = _delta_rule_inputs(rng, lens, chunk, key_heads, 4, 16, 8, periods)
+    _assert_is_the_recurrence(q, k, v, g, beta, ok, lens, member, period, chunk)
 
-    rng = np.random.default_rng(n)
-    for a in (np.tril(rng.normal(size=(3, n, n)) * 0.3, -1), np.tril(np.ones((1, n, n)), -1)):
-        got = np.asarray(gdn_moe._unit_lower_inverse(jnp.asarray(a, jnp.float32)))
-        want = np.stack([scipy.linalg.solve_triangular(np.eye(n) + m, np.eye(n), lower=True) for m in a])
-        np.testing.assert_allclose(got, want, atol=1e-4 * max(1.0, np.abs(want).max()))
-        assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
+
+@pytest.mark.parametrize("lens, chunk", [((5, 2), 5), ((8, 1), 8), ((24, 9), 24), ((64, 33), 32), ((128,), 64)])
+def test_the_kernel_is_the_recurrence_where_every_key_is_the_same(lens, chunk):
+    """THE HARD CASE of the solve, through the whole chunked form and its
+    kernel: every key of a row equal and beta one (a prompt that repeats
+    itself), no decay: (I + A) is the all-ones lower triangle, its inverse
+    is bounded by one, and the powers of A, which a Neumann series over the
+    chunk would sum, reach 1e17 at 64 positions. The kernel's forward
+    substitution, a column at a time in groups of 8 (chunks below, at and
+    over a group; rows that end inside one), holds it. Equal keys make every
+    product with the state a coherent sum, so the 16 bits that its three
+    bfloat16 passes carry show: 9e-5 on entries of unit scale, where random
+    keys read 1e-5."""
+    rng = np.random.default_rng(4)
+    q, k, v, g, beta, ok, member = _delta_rule_inputs(rng, lens, chunk, 2, 4, 16, 8, 1)
+    k = jnp.broadcast_to(k[:, :, :1], k.shape)
+    beta = jnp.where(ok[:, None], 1.0, 0.0).astype(jnp.float32) * jnp.ones_like(beta)
+    _assert_is_the_recurrence(q, k, v, g * 0.0, beta, ok, lens, member, 0, chunk, atol=1e-4)
 
 
 def test_the_convolution_and_the_state_cross_every_join():
@@ -131,20 +155,20 @@ def test_the_convolution_and_the_state_cross_every_join():
     T = sum(cuts)
     u = jnp.asarray(rng.normal(size=(1, T, cfg.d_model)), jnp.float32)
     zero = gdn_moe.zero_state(cfg, 1)
-    s0, w0 = zero[0][0], zero[-1][0]
+    s0, w0 = zero[0][:1], zero[-1][0]  # one period's entry of a member; its window
     ones = lambda n: jnp.ones((1, n), bool)  # noqa: E731
-    whole, s_whole, w_whole = gdn_moe.gdn_mixer(lp, cfg, u, ones(T), jnp.asarray([T]), s0, w0)
+    whole, s_whole, w_whole = gdn_moe.gdn_mixer(lp, cfg, u, ones(T), jnp.asarray([T]), s0, 0, w0)
     s, w, at, pieces = s0, w0, 0, []
     for n, width in zip(cuts, (64, 16, 8)):
         piece = jnp.zeros((1, width, cfg.d_model), jnp.float32).at[:, :n].set(u[:, at: at + n])
-        y, s, w = gdn_moe.gdn_mixer(lp, cfg, piece, jnp.arange(width)[None] < n, jnp.asarray([n]), s, w)
+        y, s, w = gdn_moe.gdn_mixer(lp, cfg, piece, jnp.arange(width)[None] < n, jnp.asarray([n]), s, 0, w)
         pieces.append(y[:, :n])
         at += n
     np.testing.assert_allclose(np.asarray(jnp.concatenate(pieces, axis=1)), np.asarray(whole), atol=2e-5)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_whole), atol=2e-5)
     np.testing.assert_array_equal(np.asarray(w), np.asarray(w_whole))  # the last three inputs, as they were
     # and without the window the first tokens behind a join differ
-    y, _, _ = gdn_moe.gdn_mixer(lp, cfg, u[:, cuts[0]: cuts[0] + 8], ones(8), jnp.asarray([8]), s_whole * 0, w0)
+    y, _, _ = gdn_moe.gdn_mixer(lp, cfg, u[:, cuts[0]: cuts[0] + 8], ones(8), jnp.asarray([8]), s_whole * 0, 0, w0)
     assert float(jnp.max(jnp.abs(y[:, :3] - whole[:, cuts[0]: cuts[0] + 3]))) > 1e-2
 
 
@@ -331,6 +355,8 @@ def test_the_lowered_forwards_hold_the_scopes_and_kernel_names():
         jax.ShapeDtypeStruct((cfg.n_attn_layers, *lead, *s), cfg.dtype) for s in gdn_moe.cache_token_shapes(cfg))
     state = lambda *lead: tuple(  # noqa: E731
         jax.ShapeDtypeStruct((gdn_moe.state_layers(cfg), *lead, *s), d) for s, d in gdn_moe.state_shapes(cfg))
+    prefix = jax.jit(gdn_moe.forward_prefill_kv, static_argnums=1).lower(
+        params, cfg, i32(1, 256), i32(1)).as_text(debug_info=True)
     suffix = jax.jit(gdn_moe.forward_prefill_suffix_dense, static_argnums=1).lower(
         params, cfg, i32(R, SS), i32(R), *cache(256), i32(), state=state()).as_text(debug_info=True)
     decode = jax.jit(gdn_moe.forward_block_decode, static_argnums=1).lower(
@@ -346,6 +372,29 @@ def test_the_lowered_forwards_hold_the_scopes_and_kernel_names():
     assert "kv_writeback" in decode
     for kernel in ("moe_grouped_swiglu", "moe_grouped_matmul"):
         assert kernel in decode, kernel
+    # the delta rule's kernel, under the scope gdn_scan_device_ms_per_bind.tput reads, in all three forwards
+    for name, text in (("prefix", prefix), ("suffix", suffix), ("decode", decode)):
+        assert re.search(r"attn/gdn/gdn_scan/[^\"]*gdn_chunk_scan", text), name
+        assert "prefix_prefill/" in text if name == "prefix" else "prefix_prefill/" not in text
+
+
+def test_a_suffix_call_leaves_the_pins_state_bit_identical(toy):
+    """The kernel updates a state where it lies, and the rows' state it is
+    handed in the suffix call is their own copy (`state_seed`): the prefix's
+    arrays, which a pin holds for every later wave, still hold the same
+    bits after a wave has been seeded from them, and are still alive."""
+    cfg, params, wave, _ = toy
+    held = wave.prefix_state
+    before = [np.asarray(a).copy() for a in held]
+    pk = jnp.zeros((cfg.n_attn_layers, P_BUCKET, *gdn_moe.cache_token_shapes(cfg)[0]), cfg.dtype)
+    tokens = jnp.ones((R, SS), jnp.int32)
+    out = jax.jit(gdn_moe.forward_prefill_suffix_dense, static_argnums=1)(
+        params, cfg, tokens, jnp.asarray(SUFFIX_LENS, jnp.int32), pk, pk, jnp.int32(P), state=held)
+    rows = out[3]
+    assert any(float(jnp.max(jnp.abs(r[:, 0] - h))) > 1e-3 for r, h in zip(rows, held))  # the rows moved on
+    for mine, theirs in zip(before, held):
+        assert not theirs.is_deleted()
+        np.testing.assert_array_equal(mine, np.asarray(theirs))
 
 
 # --------------------------------------------------------- a whole decision

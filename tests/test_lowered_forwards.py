@@ -13,6 +13,7 @@ records the file again from its own tree and says so.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import sys
@@ -28,6 +29,7 @@ PRESETS = ("tiny", "tiny-mla-moe", "tiny-mla-scmoe")
 R, SS, SP, F, CAP = 4, 128, 256, 24, 48
 
 
+@functools.lru_cache(maxsize=None)
 def _texts(name: str) -> dict[str, str]:
     """Lowered text of the preset's three forwards and of its wave program."""
     from k8s_llm_scheduler_tpu.engine.engine import InferenceEngine
@@ -76,6 +78,15 @@ def test_a_family_without_state_lowers_as_the_parent_did(name):
     recorded = json.loads(FIXTURE.read_text())
     assert recorded["jax"] == jax.__version__, "recorded under another jax: record again"
     assert digests(name) == recorded["digests"][name]
+
+
+@pytest.mark.parametrize("name", PRESETS)
+def test_a_family_without_state_runs_nothing_of_the_delta_rules_kernel(name):
+    """ops/gdn_scan.py's kernel (PR 38) serves models/gdn_moe.py alone: the
+    three forwards and the wave program of the other families do not hold
+    it, so the cells that run them run their parent's programs."""
+    for program, text in _texts(name).items():
+        assert "gdn_chunk_scan" not in text and "gdn_scan" not in text, program
 
 
 if __name__ == "__main__" and "--write" in sys.argv:

@@ -12,6 +12,8 @@ a time.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -80,19 +82,34 @@ def test_the_limit_is_asked_for_only_where_the_blocks_outgrow_the_default():
     assert gm._vmem_limit(128, 2048, 512, 1, 2, 4) is None  # its down projection fits
 
 
-@pytest.mark.parametrize("rows, positions, chunk", [(8, 24, 24), (8, 128, 64)])
-def test_the_chunked_delta_rule_compiles_for_the_chip(one_chip, no_compile_cache, rows, positions, chunk):
+@pytest.mark.parametrize("rows, positions, chunk", [(8, 24, 24), (8, 128, 32), (1, 2048, 32)])
+def test_the_chunked_delta_rule_compiles_for_the_chip(one_chip, no_compile_cache, monkeypatch, rows, positions, chunk):
     """models/gdn_moe.py `gated_delta_chunks` at the published head sizes (32
-    value heads of 128 x 128, float32): a decode block as one chunk, a suffix
-    call as two; the unit-lower-triangular solve is expanded by the TPU's
-    compiler (no custom call is left for it)."""
+    value heads of 128 x 128, float32; 16 key heads serve them) on a member
+    of three periods: a decode block as one chunk, a suffix call as four, a
+    prefix prefill as 64 for one row. The whole chunk is ops/gdn_scan.py's
+    kernel, compiled by Mosaic."""
     from k8s_llm_scheduler_tpu.models import gdn_moe
+    from k8s_llm_scheduler_tpu.ops import gdn_scan
 
-    def shape(*dims):
-        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    # the default backend here is the CPU, where a kernel is interpreted: compile it as the chip does
+    monkeypatch.setattr(gdn_scan, "pallas_interpret", lambda interpret=None: False)
 
-    qk, gb = shape(rows, 32, positions, 128), shape(rows, 32, positions)
-    compiled = jax.jit(lambda q, k, v, g, b, s: gdn_moe.gated_delta_chunks(q, k, v, g, b, s, chunk)).lower(
-        qk, qk, qk, gb, gb, shape(rows, 32, 128, 128)).compile()
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    qk, v, gb = shape(rows, 16, positions, 128), shape(rows, 32, positions, 128), shape(rows, 32, positions)
+    # the member is DONATED, as the layer scan's carry hands it over: the kernel's state is held to HBM, and
+    # the copy XLA makes of an argument it may not overwrite, straight into such an operand, ABORTS this
+    # compiler's memory-space assignment (no program of the family has that shape: the members always ride a
+    # loop's carry; PERF.md §6 PR 38)
+    compiled = jax.jit(
+        lambda q, k, v, g, b, lens, s, p: gdn_moe.gated_delta_chunks(q, k, v, g, b, lens, s, p, chunk),
+        donate_argnums=(6,),
+    ).lower(qk, qk, v, gb, gb, shape(rows, dtype=jnp.int32), shape(3, rows, 32, 128, 128),
+            shape(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    assert "triangular-solve(" not in compiled.as_text()
+    assert "tpu_custom_call" in text and "gdn_chunk_scan" in text
+    # the state goes into the kernel and comes out of it where it lies: no copy of a member, none of an entry
+    assert not re.search(r"f32\[(3,)?%d,32,128,128\]\S* copy" % rows, text)
