@@ -65,42 +65,27 @@ def smoke_config(model: str = MODEL, bpe_fixture: bool = True):
     return cfg
 
 
-class _CompileCounter:
-    """XLA programs this process compiled, from JAX's own monitoring
-    events (a persistent-cache hit is a program loaded, not compiled).
-    jax.monitoring has no public unregister: `active` turns it off."""
+class _Takes:
+    """What the process built in each phase: deltas of the program's own
+    compile log (utils/compile_cache.py `COMPILE_LOG`, whose listeners
+    build_local_backend registers before its first jit)."""
 
     def __init__(self) -> None:
-        import jax.monitoring
+        from k8s_llm_scheduler_tpu.utils.compile_cache import COMPILE_LOG
 
-        self.active = True
-        self.programs = self.cache_hits = self.over_1s = 0
-        self.seconds = self.longest = 0.0
-        jax.monitoring.register_event_listener(self._event)
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-
-    def _event(self, name: str, **_kw) -> None:
-        if self.active and name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def _duration(self, name: str, secs: float, **_kw) -> None:
-        if self.active and name == "/jax/core/compile/backend_compile_duration":
-            self.programs += 1
-            self.seconds += secs
-            self.longest = max(self.longest, secs)
-            self.over_1s += secs >= 1.0
+        self.log = COMPILE_LOG.install()
+        self.mark = self.log.books()
 
     def take(self) -> dict:
-        """Counts since the last take()."""
-        out = {
-            "programs": self.programs,
-            "programs_over_1s": self.over_1s,
-            "loaded_from_compile_cache": self.cache_hits,
-            "compile_seconds": round(self.seconds, 2),
-            "longest_compile_seconds": round(self.longest, 2),
-        }
-        self.programs = self.cache_hits = self.over_1s = 0
-        self.seconds = self.longest = 0.0
+        """Books since the last take(), and how many programs of them took
+        a second or more to load or compile."""
+        now = self.log.books()
+        out = {k: round(now[k] - self.mark[k], 3) for k in now}
+        out["programs_over_1s"] = sum(
+            p.load_compile_s >= 1.0
+            for p in self.log.programs[self.mark["programs"]:now["programs"]]
+        )
+        self.mark = now
         return out
 
 
@@ -223,7 +208,7 @@ def run(cfg, *, nodes: int = NODES, pods: int = PODS, shapes: int = SHAPES) -> d
     from k8s_llm_scheduler_tpu.observability.profiler import measure_dispatch_rtt_ms
     from k8s_llm_scheduler_tpu.testing import pod_burst, synthetic_cluster
 
-    compiles = _CompileCounter()
+    compiles = _Takes()
     cluster = synthetic_cluster(nodes)
     scheduler, backend = _build_stack(cfg, cluster)
     engine = backend.engine
@@ -236,7 +221,11 @@ def run(cfg, *, nodes: int = NODES, pods: int = PODS, shapes: int = SHAPES) -> d
             deadline,
         )
         setup_s = time.perf_counter() - t_start
+        first = compiles.mark["programs"]
         compiled = {"setup": compiles.take()}
+        # by program (jit(wave), jit(prefix_prefill_kv), ...): where set-up went
+        print(json.dumps({"setup_programs": compiles.log.table(first, compiles.mark["programs"])}),
+              file=sys.stderr)
         engine_before = dict(engine.stats)
 
         t_serve = time.perf_counter()
@@ -259,7 +248,6 @@ def run(cfg, *, nodes: int = NODES, pods: int = PODS, shapes: int = SHAPES) -> d
         ]
         rtt_ms = measure_dispatch_rtt_ms(samples=20)
     finally:
-        compiles.active = False
         backend.close()
 
     served = {

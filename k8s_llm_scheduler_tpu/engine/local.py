@@ -75,6 +75,10 @@ from k8s_llm_scheduler_tpu.types import (
     PodSpec,
     SchedulingDecision,
 )
+from k8s_llm_scheduler_tpu.utils.compile_cache import (
+    COMPILE_LOG,
+    enable_persistent_compile_cache,
+)
 from k8s_llm_scheduler_tpu.utils.json_extract import parse_decision_json
 
 logger = logging.getLogger(__name__)
@@ -168,6 +172,9 @@ class LocalLLMBackend:
         max_pins: int = 4,
     ) -> None:
         self.engine = engine
+        # seconds by set-up span (`engine.setup_build`, `engine.setup_params`),
+        # filled by build_local_backend; empty for a backend built otherwise
+        self.setup: dict[str, float] = {}
         # Admission plane (engine/admission/): batch-surface decisions
         # admit via packed chunked prefill when the engine supports it;
         # delta_prompts renders cluster prefixes as pinned snapshot +
@@ -1316,6 +1323,14 @@ class LocalLLMBackend:
                 out["pins"] = pin_stats
         if self._kvplane is not None:
             out["kvplane"] = self._kvplane.stats()
+        # a restart as the operator sees it: how long set-up took, and what
+        # it traced, lowered, loaded or compiled (totals; chip_smoke.py
+        # prints the per-program table)
+        out["setup"] = {
+            "build_s": self.setup.get("setup_build", 0.0),
+            "params_s": self.setup.get("setup_params", 0.0),
+            **COMPILE_LOG.books(),
+        }
         # THE admission-efficiency headline (sublinearity in node count is
         # measured on this): prefill tokens actually computed per finished
         # decision — prefix prefills count only NON-REUSED tokens, so
@@ -1545,150 +1560,155 @@ def build_local_backend(
 
     Raises when JAX came up on the cpu backend without `JAX_PLATFORMS`
     naming it: that is a missing accelerator, not a request for CPU."""
-    from k8s_llm_scheduler_tpu.utils.compile_cache import (
-        enable_persistent_compile_cache,
-    )
-
-    _require_named_cpu()
-    enable_persistent_compile_cache(compile_cache_dir)
-    cfg = cfg or get_config(model)
-    builtin_tokenizer = None
-    if tokenizer_path is None and not (
-        checkpoint_path
-        and tokenizer_name == "byte"
-        and (Path(checkpoint_path) / "tokenizer.json").exists()
-    ):
-        # Builtin tokenizer: the shared rule in engine/tokenizer.py may
-        # WIDEN cfg.vocab_size (numeric NUM rows live above the byte
-        # base) — this must happen before params are built; train/
-        # distill.py calls the same helper, so checkpoints round-trip.
-        from k8s_llm_scheduler_tpu.engine.tokenizer import (
-            build_builtin_tokenizer,
-        )
-
-        builtin_tokenizer, cfg = build_builtin_tokenizer(tokenizer_name, cfg)
-    mesh = mesh_from_config(mesh_axes, devices=devices)
-    multi = mesh.devices.size > 1
-    # Serving shards over tp only: params are tp-sharded (Megatron specs)
-    # and the engine's wave batch is replicated, so a dp/sp/... axis > 1
-    # would replicate weights N times and waste every non-tp device.
-    # Reject loudly instead of silently burning chips (VERDICT r2 weak #3).
-    bad_axes = {
-        ax: n for ax, n in mesh.shape.items() if ax != "tp" and n > 1
-    }
-    if bad_axes:
-        raise ValueError(
-            f"serving mesh supports only a tp axis; got {bad_axes} — "
-            f"use llm.mesh {{tp: N}} (dp batch sharding is a training-path "
-            f"concept; the engine's continuous batching already fills the "
-            f"chip with one replica)"
-        )
-    _refuse_unserved(
-        cfg, multi=multi, quantize=quantize, checkpoint_path=checkpoint_path,
-        spec_enabled=spec_enabled,
-    )
-    if multi:
-        validate_specs_divisibility(cfg, mesh)
-    if quantize is not None and quantize != "int8":
-        raise ValueError(f"unknown quantization {quantize!r} (only 'int8')")
-    if checkpoint_path:
-        from k8s_llm_scheduler_tpu.models.loader import (
-            load_hf_checkpoint,
-            restore_checkpoint,
-        )
-
-        ckpt = Path(checkpoint_path)
-        if list(ckpt.glob("*.safetensors")):
-            # quantizes per stacked parameter as it completes — the bf16
-            # form of at most one parameter is ever resident
-            params = load_hf_checkpoint(
-                ckpt, cfg, mesh if multi else None, quantize=quantize
+    COMPILE_LOG.install()  # before the first jit: set-up's programs are booked
+    setup: dict[str, float] = {}
+    with spans.thread_span("setup_build", layer="engine", sink=setup):
+        _require_named_cpu()
+        enable_persistent_compile_cache(compile_cache_dir)
+        cfg = cfg or get_config(model)
+        builtin_tokenizer = None
+        if tokenizer_path is None and not (
+            checkpoint_path
+            and tokenizer_name == "byte"
+            and (Path(checkpoint_path) / "tokenizer.json").exists()
+        ):
+            # Builtin tokenizer: the shared rule in engine/tokenizer.py may
+            # WIDEN cfg.vocab_size (numeric NUM rows live above the byte
+            # base) — this must happen before params are built; train/
+            # distill.py calls the same helper, so checkpoints round-trip.
+            from k8s_llm_scheduler_tpu.engine.tokenizer import (
+                build_builtin_tokenizer,
             )
-        else:
-            params = restore_checkpoint(ckpt, cfg, mesh if multi else None)
-            if quantize is not None:
-                from k8s_llm_scheduler_tpu.models.quant import quantize_params
 
-                params = quantize_params(params)
-                if multi:
-                    params = _pin_quantized(params, cfg, mesh)
-    elif multi:
-        params = _init_params(rng_seed, cfg, mesh)
-        if quantize is not None:
-            from k8s_llm_scheduler_tpu.models.quant import quantize_params
-
-            params = quantize_params(params)
-            params = _pin_quantized(params, cfg, mesh)
-    elif quantize == "int8":
-        # single device: init + quantize HOST-SIDE, ship only int8 — even
-        # per-weight bf16 device transients overflow a 16 GB chip at 8B
-        from k8s_llm_scheduler_tpu.models.quant import init_params_int8_host
-
-        params = init_params_int8_host(rng_seed, cfg)
-    else:
-        params = _init_params(rng_seed, cfg)
-    if builtin_tokenizer is not None:
-        tokenizer = builtin_tokenizer
-    else:
-        # a HF tokenizer dir was given, or the checkpoint ships its own
-        # (auto-adopted only when no builtin was explicitly selected — a
-        # numeric-distilled checkpoint must keep the vocab it trained on)
-        from k8s_llm_scheduler_tpu.engine.tokenizer import HFTokenizerAdapter
-
-        tokenizer = HFTokenizerAdapter(tokenizer_path or checkpoint_path)
-    if max_pages_per_seq is None:
-        # Own pages hold only the per-pod suffix + generated tokens (the
-        # shared cluster-state prefix lives in the dense prefix buffer), so
-        # the page-table width — which sets the decode gather size — stays
-        # tight: the largest suffix we expect (1024 tokens covers a pod spec
-        # with heavy selectors/tolerations; LocalLLMBackend fails bigger ones
-        # individually via max_suffix_tokens) + decode budget.
-        max_pages_per_seq = -(-(1024 + max_new_tokens + chunk_steps) // page_size)
-    engine = InferenceEngine(
-        params, cfg, tokenizer,
-        num_pages=num_pages, page_size=page_size, max_slots=max_slots,
-        max_pages_per_seq=max_pages_per_seq,
-        prefill_buckets=prefill_buckets, chunk_steps=chunk_steps,
-        prefix_chunk=prefix_chunk, paged_attn=paged_attn,
-        temperature=temperature,
-        # On a tp mesh the engine wraps the Pallas kernels in shard_map
-        # over the kv-head axis (ops/pallas_prefix_attention.py shmap
-        # wrappers), so the sharded serving path keeps flash attention.
-        prefix_attn_impl=prefix_attn_impl,
-        decode_matmul=decode_matmul,
-        mesh=mesh if multi else None,
-        admission_chunk_tokens=admission_chunk_tokens,
-        fused_decode=fused_decode,
-        top_k=top_k,
-    )
-    if spec_enabled:
+            builtin_tokenizer, cfg = build_builtin_tokenizer(tokenizer_name, cfg)
+        mesh = mesh_from_config(mesh_axes, devices=devices)
+        multi = mesh.devices.size > 1
+        # Serving shards over tp only: params are tp-sharded (Megatron specs)
+        # and the engine's wave batch is replicated, so a dp/sp/... axis > 1
+        # would replicate weights N times and waste every non-tp device.
+        # Reject loudly instead of silently burning chips (VERDICT r2 weak #3).
+        bad_axes = {
+            ax: n for ax, n in mesh.shape.items() if ax != "tp" and n > 1
+        }
+        if bad_axes:
+            raise ValueError(
+                f"serving mesh supports only a tp axis; got {bad_axes} — "
+                f"use llm.mesh {{tp: N}} (dp batch sharding is a training-path "
+                f"concept; the engine's continuous batching already fills the "
+                f"chip with one replica)"
+            )
+        _refuse_unserved(
+            cfg, multi=multi, quantize=quantize, checkpoint_path=checkpoint_path,
+            spec_enabled=spec_enabled,
+        )
         if multi:
-            # The spec programs carry no sharding annotations yet; on a tp
-            # mesh they would gather the sharded caches through GSPMD's
-            # worst guesses. Plain decode is the honest multi-device path.
-            logger.warning(
-                "spec_enabled is single-device; tp mesh keeps plain decode"
+            validate_specs_divisibility(cfg, mesh)
+        if quantize is not None and quantize != "int8":
+            raise ValueError(f"unknown quantization {quantize!r} (only 'int8')")
+        # from the weights' dispatch until they are resident; the wait is
+        # the block's last line, so the host work below still overlaps it
+        with spans.thread_span("setup_params", layer="engine", sink=setup):
+            if checkpoint_path:
+                from k8s_llm_scheduler_tpu.models.loader import (
+                    load_hf_checkpoint,
+                    restore_checkpoint,
+                )
+
+                ckpt = Path(checkpoint_path)
+                if list(ckpt.glob("*.safetensors")):
+                    # quantizes per stacked parameter as it completes — the bf16
+                    # form of at most one parameter is ever resident
+                    params = load_hf_checkpoint(
+                        ckpt, cfg, mesh if multi else None, quantize=quantize
+                    )
+                else:
+                    params = restore_checkpoint(ckpt, cfg, mesh if multi else None)
+                    if quantize is not None:
+                        from k8s_llm_scheduler_tpu.models.quant import quantize_params
+
+                        params = quantize_params(params)
+                        if multi:
+                            params = _pin_quantized(params, cfg, mesh)
+            elif multi:
+                params = _init_params(rng_seed, cfg, mesh)
+                if quantize is not None:
+                    from k8s_llm_scheduler_tpu.models.quant import quantize_params
+
+                    params = quantize_params(params)
+                    params = _pin_quantized(params, cfg, mesh)
+            elif quantize == "int8":
+                # single device: init + quantize HOST-SIDE, ship only int8 — even
+                # per-weight bf16 device transients overflow a 16 GB chip at 8B
+                from k8s_llm_scheduler_tpu.models.quant import init_params_int8_host
+
+                params = init_params_int8_host(rng_seed, cfg)
+            else:
+                params = _init_params(rng_seed, cfg)
+            if builtin_tokenizer is not None:
+                tokenizer = builtin_tokenizer
+            else:
+                # a HF tokenizer dir was given, or the checkpoint ships its own
+                # (auto-adopted only when no builtin was explicitly selected — a
+                # numeric-distilled checkpoint must keep the vocab it trained on)
+                from k8s_llm_scheduler_tpu.engine.tokenizer import HFTokenizerAdapter
+
+                tokenizer = HFTokenizerAdapter(tokenizer_path or checkpoint_path)
+            if max_pages_per_seq is None:
+                # Own pages hold only the per-pod suffix + generated tokens (the
+                # shared cluster-state prefix lives in the dense prefix buffer), so
+                # the page-table width — which sets the decode gather size — stays
+                # tight: the largest suffix we expect (1024 tokens covers a pod spec
+                # with heavy selectors/tolerations; LocalLLMBackend fails bigger ones
+                # individually via max_suffix_tokens) + decode budget.
+                max_pages_per_seq = -(-(1024 + max_new_tokens + chunk_steps) // page_size)
+            engine = InferenceEngine(
+                params, cfg, tokenizer,
+                num_pages=num_pages, page_size=page_size, max_slots=max_slots,
+                max_pages_per_seq=max_pages_per_seq,
+                prefill_buckets=prefill_buckets, chunk_steps=chunk_steps,
+                prefix_chunk=prefix_chunk, paged_attn=paged_attn,
+                temperature=temperature,
+                # On a tp mesh the engine wraps the Pallas kernels in shard_map
+                # over the kv-head axis (ops/pallas_prefix_attention.py shmap
+                # wrappers), so the sharded serving path keeps flash attention.
+                prefix_attn_impl=prefix_attn_impl,
+                decode_matmul=decode_matmul,
+                mesh=mesh if multi else None,
+                admission_chunk_tokens=admission_chunk_tokens,
+                fused_decode=fused_decode,
+                top_k=top_k,
             )
-        else:
-            _attach_spec(
-                engine,
-                arm=spec_arm,
-                draft_model=spec_draft_model,
-                draft_checkpoint=spec_draft_checkpoint,
-                k=spec_k,
-                disable_threshold=spec_disable_threshold,
-                rng_seed=rng_seed,
+            if spec_enabled:
+                if multi:
+                    # The spec programs carry no sharding annotations yet; on a tp
+                    # mesh they would gather the sharded caches through GSPMD's
+                    # worst guesses. Plain decode is the honest multi-device path.
+                    logger.warning(
+                        "spec_enabled is single-device; tp mesh keeps plain decode"
+                    )
+                else:
+                    _attach_spec(
+                        engine,
+                        arm=spec_arm,
+                        draft_model=spec_draft_model,
+                        draft_checkpoint=spec_draft_checkpoint,
+                        k=spec_k,
+                        disable_threshold=spec_disable_threshold,
+                        rng_seed=rng_seed,
+                    )
+            backend = LocalLLMBackend(
+                engine, tokenizer, max_new_tokens=max_new_tokens, constrained=constrained,
+                request_timeout_s=request_timeout_s,
+                group_switch_after_s=group_switch_after_s,
+                partial_hold_s=partial_hold_s,
+                prewarm_idle_delay_s=prewarm_idle_delay_s,
+                answer_style=answer_style,
+                max_reason_tokens=max_reason_tokens,
+                packed_admission=packed_admission,
+                delta_prompts=delta_prompts,
+                repin_fraction=repin_fraction,
+                max_pins=max_pins,
             )
-    return LocalLLMBackend(
-        engine, tokenizer, max_new_tokens=max_new_tokens, constrained=constrained,
-        request_timeout_s=request_timeout_s,
-        group_switch_after_s=group_switch_after_s,
-        partial_hold_s=partial_hold_s,
-        prewarm_idle_delay_s=prewarm_idle_delay_s,
-        answer_style=answer_style,
-        max_reason_tokens=max_reason_tokens,
-        packed_admission=packed_admission,
-        delta_prompts=delta_prompts,
-        repin_fraction=repin_fraction,
-        max_pins=max_pins,
-    )
+            jax.block_until_ready(backend.engine.params)
+    backend.setup = setup
+    return backend

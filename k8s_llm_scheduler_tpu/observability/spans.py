@@ -488,15 +488,42 @@ def _annotation(layer: str, name: str, stats: dict):
     return _TraceAnnotation(annotation_name(layer, name), **stats)
 
 
-def thread_span(name: str, layer: str = "app", **stats: Any):
+def thread_span(name: str, layer: str = "app", *, sink: dict[str, float] | None = None,
+                **stats: Any):
     """A span of the calling THREAD, tied to no decision: the annotation
     alone, no flight-recorder span, no trace. The engine worker's phases
     (engine/local.py) are these. Yields the annotation, whose
     `set_metadata(**stats)` adds what is known only at the end, or None
-    (the shared no-op) when tracing is disabled."""
+    (the shared no-op) when tracing is disabled.
+
+    With a `sink` the span also adds its seconds to `sink[name]` on exit,
+    whether tracing is on or not: the record of a phase that runs once, as
+    `build_local_backend`'s set-up does (`LocalLLMBackend.setup`)."""
+    if sink is not None:
+        return _Timed(name, sink, _annotation(layer, name, stats) if _enabled else _NULL)
     if not _enabled:
         return _NULL
     return _annotation(layer, name, stats)
+
+
+class _Timed:
+    """`thread_span` with a sink: the annotation (or the no-op), and the
+    block's seconds added to `sink[name]` when it ends."""
+
+    __slots__ = ("_name", "_sink", "_ann", "_t0")
+
+    def __init__(self, name: str, sink: dict[str, float], ann) -> None:
+        self._name, self._sink, self._ann = name, sink, ann
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self._ann.__enter__()
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._ann.__exit__(*exc)
+        self._sink[self._name] = (self._sink.get(self._name, 0.0)
+                                  + time.perf_counter() - self._t0)
+        return False
 
 
 class _AnnotationOnly:

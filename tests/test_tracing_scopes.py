@@ -19,6 +19,7 @@ import ast
 import json
 import sys
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
@@ -250,6 +251,38 @@ class TestSpanSink:
         with spans.span("decide", layer="sched") as sp, spans.thread_span("tick", layer="engine") as ann:
             assert sp is None and ann is None
         assert annotations == [] and recorder.stats()["recorded"] == 0
+
+    def test_a_sink_takes_each_span_s_seconds_by_name(self, recorder, annotations):
+        """`thread_span(..., sink=)` is the set-up record's one way in: the
+        annotation as any thread span, and on exit the block's seconds
+        added to `sink[name]`."""
+        record: dict[str, float] = {}
+        with spans.thread_span("setup_build", layer="engine", sink=record):
+            with spans.thread_span("setup_params", layer="engine", sink=record) as ann:
+                ann.set_metadata(where="inner")
+                time.sleep(0.01)
+        assert [a.name for a in annotations] == ["engine.setup_build", "engine.setup_params"]
+        assert record["setup_build"] >= record["setup_params"] >= 0.01
+        first = record["setup_params"]
+        with spans.thread_span("setup_params", layer="engine", sink=record):
+            pass
+        assert record["setup_params"] >= first  # added, not replaced
+
+    def test_a_sink_keeps_its_record_while_tracing_is_off(self, recorder, annotations, monkeypatch):
+        spans.configure(enabled=False)
+        monkeypatch.setattr(spans, "_annotation", lambda *a, **k: pytest.fail("annotated while off"))
+        record: dict[str, float] = {}
+        with spans.thread_span("setup_build", layer="engine", sink=record) as ann:
+            assert ann is None
+        assert set(record) == {"setup_build"} and annotations == []
+
+    def test_build_local_backend_records_its_set_up(self, backend):
+        """`engine.setup_params` lies inside `engine.setup_build`, and both
+        reach `get_stats()["setup"]` beside the compile log's books."""
+        setup = backend.get_stats()["setup"]
+        assert 0.0 < setup["params_s"] <= setup["build_s"]
+        assert setup["build_s"] == backend.setup["setup_build"]
+        assert setup["programs"] >= 1 and setup["trace_lower_s"] > 0.0
 
     def test_every_call_site_names_its_layer(self):
         """Over every `spans.span(` / `spans.thread_span(` /
