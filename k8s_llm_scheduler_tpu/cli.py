@@ -736,9 +736,9 @@ def cmd_verify(args: argparse.Namespace, cfg: Config) -> int:
 
             tok = HFTokenizerAdapter(path)
         except ImportError:
-            # transformers is an optional extra; the hermetic byte-level
-            # path needs no files (mirror kube_check's degrade).
-            return "transformers not installed (ByteTokenizer available)"
+            # tokenizers and jinja2 are the optional `hf` extra; the hermetic
+            # byte-level path needs no files (mirror kube_check's degrade).
+            return "tokenizers/jinja2 not installed (ByteTokenizer available)"
         sample = "Node: node-1"
         if tok.decode(tok.encode(sample)) != sample:
             raise RuntimeError(f"tokenizer round-trip failed for {sample!r}")

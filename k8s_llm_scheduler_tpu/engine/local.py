@@ -172,7 +172,8 @@ class LocalLLMBackend:
         max_pins: int = 4,
     ) -> None:
         self.engine = engine
-        # seconds by set-up span (`engine.setup_build`, `engine.setup_params`),
+        # seconds by set-up span (`engine.setup_build`, `engine.setup_params`
+        # and, inside the latter, `engine.setup_tokenizer`, `engine.setup_wait`),
         # filled by build_local_backend; empty for a backend built otherwise
         self.setup: dict[str, float] = {}
         # Admission plane (engine/admission/): batch-surface decisions
@@ -1329,6 +1330,8 @@ class LocalLLMBackend:
         out["setup"] = {
             "build_s": self.setup.get("setup_build", 0.0),
             "params_s": self.setup.get("setup_params", 0.0),
+            "tokenizer_s": self.setup.get("setup_tokenizer", 0.0),
+            "params_wait_s": self.setup.get("setup_wait", 0.0),
             **COMPILE_LOG.books(),
         }
         # THE admission-efficiency headline (sublinearity in node count is
@@ -1644,15 +1647,19 @@ def build_local_backend(
                 params = init_params_int8_host(rng_seed, cfg)
             else:
                 params = _init_params(rng_seed, cfg)
-            if builtin_tokenizer is not None:
-                tokenizer = builtin_tokenizer
-            else:
-                # a HF tokenizer dir was given, or the checkpoint ships its own
-                # (auto-adopted only when no builtin was explicitly selected — a
-                # numeric-distilled checkpoint must keep the vocab it trained on)
-                from k8s_llm_scheduler_tpu.engine.tokenizer import HFTokenizerAdapter
+            with spans.thread_span("setup_tokenizer", layer="engine", sink=setup):
+                if builtin_tokenizer is not None:
+                    tokenizer = builtin_tokenizer
+                else:
+                    # a HF tokenizer dir was given, or the checkpoint ships its
+                    # own (auto-adopted only when no builtin was explicitly
+                    # selected — a numeric-distilled checkpoint must keep the
+                    # vocab it trained on)
+                    from k8s_llm_scheduler_tpu.engine.tokenizer import (
+                        HFTokenizerAdapter,
+                    )
 
-                tokenizer = HFTokenizerAdapter(tokenizer_path or checkpoint_path)
+                    tokenizer = HFTokenizerAdapter(tokenizer_path or checkpoint_path)
             if max_pages_per_seq is None:
                 # Own pages hold only the per-pod suffix + generated tokens (the
                 # shared cluster-state prefix lives in the dense prefix buffer), so
@@ -1709,6 +1716,9 @@ def build_local_backend(
                 repin_fraction=repin_fraction,
                 max_pins=max_pins,
             )
-            jax.block_until_ready(backend.engine.params)
+            # what the weights' draw or load still costs once the host work
+            # above no longer hides it
+            with spans.thread_span("setup_wait", layer="engine", sink=setup):
+                jax.block_until_ready(backend.engine.params)
     backend.setup = setup
     return backend

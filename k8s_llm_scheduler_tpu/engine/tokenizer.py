@@ -8,9 +8,10 @@ Two implementations behind one tiny interface:
   This is what lets the framework exercise the full TPU path hermetically
   (the reference can't test its LLM path without the live HF API,
   SURVEY §4).
-- `HFTokenizerAdapter`: wraps a local HuggingFace tokenizer directory for
-  real Llama checkpoints (transformers is in-image; loading is from local
-  files only — zero external API calls is the north star).
+- `HFTokenizerAdapter`: reads a local HuggingFace tokenizer directory for
+  real Llama checkpoints with `tokenizers` and jinja2 (no transformers;
+  loading is from local files only — zero external API calls is the north
+  star).
 
 The chat template mirrors the reference's two-message structure
 (system + user, reference scheduler.py:425-430) with explicit role tokens.
@@ -18,6 +19,8 @@ The chat template mirrors the reference's two-message structure
 
 from __future__ import annotations
 
+import json
+from pathlib import Path
 from typing import Protocol, Sequence
 
 
@@ -190,19 +193,40 @@ def build_builtin_tokenizer(name: str, cfg):
 
 
 class HFTokenizerAdapter:
-    """Local-files-only wrapper over a HuggingFace fast tokenizer.
+    """Local-files-only reader of a HuggingFace fast-tokenizer directory.
 
-    `path` must contain tokenizer.json etc. (e.g. an exported Llama 3
-    tokenizer dir). Import is deferred so hermetic environments never touch
-    transformers.
+    `path` must contain tokenizer.json (the `tokenizers` library reads it)
+    and may contain tokenizer_config.json (special tokens, chat template,
+    clean-up flag), as an exported Llama 3 tokenizer dir does. transformers
+    is never imported: its import chain (torch included) cost ~8 s on a CPU
+    and more on the chip's host, inside set-up, for ids and text that
+    `tokenizers` plus a jinja2 render give identically
+    (tests/test_tokenizer_equivalence.py holds the two to each other).
     """
 
     def __init__(self, path: str) -> None:
-        from transformers import AutoTokenizer  # local import by design
+        from tokenizers import Tokenizer  # local import by design
 
-        self._tok = AutoTokenizer.from_pretrained(path, local_files_only=True)
-        self.vocab_size = len(self._tok)
-        self.eos_id = self._tok.eos_token_id
+        root = Path(path)
+        if not (root / "tokenizer.json").is_file():
+            raise FileNotFoundError(
+                f"tokenizer directory {path!r} has no tokenizer.json"
+            )
+        self._tok = Tokenizer.from_file(str(root / "tokenizer.json"))
+        # transformers' encode never truncates or pads by default
+        self._tok.no_truncation()
+        self._tok.no_padding()
+        config_file = root / "tokenizer_config.json"
+        config = json.loads(config_file.read_text()) if config_file.is_file() else {}
+        self._special = {
+            key: _token_content(config[key])
+            for key in ("bos_token", "eos_token", "unk_token", "pad_token")
+            if config.get(key) is not None
+        }
+        self._clean_up = bool(config.get("clean_up_tokenization_spaces", False))
+        self._template = _compile_chat_template(config.get("chat_template"))
+        self.vocab_size = self._tok.get_vocab_size(with_added_tokens=True)
+        self.eos_id = self._token_id("eos_token")
         self.pad_id = self._pick_pad_sentinel()
         # rendered-prefix STRING -> its token ids. A burst shares ONE
         # cluster-state prefix across every pod; re-encoding its ~10k chars
@@ -213,6 +237,10 @@ class HFTokenizerAdapter:
         # (~0.1 ms) and the split validation — still run per call.
         self._prefix_encode_memo: dict[str, list[int]] = {}
 
+    def _token_id(self, key: str) -> int | None:
+        token = self._special.get(key)
+        return None if token is None else self._tok.token_to_id(token)
+
     def _pick_pad_sentinel(self) -> int:
         """An id the engine can use as the idle-slot emission sentinel.
 
@@ -221,16 +249,17 @@ class HFTokenizerAdapter:
         would silently strip '!' from generated output (engine/engine.py
         filters pad from emissions). Prefer the tokenizer's own pad token,
         then a reserved special token; raise rather than guess."""
-        if self._tok.pad_token_id is not None:
-            return self._tok.pad_token_id
+        pad = self._token_id("pad_token")
+        if pad is not None:
+            return pad
         for name in ("<|finetune_right_pad_id|>",):
-            tid = self._tok.convert_tokens_to_ids(name)
-            if tid is not None and tid != getattr(self._tok, "unk_token_id", None):
+            tid = self._tok.token_to_id(name)
+            if tid is not None:
                 return tid
-        for tok_str, tid in sorted(
-            self._tok.get_added_vocab().items(), key=lambda kv: -kv[1]
+        for tid, added in sorted(
+            self._tok.get_added_tokens_decoder().items(), key=lambda kv: -kv[0]
         ):
-            if "reserved" in tok_str and tid not in (self.eos_id,):
+            if "reserved" in added.content and tid != self.eos_id:
                 return tid
         raise ValueError(
             "tokenizer has no pad token and no reserved special token to use "
@@ -238,17 +267,27 @@ class HFTokenizerAdapter:
         )
 
     def encode(self, text: str) -> list[int]:
-        return self._tok.encode(text, add_special_tokens=False)
+        return self._tok.encode(text, add_special_tokens=False).ids
 
     def decode(self, ids: Sequence[int]) -> str:
-        return self._tok.decode(ids, skip_special_tokens=True)
+        text = self._tok.decode(list(ids), skip_special_tokens=True)
+        return _clean_up_tokenization(text) if self._clean_up else text
 
-    def chat_prompt(self, system: str, user: str) -> list[int]:
+    def _render(self, system: str, user: str) -> str:
+        """The chat template over (system, user), as transformers'
+        `apply_chat_template(..., add_generation_prompt=True)` renders it."""
+        if self._template is None:
+            raise ValueError("tokenizer has no chat_template")
         messages = [
             {"role": "system", "content": system},
             {"role": "user", "content": user},
         ]
-        return self._tok.apply_chat_template(messages, add_generation_prompt=True)
+        return self._template.render(
+            messages=messages, add_generation_prompt=True, **self._special
+        )
+
+    def chat_prompt(self, system: str, user: str) -> list[int]:
+        return self.encode(self._render(system, user))
 
     def chat_prompt_parts(
         self, system: str, user_prefix: str, user_suffix: str
@@ -267,13 +306,7 @@ class HFTokenizerAdapter:
         sharing instead of mis-splitting. Only the ~10k-char prefix ENCODE
         (~6 ms) is memoized, keyed on the exact rendered prefix text; the
         render (~0.1 ms) and this validation run on every call."""
-        messages = [
-            {"role": "system", "content": system},
-            {"role": "user", "content": user_prefix + user_suffix},
-        ]
-        rendered = self._tok.apply_chat_template(
-            messages, add_generation_prompt=True, tokenize=False
-        )
+        rendered = self._render(system, user_prefix + user_suffix)
         split_at = -1
         if user_prefix and user_suffix:
             pos = rendered.rfind(user_prefix)
@@ -284,9 +317,54 @@ class HFTokenizerAdapter:
         prefix_str = rendered[:split_at]
         prefix = self._prefix_encode_memo.get(prefix_str)
         if prefix is None:
-            prefix = self._tok.encode(prefix_str, add_special_tokens=False)
+            prefix = self.encode(prefix_str)
             if len(self._prefix_encode_memo) > 8:
                 self._prefix_encode_memo.clear()
             self._prefix_encode_memo[prefix_str] = prefix
-        suffix = self._tok.encode(rendered[split_at:], add_special_tokens=False)
+        suffix = self.encode(rendered[split_at:])
         return list(prefix), suffix
+
+
+def _token_content(token) -> str:
+    """A special token as tokenizer_config.json gives it: a string, or an
+    AddedToken's serialised dict."""
+    return token["content"] if isinstance(token, dict) else token
+
+
+def _compile_chat_template(source: str | None):
+    """Compiled once, in the environment transformers gives a chat
+    template: sandboxed, trimmed blocks, loop controls, `raise_exception`,
+    `strftime_now` and a `tojson` that escapes no HTML."""
+    if source is None:
+        return None
+    from datetime import datetime
+
+    import jinja2
+    import jinja2.ext
+    from jinja2.sandbox import ImmutableSandboxedEnvironment
+
+    def raise_exception(message):
+        raise jinja2.exceptions.TemplateError(message)
+
+    def tojson(x, ensure_ascii=False, indent=None, separators=None, sort_keys=False):
+        return json.dumps(x, ensure_ascii=ensure_ascii, indent=indent,
+                          separators=separators, sort_keys=sort_keys)
+
+    env = ImmutableSandboxedEnvironment(
+        trim_blocks=True, lstrip_blocks=True, extensions=[jinja2.ext.loopcontrols]
+    )
+    env.filters["tojson"] = tojson
+    env.globals["raise_exception"] = raise_exception
+    env.globals["strftime_now"] = lambda fmt: datetime.now().strftime(fmt)
+    return env.from_string(source)
+
+
+def _clean_up_tokenization(text: str) -> str:
+    """transformers' `clean_up_tokenization`, for a config that asks for it."""
+    for before, after in (
+        (" .", "."), (" ?", "?"), (" !", "!"), (" ,", ","), (" ' ", "'"),
+        (" n't", "n't"), (" 'm", "'m"), (" 's", "'s"), (" 've", "'ve"),
+        (" 're", "'re"),
+    ):
+        text = text.replace(before, after)
+    return text

@@ -37,7 +37,7 @@ class TestHFTokenizerAdapter:
     def test_pad_and_eos_sentinels(self, adapter):
         # <|pad|> is id 0 in the fixture; eos is <|eot_id|>
         assert adapter.pad_id == 0
-        assert adapter.eos_id == adapter._tok.convert_tokens_to_ids("<|eot_id|>")
+        assert adapter.eos_id == adapter._tok.token_to_id("<|eot_id|>")
         assert adapter.pad_id != adapter.eos_id
         assert adapter.vocab_size % 128 == 0  # MXU-friendly embedding rows
 
@@ -116,7 +116,7 @@ class TestHFTokenizerAdapter:
         del config["pad_token"]
         (tmp_path / "tokenizer_config.json").write_text(json.dumps(config))
         adapter = HFTokenizerAdapter(str(tmp_path))
-        name = adapter._tok.convert_ids_to_tokens(adapter.pad_id)
+        name = adapter._tok.id_to_token(adapter.pad_id)
         assert "reserved" in name or "pad" in name
         assert adapter.pad_id != adapter.eos_id
 

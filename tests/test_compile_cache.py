@@ -193,9 +193,9 @@ def test_warm_calls_leave_every_book_as_it_was(log):
 
 
 def test_the_set_up_record_becomes_a_handful_of_gauges():
-    """`get_stats()["setup"]`: the two spans' seconds and the log's totals,
-    no per-program table, so `/metrics` gains eight gauges and no name per
-    program."""
+    """`get_stats()["setup"]`: the four spans' seconds and the log's totals,
+    no per-program table, so `/metrics` gains ten gauges and no name per
+    program. The tokenizer's and the wait's spans lie inside the params'."""
     from k8s_llm_scheduler_tpu.engine.local import build_local_backend
     from k8s_llm_scheduler_tpu.observability.metrics import _flatten
 
@@ -207,9 +207,12 @@ def test_the_set_up_record_becomes_a_handful_of_gauges():
     for key in ("build_s", "params_s", "trace_lower_s", "load_compile_s", "programs_compiled"):
         assert isinstance(setup[key], (int, float)) and setup[key] >= 0, key
     assert setup["build_s"] >= setup["params_s"] > 0.0
+    for key in ("tokenizer_s", "params_wait_s"):
+        assert isinstance(setup[key], float) and setup[key] >= 0.0, key
+    assert setup["tokenizer_s"] + setup["params_wait_s"] <= setup["params_s"]
     assert setup["programs"] == setup["programs_compiled"] + setup["programs_loaded"] >= 1
     gauges = {k: v for k, v in _flatten({"engine": {"setup": setup}}).items()}
     assert set(gauges) == {f"engine_setup_{k}" for k in (
-        "build_s", "params_s", "programs", "programs_compiled", "programs_loaded",
-        "trace_lower_s", "load_compile_s", "retrieval_s")}
+        "build_s", "params_s", "tokenizer_s", "params_wait_s", "programs",
+        "programs_compiled", "programs_loaded", "trace_lower_s", "load_compile_s", "retrieval_s")}
     assert gauges["engine_setup_build_s"] == setup["build_s"]

@@ -191,6 +191,7 @@ class TestClientServer:
         finally:
             client.close()
             srv.close()
+            srv._pool.shutdown(wait=True)  # the running 0.5 s decide, as below
 
 
 class TestPrewarmOverWire:
@@ -976,3 +977,7 @@ class TestFanoutSchedulerE2E:
             cluster.close()
             client.close()
             srv.close()
+            # decides already running outlive close() by up to the stub's
+            # 0.5 s; left alone they record `replica.decide` traces into
+            # whatever flight recorder the NEXT test installed
+            srv._pool.shutdown(wait=True)
