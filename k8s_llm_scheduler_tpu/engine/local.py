@@ -55,13 +55,7 @@ from k8s_llm_scheduler_tpu.engine.constrained import build_decision_dfa
 from k8s_llm_scheduler_tpu.engine.engine import InferenceEngine
 from k8s_llm_scheduler_tpu.engine.tokenizer import ByteTokenizer, Tokenizer
 from k8s_llm_scheduler_tpu.models import family
-from k8s_llm_scheduler_tpu.models.configs import (
-    GdnMoeConfig,
-    LlamaConfig,
-    MlaMoeConfig,
-    MlaScmoeConfig,
-    get_config,
-)
+from k8s_llm_scheduler_tpu.models.configs import LlamaConfig, get_config
 from k8s_llm_scheduler_tpu.parallel.mesh import mesh_from_config
 from k8s_llm_scheduler_tpu.parallel.sharding import (
     named_shardings,
@@ -1437,7 +1431,7 @@ def _pin_quantized(params, cfg, mesh):
     )
 
 
-def _init_params(rng_seed: int, cfg: LlamaConfig | MlaMoeConfig | MlaScmoeConfig | GdnMoeConfig, mesh=None):
+def _init_params(rng_seed: int, cfg, mesh=None):
     """Random-init the bf16 tree in ONE jitted program, for every layout.
     With a mesh the outputs are born on it (param_specs match the
     unquantized tree): each device draws only its own 1/N of every weight
@@ -1454,18 +1448,17 @@ def _init_params(rng_seed: int, cfg: LlamaConfig | MlaMoeConfig | MlaScmoeConfig
 
 
 def _refuse_unserved(cfg, *, multi, quantize, checkpoint_path, spec_enabled) -> None:
-    """What the latent-attention families (models/mla_moe.py,
-    models/mla_scmoe.py) and the delta-rule hybrid (models/gdn_moe.py) do
-    not bring refuses HERE, at build time, naming
-    the model and the path — never inside a trace: what would otherwise
-    fail before the engine exists. (InferenceEngine refuses a tp mesh and
+    """What the families other than the dense one (models/llama.py) do not
+    bring refuses HERE, at build time, naming the model and the path —
+    never inside a trace: what would otherwise fail before the engine
+    exists. (InferenceEngine refuses a tp mesh and
     ragged decode in its constructor, and the paged entry points at the
     call: _require_paged.)"""
-    if not isinstance(cfg, (MlaMoeConfig, MlaScmoeConfig, GdnMoeConfig)):
+    if isinstance(cfg, LlamaConfig):
         return
     unsharded = (
         "a per-sequence state has no sharding rule"
-        if isinstance(cfg, GdnMoeConfig)
+        if family(cfg).state_shapes(cfg)
         else "a latent cache has no head axis to shard"
     )
     asked = {

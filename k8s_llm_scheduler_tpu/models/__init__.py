@@ -1,9 +1,11 @@
 """Decision-model families in functional JAX: Llama 3.x dense
 (models/llama.py), latent attention with sparse experts
 (models/mla_moe.py), shortcut-connected double layers over latent
-attention with identity experts (models/mla_scmoe.py) and gated-delta-rule
+attention with identity experts (models/mla_scmoe.py), gated-delta-rule
 linear attention, three layers to one of gated softmax attention, over
-sparse experts (models/gdn_moe.py)."""
+sparse experts (models/gdn_moe.py) and Mamba-2 state-space mixers, nine
+layers to one of softmax attention without position encoding, over dense
+SwiGLUs (models/mamba2_hybrid.py)."""
 
 from k8s_llm_scheduler_tpu.models.configs import (  # noqa: F401
     LLAMA_3_1_8B,
@@ -12,6 +14,7 @@ from k8s_llm_scheduler_tpu.models.configs import (  # noqa: F401
     TINY,
     GdnMoeConfig,
     LlamaConfig,
+    Mamba2HybridConfig,
     MlaMoeConfig,
     MlaScmoeConfig,
     get_config,
@@ -34,6 +37,10 @@ def family(cfg):
     return it behind the cache (prefix prefill: after `seq_lens` tokens;
     suffix: each row's, seeded from the prefix's; block decode: advanced by
     `blk_len`)."""
+    if isinstance(cfg, Mamba2HybridConfig):
+        from k8s_llm_scheduler_tpu.models import mamba2_hybrid
+
+        return mamba2_hybrid
     if isinstance(cfg, GdnMoeConfig):
         from k8s_llm_scheduler_tpu.models import gdn_moe
 

@@ -1,8 +1,10 @@
 """Model-family configurations: the Llama dense family, the latent-
 attention sparse-expert family (`MlaMoeConfig`, models/mla_moe.py), the
 shortcut-connected double layer over it (`MlaScmoeConfig`,
-models/mla_scmoe.py) and the gated-delta-rule / gated-attention hybrid over
-sparse experts (`GdnMoeConfig`, models/gdn_moe.py).
+models/mla_scmoe.py), the gated-delta-rule / gated-attention hybrid over
+sparse experts (`GdnMoeConfig`, models/gdn_moe.py) and the Mamba-2 /
+attention hybrid with dense SwiGLUs (`Mamba2HybridConfig`,
+models/mamba2_hybrid.py).
 
 The reference consumes Llama-3.3-70B-Instruct behind the HuggingFace API
 (reference scheduler.py:425, config.yaml:8); the BASELINE ladder also names
@@ -487,6 +489,146 @@ class GdnMoeConfig:
         return 4.0 * self.n_attn_layers * self.n_heads * self.head_dim
 
 
+@dataclasses.dataclass(frozen=True)
+class Mamba2HybridConfig:
+    """Mamba-2 state-space mixers and softmax attention without position
+    encoding in a fixed period, every layer with a dense SwiGLU, a tied
+    output head and four scalar multipliers: the `granitemoehybrid` layer
+    with no experts (models/mamba2_hybrid.py writes the equations out). The
+    layers listed in `attn_layers` attend, the others are Mamba-2 mixers; the
+    pattern repeats whole every `period` layers. A Mamba-2 layer keeps no
+    per-token cache: it keeps a STATE a sequence, `ssm_heads` matrices
+    [ssm_head_dim, ssm_state] in float32 and the last `conv_kernel - 1`
+    inputs of its convolution."""
+
+    name: str
+    vocab_size: int
+    d_model: int
+    n_layers: int
+    attn_layers: tuple[int, ...]  # the layers whose layer_types entry is "attention"
+    n_heads: int                  # the attention layers' query heads
+    n_kv_heads: int
+    d_ff: int                     # every layer's SwiGLU width (shared_intermediate_size)
+    ssm_heads: int                # mamba_n_heads
+    ssm_head_dim: int             # mamba_d_head
+    ssm_state: int                # mamba_d_state: B and C, one group shared by every head
+    conv_kernel: int              # mamba_d_conv
+    embedding_multiplier: float
+    residual_multiplier: float
+    attention_multiplier: float   # the softmax's scale, in place of head_dim^-1/2
+    logits_scaling: float         # the head's logits are divided by it
+    max_seq_len: int = 131072
+    rms_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+    tie_embeddings: bool = True
+
+    def __post_init__(self) -> None:
+        if not self.tie_embeddings:
+            raise ValueError(f"{self.name}: Mamba2HybridConfig serves a tied output head only")
+        if self.d_model % self.n_heads or self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.name}: query heads must divide d_model and be a multiple of KV heads")
+        per = self.period
+        if not self.attn_layers or self.n_layers % len(self.attn_layers) or any(
+                i != p * per + self.attn_position for p, i in enumerate(self.attn_layers)):
+            raise ValueError(f"{self.name}: the attention layers must repeat at one place of a whole period")
+
+    @property
+    def period(self) -> int:
+        return self.n_layers // len(self.attn_layers)
+
+    @property
+    def attn_position(self) -> int:
+        """Where in its period a layer attends."""
+        return self.attn_layers[0] % self.period
+
+    @property
+    def n_periods(self) -> int:
+        return len(self.attn_layers)
+
+    @property
+    def n_attn_layers(self) -> int:
+        return len(self.attn_layers)
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return self.n_layers - self.n_attn_layers
+
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """Channels the causal convolution runs over: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_state
+
+    @classmethod
+    def from_hf(cls, name: str, conf: dict, **overrides) -> "Mamba2HybridConfig":
+        """From the published `config.json` keys (granitemoehybrid)."""
+        if conf.get("num_local_experts", 0) or conf.get("num_experts_per_tok", 0):
+            raise ValueError(f"{name}: sparse experts (num_local_experts > 0) are not served")
+        if conf.get("position_embedding_type", "nope") != "nope":
+            raise ValueError(f"{name}: only position_embedding_type nope")
+        if conf.get("mamba_proj_bias", False) or not conf.get("mamba_conv_bias", True):
+            raise ValueError(f"{name}: bias-free projections and a biased convolution only")
+        if conf["mamba_n_groups"] != 1:
+            raise ValueError(f"{name}: one group of B and C for every head (mamba_n_groups 1) only")
+        if conf["mamba_expand"] * conf["hidden_size"] != conf["mamba_n_heads"] * conf["mamba_d_head"]:
+            raise ValueError(f"{name}: mamba_n_heads x mamba_d_head must be mamba_expand x hidden_size")
+        types = conf["layer_types"]
+        if len(types) != conf["num_hidden_layers"] or set(types) - {"mamba", "attention"}:
+            raise ValueError(f"{name}: layer_types must name every layer mamba or attention")
+        kw = dict(
+            name=name, vocab_size=conf["vocab_size"], d_model=conf["hidden_size"],
+            n_layers=conf["num_hidden_layers"],
+            attn_layers=tuple(i for i, t in enumerate(types) if t == "attention"),
+            n_heads=conf["num_attention_heads"], n_kv_heads=conf["num_key_value_heads"],
+            d_ff=conf["shared_intermediate_size"], ssm_heads=conf["mamba_n_heads"],
+            ssm_head_dim=conf["mamba_d_head"], ssm_state=conf["mamba_d_state"], conv_kernel=conf["mamba_d_conv"],
+            embedding_multiplier=float(conf["embedding_multiplier"]),
+            residual_multiplier=float(conf["residual_multiplier"]),
+            attention_multiplier=float(conf["attention_multiplier"]),
+            logits_scaling=float(conf["logits_scaling"]),
+            max_seq_len=conf["max_position_embeddings"], rms_eps=conf["rms_norm_eps"],
+            tie_embeddings=conf["tie_word_embeddings"],
+        )
+        kw.update(overrides)
+        return cls(**kw)
+
+    def ssm_params(self) -> int:
+        """Matrix parameters of one Mamba-2 mixer: W_in ([z | x B C | dt])
+        and W_out."""
+        d = self.d_model
+        return d * (self.ssm_inner + self.conv_width + self.ssm_heads) + self.ssm_inner * d
+
+    def attn_params(self) -> int:
+        d, hd = self.d_model, self.head_dim
+        return d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
+
+    def ssm_state_flops_per_token(self) -> float:
+        """What a token costs a Mamba-2 layer beside its projections,
+        counted per token as the recurrence states it: the update x B^T and
+        the read-out S C, 2 x head_dim x d_state each a head."""
+        return 2 * 2.0 * self.ssm_heads * self.ssm_head_dim * self.ssm_state
+
+    def matmul_flops_per_token(self) -> float:
+        """Matmul FLOPs of one token: the mixers, every layer's SwiGLU, the
+        state products of the Mamba-2 layers and the tied head."""
+        d = self.d_model
+        return (2.0 * (self.n_ssm_layers * self.ssm_params() + self.n_attn_layers * self.attn_params()
+                       + self.n_layers * 3 * d * self.d_ff + d * self.vocab_size)
+                + self.n_ssm_layers * self.ssm_state_flops_per_token())
+
+    def attn_flops_per_key(self) -> float:
+        """Score + value FLOPs of one token against one key, in the layers
+        that attend (a Mamba-2 layer's cost does not grow with context)."""
+        return 4.0 * self.n_attn_layers * self.n_heads * self.head_dim
+
+
 TINY = LlamaConfig(
     name="tiny",
     vocab_size=512,          # byte tokenizer fits in 512
@@ -634,14 +776,37 @@ TINY_GDN_MOE = GdnMoeConfig(
     rope_theta=10000.0,
 )
 
+# Toy of the Mamba-2 / attention hybrid for the CPU tests: two periods of
+# five layers, attention at the third place of each (not the last, as
+# published), every width shrunk, the four multipliers as published.
+TINY_MAMBA2_HYBRID = Mamba2HybridConfig(
+    name="tiny-mamba2-hybrid",
+    vocab_size=512,
+    d_model=64,
+    n_layers=10,
+    attn_layers=(2, 7),
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=128,
+    ssm_heads=8,
+    ssm_head_dim=16,
+    ssm_state=32,
+    conv_kernel=4,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    attention_multiplier=1 / 16,
+    logits_scaling=8.0,
+    max_seq_len=2048,
+)
+
 _REGISTRY = {
     c.name: c
     for c in (TINY, SMALL, LLAMA_3_2_1B, LLAMA_3_1_8B, LLAMA_3_3_70B, TINY_MLA_MOE,
-              TINY_MLA_SCMOE, TINY_GDN_MOE)
+              TINY_MLA_SCMOE, TINY_GDN_MOE, TINY_MAMBA2_HYBRID)
 }
 
 
-def get_config(name: str) -> LlamaConfig | MlaMoeConfig | MlaScmoeConfig | GdnMoeConfig:
+def get_config(name: str) -> LlamaConfig | MlaMoeConfig | MlaScmoeConfig | GdnMoeConfig | Mamba2HybridConfig:
     key = name.lower()
     if key not in _REGISTRY:
         raise KeyError(f"unknown model config {name!r}; known: {sorted(_REGISTRY)}")

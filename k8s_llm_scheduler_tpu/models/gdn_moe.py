@@ -81,6 +81,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from k8s_llm_scheduler_tpu.models._state import STATE_COUNTERS, window_at
 from k8s_llm_scheduler_tpu.models.configs import GdnMoeConfig
 from k8s_llm_scheduler_tpu.models.llama import apply_rope
 from k8s_llm_scheduler_tpu.models.mla_moe import BOUND_COUNTERS, EXPERT_LEAVES
@@ -96,10 +97,6 @@ from k8s_llm_scheduler_tpu.ops.gdn_scan import gdn_chunk_scan
 
 Params = dict[str, Any]
 
-# What the delta-rule layers of a wave count: positions that were valid
-# (sum of suffix_lens and of blk_len over the model calls, once a call, not a
-# layer) and positions the chunked scan ran over, padding included.
-STATE_COUNTERS = ("state_tokens_valid", "state_tokens_computed")
 COUNTERS = EXPERT_COUNTERS + BOUND_COUNTERS + STATE_COUNTERS
 
 # Positions a chunk of the delta rule holds in prefill: the solve's cost a
@@ -262,14 +259,6 @@ def _chunk(S: int) -> int:
     return min(S, CHUNK)
 
 
-def _window_at(xx: jax.Array, lens: jax.Array, width: int) -> jax.Array:
-    """Row r's `width` entries of xx [B, width + S, C] that end at its valid
-    length: xx[r, lens[r] : lens[r] + width] (xx starts with the window the
-    call was handed, so a row of length 0 keeps it)."""
-    idx = lens[:, None] + jnp.arange(width)[None, :]
-    return jnp.take_along_axis(xx, idx[:, :, None], axis=1)
-
-
 def gdn_mixer(lp: Params, cfg: GdnMoeConfig, u: jax.Array, valid: jax.Array, lens: jax.Array,
               state: jax.Array, period, window: jax.Array):
     """The delta-rule mixer's output [B, S, D] f32 for normed tokens u [B,
@@ -295,7 +284,7 @@ def gdn_mixer(lp: Params, cfg: GdnMoeConfig, u: jax.Array, valid: jax.Array, len
         mixed = sum(xx[:, j: j + S] * conv[j] for j in range(taps))
         mixed = jax.nn.silu(mixed)
     with jax.named_scope("state_writeback"):
-        window = _window_at(xx, lens, taps - 1)
+        window = window_at(xx, lens, taps - 1)
     with jax.named_scope("gdn_scan"):
         q = _l2(mixed[..., :kw].reshape(B, S, Hk, dk)) * dk**-0.5
         k = _l2(mixed[..., kw: 2 * kw].reshape(B, S, Hk, dk))

@@ -80,28 +80,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from k8s_llm_scheduler_tpu.ops import pallas_interpret
+from k8s_llm_scheduler_tpu.ops._f32dot import HIGHEST, dot3
 
-HIGHEST = jax.lax.Precision.HIGHEST
 # Value heads a grid step holds: 8 x [128, 128] float32 = 512 KB of state,
 # so a layer of 8 rows x 32 heads is 32 steps, each long enough to hide the
 # next step's copy behind its products.
 HEAD_BLOCK = 8
 # Columns of the solve that go (or are passed over) together.
 SWEEP = 8
-
-
-def _split(a: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """a = hi + lo to 16 bits of mantissa, each half a bfloat16."""
-    hi = a.astype(jnp.bfloat16)
-    return hi, (a - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-
-
-def _dot3(a, b, dims) -> jax.Array:
-    """a . b over `dims` in three bfloat16 passes with float32 sums."""
-    (a_hi, a_lo), (b_hi, b_lo) = _split(a), _split(b)
-    dot = functools.partial(jax.lax.dot_general, dimension_numbers=(dims, ((), ())),
-                            preferred_element_type=jnp.float32)
-    return dot(a_hi, b_hi) + (dot(a_lo, b_hi) + dot(a_hi, b_lo))
 
 
 def _kernel(_period_ref, lens_ref, gcol_ref, grow_ref, bcol_ref, q_ref, k_ref, v_ref, s_in_ref, o_ref, s_ref,
@@ -146,13 +132,13 @@ def _kernel(_period_ref, lens_ref, gcol_ref, grow_ref, bcol_ref, q_ref, k_ref, v
             u, w = x_ref[r, :, :dv], x_ref[r, :, dv:]
             s = s_ref[0, 0, h]
             # what reads S_0, stacked: one product with the state for both
-            read = _dot3(jnp.concatenate([w, q * jnp.exp(gamma)], axis=0), s, ((1,), (0,)))
+            read = dot3(jnp.concatenate([w, q * jnp.exp(gamma)], axis=0), s, ((1,), (0,)))
             delta = u - read[:chunk]
             o_ref[0, h, 0] = read[chunk:] + jnp.dot(qkt * decay, delta, precision=HIGHEST,
                                                     preferred_element_type=jnp.float32)
             # (a [1, 1] is not broadcast both ways at once: along the lanes first)
             total = jnp.exp(last + jnp.zeros((1, dv), jnp.float32))
-            s_ref[0, 0, h] = total * s + _dot3(k * jnp.exp(last - gamma), delta, ((0,), (0,)))
+            s_ref[0, 0, h] = total * s + dot3(k * jnp.exp(last - gamma), delta, ((0,), (0,)))
         return 0
 
     @pl.when(n_valid > 0)

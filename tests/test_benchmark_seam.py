@@ -331,8 +331,8 @@ class TestQwen3NextThroughTheSeam:
     def test_the_cell_is_listed_where_its_readers_find_something(self):
         bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
         cell = "qwen3_next-backlog20"
-        assert bench["workloads"][-1] == {**bench["workloads"][-1], "name": cell, "config": "qwen3-next-80b-a3b",
-                                          "traffic": "backlog20_pool80", "chips": 1}
+        entry = next(w for w in bench["workloads"] if w["name"] == cell)
+        assert entry == {**entry, "config": "qwen3-next-80b-a3b", "traffic": "backlog20_pool80", "chips": 1}
         listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
         new = {"gdn_device_ms_per_bind.tput", "gdn_scan_device_ms_per_bind.tput",
                "full_attn_device_ms_per_bind.tput", "state_carry_device_ms_per_bind.tput",
@@ -349,10 +349,12 @@ class TestQwen3NextThroughTheSeam:
                              "scmoe_grouped_swiglu_roofline.tput", "scmoe_grouped_matmul_roofline.tput",
                              "prefix_attn_roofline.tput"}
         for m in bench["per_layer"]:
-            if cell in m["workloads"]:
-                assert m["workloads"][-1] == cell  # appended, nothing moved
-        assert next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"][-1] == cell
-        assert len(bench["workloads"]) == 4 and all(w["chips"] == 1 for w in bench["workloads"])
+            if cell in m["workloads"]:  # appended, nothing moved: behind it the fifth cell alone
+                assert m["workloads"][m["workloads"].index(cell) + 1:] in ([], [GRANITE_CELL])
+        rate = next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"]
+        assert rate[-2:] == [cell, GRANITE_CELL]
+        assert [w["name"] for w in bench["workloads"]] == CELLS + [GRANITE_CELL]
+        assert all(w["chips"] == 1 for w in bench["workloads"])
 
 
 @pytest.mark.parametrize("name, stats, want", [
@@ -463,12 +465,18 @@ def test_gdn_reference_runs_in_both_modes_and_int8_differs():
 
 # ------------------------------------------------------- set-up (PR 39)
 CELLS = ["internlm1_8b-backlog20", "glm4_7_flash-backlog20", "longcat_flash-backlog20", "qwen3_next-backlog20"]
+GRANITE_CELL = "granite4_h_micro-backlog20"
+GRANITE_METRICS = ["ssm_device_ms_per_bind.tput", "ssm_scan_device_ms_per_bind.tput", "ssd_chunk_scan_roofline.tput"]
 SETUP_METRICS = {"setup_build_s": "build_s", "setup_params_s": "params_s",
                  "setup_trace_lower_s": "trace_lower_s", "setup_load_compile_s": "load_compile_s",
                  "setup_programs_compiled": "programs_compiled"}
 
 
-def test_the_state_entries_follow_one_another_and_name_their_cell_alone():
+def test_the_state_entries_follow_one_another_and_name_their_cells_alone():
+    """The fourth configuration's five entries; the three that read any
+    family with a state and an attention under `full_attn` list the fifth
+    cell behind the fourth, the two of the delta rule's scopes the fourth
+    alone."""
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index("gdn_device_ms_per_bind.tput")
@@ -477,23 +485,31 @@ def test_the_state_entries_follow_one_another_and_name_their_cell_alone():
         "gdn_device_ms_per_bind.tput", "gdn_scan_device_ms_per_bind.tput", "full_attn_device_ms_per_bind.tput",
         "state_carry_device_ms_per_bind.tput", "state_valid_share.tput"]
     for m in entries:
-        assert m["workloads"] == ["qwen3_next-backlog20"] and m["moves"] == "binds_per_s" and m["layer"] == "model"
+        assert m["workloads"] == ["qwen3_next-backlog20"] + ([GRANITE_CELL] if m["name"] in (
+            "full_attn_device_ms_per_bind.tput", "state_carry_device_ms_per_bind.tput",
+            "state_valid_share.tput") else [])
+        assert m["moves"] == "binds_per_s" and m["layer"] == "model"
     assert [m["source"] for m in entries] == ["device_trace"] * 4 + ["program_counter"]
     assert entries[-1]["unit"] == "%" and entries[-1]["better"] == "higher"
-    assert names[first + 5:] == list(SETUP_METRICS)  # nothing but the set-up entries after them
+    # nothing but the set-up entries after them, then the fifth cell's three
+    assert names[first + 5:] == list(SETUP_METRICS) + GRANITE_METRICS
 
 
-def test_the_set_up_entries_close_the_list_and_move_setup_s():
+def test_the_set_up_entries_follow_the_state_entries_and_move_setup_s():
+    """The five set-up entries, every cell listed, the fifth cell last;
+    every other entry moves `binds_per_s`."""
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    entries = bench["per_layer"][-5:]
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("setup_build_s")
+    entries = bench["per_layer"][first:first + 5]
     assert [m["name"] for m in entries] == list(SETUP_METRICS)
-    assert [w["name"] for w in bench["workloads"]] == CELLS
+    assert [w["name"] for w in bench["workloads"]] == CELLS + [GRANITE_CELL]
     for m in entries:
         assert m == {"name": m["name"], "unit": "count" if m["name"] == "setup_programs_compiled" else "s",
                      "better": "lower", "source": "program_counter",
                      "layer": "entry" if m["name"] in ("setup_build_s", "setup_params_s") else "device",
-                     "moves": "setup_s", "workloads": CELLS}
-    assert all(m["moves"] == "binds_per_s" for m in bench["per_layer"][:-5])
+                     "moves": "setup_s", "workloads": CELLS + [GRANITE_CELL]}
+    assert all(m["moves"] == "binds_per_s" for m in bench["per_layer"] if m not in entries)
 
 
 def setup_ctx(setup: dict | None):
@@ -519,3 +535,206 @@ def test_a_set_up_reader_reads_the_record_and_none_without_it(name):
     assert read(setup_ctx(RECORD)) == float(RECORD[SETUP_METRICS[name]])
     assert read(setup_ctx(None)) is None  # the parent
     assert read(setup_ctx({k: v for k, v in RECORD.items() if k != SETUP_METRICS[name]})) is None
+
+
+# ------------------------------------------------------- granite-4.0-h-micro
+GRANITE_REF = _load(BENCH / "reference" / "mamba2_hybrid.py", "bench_reference_mamba2_hybrid_pins")
+
+
+class TestGraniteThroughTheSeam:
+    """benchmark/configs/granite-4_0-h-micro.json loaded the way run.py loads
+    it: `"architecture": "mamba2_hybrid"` selects arch/ and reference/,
+    `register` hands the program a config of its own type, and the arch
+    file's count of what a token needs is the config type's books."""
+
+    IN = 2048 * (4096 + 4352 + 64)        # 17.43 M: W_in [z | x B C | dt]
+    OUT = 4096 * 2048                     # 8.39 M: W_out
+    ATTN = 2 * 2048 * 2048 + 2 * 2048 * 512   # 10.49 M: W_q, W_o, W_k, W_v
+    MLP = 3 * 2048 * 8192                 # 50.33 M: [gate | up], down
+    STATE = 2 * 2 * 64 * 64 * 128         # x B^T and S C a head, 2 FLOPs a multiply-add
+
+    @pytest.fixture(scope="class")
+    def loaded(self):
+        from harness import seam
+
+        conf = seam.load_config(BENCH / "configs" / "granite-4_0-h-micro.json")
+        return conf, seam.program(conf), seam.reference(conf)
+
+    def test_the_file_selects_its_architecture_and_registers_its_own_config_type(self, loaded):
+        from k8s_llm_scheduler_tpu.models import family, mamba2_hybrid
+        from k8s_llm_scheduler_tpu.models.configs import Mamba2HybridConfig, get_config
+
+        conf, arch, ref = loaded
+        assert arch.__file__.endswith("arch/mamba2_hybrid.py")
+        assert ref.__file__.endswith("reference/mamba2_hybrid.py")
+        cfg = get_config(arch.register(conf))
+        assert isinstance(cfg, Mamba2HybridConfig) and family(cfg) is mamba2_hybrid
+        assert (cfg.n_layers, cfg.period, cfg.attn_position, cfg.n_periods, cfg.n_ssm_layers) == (40, 10, 5, 4, 36)
+        assert (cfg.head_dim, cfg.ssm_inner, cfg.conv_width, cfg.d_ff) == (64, 4096, 4352, 8192)
+        assert mamba2_hybrid.cache_layers(cfg) == 4 and mamba2_hybrid.state_layers(cfg) == 4
+        members = mamba2_hybrid.state_shapes(cfg)  # a member a Mamba-2 position of the period
+        assert [m[0] for m in members] == [(64, 64, 128)] * 9 + [(3, 4352)] * 9
+        per_sequence = 4 * sum(4 * int(np.prod(m[0])) for m in members)
+        assert per_sequence == 77_377_536  # 77.4 MB of float32 a sequence; 619 MB a wave of 8 rows
+
+    def test_a_token_by_hand_is_the_arch_files_count_and_the_config_types_books(self, loaded):
+        from k8s_llm_scheduler_tpu.models.configs import get_config
+        from k8s_llm_scheduler_tpu.observability.profiler import matmul_flops_per_token
+
+        conf, arch, _ = loaded
+        by_hand = 2 * (36 * (self.IN + self.OUT) + 4 * self.ATTN + 40 * self.MLP) + 36 * self.STATE
+        assert by_hand == 6_045_040_640  # 6.05 GFLOP a token through 40 layers, 75 MFLOP of it state products
+        assert arch.flops_per_token(conf, with_head=False) == by_hand
+        assert arch.flops_per_token(conf, with_head=True) - by_hand == 2 * 2048 * 100_352
+        cfg = get_config(arch.register(conf))
+        assert matmul_flops_per_token(cfg) == arch.flops_per_token(conf, with_head=True)
+        assert (cfg.ssm_params(), cfg.attn_params()) == (self.IN + self.OUT, self.ATTN)
+        assert cfg.ssm_state_flops_per_token() == arch.ssm_state_flops_per_token(conf) == self.STATE
+        # the four layers that attend alone: 32 heads x 2 x 2 x 64 a key
+        assert arch.attention_flops(conf, 1, 1) == cfg.attn_flops_per_key() == 4 * 32 * 4 * 64
+
+    def test_a_scan_call_reads_and_writes_each_rows_state_once(self, loaded):
+        conf, arch, _ = loaded
+        state = 64 * 64 * 128 * 4
+        flops, moved = arch.ssd_kernel_cost(8, 2.5, 24, conf)   # a decode call: 8 rows, ~2.5 valid positions
+        assert moved == 8 * 2 * state + 20 * (2 * 64 * 64 + 2 * 128 + 3 * 64) * 4
+        assert flops == 20 * (64 * (4.0 * 64 * 128 + 24 * 64) + 24 * 128)
+        assert moved / 819e9 > 10 * flops / 197e12   # byte-bound in decode
+        _, empty = arch.ssd_kernel_cost(8, 0, 24, conf)   # a call with no valid position still moves the state
+        assert empty == 8 * 2 * state
+
+    def test_the_configuration_file_holds_the_published_row(self, loaded):
+        """Every number of the catalog row under its own key; nothing cut:
+        all 40 layers, the whole vocabulary, every width."""
+        conf, _, _ = loaded
+        published = {
+            "attention_bias": False, "attention_multiplier": 0.015625, "embedding_multiplier": 12,
+            "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 8192, "logits_scaling": 8,
+            "mamba_chunk_size": 256, "mamba_conv_bias": True, "mamba_d_conv": 4, "mamba_d_head": 64,
+            "mamba_d_state": 128, "mamba_expand": 2, "mamba_n_groups": 1, "mamba_n_heads": 64,
+            "mamba_proj_bias": False, "max_position_embeddings": 131072, "model_type": "granitemoehybrid",
+            "normalization_function": "rmsnorm", "num_attention_heads": 32, "num_experts_per_tok": 0,
+            "num_hidden_layers": 40, "num_key_value_heads": 8, "num_local_experts": 0,
+            "position_embedding_type": "nope", "residual_multiplier": 0.22, "rms_norm_eps": 1e-05,
+            "rope_scaling": None, "rope_theta": 10000, "shared_intermediate_size": 8192,
+            "tie_word_embeddings": True, "vocab_size": 100352,
+        }
+        assert {k: conf[k] for k in published} == published
+        assert [i for i, t in enumerate(conf["layer_types"]) if t == "attention"] == [5, 15, 25, 35]
+        assert len(conf["layer_types"]) == 40 and conf["reduced"] == []
+        entry = next(c for c in json.loads((BENCH.parent / "BENCHMARK.json").read_text())["configs"]
+                     if c["name"] == conf["name"])
+        assert entry["reduced"] == [] and entry["source"] == conf["source"]
+        mamba = self.IN + self.OUT + 4 * 4352 + 4352 + 3 * 64 + 4096 + self.MLP + 2 * 2048
+        attn = self.ATTN + self.MLP + 2 * 2048
+        assert conf["parameters"] == 36 * mamba + 4 * attn + 100_352 * 2048 + 2048 == 3_191_396_096
+
+    def test_the_reference_imports_nothing_of_the_program_or_the_harness(self):
+        text = (BENCH / "reference" / "mamba2_hybrid.py").read_text()
+        imports = [ln for ln in text.splitlines() if ln.startswith(("import ", "from "))]
+        assert imports == ["from __future__ import annotations", "import functools", "import jax",
+                           "import jax.numpy as jnp", "import numpy as np"]
+        assert "lax.scan(step, s0" in text and "pallas" not in text  # the recurrence, not the chunked form
+
+    def test_the_cell_is_listed_where_its_readers_find_something(self):
+        bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+        cell = GRANITE_CELL
+        assert bench["workloads"][-1] == {**bench["workloads"][-1], "name": cell, "config": "granite-4_0-h-micro",
+                                          "traffic": "backlog20_pool80", "chips": 1}
+        assert bench["configs"][-1]["name"] == "granite-4_0-h-micro"
+        listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
+        assert set(GRANITE_METRICS) <= listed and [m["name"] for m in bench["per_layer"][-3:]] == GRANITE_METRICS
+        for m in bench["per_layer"][-3:]:
+            assert m["workloads"] == [cell] and m["moves"] == "binds_per_s"
+        assert {"full_attn_device_ms_per_bind.tput", "state_carry_device_ms_per_bind.tput",
+                "state_valid_share.tput", "prefix_attn_roofline.tput", "model_mfu.tput", *SETUP_METRICS} <= listed
+        # readers of another family's scopes or counters
+        assert not listed & {"gdn_device_ms_per_bind.tput", "gdn_scan_device_ms_per_bind.tput",
+                             "moe_bounded_share.tput", "experts_hit_per_layer_call.tput",
+                             "moe_experts_device_ms_per_bind.tput", "mla_proj_device_ms_per_bind.tput",
+                             "dense_ffn_device_ms_per_bind.tput", "moe_grouped_swiglu_roofline.tput"}
+        for m in bench["per_layer"]:
+            if cell in m["workloads"]:
+                assert m["workloads"][-1] == cell  # appended, nothing moved
+        assert next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"][-1] == cell
+
+
+def test_mamba2_reference_runs_in_both_modes_and_int8_differs():
+    """reference/mamba2_hybrid.py at a toy size: `f32` and the `int8`
+    control see the same wave and give different logits, both finite; a
+    tail is seeded from the prefix's state and sees the prefix and itself
+    alone; the prefix's padding is not there."""
+    toy = {
+        "hidden_size": 64, "layer_types": ["mamba", "attention", "mamba"], "num_attention_heads": 4,
+        "num_key_value_heads": 2, "shared_intermediate_size": 96, "mamba_n_heads": 8, "mamba_d_head": 16,
+        "mamba_d_state": 32, "mamba_d_conv": 4, "vocab_size": 512, "rms_norm_eps": 1e-5,
+        "embedding_multiplier": 12, "residual_multiplier": 0.22, "attention_multiplier": 1 / 16,
+        "logits_scaling": 8,
+    }
+    weights = GRANITE_REF.init_weights(toy, 3)
+    assert weights["ssm"]["w_in"].shape == (2, 64, 128 + 192 + 8) and weights["attn"]["wq"].shape == (1, 64, 64)
+    assert weights["layers"]["w_in"].shape == (3, 64, 192) and "lm_head" not in weights  # the table is tied
+    rng = np.random.default_rng(5)
+    prefix = rng.integers(1, 500, 40).tolist()
+    tails = [rng.integers(1, 500, n).tolist() for n in (12, 9)]
+    spans = [(7, 5), (5, 4)]
+    f32 = GRANITE_REF.wave_logits(toy, weights, prefix, tails, spans, "f32", 300)
+    low = GRANITE_REF.wave_logits(toy, weights, prefix, tails, spans, "int8", 300)
+    assert f32.shape == low.shape == (9, 300)
+    assert np.isfinite(f32).all() and np.isfinite(low).all()
+    assert float(np.max(np.abs(f32 - low))) > 1e-3
+    assert float(np.mean(np.abs(f32 - low))) < 0.25 * float(np.std(f32))
+    alone = GRANITE_REF.wave_logits(toy, weights, prefix, tails[:1], spans[:1], "f32", 300)
+    np.testing.assert_allclose(alone, f32[:5], rtol=1e-4, atol=1e-5)
+    # a shorter prefix is another state and another answer (the tail is seeded from it): 100 x the
+    # tolerance above (each mixer's output joins the stream times residual_multiplier 0.22)
+    shorter = GRANITE_REF.wave_logits(toy, weights, prefix[:-1], tails[:1], spans[:1], "f32", 300)
+    assert float(np.max(np.abs(shorter - alone))) > 1e-3
+
+
+@pytest.mark.parametrize("name", GRANITE_METRICS)
+def test_the_granite_readers_read_none_without_a_trace(name):
+    """A run without a trace, or a program without the counters (a parent),
+    reads None and raises nothing."""
+    import run as bench_run
+    from types import SimpleNamespace
+
+    engine = {"waves": 3}
+    snap = {"sched": {"client": {"engine": engine}}, "compiles": {"programs": 40}}
+    ctx = bench_run.Ctx(outcome=SimpleNamespace(before=snap, after=snap, trace_span=None), profile=None,
+                        xplane_path=None, conf={"architecture": "mamba2_hybrid"})
+    assert bench_run.reader_for(name)(ctx) is None
+
+
+def test_the_roofline_reader_reads_the_kernels_events_inside_wave_runs():
+    """`ssd_chunk_scan_roofline.tput` on a hand-made trace: two kernel events
+    inside a `jit_wave` run (a decode call [8, 64, 1, 64, 24] and a suffix
+    call [8, 64, 2, 64, 64]: Y as the kernel leaves it, [rows, heads, chunks,
+    head width, chunk]) and one of a prefix prefill outside any, which
+    is not counted; least time by `ssd_kernel_cost` at the window's valid
+    share, over the two events' device time."""
+    import run as bench_run
+    from types import SimpleNamespace
+
+    from harness import seam
+
+    conf = seam.load_config(BENCH / "configs" / "granite-4_0-h-micro.json")
+    ev = lambda name, start, dur: SimpleNamespace(name=name, start_ns=start, duration_ns=dur)  # noqa: E731
+    ops = [ev("%ssd_chunk_scan.3 = (f32[8,64,1,64,24]{4,3,2,1,0}, f32[4,8,64,64,128]) custom-call(...)", 100, 200_000),
+           ev("%ssd_chunk_scan.1 = (f32[8,64,2,64,64]{4,3,2,1,0}, f32[4,8,64,64,128]) custom-call(...)", 300_000,
+              500_000),
+           ev("%ssd_chunk_scan.9 = (f32[1,64,32,64,64]{4,3,2,1,0}, f32[4,1,64,64,128]) custom-call(...)",
+              5_000_000, 900_000)]
+    runs = [ev("jit_wave(123)", 0, 1_000_000), ev("jit_prefix_prefill_kv(9)", 4_000_000, 2_000_000)]
+    profile = SimpleNamespace(planes=[SimpleNamespace(name="/device:TPU:0", lines=[
+        SimpleNamespace(name="XLA Ops", events=ops), SimpleNamespace(name="XLA Modules", events=runs)])])
+    before = {"sched": {"client": {"engine": {"state_tokens_valid": 0, "state_tokens_computed": 0}}}}
+    after = {"sched": {"client": {"engine": {"state_tokens_valid": 200, "state_tokens_computed": 1000}}}}
+    peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    ctx = bench_run.Ctx(outcome=SimpleNamespace(before=before, after=after), profile=profile, conf=conf, peaks=peaks)
+    arch = seam.program(conf)
+    least = sum(max(f / 197e12, b / 819e9) for f, b in (
+        arch.ssd_kernel_cost(8, 0.2 * 24, 24, conf), arch.ssd_kernel_cost(8, 0.2 * 128, 64, conf)))
+    got = bench_run.reader_for("ssd_chunk_scan_roofline.tput")(ctx)
+    assert got == pytest.approx(100.0 * least / 700e-6)
+    assert 0 < got <= 100

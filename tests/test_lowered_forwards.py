@@ -1,5 +1,8 @@
-"""The three families that carry no per-sequence state lower to the text
-they lowered to before the engine learnt of one (PR 37).
+"""The families the benchmark runs lower to the text their parent lowered
+to: the three that carry no per-sequence state as before the engine learnt
+of one, and the delta-rule hybrid (`tiny-gdn-moe`) as before a
+second state-space family shared its layer scan's pattern and the
+convolution's window (models/mamba2_hybrid.py).
 
 A family that declares `state_shapes(cfg) == ()` must cost nothing: the
 engine's wave program and the family's three forwards are traced through
@@ -25,7 +28,8 @@ import numpy as np
 import pytest
 
 FIXTURE = Path(__file__).resolve().parent / "fixtures" / "lowered_forwards.json"
-PRESETS = ("tiny", "tiny-mla-moe", "tiny-mla-scmoe")
+STATELESS = ("tiny", "tiny-mla-moe", "tiny-mla-scmoe")
+PRESETS = (*STATELESS, "tiny-gdn-moe")
 R, SS, SP, F, CAP = 4, 128, 256, 24, 48
 
 
@@ -44,15 +48,21 @@ def _texts(name: str) -> dict[str, str]:
     def cache(*lead):
         return tuple(jax.ShapeDtypeStruct((layers, *lead, *s), cfg.dtype) for s in shapes)
 
+    def state(*lead):  # a family without a state is called as before: no keyword at all
+        members = model.state_shapes(cfg)
+        if not members:
+            return {}
+        return {"state": tuple(jax.ShapeDtypeStruct((model.state_layers(cfg), *lead, *s), d) for s, d in members)}
+
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     out = {}
     out["prefill_kv"] = jax.jit(model.forward_prefill_kv, static_argnums=(1,)).lower(
         params, cfg, i32(1, SP), i32(1)).as_text()
     out["suffix_dense"] = jax.jit(model.forward_prefill_suffix_dense, static_argnums=(1,)).lower(
-        params, cfg, i32(R, SS), i32(R), *cache(SP), i32()).as_text()
+        params, cfg, i32(R, SS), i32(R), *cache(SP), i32(), **state()).as_text()
     out["block_decode"] = jax.jit(model.forward_block_decode, static_argnums=(1,)).lower(
         params, cfg, i32(R, F), jax.ShapeDtypeStruct((R, F), jnp.bool_), i32(R), i32(R, F),
-        *cache(R, SS), i32(R), *cache(R, CAP + F), i32(R), *cache(SP), i32()).as_text()
+        *cache(R, SS), i32(R), *cache(R, CAP + F), i32(R), *cache(SP), i32(), **state(R)).as_text()
 
     real = jax.jit(lambda k: model.init_params(k, cfg))(jax.random.PRNGKey(0))
     eng = InferenceEngine(real, cfg, num_pages=8, page_size=64, max_slots=R, max_pages_per_seq=8)
@@ -64,7 +74,7 @@ def _texts(name: str) -> dict[str, str]:
         eng._sp_tokens, eng._sp_next, eng._forced, eng._forced_next, eng._done_state,
         jnp.int32(eng.tokenizer.eos_id), jnp.int32(eng.tokenizer.pad_id), jnp.int32(0),
         jax.random.PRNGKey(0), jnp.float32(0.0),
-        n_iters, 1, n_iters, False,
+        n_iters, 1, n_iters, False, **eng._prefix_state_kw(prefix),
     ).as_text()
     return out
 
@@ -74,19 +84,26 @@ def digests(name: str) -> dict[str, str]:
 
 
 @pytest.mark.parametrize("name", PRESETS)
-def test_a_family_without_state_lowers_as_the_parent_did(name):
+def test_a_family_lowers_as_the_parent_did(name):
     recorded = json.loads(FIXTURE.read_text())
     assert recorded["jax"] == jax.__version__, "recorded under another jax: record again"
     assert digests(name) == recorded["digests"][name]
 
 
-@pytest.mark.parametrize("name", PRESETS)
+@pytest.mark.parametrize("name", STATELESS)
 def test_a_family_without_state_runs_nothing_of_the_delta_rules_kernel(name):
     """ops/gdn_scan.py's kernel (PR 38) serves models/gdn_moe.py alone: the
     three forwards and the wave program of the other families do not hold
     it, so the cells that run them run their parent's programs."""
     for program, text in _texts(name).items():
         assert "gdn_chunk_scan" not in text and "gdn_scan" not in text, program
+
+
+@pytest.mark.parametrize("name", PRESETS)
+def test_no_family_here_runs_the_mamba2_scan(name):
+    """ops/ssd_scan.py's kernel serves models/mamba2_hybrid.py alone."""
+    for program, text in _texts(name).items():
+        assert "ssd_chunk_scan" not in text and "ssm_scan" not in text, program
 
 
 if __name__ == "__main__" and "--write" in sys.argv:

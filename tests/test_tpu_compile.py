@@ -113,3 +113,33 @@ def test_the_chunked_delta_rule_compiles_for_the_chip(one_chip, no_compile_cache
     assert "tpu_custom_call" in text and "gdn_chunk_scan" in text
     # the state goes into the kernel and comes out of it where it lies: no copy of a member, none of an entry
     assert not re.search(r"f32\[(3,)?%d,32,128,128\]\S* copy" % rows, text)
+
+
+@pytest.mark.parametrize("rows, positions, chunk", [(8, 24, 24), (8, 128, 64), (1, 2048, 64)])
+def test_the_chunked_mamba2_scan_compiles_for_the_chip(one_chip, no_compile_cache, monkeypatch, rows, positions,
+                                                      chunk):
+    """models/mamba2_hybrid.py `ssd_chunks` at the published sizes (64 heads
+    of 64 x 128, float32; B and C of 128 shared by the heads) on a member of
+    four periods: a decode block as one chunk, a suffix call as two, a
+    prefix prefill as 32 for one row. The whole chunk is ops/ssd_scan.py's
+    kernel, compiled by Mosaic; the member is donated, as the layer scan's
+    carry hands it over."""
+    from k8s_llm_scheduler_tpu.models import mamba2_hybrid
+    from k8s_llm_scheduler_tpu.ops import ssd_scan
+
+    monkeypatch.setattr(ssd_scan, "pallas_interpret", lambda interpret=None: False)
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, lens, s, p: mamba2_hybrid.ssd_chunks(x, dt, a, b, c, lens, s, p, chunk),
+        donate_argnums=(6,),
+    ).lower(shape(rows, 64, positions, 64), shape(rows, 64, positions), shape(64), shape(rows, positions, 128),
+            shape(rows, positions, 128), shape(rows, dtype=jnp.int32), shape(4, rows, 64, 64, 128),
+            shape(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert "tpu_custom_call" in text and "ssd_chunk_scan" in text
+    # the state goes into the kernel and comes out of it where it lies: no copy of a member, none of an entry
+    assert not re.search(r"f32\[(4,)?%d,64,64,128\]\S* copy" % rows, text)
