@@ -59,6 +59,7 @@ forwards return one more value, the expert-load counters COUNTERS names.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -73,6 +74,7 @@ from k8s_llm_scheduler_tpu.models.llama import (
 )
 from k8s_llm_scheduler_tpu.ops.attention import NEG_INF, merge_attention_parts, write_block
 from k8s_llm_scheduler_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
+from k8s_llm_scheduler_tpu.ops.router_top_k import router_top_k
 
 Params = dict[str, Any]
 
@@ -210,6 +212,65 @@ BOUND_COUNTERS = ("moe_bounded_calls",)
 # same: it takes every row.
 HELD_SLACK = 2
 
+# Rows a block of `group_positions`' rank product: within a block, the rows of
+# each group before a row are one strictly-lower-triangular product on the
+# MXU (the systolic array's width), exact in float32 for 0/1 operands.
+RANK_BLOCK = 128
+# The longest head `order_head` finds by comparing. Up to it, each place of
+# the head compares every row's position with its own and sums the one row
+# that matches; past it, the rows are scattered to their places. On a TPU v5e
+# the compare cost ~1.1-1.7 ns a thousand (place, row) pairs and the scatter
+# ~4.6 ns a row (a decode call's head of 1,024 of 1,920 rows: ~3 us against
+# ~14; 512 of 12,288: ~7 against ~60), so the compare is the faster up to a
+# head of ~3,000 whatever the rows (PERF.md §6).
+COMPARE_HEAD_ROWS = 2048
+
+
+# group_positions and order_head are jitted, as router_top_k is: a process
+# traces and lowers each once a shape, not once a layer of each program.
+@functools.partial(jax.jit, static_argnums=1)
+def group_positions(group: jax.Array, n_held: int) -> tuple[jax.Array, jax.Array]:
+    """(sizes [n_held] int32, position [n] int32) of the group ids [n] in
+    0 .. n_held, where `n_held` is no group: how many rows each group holds
+    (the scatter-add's count), and where each row lands in
+    `jnp.argsort(group, stable=True)` (row i at position[i], so position is
+    that order's inverse). A stable counting sort: a group's rows follow
+    the groups before it in their own order, rows of no group follow every
+    group. The rows of each group before a row are a prefix count, one
+    triangular product a block of RANK_BLOCK rows plus the blocks before."""
+    n = group.shape[0]
+    pad = -n % RANK_BLOCK
+    g = jnp.pad(group, (0, pad), constant_values=n_held)
+    blocks = (g[:, None] == jnp.arange(n_held, dtype=g.dtype)[None, :]).reshape(-1, RANK_BLOCK, n_held)
+    rows = jnp.arange(RANK_BLOCK)
+    earlier = (rows[:, None] > rows[None, :]).astype(jnp.bfloat16)
+    within = jnp.einsum("ij,bjg->big", earlier, blocks.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32).astype(jnp.int32)
+    per_block = jnp.sum(blocks, axis=1, dtype=jnp.int32)  # [blocks, n_held]
+    before = (within + (jnp.cumsum(per_block, axis=0) - per_block)[:, None, :]).reshape(-1, n_held)[:n]
+    sizes = jnp.sum(per_block, axis=0)
+    starts = jnp.cumsum(sizes) - sizes
+    held = group < n_held
+    mine = jnp.sum(jnp.where(blocks.reshape(-1, n_held)[:n], before + starts, 0), axis=1)
+    # a row of no group: every held row, then the rows of no group before it
+    none = jnp.sum(sizes) + jnp.arange(n, dtype=jnp.int32) - jnp.sum(before, axis=1)
+    return sizes, jnp.where(held, mine, none)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def order_head(position: jax.Array, m: int) -> jax.Array:
+    """The first `m` entries [m] of the order whose inverse is the
+    permutation `position` [n] (row i at position[i]): the row at each
+    place under m, found by comparing every row's position with it, or,
+    for a head longer than COMPARE_HEAD_ROWS, each row with a position
+    under m written to it and the rest dropped."""
+    n = position.shape[0]
+    rows = jnp.arange(n, dtype=jnp.int32)
+    if m <= COMPARE_HEAD_ROWS:
+        here = position[None, :] == jnp.arange(m, dtype=jnp.int32)[:, None]
+        return jnp.sum(jnp.where(here, rows[None, :], 0), axis=1, dtype=jnp.int32)
+    return jnp.zeros((m,), jnp.int32).at[position].set(rows, mode="drop", unique_indices=True)
+
 
 def route(lp: Params, cfg, h: jax.Array, sel=None) -> tuple[jax.Array, jax.Array]:
     """(selected router outputs [T, k] int32, their weights [T, k] f32) of
@@ -226,9 +287,9 @@ def route(lp: Params, cfg, h: jax.Array, sel=None) -> tuple[jax.Array, jax.Array
     scores = SCORES[cfg.router_score](logits)
     if sel is None:
         # a router without a selection bias (models/gdn_moe.py) holds no such leaf
-        bias = lp.get("router_bias")
-        _, sel = jax.lax.top_k(scores if bias is None else scores + bias, cfg.n_experts_per_tok)
-    w = jnp.take_along_axis(scores, sel, axis=1)
+        sel, w = router_top_k(scores, lp.get("router_bias"), cfg.n_experts_per_tok)
+    else:
+        w = jnp.take_along_axis(scores, sel, axis=1)
     if cfg.norm_topk_prob:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
     return sel.astype(jnp.int32), w * cfg.routed_scaling_factor
@@ -286,8 +347,7 @@ def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax
         held = valid[:, None] & (local >= 0) & (local < held_n)
         # not held -> group `held_n`, which sorts behind every expert
         group = jnp.where(held, local, held_n).reshape(T * k)
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.zeros((held_n + 1,), jnp.int32).at[group].add(1)[:held_n]
+        sizes, position = group_positions(group, held_n)
 
     def experts(head):
         """f32 [M, D]: the grouped experts' output for the assignments
@@ -303,15 +363,17 @@ def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax
             return grouped_matmul(mid, (down,), sizes, layer, out_dtype=jnp.float32)
 
     def every_row():
+        with jax.named_scope("moe_dispatch"):
+            order = order_head(position, T * k)
         out = experts(order)
         with jax.named_scope("moe_combine"):
             # rows of no group were never written: select, never scale
-            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
-            back = out[inverse].reshape(T, k, D)
+            back = out[position].reshape(T, k, D)
             return jnp.sum(jnp.where(held[..., None], back * w[..., None], 0.0), axis=1)
 
     def held_rows():
-        head = order[:bound]
+        with jax.named_scope("moe_dispatch"):
+            head = order_head(position, bound)
         out = experts(head)
         with jax.named_scope("moe_combine"):
             live = jnp.arange(bound)[:, None] < jnp.sum(sizes)

@@ -429,11 +429,12 @@ def test_four_shares_of_four_experts_add_up_to_the_uncut_layer():
 
 # ------------------------------------------------------- the share's short path
 def routed_experts_every_row(lp, cfg, h, valid):
-    """`models/mla_moe.py` `routed_experts` as it stood before a share had a
-    short path (PR 34's, COUNTERS and the identity experts' part alone): every
-    token x pick row sorted, gathered, multiplied and un-sorted. Kept here as
-    what the short path has to equal, and as the text a layer that holds
-    every output still has to lower to."""
+    """`models/mla_moe.py` `routed_experts` without a short path (COUNTERS and
+    the identity experts' part alone): every token x pick row ordered by
+    expert, gathered, multiplied and put back. Kept here as what the short
+    path has to equal, and as the text a layer that holds every output
+    still has to lower to. (The order as a stable sort, as it stood before
+    the counting sort, is tests/test_routed_order.py's.)"""
     T, D = h.shape
     k, held_n = cfg.n_experts_per_tok, cfg.experts_held
     with jax.named_scope("moe_router"):
@@ -442,8 +443,9 @@ def routed_experts_every_row(lp, cfg, h, valid):
         local = sel - cfg.expert_first
         held = valid[:, None] & (local >= 0) & (local < held_n)
         group = jnp.where(held, local, held_n).reshape(T * k)
-        order = jnp.argsort(group, stable=True)
-        sizes = jnp.zeros((held_n + 1,), jnp.int32).at[group].add(1)[:held_n]
+        sizes, position = mla_moe.group_positions(group, held_n)
+    with jax.named_scope("moe_dispatch"):
+        order = mla_moe.order_head(position, T * k)
         rows = h.astype(lp["we_gate"].dtype)[order // k]
     with jax.named_scope("moe_experts"):
         gate, up, down = (
@@ -453,8 +455,7 @@ def routed_experts_every_row(lp, cfg, h, valid):
         mid = mla_moe.grouped_matmul(rows, (gate, up), sizes, layer, swiglu=True)
         out = mla_moe.grouped_matmul(mid, (down,), sizes, layer, out_dtype=jnp.float32)
     with jax.named_scope("moe_combine"):
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(T * k, dtype=order.dtype))
-        back = out[inverse].reshape(T, k, D)
+        back = out[position].reshape(T, k, D)
         y = jnp.sum(jnp.where(held[..., None], back * w[..., None], 0.0), axis=1)
     counters = jnp.stack([
         jnp.sum(held), jnp.sum(sizes > 0), jnp.int32(1), jnp.max(sizes),

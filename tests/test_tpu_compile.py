@@ -143,3 +143,57 @@ def test_the_chunked_mamba2_scan_compiles_for_the_chip(one_chip, no_compile_cach
     assert "tpu_custom_call" in text and "ssd_chunk_scan" in text
     # the state goes into the kernel and comes out of it where it lies: no copy of a member, none of an entry
     assert not re.search(r"f32\[(4,)?%d,64,64,128\]\S* copy" % rows, text)
+
+
+# The routers the sparse cells run: (router outputs, picks, a selection bias)
+# of glm-4_7-flash, longcat-flash-chat (512 experts and 256 identity ones) and
+# qwen3-next-80b-a3b
+ROUTERS = [(64, 4, True), (768, 12, True), (512, 10, False)]
+
+
+@pytest.mark.parametrize("rows", [192, 1024, 2048])  # a decode call, a suffix call, a prefix prefill
+@pytest.mark.parametrize("outputs, k, biased", ROUTERS)
+def test_the_router_top_k_compiles_for_the_chip(one_chip, no_compile_cache, rows, outputs, k, biased):
+    from k8s_llm_scheduler_tpu.ops.router_top_k import router_top_k
+
+    scores = jax.ShapeDtypeStruct((rows, outputs), jnp.float32, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((outputs,), jnp.float32, sharding=one_chip) if biased else None
+    compiled = jax.jit(lambda s, b: router_top_k(s, b, k, interpret=False)).lower(scores, bias).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "router_top_k" in text and not re.search(r"\bsort\(", text)
+
+
+# (router outputs, picks, bias, score, experts held, identity experts, model width, expert width)
+LAYERS = [(64, 4, True, "sigmoid", 64, None, 2048, 1536), (768, 12, True, "softmax", 16, 256, 6144, 2048),
+          (512, 10, False, "softmax", 128, None, 2048, 512)]
+
+
+@pytest.mark.parametrize("outputs, k, biased, score, held, zero, d, fe", LAYERS)
+def test_the_routed_layer_sorts_nothing_on_the_chip(one_chip, no_compile_cache, monkeypatch, outputs, k, biased,
+                                                    score, held, zero, d, fe):
+    """models/mla_moe.py `routed_experts` at a decode call's 192 tokens and
+    each sparse cell's widths, compiled as the chip does: the top k is the
+    kernel, and neither it nor the order by expert lowers to a sort."""
+    import types
+
+    from k8s_llm_scheduler_tpu.models import mla_moe
+    from k8s_llm_scheduler_tpu.ops import router_top_k as rtk
+
+    monkeypatch.setattr(gm, "pallas_interpret", lambda interpret=None: False)
+    monkeypatch.setattr(rtk, "pallas_interpret", lambda interpret=None: False)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cfg = types.SimpleNamespace(n_experts_per_tok=k, experts_held=held, expert_first=0, router_score=score,
+                                norm_topk_prob=True, routed_scaling_factor=1.0, n_zero_experts=zero,
+                                n_routed_experts=outputs - (zero or 0))
+    lp = {"router": shape(d, outputs), "we_gate": shape(1, held, d, fe), "we_up": shape(1, held, d, fe),
+          "we_down": shape(1, held, fe, d), "layer": shape(dtype=jnp.int32)}
+    if biased:
+        lp["router_bias"] = shape(outputs, dtype=jnp.float32)
+    compiled = jax.jit(lambda lp_, h, v: mla_moe.routed_experts(lp_, cfg, h, v)).lower(
+        lp, shape(192, d, dtype=jnp.float32), shape(192, dtype=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"\bsort\(", text)
+    assert "router_top_k" in text
