@@ -518,6 +518,24 @@ def _wave_impl(
     return out, carry[2], iters_run
 
 
+def _lcp_seed_impl(bufs, seed_kv, reuse, shardings=None):
+    """Each buffer of `bufs` ([L, cap, *token shape], zeros) with its first
+    `reuse` tokens copied from the same member of `seed_kv` (a cached
+    prefix's buffers, of their own length), under scope `lcp_seed`: a
+    masked copy of fixed shape, `reuse` a traced scalar. On a tp mesh the
+    buffers keep the prefix's head-sharded layout (`shardings.kv4`)."""
+    if shardings is not None:
+        bufs = tuple(shardings.kv4(a) for a in bufs)
+    out = []
+    with jax.named_scope("lcp_seed"):
+        for buf, old in zip(bufs, seed_kv):
+            n = min(buf.shape[1], old.shape[1])
+            keep = (jnp.arange(n) < reuse).reshape(1, n, *(1,) * (buf.ndim - 2))
+            head = jnp.where(keep, old[:, :n].astype(buf.dtype), buf[:, :n])
+            out.append(jax.lax.dynamic_update_slice_in_dim(buf, head, 0, axis=1))
+    return tuple(out)
+
+
 @dataclasses.dataclass
 class _PrefixKV:
     """The model's cache of a burst-shared prompt prefix, prefilled once:
@@ -700,11 +718,11 @@ class InferenceEngine:
         tp_size = mesh.shape.get("tp", 1) if mesh is not None else 1
         if not self.paged:
             if tp_size > 1:
-                what = (
+                what = getattr(self._model, "UNSHARDED", (
                     "a per-sequence state has no sharding rule"
                     if self._stateful
                     else "a latent cache has no head axis to shard"
-                )
+                ))
                 raise ValueError(
                     f"{cfg.name}: llm.mesh tp={tp_size} is not served — "
                     f"{what} and the experts' "
@@ -881,6 +899,15 @@ class InferenceEngine:
             ),
             static_argnums=(1, 17, 18, 19, 20),
         )
+        # The LCP seed of a chunked prefix prefill: the first `reuse` tokens'
+        # cache copied from the seeding entry into the new buffers, `reuse`
+        # traced (one program for each pair of buffer lengths, not one for
+        # each reuse length; _warm_lcp_seed).
+        self._lcp_seed = jax.jit(
+            named_program(_lcp_seed_impl, program="lcp_seed", shardings=shardings),
+            donate_argnums=(0,),
+        )
+        self._lcp_caps: set[int] = set()
         # Chunked long-prefix prefill reuses the dense cascade directly.
         self._suffix_dense = jax.jit(
             named_program(
@@ -1418,19 +1445,10 @@ class InferenceEngine:
         done = 0 if seed is None else seed[1]
         pad = self.tokenizer.pad_id
         bufs = self._prefix_buffers(cap)
+        self._warm_lcp_seed(cap)
         if seed is not None:
             seed_kv, reuse = seed
-            # eager ops: each distinct `reuse` is its own small XLA program,
-            # and the scope is how a trace tells them from anything else
-            with jax.named_scope("lcp_seed"):
-                bufs = tuple(
-                    jax.lax.dynamic_update_slice_in_dim(
-                        buf,
-                        jax.lax.slice_in_dim(old, 0, reuse, axis=1).astype(buf.dtype),
-                        0, axis=1,
-                    )
-                    for buf, old in zip(bufs, seed_kv)
-                )
+            bufs = self._lcp_seed(bufs, seed_kv, jnp.int32(reuse))
             self.stats["prefix_reused_tokens"] = (
                 self.stats.get("prefix_reused_tokens", 0) + reuse
             )
@@ -1459,6 +1477,19 @@ class InferenceEngine:
             done += m
         return bufs, state
 
+    def _warm_lcp_seed(self, cap: int) -> None:
+        """The seed copy is one program for each pair of buffer lengths
+        (new, seeding entry's): the first time a chunked prefix buffer
+        `cap` long is made, run it once on zeros for `cap` against every
+        length seen before, both ways, so that no pair first meets a
+        seed mid-serving."""
+        if self._stateful or cap in self._lcp_caps:
+            return
+        self._lcp_caps.add(cap)
+        for other in sorted(self._lcp_caps):
+            for a, b in {(cap, other), (other, cap)}:
+                self._lcp_seed(self._prefix_buffers(a), self._prefix_buffers(b), jnp.int32(0))
+
     @property
     def prefix_len(self) -> int:
         return self._prefix.length if self._prefix else 0
@@ -1467,7 +1498,8 @@ class InferenceEngine:
         """Refuse, before anything is traced, an entry point that runs the
         paged pool for a model family that has no paged forwards."""
         if not self.paged:
-            what = "a per-sequence state" if self._stateful else "a latent cache"
+            what = getattr(self._model, "PAGED_MISSING",
+                           "a per-sequence state" if self._stateful else "a latent cache")
             raise ValueError(
                 f"{self.cfg.name}: {path} is not served — it runs the paged "
                 f"KV pool, and {self._model_file} brings the decision wave's "

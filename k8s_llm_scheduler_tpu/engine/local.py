@@ -1456,11 +1456,11 @@ def _refuse_unserved(cfg, *, multi, quantize, checkpoint_path, spec_enabled) -> 
     call: _require_paged.)"""
     if isinstance(cfg, LlamaConfig):
         return
-    unsharded = (
+    unsharded = getattr(family(cfg), "UNSHARDED", (
         "a per-sequence state has no sharding rule"
         if family(cfg).state_shapes(cfg)
         else "a latent cache has no head axis to shard"
-    )
+    ))
     asked = {
         f"llm.mesh with tp > 1 ({unsharded}; "
         "experts over chips and their exchange: parallel/sharding.py, "

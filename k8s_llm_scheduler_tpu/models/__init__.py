@@ -3,15 +3,18 @@
 (models/mla_moe.py), shortcut-connected double layers over latent
 attention with identity experts (models/mla_scmoe.py), gated-delta-rule
 linear attention, three layers to one of gated softmax attention, over
-sparse experts (models/gdn_moe.py) and Mamba-2 state-space mixers, nine
+sparse experts (models/gdn_moe.py), Mamba-2 state-space mixers, nine
 layers to one of softmax attention without position encoding, over dense
-SwiGLUs (models/mamba2_hybrid.py)."""
+SwiGLUs (models/mamba2_hybrid.py) and window attention, three layers to one
+of global attention without position encoding, each beside a sparse-expert
+feed-forward in a parallel block (models/cohere2_moe.py)."""
 
 from k8s_llm_scheduler_tpu.models.configs import (  # noqa: F401
     LLAMA_3_1_8B,
     LLAMA_3_2_1B,
     LLAMA_3_3_70B,
     TINY,
+    Cohere2MoeConfig,
     GdnMoeConfig,
     LlamaConfig,
     Mamba2HybridConfig,
@@ -37,6 +40,10 @@ def family(cfg):
     return it behind the cache (prefix prefill: after `seq_lens` tokens;
     suffix: each row's, seeded from the prefix's; block decode: advanced by
     `blk_len`)."""
+    if isinstance(cfg, Cohere2MoeConfig):
+        from k8s_llm_scheduler_tpu.models import cohere2_moe
+
+        return cohere2_moe
     if isinstance(cfg, Mamba2HybridConfig):
         from k8s_llm_scheduler_tpu.models import mamba2_hybrid
 

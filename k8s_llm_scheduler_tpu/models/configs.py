@@ -2,9 +2,10 @@
 attention sparse-expert family (`MlaMoeConfig`, models/mla_moe.py), the
 shortcut-connected double layer over it (`MlaScmoeConfig`,
 models/mla_scmoe.py), the gated-delta-rule / gated-attention hybrid over
-sparse experts (`GdnMoeConfig`, models/gdn_moe.py) and the Mamba-2 /
+sparse experts (`GdnMoeConfig`, models/gdn_moe.py), the Mamba-2 /
 attention hybrid with dense SwiGLUs (`Mamba2HybridConfig`,
-models/mamba2_hybrid.py).
+models/mamba2_hybrid.py) and window / global attention in parallel blocks
+over sparse experts (`Cohere2MoeConfig`, models/cohere2_moe.py).
 
 The reference consumes Llama-3.3-70B-Instruct behind the HuggingFace API
 (reference scheduler.py:425, config.yaml:8); the BASELINE ladder also names
@@ -629,6 +630,161 @@ class Mamba2HybridConfig:
         return 4.0 * self.n_attn_layers * self.n_heads * self.head_dim
 
 
+@dataclasses.dataclass(frozen=True)
+class Cohere2MoeConfig:
+    """Window and global attention in a fixed period, each layer a PARALLEL
+    block (attention and a sparse-expert feed-forward on one LayerNorm of
+    the stream), four averaged shared experts and a tied head: the
+    `cohere2_moe` layer (Command A+; models/cohere2_moe.py writes the
+    equations out). The layers in `global_layers` attend causally with no
+    position encoding; the others see the last `window` positions and are
+    rotated. `expert_first` / `expert_count`: the range of routed experts
+    held here, as in MlaMoeConfig (an expert-parallel share)."""
+
+    name: str
+    vocab_size: int
+    d_model: int
+    n_layers: int
+    global_layers: tuple[int, ...]  # the layers whose layer_types entry is "full_attention"
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    window: int                     # sliding_window: a window layer's query sees this many positions
+    d_ff_expert: int                # one routed or shared expert's width (intermediate_size)
+    n_routed_experts: int
+    n_shared_experts: int
+    n_experts_per_tok: int
+    norm_topk_prob: bool = True
+    logit_scale: float = 1.0
+    expert_first: int = 0
+    expert_count: int | None = None  # None: every routed expert
+    max_seq_len: int = 200000
+    rope_theta: float = 50000.0
+    norm_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.bfloat16
+    tie_embeddings: bool = True
+
+    # what `route`, `routed_experts` and `shared_experts` of models/mla_moe.py
+    # ask of a config: sigmoid scores without a selection bias, no identity
+    # experts, no routed scaling; the shared experts AVERAGED
+    # (shared_expert_combination_strategy "average")
+    router_score = "sigmoid"
+    n_zero_experts = None
+    routed_scaling_factor = 1.0
+
+    def __post_init__(self) -> None:
+        if not self.tie_embeddings:
+            raise ValueError(f"{self.name}: Cohere2MoeConfig serves a tied output head only")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.name}: query heads must be a multiple of KV heads")
+        if self.head_dim % 2:
+            raise ValueError(f"{self.name}: a rotated head must be even")
+        per = self.period
+        if not self.global_layers or self.n_layers % len(self.global_layers) or any(
+                i != p * per + self.global_position for p, i in enumerate(self.global_layers)):
+            raise ValueError(f"{self.name}: the global layers must repeat at one place of a whole period")
+        if not 0 < self.n_experts_per_tok <= self.n_routed_experts:
+            raise ValueError(f"{self.name}: n_experts_per_tok outside 1..n_routed_experts")
+        if self.expert_first < 0 or self.expert_first + self.experts_held > self.n_routed_experts:
+            raise ValueError(f"{self.name}: held expert range outside the routed experts")
+
+    @property
+    def period(self) -> int:
+        return self.n_layers // len(self.global_layers)
+
+    @property
+    def global_position(self) -> int:
+        """Where in its period a layer attends globally."""
+        return self.global_layers[0] % self.period
+
+    @property
+    def n_periods(self) -> int:
+        return len(self.global_layers)
+
+    @property
+    def n_window_layers(self) -> int:
+        return self.n_layers - len(self.global_layers)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_routed_experts if self.expert_count is None else self.expert_count
+
+    @property
+    def d_ff_shared(self) -> int:
+        """The shared experts as one SwiGLU: their widths side by side."""
+        return self.n_shared_experts * self.d_ff_expert
+
+    @property
+    def shared_scale(self) -> float:
+        """The shared experts' mean: their sum times 1 / n_shared_experts."""
+        return 1.0 / self.n_shared_experts
+
+    @classmethod
+    def from_hf(cls, name: str, conf: dict, **overrides) -> "Cohere2MoeConfig":
+        """From the published `config.json` keys (cohere2_moe). `layer_types`
+        may be the published list whole: its first `num_hidden_layers`
+        entries are read."""
+        refused = {
+            "shared_expert_combination_strategy other than average":
+                conf.get("shared_expert_combination_strategy", "average") != "average",
+            "use_qk_norm": conf.get("use_qk_norm", False),
+            "first_k_dense_replace > 0 (leading dense layers)": conf.get("first_k_dense_replace", 0) > 0,
+            "attention_bias": conf.get("attention_bias", False),
+            "rotary_pct other than 1": conf.get("rotary_pct", 1) != 1,
+            "use_parallel_block false": not conf.get("use_parallel_block", True),
+            "expert_selection_fn other than sigmoid": conf.get("expert_selection_fn", "sigmoid") != "sigmoid",
+            "use_gated_activation false": not conf.get("use_gated_activation", True),
+            "hidden_act other than silu": conf.get("hidden_act", "silu") != "silu",
+            "rope scaling": (conf.get("rope_scaling") is not None
+                             or conf.get("rope_parameters", {}).get("rope_type", "default") != "default"),
+        }
+        for what, on in refused.items():
+            if on:
+                raise ValueError(f"{name}: {what} is not served by models/cohere2_moe.py")
+        n = conf["num_hidden_layers"]
+        types = conf["layer_types"][:n]
+        if len(types) != n or set(types) - {"sliding_attention", "full_attention"}:
+            raise ValueError(f"{name}: layer_types must name every layer sliding_attention or full_attention")
+        kw = dict(
+            name=name, vocab_size=conf["vocab_size"], d_model=conf["hidden_size"], n_layers=n,
+            global_layers=tuple(i for i, t in enumerate(types) if t == "full_attention"),
+            n_heads=conf["num_attention_heads"], n_kv_heads=conf["num_key_value_heads"],
+            head_dim=conf["head_dim"], window=conf["sliding_window"],
+            d_ff_expert=conf["intermediate_size"], n_routed_experts=conf["num_experts"],
+            n_shared_experts=conf["num_shared_experts"], n_experts_per_tok=conf["num_experts_per_tok"],
+            norm_topk_prob=conf["norm_topk_prob"], logit_scale=float(conf["logit_scale"]),
+            max_seq_len=conf["max_position_embeddings"], rope_theta=float(conf["rope_theta"]),
+            norm_eps=conf["layer_norm_eps"], tie_embeddings=conf["tie_word_embeddings"],
+        )
+        kw.update(overrides)
+        return cls(**kw)
+
+    def attn_params(self) -> int:
+        d, hd = self.d_model, self.head_dim
+        return 2 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
+
+    def matmul_flops_per_token(self) -> float:
+        """Matmul FLOPs of one token AS THIS SHARE RUNS IT: every layer's
+        attention projections, the router over all its outputs, the shared
+        experts whole and, of the `n_experts_per_tok` picks, the part that
+        falls, under even routing, on the experts held here; the tied head."""
+        d = self.d_model
+        held_picks = self.n_experts_per_tok * self.experts_held / self.n_routed_experts
+        ffn = d * self.n_routed_experts + (held_picks * 3 * d * self.d_ff_expert + 3 * d * self.d_ff_shared)
+        return 2.0 * (self.n_layers * (self.attn_params() + ffn) + d * self.vocab_size)
+
+    def attn_flops_per_key(self) -> float:
+        """Score + value FLOPs of one token against one key it sees, all
+        layers (a context within the window: every layer sees every key)."""
+        return 4.0 * self.n_layers * self.n_heads * self.head_dim
+
+    def attn_flops_per_token(self, ctx: float) -> float:
+        """Score + value FLOPs of one token at context `ctx`: the global
+        layers see every key, a window layer `window` of them at most."""
+        per_key = 4.0 * self.n_heads * self.head_dim
+        return per_key * (len(self.global_layers) * ctx + self.n_window_layers * min(ctx, self.window))
+
+
 TINY = LlamaConfig(
     name="tiny",
     vocab_size=512,          # byte tokenizer fits in 512
@@ -799,14 +955,39 @@ TINY_MAMBA2_HYBRID = Mamba2HybridConfig(
     max_seq_len=2048,
 )
 
+# Toy of the window / global parallel-block family for the CPU tests: two
+# periods of three window layers and one global layer, a window of 32 (so
+# that the tests' prompts outrun it), a share of the routed experts (4 of
+# 16), four averaged shared experts, a logit scale, every width shrunk.
+TINY_COHERE2_MOE = Cohere2MoeConfig(
+    name="tiny-cohere2-moe",
+    vocab_size=512,
+    d_model=64,
+    n_layers=8,
+    global_layers=(3, 7),
+    n_heads=8,
+    n_kv_heads=2,
+    head_dim=16,
+    window=32,
+    d_ff_expert=32,
+    n_routed_experts=16,
+    n_shared_experts=4,
+    n_experts_per_tok=4,
+    expert_count=4,
+    logit_scale=0.5,
+    max_seq_len=2048,
+    rope_theta=10000.0,
+)
+
 _REGISTRY = {
     c.name: c
     for c in (TINY, SMALL, LLAMA_3_2_1B, LLAMA_3_1_8B, LLAMA_3_3_70B, TINY_MLA_MOE,
-              TINY_MLA_SCMOE, TINY_GDN_MOE, TINY_MAMBA2_HYBRID)
+              TINY_MLA_SCMOE, TINY_GDN_MOE, TINY_MAMBA2_HYBRID, TINY_COHERE2_MOE)
 }
 
 
-def get_config(name: str) -> LlamaConfig | MlaMoeConfig | MlaScmoeConfig | GdnMoeConfig | Mamba2HybridConfig:
+def get_config(name: str) -> (LlamaConfig | MlaMoeConfig | MlaScmoeConfig | GdnMoeConfig | Mamba2HybridConfig
+                              | Cohere2MoeConfig):
     key = name.lower()
     if key not in _REGISTRY:
         raise KeyError(f"unknown model config {name!r}; known: {sorted(_REGISTRY)}")

@@ -400,9 +400,13 @@ def routed_experts(lp: Params, cfg, h: jax.Array, valid: jax.Array) -> tuple[jax
     return y, jnp.concatenate([counters, *more]) if more else counters
 
 
-def shared_experts(lp: Params, h: jax.Array) -> jax.Array:
+def shared_experts(lp: Params, h: jax.Array, scale: float = 1.0) -> jax.Array:
+    """The shared experts as one SwiGLU of their widths side by side, times
+    `scale` in float32 (models/cohere2_moe.py averages its four: 1/4; the
+    other families add theirs whole, and their programs hold no product)."""
     with jax.named_scope("moe_shared"):
-        return _swiglu(h.astype(lp["ws_gate"].dtype), lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        y = _swiglu(h.astype(lp["ws_gate"].dtype), lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        return y if scale == 1.0 else y * scale
 
 
 @jax.named_scope("mlp")

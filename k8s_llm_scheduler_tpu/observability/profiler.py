@@ -151,7 +151,11 @@ def matmul_flops_per_token(cfg) -> float:
 
 
 def attn_flops_per_token(cfg, ctx: float) -> float:
-    """Attention score+value FLOPs for one token attending to `ctx` keys."""
+    """Attention score+value FLOPs for one token attending to `ctx` keys
+    (a config whose layers see a window of them counts it itself:
+    Cohere2MoeConfig.attn_flops_per_token)."""
+    if hasattr(cfg, "attn_flops_per_token"):
+        return cfg.attn_flops_per_token(ctx)
     return cfg.attn_flops_per_key() * ctx
 
 

@@ -97,7 +97,7 @@ def set_prefix_attn_impl(impl: str) -> None:
     PREFIX_ATTN_IMPL = impl
 
 
-def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None):
+def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None, window=None):
     """Flash partials (o, m, l) of queries vs the shared dense prefix.
 
     `q` is [B, S, n_heads, hd] post-RoPE (kernel layout); `qg` is the same
@@ -105,7 +105,13 @@ def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None):
     callers already have both, so the dispatch costs nothing. `impl`
     overrides the module default per call site (the engine plumbs its
     per-instance setting through; None falls back to PREFIX_ATTN_IMPL).
+
+    `window` = (size, key_lo [B, S] int32): each query sees the prefix keys
+    from key_lo[b, s] on alone, at most `size` positions back
+    (window_prefix_attend_parts). None: every valid key.
     """
+    if window is not None:
+        return window_prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl, *window)
     kind, mesh, axis, shards, record = _resolve_impl(impl)
     use_pallas = False
     if kind == "pallas" or (kind == "auto" and jax.default_backend() == "tpu"):
@@ -135,6 +141,53 @@ def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None):
     Sp = prefix_k.shape[0]
     pre_mask = (jnp.arange(Sp) < prefix_len)[None, None, None, None, :]
     return attend_part(qg, prefix_k, prefix_v, pre_mask, "bqkgh,skh->bkgqs")
+
+
+def window_prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl, size: int, key_lo):
+    """prefix_attend_parts for queries that each see the prefix keys
+    key_lo[b, s] <= j < prefix_len (a window of `size` positions ending at
+    the query, which lies behind the prefix): the Pallas kernel that visits
+    only the key blocks a window reaches (ops/pallas_prefix_attention.py
+    window_prefix_attention), or the einsum with the window in its mask."""
+    use_pallas, mesh, record = _window_uses_pallas(q.shape, prefix_k.shape, impl)
+    _note_resolved(record, "window_prefix", q.shape, prefix_k.shape[0], use_pallas, mesh)
+    if use_pallas:
+        from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
+            window_prefix_attention,
+        )
+
+        return window_prefix_attention(q, prefix_k, prefix_v, prefix_len, key_lo, window=size)
+    j = jnp.arange(prefix_k.shape[0])
+    mask = (j < prefix_len) & (j >= key_lo[:, :, None])  # [B, S, Sp]
+    return attend_part(qg, prefix_k, prefix_v, mask[:, None, None], "bqkgh,skh->bkgqs")
+
+
+def _window_uses_pallas(q_shape, prefix_shape, impl):
+    """(whether window_prefix_attend_parts takes the kernel, mesh, record)."""
+    kind, mesh, _axis, _shards, record = _resolve_impl(impl)
+    use_pallas = False
+    if kind == "pallas" or (kind == "auto" and jax.default_backend() == "tpu"):
+        from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
+            prefix_attention_supported,
+        )
+
+        use_pallas = mesh is None and prefix_attention_supported(
+            q_shape, prefix_shape[1], prefix_shape[0]
+        )
+    return use_pallas, mesh, record
+
+
+def window_prefix_keys_read(q_shape, prefix_shape, size: int, impl=None) -> int:
+    """Prefix keys window_prefix_attend_parts reads for each query row, a
+    static count: the key blocks the kernel's grid visits, or the whole
+    buffer, which the einsum masks."""
+    if _window_uses_pallas(q_shape, prefix_shape, impl)[0]:
+        from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
+            window_keys_visited,
+        )
+
+        return window_keys_visited(size, prefix_shape[0])
+    return prefix_shape[0]
 
 
 def causal_chunk_attend_parts(q, qg, k_chunk, v_chunk, chunk_lens, impl=None):

@@ -385,6 +385,181 @@ def flash_prefix_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
     return o, m, l
 
 
+# ------------------------------------------------- window over the prefix
+def _window_kernel(
+    # scalar prefetch
+    plen_ref,   # [1] int32 (SMEM) — valid prefix tokens
+    first_ref,  # [n_qb] int32 (SMEM) — first key block a query block visits
+    lo_ref,     # [n_qb] int32 (SMEM) — lowest key any row of the block sees
+    # blocked inputs
+    q_ref,      # [1, q_block, hd] f32, pre-scaled
+    k_ref,      # [1, k_block, hd]
+    v_ref,      # [1, k_block, hd]
+    row_lo_ref,  # [q_block, 1] int32 — each row's lowest visible key
+    # blocked outputs
+    o_ref, m_ref, l_ref,
+    # scratch
+    m_scr, l_scr, acc_scr,
+):
+    qb = pl.program_id(1)
+    kb = pl.program_id(2)
+    k_block = k_ref.shape[1]
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    start = (first_ref[qb] + kb) * k_block
+
+    # a block wholly past the prefix or wholly below every row's window
+    # holds nothing the block's rows see
+    @pl.when((start < plen_ref[0]) & (start + k_block > lo_ref[qb]))
+    def _attend():
+        q = q_ref[0].astype(jnp.bfloat16)
+        k = k_ref[0].astype(jnp.bfloat16)
+        v = v_ref[0].astype(jnp.bfloat16)
+        scores = jax.lax.dot_general(
+            q, k,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [q_block, k_block]
+        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, k_block), 1)
+        mask = (kpos < plen_ref[0]) & (kpos >= row_lo_ref[...])
+        scores = jnp.where(mask, scores, NEG_INF)
+
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        probs = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+        l_scr[:] = jnp.broadcast_to(
+            l_scr[:, :1] * alpha + jnp.sum(probs, axis=1, keepdims=True),
+            l_scr.shape,
+        )
+        pv = jax.lax.dot_general(
+            probs.astype(jnp.bfloat16), v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finish():
+        o_ref[0] = acc_scr[:]
+        m_ref[0] = m_scr[:]
+        l_ref[0] = l_scr[:]
+
+
+def window_key_blocks(window: int, k_block: int, n_blocks: int) -> int:
+    """Key blocks a query block of the windowed kernel visits: the keys its
+    rows see lie in [lowest row's bound, prefix_len), at most window - 1 of
+    them (every query lies behind the prefix), so they span at most
+    ceil((window - 1) / k_block) + 1 blocks."""
+    return min(n_blocks, -(-(window - 1) // k_block) + 1)
+
+
+def window_keys_visited(window: int, prefix_cap: int) -> int:
+    """Prefix keys the windowed kernel reads for each query row: the key
+    blocks its grid visits, whole, in a prefix buffer of `prefix_cap`."""
+    k_block = _largest_divisor(prefix_cap, 1024, 128)
+    return window_key_blocks(window, k_block, prefix_cap // k_block) * k_block
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def window_prefix_attention(  # graftlint: ok[unconstrained-sharding] — single-device pallas kernel, as flash_prefix_attention_parts
+    q: jax.Array,  # [B, S, n_heads, hd] post-RoPE queries (UNscaled)
+    prefix_k: jax.Array,  # [Sp, n_kv, hd] shared dense prefix KV
+    prefix_v: jax.Array,
+    prefix_len: jax.Array,  # scalar int32 — valid prefix tokens
+    key_lo: jax.Array,  # [B, S] int32 — lowest prefix key each query sees
+    *,
+    window: int,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """flash_prefix_attention_parts for queries that see a WINDOW of the
+    prefix: query (b, s) sees prefix keys key_lo[b, s] <= j < prefix_len.
+    The queries lie behind the prefix and see at most `window` positions
+    back (key_lo >= prefix_len - window + 1), so a query block's keys lie
+    in at most `window_key_blocks` key blocks: the grid walks those alone,
+    from the first block its lowest row sees, and never visits (nor reads)
+    a key block wholly below the window; the block at the window's edge is
+    masked row by row. Same (o, m, l) as the full kernel.
+
+    Its own name, apart from the full kernel's: the full kernel's readers
+    (benchmark/metrics/prefix_attn_roofline.py) count every prefix key of
+    each of its calls."""
+    B, S, n_heads, hd = q.shape
+    Sp, n_kv, _ = prefix_k.shape
+    g = n_heads // n_kv
+    interpret = pallas_interpret(interpret)
+    nq = B * g * S
+    q_block = _largest_divisor(nq, 1024, 8)
+    k_block = _largest_divisor(Sp, 1024, 128)
+    if q_block is None or k_block is None:
+        raise ValueError(
+            f"unsupported shapes for window prefix attention: nq={nq}, Sp={Sp}"
+        )
+    n_blocks = Sp // k_block
+    n_kb = window_key_blocks(window, k_block, n_blocks)
+    n_qb = nq // q_block
+
+    qr = q.reshape(B, S, n_kv, g, hd).transpose(2, 0, 3, 1, 4)
+    qr = (qr.astype(jnp.float32) * hd**-0.5).reshape(n_kv, nq, hd)
+    pk_t = prefix_k.transpose(1, 0, 2)
+    pv_t = prefix_v.transpose(1, 0, 2)
+    # rows in the kernel's order (b, g, s), as qr's
+    row_lo = jnp.broadcast_to(key_lo.astype(jnp.int32)[:, None, :], (B, g, S)).reshape(nq)
+    lo = jnp.min(row_lo.reshape(n_qb, q_block), axis=1)
+    first = jnp.clip(lo // k_block, 0, n_blocks - n_kb)
+
+    def kv_block(kv, qb, kb, plen, first, lo):
+        return (kv, first[qb] + kb, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_kv, n_qb, n_kb),
+        in_specs=[
+            pl.BlockSpec((1, q_block, hd), lambda kv, qb, kb, *_: (kv, qb, 0)),
+            pl.BlockSpec((1, k_block, hd), kv_block),
+            pl.BlockSpec((1, k_block, hd), kv_block),
+            pl.BlockSpec((q_block, 1), lambda kv, qb, kb, *_: (qb, 0)),
+        ],
+        out_specs=(
+            pl.BlockSpec((1, q_block, hd), lambda kv, qb, kb, *_: (kv, qb, 0)),
+            pl.BlockSpec((1, q_block, 128), lambda kv, qb, kb, *_: (kv, qb, 0)),
+            pl.BlockSpec((1, q_block, 128), lambda kv, qb, kb, *_: (kv, qb, 0)),
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((q_block, 128), jnp.float32),
+            pltpu.VMEM((q_block, 128), jnp.float32),
+            pltpu.VMEM((q_block, hd), jnp.float32),
+        ],
+    )
+    o, m, l = pl.pallas_call(
+        _window_kernel,
+        name="window_prefix_attention",
+        out_shape=(
+            jax.ShapeDtypeStruct((n_kv, nq, hd), jnp.float32),
+            jax.ShapeDtypeStruct((n_kv, nq, 128), jnp.float32),
+            jax.ShapeDtypeStruct((n_kv, nq, 128), jnp.float32),
+        ),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+    )(
+        jnp.asarray(prefix_len, dtype=jnp.int32).reshape(1), first, lo,
+        qr, pk_t, pv_t, row_lo[:, None],
+    )
+    o = o.reshape(n_kv, B, g, S, hd).transpose(1, 0, 2, 3, 4)
+    m = m[:, :, 0].reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
+    l = l[:, :, 0].reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
+    return o, m, l
+
+
 # ------------------------------------------------ tp-sharded (shard_map)
 # GSPMD cannot partition a pallas_call, but both kernels are embarrassingly
 # parallel over the kv-head axis — exactly the axis Megatron tp shards

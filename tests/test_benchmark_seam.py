@@ -56,7 +56,7 @@ def test_the_bounded_share_entry_keeps_its_keys_and_its_first_cell():
     entry = next(m for m in bench["per_layer"] if m["name"] == "moe_bounded_share.tput")
     assert entry == {"name": "moe_bounded_share.tput", "unit": "%", "better": "higher",
                      "source": "program_counter", "layer": "model", "moves": "binds_per_s",
-                     "workloads": ["longcat_flash-backlog20", "qwen3_next-backlog20"]}
+                     "workloads": ["longcat_flash-backlog20", "qwen3_next-backlog20", COHERE_CELL]}
 
 ARCH = _load(BENCH / "arch" / "mla_moe.py", "bench_arch_mla_moe_pins")
 REF = _load(BENCH / "reference" / "mla_moe.py", "bench_reference_mla_moe_pins")
@@ -349,11 +349,12 @@ class TestQwen3NextThroughTheSeam:
                              "scmoe_grouped_swiglu_roofline.tput", "scmoe_grouped_matmul_roofline.tput",
                              "prefix_attn_roofline.tput"}
         for m in bench["per_layer"]:
-            if cell in m["workloads"]:  # appended, nothing moved: behind it the fifth cell alone
-                assert m["workloads"][m["workloads"].index(cell) + 1:] in ([], [GRANITE_CELL])
+            if cell in m["workloads"]:  # appended, nothing moved: behind it the fifth and sixth cells alone
+                assert m["workloads"][m["workloads"].index(cell) + 1:] in (
+                    [], [GRANITE_CELL], [COHERE_CELL], [GRANITE_CELL, COHERE_CELL])
         rate = next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"]
-        assert rate[-2:] == [cell, GRANITE_CELL]
-        assert [w["name"] for w in bench["workloads"]] == CELLS + [GRANITE_CELL]
+        assert rate[-3:] == [cell, GRANITE_CELL, COHERE_CELL]
+        assert [w["name"] for w in bench["workloads"]] == CELLS + [GRANITE_CELL, COHERE_CELL]
         assert all(w["chips"] == 1 for w in bench["workloads"])
 
 
@@ -467,6 +468,8 @@ def test_gdn_reference_runs_in_both_modes_and_int8_differs():
 CELLS = ["internlm1_8b-backlog20", "glm4_7_flash-backlog20", "longcat_flash-backlog20", "qwen3_next-backlog20"]
 GRANITE_CELL = "granite4_h_micro-backlog20"
 GRANITE_METRICS = ["ssm_device_ms_per_bind.tput", "ssm_scan_device_ms_per_bind.tput", "ssd_chunk_scan_roofline.tput"]
+COHERE_CELL = "command_a_plus-backlog128"
+COHERE_METRICS = ["swa_attn_device_ms_per_bind.tput", "window_attn_roofline.tput", "window_kv_read_share.tput"]
 SETUP_METRICS = {"setup_build_s": "build_s", "setup_params_s": "params_s",
                  "setup_trace_lower_s": "trace_lower_s", "setup_load_compile_s": "load_compile_s",
                  "setup_programs_compiled": "programs_compiled"}
@@ -476,7 +479,7 @@ def test_the_state_entries_follow_one_another_and_name_their_cells_alone():
     """The fourth configuration's five entries; the three that read any
     family with a state and an attention under `full_attn` list the fifth
     cell behind the fourth, the two of the delta rule's scopes the fourth
-    alone."""
+    alone; `full_attn` the sixth cell's global layer last."""
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index("gdn_device_ms_per_bind.tput")
@@ -487,28 +490,29 @@ def test_the_state_entries_follow_one_another_and_name_their_cells_alone():
     for m in entries:
         assert m["workloads"] == ["qwen3_next-backlog20"] + ([GRANITE_CELL] if m["name"] in (
             "full_attn_device_ms_per_bind.tput", "state_carry_device_ms_per_bind.tput",
-            "state_valid_share.tput") else [])
+            "state_valid_share.tput") else []) + ([COHERE_CELL] if m["name"] == "full_attn_device_ms_per_bind.tput"
+                                                   else [])
         assert m["moves"] == "binds_per_s" and m["layer"] == "model"
     assert [m["source"] for m in entries] == ["device_trace"] * 4 + ["program_counter"]
     assert entries[-1]["unit"] == "%" and entries[-1]["better"] == "higher"
-    # nothing but the set-up entries after them, then the fifth cell's three
-    assert names[first + 5:] == list(SETUP_METRICS) + GRANITE_METRICS
+    # nothing but the set-up entries after them, then the fifth cell's three and the sixth's
+    assert names[first + 5:] == list(SETUP_METRICS) + GRANITE_METRICS + COHERE_METRICS
 
 
 def test_the_set_up_entries_follow_the_state_entries_and_move_setup_s():
-    """The five set-up entries, every cell listed, the fifth cell last;
-    every other entry moves `binds_per_s`."""
+    """The five set-up entries, every cell listed, the fifth and sixth
+    cells last; every other entry moves `binds_per_s`."""
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index("setup_build_s")
     entries = bench["per_layer"][first:first + 5]
     assert [m["name"] for m in entries] == list(SETUP_METRICS)
-    assert [w["name"] for w in bench["workloads"]] == CELLS + [GRANITE_CELL]
+    assert [w["name"] for w in bench["workloads"]] == CELLS + [GRANITE_CELL, COHERE_CELL]
     for m in entries:
         assert m == {"name": m["name"], "unit": "count" if m["name"] == "setup_programs_compiled" else "s",
                      "better": "lower", "source": "program_counter",
                      "layer": "entry" if m["name"] in ("setup_build_s", "setup_params_s") else "device",
-                     "moves": "setup_s", "workloads": CELLS + [GRANITE_CELL]}
+                     "moves": "setup_s", "workloads": CELLS + [GRANITE_CELL, COHERE_CELL]}
     assert all(m["moves"] == "binds_per_s" for m in bench["per_layer"] if m not in entries)
 
 
@@ -639,12 +643,13 @@ class TestGraniteThroughTheSeam:
     def test_the_cell_is_listed_where_its_readers_find_something(self):
         bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
         cell = GRANITE_CELL
-        assert bench["workloads"][-1] == {**bench["workloads"][-1], "name": cell, "config": "granite-4_0-h-micro",
+        # the fifth cell, the sixth behind it
+        assert bench["workloads"][-2] == {**bench["workloads"][-2], "name": cell, "config": "granite-4_0-h-micro",
                                           "traffic": "backlog20_pool80", "chips": 1}
-        assert bench["configs"][-1]["name"] == "granite-4_0-h-micro"
+        assert bench["configs"][-2]["name"] == "granite-4_0-h-micro"
         listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
-        assert set(GRANITE_METRICS) <= listed and [m["name"] for m in bench["per_layer"][-3:]] == GRANITE_METRICS
-        for m in bench["per_layer"][-3:]:
+        assert set(GRANITE_METRICS) <= listed and [m["name"] for m in bench["per_layer"][-6:-3]] == GRANITE_METRICS
+        for m in bench["per_layer"][-6:-3]:
             assert m["workloads"] == [cell] and m["moves"] == "binds_per_s"
         assert {"full_attn_device_ms_per_bind.tput", "state_carry_device_ms_per_bind.tput",
                 "state_valid_share.tput", "prefix_attn_roofline.tput", "model_mfu.tput", *SETUP_METRICS} <= listed
@@ -654,9 +659,9 @@ class TestGraniteThroughTheSeam:
                              "moe_experts_device_ms_per_bind.tput", "mla_proj_device_ms_per_bind.tput",
                              "dense_ffn_device_ms_per_bind.tput", "moe_grouped_swiglu_roofline.tput"}
         for m in bench["per_layer"]:
-            if cell in m["workloads"]:
-                assert m["workloads"][-1] == cell  # appended, nothing moved
-        assert next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"][-1] == cell
+            if cell in m["workloads"]:  # appended, nothing moved: behind it the sixth cell alone
+                assert m["workloads"][m["workloads"].index(cell) + 1:] in ([], [COHERE_CELL])
+        assert next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"][-2] == cell
 
 
 def test_mamba2_reference_runs_in_both_modes_and_int8_differs():
@@ -738,3 +743,221 @@ def test_the_roofline_reader_reads_the_kernels_events_inside_wave_runs():
     got = bench_run.reader_for("ssd_chunk_scan_roofline.tput")(ctx)
     assert got == pytest.approx(100.0 * least / 700e-6)
     assert 0 < got <= 100
+
+
+# ---------------------------------------------------- command-a-plus-05-2026
+COHERE_REF = _load(BENCH / "reference" / "cohere2_moe.py", "bench_reference_cohere2_moe_pins")
+
+
+class TestCommandAPlusThroughTheSeam:
+    """benchmark/configs/command-a-plus-05-2026.json loaded the way run.py
+    loads it: `"architecture": "cohere2_moe"` selects arch/ and reference/,
+    `register` hands the program a config of its own type, and the arch
+    file's count of what a token needs is the config type's books."""
+
+    ATTN = 2 * 4096 * 16384 + 2 * 4096 * 1024   # 142.6 M: W_q, W_o (128 heads of 128); W_k, W_v (8)
+    ROUTER = 4096 * 128
+    EXPERT = 3 * 4096 * 4096                    # 50.3 M: gate, up, down of one expert
+
+    @pytest.fixture(scope="class")
+    def loaded(self):
+        from harness import seam
+
+        conf = seam.load_config(BENCH / "configs" / "command-a-plus-05-2026.json")
+        return conf, seam.program(conf), seam.reference(conf)
+
+    def test_the_file_selects_its_architecture_and_registers_its_own_config_type(self, loaded):
+        from k8s_llm_scheduler_tpu.models import cohere2_moe, family
+        from k8s_llm_scheduler_tpu.models.configs import Cohere2MoeConfig, get_config
+
+        conf, arch, ref = loaded
+        assert arch.__file__.endswith("arch/cohere2_moe.py")
+        assert ref.__file__.endswith("reference/cohere2_moe.py")
+        cfg = get_config(arch.register(conf))
+        assert isinstance(cfg, Cohere2MoeConfig) and family(cfg) is cohere2_moe
+        assert (cfg.n_layers, cfg.global_layers, cfg.window, cfg.experts_held, cfg.vocab_size) == (
+            4, (3,), 4096, 16, 32768)
+        assert (cfg.shared_scale, cfg.d_ff_shared, cfg.logit_scale, cfg.norm_eps) == (0.25, 16384, 1.0, 1e-5)
+        with pytest.raises(ValueError, match="rotary"):
+            arch.register({**conf, "position_embedding_type": "rope"})
+
+    def test_a_token_by_hand_is_the_arch_files_count_and_the_config_types_books(self, loaded):
+        from k8s_llm_scheduler_tpu.models.configs import get_config
+
+        conf, arch, _ = loaded
+        layer = self.ATTN + self.ROUTER + (8 * 16 / 128 + 4) * self.EXPERT
+        body = 2.0 * 4 * layer
+        assert arch.flops_per_token(conf, with_head=False) == body
+        assert arch.flops_per_token(conf, with_head=True) == body + 2.0 * 4096 * 32768
+        cfg = get_config(arch.register(conf))
+        assert cfg.matmul_flops_per_token() == arch.flops_per_token(conf, with_head=True)
+        # the global layer sees every key, a window layer 4,096 of them at most
+        per_key = 4.0 * 128 * 128
+        assert arch.attention_flops(conf, 10, 3000) == 10 * per_key * 4 * 3000
+        assert arch.attention_flops(conf, 10, 10000) == 10 * per_key * (10000 + 3 * 4096)
+        assert cfg.attn_flops_per_token(10000) == per_key * (10000 + 3 * 4096)
+        assert cfg.attn_flops_per_token(3000) == cfg.attn_flops_per_key() * 3000
+
+    def test_a_grouped_kernel_call_is_bound_by_the_touched_experts_bytes(self, loaded):
+        _, arch, _ = loaded
+        flops, moved = arch.grouped_kernel_cost(16, 8, 4096, 4096, 2, 2)
+        assert flops == 2.0 * 16 * 4096 * 4096 * 2
+        assert moved == 8 * 4096 * 4096 * 2 * 2 + 16 * (4096 * 2 + 4096 * 2)
+        assert moved / 819e9 > 10 * flops / 197e12
+
+    def test_the_configuration_file_holds_the_published_row(self, loaded):
+        """Every number of the catalog row under its own key, the nested rope
+        group whole and the 32 layer types as published; three cuts, each in
+        `reduced`: depth, experts held, vocabulary."""
+        conf, _, _ = loaded
+        published = {
+            "attention_bias": False, "expert_selection_fn": "sigmoid", "first_k_dense_replace": 0, "head_dim": 128,
+            "hidden_act": "silu", "hidden_size": 4096, "intermediate_size": 4096, "layer_norm_eps": 1e-05,
+            "layer_switch": 4, "logit_scale": 1, "max_position_embeddings": 200000, "model_type": "cohere2_moe",
+            "norm_topk_prob": True, "num_attention_heads": 128, "num_experts": 128, "num_experts_per_tok": 8,
+            "num_key_value_heads": 8, "num_shared_experts": 4, "order_of_interleaved_layers": "local_attn_first",
+            "position_embedding_type": "rope_gptj", "prefix_dense_intermediate_size": 16384,
+            "prefix_dense_sliding_window_pattern": 1, "rms_norm_eps": None,
+            "rope_parameters": {"rope_theta": 50000, "rope_type": "default"}, "rope_theta": 50000, "rotary_pct": 1,
+            "shared_expert_combination_strategy": "average", "sliding_window": 4096, "tf_legacy_loss": False,
+            "tie_word_embeddings": True, "use_embedding_sharing": True, "use_gated_activation": True,
+            "use_parallel_block": True, "use_parallel_embedding": False, "use_qk_norm": False,
+        }
+        assert {k: conf[k] for k in published} == published
+        assert conf["layer_types"] == (["sliding_attention"] * 3 + ["full_attention"]) * 8
+        assert conf["reduced"] == ["num_hidden_layers", "experts_held", "vocab_size"]
+        assert (conf["num_hidden_layers"], conf["experts_held"], conf["vocab_size"]) == (4, 16, 32768)
+        assert conf["published"] == {**conf["published"], "num_hidden_layers": 32, "experts_held": 128,
+                                     "vocab_size": 262144}
+        entry = next(c for c in json.loads((BENCH.parent / "BENCHMARK.json").read_text())["configs"]
+                     if c["name"] == conf["name"])
+        assert entry["reduced"] == conf["reduced"] and entry["source"] == conf["source"]
+        layer = self.ATTN + self.ROUTER + 3 * 4096 * 16384 + 4096 + 16 * self.EXPERT
+        assert conf["parameters"] == 4 * layer + 32768 * 4096 + 4096 == 4_733_292_544
+
+    def test_the_reference_imports_nothing_of_the_program_or_the_harness(self):
+        text = (BENCH / "reference" / "cohere2_moe.py").read_text()
+        imports = [ln for ln in text.splitlines() if ln.startswith(("import ", "from "))]
+        assert imports == ["from __future__ import annotations", "import functools", "import jax",
+                           "import jax.numpy as jnp", "import numpy as np"]
+        assert "pallas" not in text
+
+    def test_the_cell_is_listed_where_its_readers_find_something(self):
+        bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+        cell = COHERE_CELL
+        assert bench["workloads"][-1] == {**bench["workloads"][-1], "name": cell,
+                                          "config": "command-a-plus-05-2026", "traffic": "backlog", "chips": 1}
+        assert bench["configs"][-1]["name"] == "command-a-plus-05-2026"
+        listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
+        assert [m["name"] for m in bench["per_layer"][-3:]] == COHERE_METRICS
+        for m in bench["per_layer"][-3:]:
+            assert m["workloads"] == [cell] and m["moves"] == "binds_per_s"
+        assert {"full_attn_device_ms_per_bind.tput", "moe_experts_device_ms_per_bind.tput",
+                "moe_router_device_ms_per_bind.tput", "moe_shared_device_ms_per_bind.tput",
+                "experts_hit_per_layer_call.tput", "moe_bounded_share.tput", "moe_grouped_swiglu_roofline.tput",
+                "moe_grouped_matmul_roofline.tput", "window_compiles.tput", "model_mfu.tput",
+                "prefix_attn_roofline.tput", *SETUP_METRICS} <= listed
+        # readers of another family's scopes or counters
+        assert not listed & {"gdn_device_ms_per_bind.tput", "ssm_device_ms_per_bind.tput",
+                             "state_valid_share.tput", "state_carry_device_ms_per_bind.tput",
+                             "mla_proj_device_ms_per_bind.tput", "dense_ffn_device_ms_per_bind.tput",
+                             "zero_expert_share.tput", "scmoe_grouped_swiglu_roofline.tput"}
+        for m in bench["per_layer"]:
+            if cell in m["workloads"]:
+                assert m["workloads"][-1] == cell  # appended, nothing moved
+        assert next(m for m in bench["end_to_end"] if m["name"] == "binds_per_s")["workloads"][-1] == cell
+
+
+def test_cohere2_reference_runs_in_both_modes_and_int8_differs():
+    """reference/cohere2_moe.py at a toy size: `f32` and the `int8` control
+    see the same wave and give different logits, both finite; a tail sees
+    the prefix and itself alone; a window shorter than the prefix is another
+    answer than one wider than it."""
+    toy = {
+        "hidden_size": 64, "num_hidden_layers": 4, "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+        "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 16, "sliding_window": 16,
+        "intermediate_size": 32, "num_experts": 16, "num_shared_experts": 4, "num_experts_per_tok": 4,
+        "experts_held": 4, "expert_first": 0, "norm_topk_prob": True, "logit_scale": 1, "vocab_size": 512,
+        "rope_theta": 10000, "layer_norm_eps": 1e-5,
+    }
+    weights = COHERE_REF.init_weights(toy, 3)
+    assert weights["layers"]["we_gate"].shape == (4, 4, 64, 32) and weights["layers"]["ws_gate"].shape == (4, 64, 128)
+    assert "lm_head" not in weights  # the table is tied
+    rng = np.random.default_rng(5)
+    prefix = rng.integers(1, 500, 40).tolist()
+    tails = [rng.integers(1, 500, n).tolist() for n in (12, 9)]
+    spans = [(7, 5), (5, 4)]
+    f32 = COHERE_REF.wave_logits(toy, weights, prefix, tails, spans, "f32", 300)
+    low = COHERE_REF.wave_logits(toy, weights, prefix, tails, spans, "int8", 300)
+    assert f32.shape == low.shape == (9, 300)
+    assert np.isfinite(f32).all() and np.isfinite(low).all()
+    assert float(np.max(np.abs(f32 - low))) > 1e-3
+    assert float(np.mean(np.abs(f32 - low))) < 0.25 * float(np.std(f32))
+    alone = COHERE_REF.wave_logits(toy, weights, prefix, tails[:1], spans[:1], "f32", 300)
+    np.testing.assert_allclose(alone, f32[:5], rtol=1e-4, atol=1e-5)
+    wide = COHERE_REF.wave_logits({**toy, "sliding_window": 4096}, weights, prefix, tails[:1], spans[:1], "f32", 300)
+    assert float(np.max(np.abs(wide - alone))) > 1e-3
+
+
+@pytest.mark.parametrize("name", COHERE_METRICS)
+def test_the_window_readers_read_none_without_a_trace(name):
+    """A run without a trace, or a program without the counters (a parent),
+    reads None and raises nothing."""
+    import run as bench_run
+    from types import SimpleNamespace
+
+    engine = {"waves": 3}
+    snap = {"sched": {"client": {"engine": engine}}, "compiles": {"programs": 40}}
+    ctx = bench_run.Ctx(outcome=SimpleNamespace(before=snap, after=snap, trace_span=None), profile=None,
+                        xplane_path=None, conf={"architecture": "cohere2_moe", "sliding_window": 4096},
+                        waves=[], trace_waves=[])
+    assert bench_run.reader_for(name)(ctx) is None
+
+
+def test_the_window_share_reader_reads_the_counters():
+    import run as bench_run
+    from types import SimpleNamespace
+
+    before = {"sched": {"client": {"engine": {"window_keys_read": 100, "window_keys_causal": 200}}}}
+    after = {"sched": {"client": {"engine": {"window_keys_read": 4196, "window_keys_causal": 10200}}}}
+    ctx = bench_run.Ctx(outcome=SimpleNamespace(before=before, after=after))
+    assert bench_run.reader_for("window_kv_read_share.tput")(ctx) == pytest.approx(100 * 4096 / 10000)
+
+
+def test_the_window_roofline_reader_counts_the_keys_in_the_window():
+    """`window_attn_roofline.tput` on a hand-made trace: two kernel events
+    inside a `jit_wave` run (a suffix call of 8 x 16 x 128 query rows a KV
+    head, a decode call of 8 x 16 x 24) and one of a chunked prefix prefill
+    outside any, which is not counted; least time by
+    `_window.window_kernel_cost` over the keys the waves' queries see
+    through the window, over the two events' device time. The full
+    kernel's reader does not see these events."""
+    import run as bench_run
+    from types import SimpleNamespace
+
+    from harness import seam
+    from metrics import _window
+
+    conf = seam.load_config(BENCH / "configs" / "command-a-plus-05-2026.json")
+    ev = lambda name, start, dur: SimpleNamespace(name=name, start_ns=start, duration_ns=dur)  # noqa: E731
+    ops = [ev("%window_prefix_attention.3 = (f32[8,16384,128]{2,1,0}, f32[8,16384,128]) custom-call(...)",
+              100, 3_000_000),
+           ev("%window_prefix_attention.1 = (f32[8,3072,128]{2,1,0}, f32[8,3072,128]) custom-call(...)",
+              3_100_000, 1_000_000),
+           ev("%window_prefix_attention.9 = (f32[8,32768,128]{2,1,0}, f32[8,32768,128]) custom-call(...)",
+              9_000_000, 6_000_000)]
+    runs = [ev("jit_wave(123)", 0, 5_000_000), ev("jit_suffix_dense(9)", 8_000_000, 8_000_000)]
+    profile = SimpleNamespace(planes=[SimpleNamespace(name="/device:TPU:0", lines=[
+        SimpleNamespace(name="XLA Ops", events=ops), SimpleNamespace(name="XLA Modules", events=runs)])])
+    waves = [{"prefix_ids": [0] * 10000, "prompts": [[1] * 70, [1] * 50], "served": [[2] * 40, [2] * 42]}]
+    peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    ctx = bench_run.Ctx(profile=profile, conf=conf, peaks=peaks, waves=waves, trace_waves=waves)
+    offsets = list(range(110)) + list(range(92))
+    keys = sum(4095 - o for o in offsets) / len(offsets)
+    assert _window.prefix_keys_per_query(waves, 4096) == pytest.approx(keys)
+    least = sum(max(f / 197e12, b / 819e9) for f, b in (
+        _window.window_kernel_cost(8, 16384, 128, keys, 4095), _window.window_kernel_cost(8, 3072, 128, keys, 4095)))
+    got = bench_run.reader_for("window_attn_roofline.tput")(ctx)
+    assert got == pytest.approx(100.0 * least / 4e-3)
+    assert 0 < got <= 100
+    assert bench_run.reader_for("prefix_attn_roofline.tput")(ctx) is None

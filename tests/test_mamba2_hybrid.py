@@ -294,7 +294,11 @@ def test_the_registered_toy_is_the_hand_written_one():
 
 
 def test_the_lowered_forwards_hold_the_scopes_and_kernel_names():
-    """What benchmark/metrics/ reads by name is in the program text."""
+    """What benchmark/metrics/ reads by name is in the program text. JAX's
+    caches are cleared first: a helper traced earlier in the process
+    (ops/_f32dot.py under the delta rule's kernel) would otherwise bring the
+    name scopes of that first trace into these programs' locations."""
+    jax.clear_caches()
     cfg = toy_cfg(jnp.bfloat16)
     model = mamba2_hybrid
     params = jax.eval_shape(lambda k: model.init_params(k, cfg), jax.random.PRNGKey(0))

@@ -197,3 +197,77 @@ def test_the_routed_layer_sorts_nothing_on_the_chip(one_chip, no_compile_cache, 
     text = compiled.as_text()
     assert not re.search(r"\bsort\(", text)
     assert "router_top_k" in text
+
+
+# (rows, positions): the command-a-plus cell's decode call, suffix call and a
+# chunk of its prefix prefill, against a 12,288-token prefix buffer
+@pytest.mark.parametrize("rows, positions", [(8, 24), (8, 128), (1, 2048)])
+def test_the_window_prefix_kernel_compiles_for_the_chip(one_chip, no_compile_cache, rows, positions):
+    """ops/pallas_prefix_attention.py `window_prefix_attention` at the
+    published heads (128 query heads of 128, 8 KV heads) and window (4,096):
+    each row's lowest key a (q_block, 1) block beside the scalar-prefetched
+    first block of each query block, compiled by Mosaic under its own name."""
+    from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import window_prefix_attention
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, n, lo: window_prefix_attention(q, k, v, n, lo, window=4096, interpret=False)
+    ).lower(shape(rows, positions, 128, 128), shape(12288, 8, 128), shape(12288, 8, 128),
+            shape(dtype=jnp.int32), shape(rows, positions, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "window_prefix_attention" in text
+    assert "flash_prefix_attention_parts" not in text
+
+
+def test_block_decode_copies_no_weight_out_of_its_stack(one_chip, no_compile_cache, monkeypatch):
+    """models/cohere2_moe.py `forward_block_decode` at the command-a-plus
+    cell's share (4 layers, 128 query heads, 16 held experts, a 12,288-token
+    prefix buffer), compiled as the chip does: no weight of a layer is
+    copied out of its stack into HBM on a model call. With W_q laid out [D,
+    H hd] or [H hd, D], its four layers were copied out whole, 512 MB a call
+    and a tenth of the cell's device time (PERF.md §5). A copy into VMEM
+    (memory space S(1)) is the matmul's own read of a weight and is allowed;
+    a copy in HBM reads and writes the weight once more."""
+    import json
+    import math
+    from pathlib import Path
+
+    from k8s_llm_scheduler_tpu.models import cohere2_moe
+    from k8s_llm_scheduler_tpu.models.configs import Cohere2MoeConfig
+    from k8s_llm_scheduler_tpu.ops import pallas_prefix_attention as ppa
+    from k8s_llm_scheduler_tpu.ops import router_top_k as rtk
+
+    for mod in (gm, ppa, rtk):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda interpret=None: False)
+    path = Path(__file__).resolve().parents[1] / "benchmark" / "configs" / "command-a-plus-05-2026.json"
+    conf = json.loads(path.read_text())
+    cfg = Cohere2MoeConfig.from_hf(conf["name"], conf, expert_first=0, expert_count=16)
+
+    def shape(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: shape(*a.shape, dtype=a.dtype),
+                                    jax.eval_shape(lambda k: cohere2_moe.init_params(k, cfg), jax.random.PRNGKey(0)))
+    R, F, L, kv = 8, 24, cfg.n_layers, (cfg.n_kv_heads, cfg.head_dim)
+    compiled = jax.jit(
+        lambda *a: cohere2_moe.forward_block_decode(a[0], cfg, *a[1:], prefix_impl="pallas")
+    ).lower(params, shape(R, F), shape(R, F, dtype=jnp.bool_), shape(R), shape(R, F),
+            *(shape(L, R, 128, *kv, dtype=cfg.dtype) for _ in range(2)), shape(R),
+            *(shape(L, R, 102, *kv, dtype=cfg.dtype) for _ in range(2)), shape(R),
+            *(shape(L, 12288, *kv, dtype=cfg.dtype) for _ in range(2)), shape()).compile()
+    # a layer's slice of a weight, whatever its layout or shape: no array of block decode's has that size
+    sizes = {math.prod(leaf.shape[1:]) for leaf in jax.tree_util.tree_leaves(params["layers"])}
+    copied, fused = [], False
+    for line in compiled.as_text().splitlines():
+        if line and not line.startswith(" "):   # a computation's header: fused ones run inside their consumer
+            fused = "fused" in line.split(" ")[0] or "wrapped" in line.split(" ")[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) (copy|fusion|transpose)\(", line)
+        if fused or not m:
+            continue
+        for dims, layout in re.findall(r"bf16\[([0-9,]+)\]\{([^}]*)\}", m.group(1)):
+            if math.prod(map(int, dims.split(","))) in sizes and "S(1)" not in layout:
+                copied.append(line.strip()[:160])
+    assert not copied, copied
