@@ -166,7 +166,7 @@ def _reference_check(engine) -> dict:
         ),
         static_argnums=(1,),
     )
-    got, _, _ = cascade(
+    got, *_ = cascade(
         params, cfg, jnp.asarray(suffix), jnp.asarray([n_suffix], jnp.int32),
         k_all[:, 0, :256], v_all[:, 0, :256], jnp.int32(n_prefix),
     )

@@ -1027,7 +1027,8 @@ class InferenceEngine:
             "wave_model_calls": 0,
             # the model family's device counters, summed inside the wave
             # program and fetched with its tokens (models/mla_moe.py
-            # COUNTERS: expert load; none for the dense family)
+            # COUNTERS: expert load; models/llama.py: block decode's prefix
+            # positions)
             **{name: 0 for name in self._model.COUNTERS},
             # wave rows seeded from a prefix's per-sequence state (a
             # family that has one: models/gdn_moe.py)

@@ -51,7 +51,9 @@ to the grouped kernels whole.
 
 The three entry points keep models/llama.py's contracts; the two wave
 forwards return COUNTERS behind the cache: the routed layer's (EXPERT and
-BOUND, summed over layers) and WINDOW_COUNTERS (once a call).
+BOUND, summed over layers), WINDOW_COUNTERS and BLOCK_PREFIX_COUNTERS (once
+a call: the prefix query positions block decode computed in every layer,
+narrowed and at full width; models/llama.py).
 """
 
 from __future__ import annotations
@@ -66,7 +68,11 @@ from k8s_llm_scheduler_tpu.models.llama import apply_rope
 from k8s_llm_scheduler_tpu.models.mla_moe import BOUND_COUNTERS, EXPERT_LEAVES, routed_experts, shared_experts
 from k8s_llm_scheduler_tpu.models.mla_moe import COUNTERS as EXPERT_COUNTERS
 from k8s_llm_scheduler_tpu.ops.attention import (
+    BLOCK_PREFIX_COUNTERS,
     attend_part,
+    block_prefix_attend_parts,
+    block_prefix_counts,
+    block_prefix_rung,
     causal_chunk_attend_parts,
     merge_attention_parts,
     prefix_attend_parts,
@@ -83,7 +89,7 @@ Params = dict[str, Any]
 # prefix_len, once a call, not a layer. The names
 # benchmark/metrics/window_kv_read_share.py reads.
 WINDOW_COUNTERS = ("window_keys_read", "window_keys_causal")
-COUNTERS = EXPERT_COUNTERS + BOUND_COUNTERS + WINDOW_COUNTERS
+COUNTERS = EXPERT_COUNTERS + BOUND_COUNTERS + WINDOW_COUNTERS + BLOCK_PREFIX_COUNTERS
 
 # What the paged pool and a tp mesh would need of this family, for the
 # refusals that name it (engine/engine.py, engine/local.py).
@@ -296,13 +302,16 @@ def _run_periods(params, cfg: Cohere2MoeConfig, x, valid, positions, cache_xs, a
     return x, (flat(k), flat(v)), counters
 
 
-def _with_window_counters(cfg: Cohere2MoeConfig, counters, valid, q_shape, prefix_k, prefix_len, impl):
+def _with_window_counters(cfg: Cohere2MoeConfig, counters, valid, q_shape, prefix_k, prefix_len, impl,
+                          block_prefix):
     """COUNTERS: the routed layer's, then, over the call's valid queries,
     the prefix keys a window layer's attention reads (a static count a
-    query, what its kernel visits) and those a causal layer would."""
+    query, what its kernel visits) and those a causal layer would, then the
+    call's BLOCK_PREFIX_COUNTERS."""
     rows = jnp.sum(valid.astype(jnp.int32))
     read = window_prefix_keys_read(q_shape, prefix_k.shape[1:], cfg.window, impl)
-    return jnp.concatenate([counters, jnp.stack([rows * read, rows * prefix_len]).astype(jnp.int32)])
+    return jnp.concatenate([counters, jnp.stack([rows * read, rows * prefix_len]).astype(jnp.int32),
+                            block_prefix])
 
 
 def _lowest_key(cfg: Cohere2MoeConfig, positions):
@@ -358,7 +367,7 @@ def forward_prefill_suffix_dense(
         params, cfg, _stream(params, tokens), valid, positions, (prefix_k, prefix_v), attend)
     return (_last_valid_logits(params, cfg, x, suffix_lens), k_sfx, v_sfx,
             _with_window_counters(cfg, counters, valid, (B, S, cfg.n_heads, cfg.head_dim), prefix_k,
-                                  prefix_len, prefix_impl))
+                                  prefix_len, prefix_impl, jnp.zeros((len(BLOCK_PREFIX_COUNTERS),), jnp.int32)))
 
 
 # ------------------------------------------------------------- block decode
@@ -385,8 +394,10 @@ def forward_block_decode(
     `forward_block_decode` says what that is). A window layer's query at
     position p sees every cached token at positions p - window < t <= p:
     prefix token t at t, suffix token t at prefix_len + t, generated token t
-    at prefix_len + suffix_len + t, block token at its position. Returns
-    (logits [R, V] f32 at each row's last valid position, gen_k, gen_v,
+    at prefix_len + suffix_len + t, block token at its position. The
+    prefix part of every layer runs at the block positions the call
+    carries (ops/attention.py block_prefix_attend_parts). Returns (logits
+    [R, V] f32 at each row's last valid position, gen_k, gen_v,
     COUNTERS)."""
     if ragged:
         raise ValueError(f"{cfg.name}: llm.decode_matmul 'ragged' is not served by models/cohere2_moe.py")
@@ -405,6 +416,7 @@ def forward_block_decode(
                      blk_ok & (positions[:, None, :] >= lowest[:, :, None])),
     }
     eq = "bqkgh,bskh->bkgqs"
+    rung = block_prefix_rung(blk_len, F)
 
     def attend(cache_l, jj, idx, window, q, qg, k, v):
         pk, pv, sk, sv = (a[jj] for a in cache_l)
@@ -412,7 +424,7 @@ def forward_block_decode(
         seen = None if window is None else (window, lowest)
         # gen_mask exposes entries < tail only: never this block's own
         return merge_attention_parts([
-            prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl, window=seen),
+            block_prefix_attend_parts(rung, q, qg, pk, pv, prefix_len, impl=prefix_impl, window=seen),
             attend_part(qg, sk, sv, sfx_mask, eq), attend_part(qg, gen_k[idx], gen_v[idx], gen_mask, eq),
             attend_part(qg, k, v, blk_mask, eq)])
 
@@ -424,4 +436,4 @@ def forward_block_decode(
         gen_v = write_block(gen_v, tail, v_blk)
     return (_last_valid_logits(params, cfg, x, blk_len), gen_k, gen_v,
             _with_window_counters(cfg, counters, blk_valid, (R, F, cfg.n_heads, cfg.head_dim), prefix_k,
-                                  prefix_len, prefix_impl))
+                                  prefix_len, prefix_impl, block_prefix_counts(rung, R, F)))

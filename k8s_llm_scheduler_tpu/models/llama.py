@@ -28,7 +28,11 @@ import jax.numpy as jnp
 
 from k8s_llm_scheduler_tpu.models.configs import LlamaConfig
 from k8s_llm_scheduler_tpu.ops.attention import (
+    BLOCK_PREFIX_COUNTERS,
     attend_part,
+    block_prefix_attend_parts,
+    block_prefix_counts,
+    block_prefix_rung,
     causal_prefill_attention,
     chunk_attention_with_prefix,
     merge_attention_parts,
@@ -39,9 +43,10 @@ from k8s_llm_scheduler_tpu.ops.attention import (
 
 Params = dict[str, Any]
 
-# What the wave forwards count on the device for engine.stats: nothing here
-# (models/mla_moe.py counts its expert load).
-COUNTERS: tuple[str, ...] = ()
+# What the wave forwards count on the device for engine.stats: the prefix
+# query positions block decode computed, narrowed and at full width
+# (ops/attention.py block_prefix_attend_parts; the suffix call counts none).
+COUNTERS = BLOCK_PREFIX_COUNTERS
 
 
 def cache_token_shapes(cfg: LlamaConfig) -> tuple[tuple[int, ...], ...]:
@@ -415,7 +420,7 @@ def forward_prefill_suffix_dense(
     prefix_v_all: jax.Array,
     prefix_len: jax.Array,  # scalar int32 — valid prefix tokens (0 = none)
     prefix_impl: str | None = None,  # static
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Batched suffix prefill against a shared dense prefix, KV kept DENSE.
 
     Identical attention semantics to forward_prefill_suffix, but instead of
@@ -425,7 +430,7 @@ def forward_prefill_suffix_dense(
     decodes to completion against (prefix | dense suffix | chunk buffer)
     without ever touching the paged KV cache — no page allocation, no
     gather/flush traffic, no multi-hundred-MB donation per dispatch.
-    Returns (last_logits [B, V] f32, k_sfx, v_sfx).
+    Returns (last_logits [B, V] f32, k_sfx, v_sfx, COUNTERS: zero).
     """
     B, S = tokens.shape
     inv_freq = rope_inv_freq(cfg)
@@ -444,7 +449,8 @@ def forward_prefill_suffix_dense(
     x, (k_sfx, v_sfx) = jax.lax.scan(
         body, x, (params["layers"], prefix_k_all, prefix_v_all)
     )
-    return _last_valid_logits(params, cfg, x, suffix_lens), k_sfx, v_sfx
+    return (_last_valid_logits(params, cfg, x, suffix_lens), k_sfx, v_sfx,
+            jnp.zeros((len(COUNTERS),), jnp.int32))
 
 
 def forward_prefill_packed(
@@ -583,7 +589,7 @@ def forward_block_decode(
     prefix_len: jax.Array,  # scalar int32
     prefix_impl: str | None = None,  # static
     ragged: bool = False,  # static: ragged-M Pallas matmuls (single device)
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One grammar-accelerated decode iteration: an F-wide mini-prefill.
 
     Where per-token decode runs the model once per emitted token, block
@@ -595,7 +601,10 @@ def forward_block_decode(
     buffers and hand back the block's K/V, which is written once they have
     all run (ops/attention.write_block: row r's F-wide window at tail[r];
     the padded positions' values land past the new tail, where nothing
-    reads them).
+    reads them). The shared-prefix part alone runs at the first w block
+    positions, w the narrowest width of ops/attention.py
+    block_prefix_widths that holds max(blk_len), picked once a call; the
+    suffix, generated and in-block parts keep width F.
 
     `ragged=True` removes the F-width padding from every projection/MLP
     matmul (SCALING.md wave roofline: 62% of decode compute at the
@@ -611,7 +620,7 @@ def forward_block_decode(
     dense path already requires.
 
     Returns (logits [R, V] f32 at each row's LAST VALID block position,
-    gen_k, gen_v).
+    gen_k, gen_v, COUNTERS).
     """
     R, F = blk_tok.shape
     hd = cfg.head_dim
@@ -619,6 +628,7 @@ def forward_block_decode(
 
     x = _embed(params, blk_tok)  # [R, F, D]
     Ss = k_sfx.shape[2]
+    rung = block_prefix_rung(blk_len, F)  # every layer's prefix part, one width
 
     if ragged:
         from k8s_llm_scheduler_tpu.ops.ragged_matmul import ragged_matmul
@@ -678,7 +688,7 @@ def forward_block_decode(
                     R, F, cfg.n_kv_heads, cfg.q_per_kv, hd
                 )
                 parts = [
-                    prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
+                    block_prefix_attend_parts(rung, q, qg, pk, pv, prefix_len, impl=prefix_impl),
                     attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
                     attend_part(qg, gk, gv, gen_mask, "bqkgh,bskh->bkgqs"),
                     attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
@@ -696,7 +706,7 @@ def forward_block_decode(
             return xc, (k, v)
 
         xc, blk_kv = jax.lax.scan(body_ragged, xc, xs)
-        return _logits(params, cfg, xc[last_c]), *written(*blk_kv)
+        return _logits(params, cfg, xc[last_c]), *written(*blk_kv), block_prefix_counts(rung, R, F)
 
     def body(x, xs):
         lp, pk, pv, ks, vs, gk, gv = xs
@@ -714,7 +724,7 @@ def forward_block_decode(
             # gen_mask only exposes entries < tail (previous iterations);
             # in-block attention comes from the dense k/v just computed.
             parts = [
-                prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
+                block_prefix_attend_parts(rung, q, qg, pk, pv, prefix_len, impl=prefix_impl),
                 attend_part(qg, ks, vs, sfx_mask, "bqkgh,bskh->bkgqs"),
                 attend_part(qg, gk, gv, gen_mask, "bqkgh,bskh->bkgqs"),
                 attend_part(qg, k, v, blk_mask, "bqkgh,bskh->bkgqs"),
@@ -727,7 +737,7 @@ def forward_block_decode(
         return x, (k, v)
 
     x, blk_kv = jax.lax.scan(body, x, xs)
-    return _last_valid_logits(params, cfg, x, blk_len), *written(*blk_kv)
+    return _last_valid_logits(params, cfg, x, blk_len), *written(*blk_kv), block_prefix_counts(rung, R, F)
 
 
 def forward_decode_buffered(

@@ -63,7 +63,11 @@ import jax.numpy as jnp
 from k8s_llm_scheduler_tpu.models.configs import Mamba2HybridConfig
 from k8s_llm_scheduler_tpu.models._state import STATE_COUNTERS, window_at
 from k8s_llm_scheduler_tpu.ops.attention import (
+    BLOCK_PREFIX_COUNTERS,
     attend_part,
+    block_prefix_attend_parts,
+    block_prefix_counts,
+    block_prefix_rung,
     causal_chunk_attend_parts,
     merge_attention_parts,
     prefix_attend_parts,
@@ -73,8 +77,10 @@ from k8s_llm_scheduler_tpu.ops.ssd_scan import ssd_chunk_scan
 
 Params = dict[str, Any]
 
-# What a wave's Mamba-2 layers count (models/_state.py says what).
-COUNTERS = STATE_COUNTERS
+# What a wave's Mamba-2 layers count (models/_state.py says what), then the
+# prefix query positions its block decode's attention computed
+# (models/llama.py says what).
+COUNTERS = STATE_COUNTERS + BLOCK_PREFIX_COUNTERS
 
 # Positions a chunk of the scan holds in prefill; block decode's chunk is
 # the block. With no solve a chunk's cost a position grows with the chunk
@@ -350,8 +356,10 @@ def _run_periods(params, cfg: Mamba2HybridConfig, x, valid, lens, state, cache_x
     return x, kv, (*matrices, *windows)
 
 
-def _counters(lens, computed: int) -> jax.Array:
-    return jnp.stack([jnp.sum(lens), jnp.int32(computed)]).astype(jnp.int32)
+def _counters(lens, computed: int, block_prefix) -> jax.Array:
+    """COUNTERS: STATE_COUNTERS of `lens` valid tokens in `computed`, then
+    the call's BLOCK_PREFIX_COUNTERS."""
+    return jnp.concatenate([jnp.stack([jnp.sum(lens), jnp.int32(computed)]).astype(jnp.int32), block_prefix])
 
 
 # ------------------------------------------------------------------ prefill
@@ -406,7 +414,7 @@ def forward_prefill_suffix_dense(
     x, (k_sfx, v_sfx), rows = _run_periods(
         params, cfg, _stream(params, cfg, tokens), valid, suffix_lens, rows, (prefix_k, prefix_v), attend)
     return (_last_valid_logits(params, cfg, x, suffix_lens), k_sfx, v_sfx, rows,
-            _counters(suffix_lens, B * _chunked(S)))
+            _counters(suffix_lens, B * _chunked(S), jnp.zeros((len(BLOCK_PREFIX_COUNTERS),), jnp.int32)))
 
 
 # ------------------------------------------------------------- block decode
@@ -435,7 +443,9 @@ def forward_block_decode(
     `forward_block_decode` says what that is): the block's positions are ONE
     chunk of the scan, so each Mamba-2 layer reads a row's state once and
     writes it once, advanced by the row's `blk_len` valid tokens; a row with
-    none keeps its state. Returns (logits [R, V] f32 at each row's last
+    none keeps its state. The attention's prefix part runs at the block
+    positions the call carries (ops/attention.py block_prefix_attend_parts).
+    Returns (logits [R, V] f32 at each row's last
     valid position, gen_k, gen_v, state, COUNTERS)."""
     if ragged:
         raise ValueError(f"{cfg.name}: llm.decode_matmul 'ragged' is not served by models/mamba2_hybrid.py")
@@ -445,12 +455,13 @@ def forward_block_decode(
     gen_mask = (jnp.arange(gen_k.shape[2])[None, :] < tail[:, None])[:, None, None, None, :]
     blk_mask = ((j[:, None] >= j[None, :])[None] & blk_valid[:, None, :])[:, None, None]
     eq = "bqkgh,bskh->bkgqs"
+    rung = block_prefix_rung(blk_len, F)
 
     def attend(cache_l, p, q, qg, k, v):
         pk, pv, sk, sv = cache_l
         # gen_mask exposes entries < tail only: never this block's own
         return merge_attention_parts([
-            prefix_attend_parts(q, qg, pk, pv, prefix_len, impl=prefix_impl),
+            block_prefix_attend_parts(rung, q, qg, pk, pv, prefix_len, impl=prefix_impl),
             attend_part(qg, sk, sv, sfx_mask, eq), attend_part(qg, gen_k[p], gen_v[p], gen_mask, eq),
             attend_part(qg, k, v, blk_mask, eq)])
 
@@ -460,4 +471,5 @@ def forward_block_decode(
     with jax.named_scope("kv_writeback"):
         gen_k = write_block(gen_k, tail, k_blk)
         gen_v = write_block(gen_v, tail, v_blk)
-    return _last_valid_logits(params, cfg, x, blk_len), gen_k, gen_v, state, _counters(blk_len, R * F)
+    return (_last_valid_logits(params, cfg, x, blk_len), gen_k, gen_v, state,
+            _counters(blk_len, R * F, block_prefix_counts(rung, R, F)))

@@ -97,6 +97,25 @@ def set_prefix_attn_impl(impl: str) -> None:
     PREFIX_ATTN_IMPL = impl
 
 
+def _prefix_uses_pallas(q_shape, prefix_shape, impl):
+    """(whether prefix_attend_parts takes the kernel, mesh, axis, record)."""
+    kind, mesh, axis, shards, record = _resolve_impl(impl)
+    use_pallas = False
+    if kind == "pallas" or (kind == "auto" and jax.default_backend() == "tpu"):
+        from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
+            prefix_attention_supported,
+        )
+
+        # "pallas" forces the kernel wherever the tiling supports it (incl.
+        # interpret mode off-TPU — parity tests); unsupported shapes always
+        # take the einsum path. On a sharded mesh the check runs on the
+        # PER-SHARD shapes (kv heads divided over the tp axis).
+        use_pallas = prefix_attention_supported(
+            q_shape, prefix_shape[1], prefix_shape[0], shards=shards
+        )
+    return use_pallas, mesh, axis, record
+
+
 def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None, window=None):
     """Flash partials (o, m, l) of queries vs the shared dense prefix.
 
@@ -112,20 +131,7 @@ def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None, window
     """
     if window is not None:
         return window_prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl, *window)
-    kind, mesh, axis, shards, record = _resolve_impl(impl)
-    use_pallas = False
-    if kind == "pallas" or (kind == "auto" and jax.default_backend() == "tpu"):
-        from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
-            prefix_attention_supported,
-        )
-
-        # "pallas" forces the kernel wherever the tiling supports it (incl.
-        # interpret mode off-TPU — parity tests); unsupported shapes always
-        # take the einsum path. On a sharded mesh the check runs on the
-        # PER-SHARD shapes (kv heads divided over the tp axis).
-        use_pallas = prefix_attention_supported(
-            q.shape, prefix_k.shape[1], prefix_k.shape[0], shards=shards
-        )
+    use_pallas, mesh, axis, record = _prefix_uses_pallas(q.shape, prefix_k.shape, impl)
     _note_resolved(record, "prefix", q.shape, prefix_k.shape[0], use_pallas, mesh)
     if use_pallas:
         from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import (
@@ -141,6 +147,121 @@ def prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl=None, window
     Sp = prefix_k.shape[0]
     pre_mask = (jnp.arange(Sp) < prefix_len)[None, None, None, None, :]
     return attend_part(qg, prefix_k, prefix_v, pre_mask, "bqkgh,skh->bkgqs")
+
+
+# Block decode attends the shared prefix at the first w positions of its
+# F-wide block alone, w the narrowest of these widths (then F) that holds
+# every row's valid run in the model call. A block holds one sampled token
+# and the grammar's forced run behind it, left-aligned. Read on a TPU v5e
+# over the decision grammar, 8 rows a wave, 128 nodes (4,809 model calls):
+# a call's longest run was 1 token in 78.9%, 4 in 5.3%, 7 in 5.3%, 8 in
+# 10.5%, never more; on 20 nodes (5,922 and 2,644 calls, two models) 1 in
+# 74-76%, 2-8 in the rest. At 2 positions a call's prefix kernels already
+# sit near the bytes of K and V, so 2 costs what 1 would and the calls of
+# 2 tokens take the narrow rung too.
+BLOCK_PREFIX_WIDTHS = (2, 8)
+# What block decode counts of it, summed over a wave's model calls (the
+# forwards of a family that narrows return them among its COUNTERS): the
+# prefix query positions it computed (rows x w a call) and those it would
+# at full width (rows x F). The names
+# benchmark/metrics/block_prefix_width_share.py reads.
+BLOCK_PREFIX_COUNTERS = ("block_prefix_positions", "block_prefix_positions_full")
+
+
+def block_prefix_widths(F: int) -> tuple[int, ...]:
+    """The ladder of widths for an F-wide block: F is the top rung, so no
+    call is ever cut short."""
+    return tuple(w for w in BLOCK_PREFIX_WIDTHS if w < F) + (F,)
+
+
+def block_prefix_rung(blk_len, F: int):
+    """Index into block_prefix_widths(F) of the narrowest width that holds
+    max(blk_len): one int32 scalar a model call."""
+    below = jnp.asarray(block_prefix_widths(F)[:-1], jnp.int32)
+    return jnp.sum(below < jnp.max(blk_len)).astype(jnp.int32)
+
+
+def block_prefix_counts(rung, rows: int, F: int):
+    """BLOCK_PREFIX_COUNTERS of one model call of `rows` rows whose prefix
+    part ran at `rung`: int32 [2]."""
+    widths = jnp.asarray(block_prefix_widths(F), jnp.int32)
+    return jnp.stack([rows * widths[rung], jnp.int32(rows * F)])
+
+
+def block_prefix_attend_parts(rung, q, qg, prefix_k, prefix_v, prefix_len, impl=None, window=None):
+    """prefix_attend_parts of an F-wide block's queries ([B, F, ..]) at its
+    first w = block_prefix_widths(F)[rung] positions alone, with o = 0,
+    m = NEG_INF, l = 0 past them (merge_attention_parts weighs those
+    positions by the other parts alone): one lax.switch branch a width.
+
+    The kernels run at [n_kv, B g w, hd], so their result shapes say the
+    rows they computed. Their query rows are made at width F outside the
+    switch, as prefix_attend_parts makes them, and a branch takes its first
+    w positions: a kernel reads the values it reads at full width, however
+    XLA fuses the rows with the queries' producer (a bf16 query handed into
+    a branch would be rounded once more), so each row's result is the full
+    width's, bit for bit. Where the kernel does not serve a width (no
+    tiling, a mesh) that branch takes prefix_attend_parts on the narrowed
+    queries."""
+    B, F, H, hd = q.shape
+    widths = block_prefix_widths(F)
+    if len(widths) == 1:
+        return prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl, window)
+    from k8s_llm_scheduler_tpu.ops import pallas_prefix_attention as kernels
+
+    n_kv = prefix_k.shape[1]
+    g = H // n_kv
+
+    def on_rows(w) -> bool:
+        shape = (B, w, H, hd)
+        if window is None:
+            use, mesh, _axis, record = _prefix_uses_pallas(shape, prefix_k.shape, impl)
+        else:
+            use, mesh, record = _window_uses_pallas(shape, prefix_k.shape, impl)
+        if use and mesh is None:
+            _note_resolved(record, "prefix" if window is None else "window_prefix", shape,
+                           prefix_k.shape[0], True, None)
+        return use and mesh is None
+
+    rows = [on_rows(w) for w in widths]
+    ops = {"plen": prefix_len, **({} if window is None else {"key_lo": window[1]})}
+    if any(rows):
+        ops.update(qr=kernels.query_rows(q, n_kv), kt=kernels.head_major(prefix_k),
+                   vt=kernels.head_major(prefix_v))
+    if not all(rows):
+        ops.update(q=q, qg=qg, k=prefix_k, v=prefix_v)
+
+    def widen(a, w, fill):
+        """[n_kv, B g w, ..] in the kernels' row order -> [n_kv, B g F, ..]."""
+        if w == F:
+            return a
+        a = a.reshape(n_kv, B * g, w, *a.shape[2:])
+        a = jnp.pad(a, [(0, 0), (0, 0), (0, F - w)] + [(0, 0)] * (a.ndim - 3), constant_values=fill)
+        return a.reshape(n_kv, B * g * F, *a.shape[3:])
+
+    def at(w, kernel):
+        def branch(ops):
+            if kernel:
+                qr = ops["qr"]
+                if w < F:
+                    qr = qr.reshape(n_kv, B * g, F, hd)[:, :, :w].reshape(n_kv, B * g * w, hd)
+                if window is None:
+                    o, m, l = kernels.prefix_rows_attention(qr, ops["kt"], ops["vt"], ops["plen"])
+                else:
+                    o, m, l = kernels.window_rows_attention(
+                        qr, ops["kt"], ops["vt"], ops["plen"], kernels.row_bounds(ops["key_lo"][:, :w], g),
+                        window=window[0])
+            else:
+                seen = None if window is None else (window[0], ops["key_lo"][:, :w])
+                o, m, l = prefix_attend_parts(ops["q"][:, :w], ops["qg"][:, :w], ops["k"], ops["v"],
+                                              ops["plen"], impl, seen)
+                o = o.transpose(1, 0, 2, 3, 4).reshape(n_kv, B * g * w, hd)
+                m, l = (a.transpose(1, 0, 2, 3).reshape(n_kv, B * g * w) for a in (m, l))
+            return widen(o, w, 0.0), widen(m, w, NEG_INF), widen(l, w, 0.0)
+        return branch
+
+    o, m, l = jax.lax.switch(rung, [at(w, r) for w, r in zip(widths, rows)], ops)
+    return kernels.parts_of_rows(o, m, l, B, F)
 
 
 def window_prefix_attend_parts(q, qg, prefix_k, prefix_v, prefix_len, impl, size: int, key_lo):

@@ -298,47 +298,59 @@ def flash_causal_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
     return o, m, l
 
 
+def query_rows(q: jax.Array, n_kv: int) -> jax.Array:
+    """The kernels' query rows: q [B, S, n_heads, hd] post-RoPE (unscaled)
+    -> [n_kv, B g S, hd] float32 times hd^-1/2, row (b g + gi) S + s."""
+    B, S, n_heads, hd = q.shape
+    g = n_heads // n_kv
+    qr = q.reshape(B, S, n_kv, g, hd).transpose(2, 0, 3, 1, 4)
+    return (qr.astype(jnp.float32) * hd**-0.5).reshape(n_kv, B * g * S, hd)
+
+
+def head_major(prefix: jax.Array) -> jax.Array:
+    """[Sp, n_kv, hd] -> [n_kv, Sp, hd]: key blocks tile (1, k_block, hd) —
+    the Pallas TPU lowering requires the last two block dims divisible by
+    (8, 128) or equal to the array dims. ~tens of MB of relayout vs the GBs
+    of score traffic the kernels eliminate."""
+    return prefix.transpose(1, 0, 2)
+
+
+def parts_of_rows(o: jax.Array, m: jax.Array, l: jax.Array, B: int, S: int):
+    """The kernels' (o [n_kv, nq, hd], m [n_kv, nq], l) in query_rows' order
+    -> (o [B, n_kv, g, S, hd], m [B, n_kv, g, S], l), attend_part's layout."""
+    n_kv, nq, hd = o.shape
+    g = nq // (B * S)
+    o = o.reshape(n_kv, B, g, S, hd).transpose(1, 0, 2, 3, 4)
+    m = m.reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
+    l = l.reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
+    return o, m, l
+
+
+def _row_blocks(nq: int, Sp: int, what: str) -> tuple[int, int]:
+    q_block = _largest_divisor(nq, 1024, 8)
+    k_block = _largest_divisor(Sp, 1024, 128)
+    if q_block is None or k_block is None:
+        raise ValueError(f"unsupported shapes for {what}: nq={nq}, Sp={Sp}")
+    return q_block, k_block
+
+
+# jitted, as the public wrappers: a program lowers a kernel once for each
+# shape it holds, however many layers call it
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def flash_prefix_attention_parts(  # graftlint: ok[unconstrained-sharding] — single-device pallas kernel: the engine refuses this path on tp>1 meshes, there is nothing for GSPMD to partition
-    q: jax.Array,  # [B, S, n_heads, hd] post-RoPE queries (UNscaled)
-    prefix_k: jax.Array,  # [Sp, n_kv, hd] shared dense prefix KV
-    prefix_v: jax.Array,
+def prefix_rows_attention(  # graftlint: ok[unconstrained-sharding] — single-device pallas kernel, as flash_prefix_attention_parts
+    qr: jax.Array,  # [n_kv, nq, hd] f32 query rows (query_rows)
+    prefix_kt: jax.Array,  # [n_kv, Sp, hd] head-major prefix KV (head_major)
+    prefix_vt: jax.Array,
     prefix_len: jax.Array,  # scalar int32 — valid prefix tokens
     *,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Flash partials of suffix-queries vs the shared prefix.
-
-    Returns (o, m, l) shaped ([B, n_kv, g, S, hd] f32, [B, n_kv, g, S],
-    [B, n_kv, g, S]) — bit-compatible with
-    ops.attention.attend_part(qg, prefix_k, prefix_v, mask, "bqkgh,skh->bkgqs")
-    for merge_attention_parts. A fully-masked prefix (prefix_len == 0)
-    reports m = NEG_INF, l = 0 (the merge then weights it to zero).
-    """
-    B, S, n_heads, hd = q.shape
-    Sp, n_kv, _ = prefix_k.shape
-    g = n_heads // n_kv
-    interpret = pallas_interpret(interpret)
-
-    nq = B * g * S
-    q_block = _largest_divisor(nq, 1024, 8)
-    k_block = _largest_divisor(Sp, 1024, 128)
-    if q_block is None or k_block is None:
-        raise ValueError(
-            f"unsupported shapes for flash prefix attention: nq={nq}, Sp={Sp}"
-        )
-
-    # [B, S, n_kv, g, hd] -> [n_kv, B, g, S, hd] -> [n_kv, nq, hd]
-    # (row index = (b*g + gi)*S + s; inverted exactly on the way out)
-    qr = q.reshape(B, S, n_kv, g, hd).transpose(2, 0, 3, 1, 4)
-    qr = (qr.astype(jnp.float32) * hd**-0.5).reshape(n_kv, nq, hd)
-    # kv-head-major KV so key blocks tile (1, k_block, hd) — the Pallas TPU
-    # lowering requires the last two block dims divisible by (8, 128) or
-    # equal to the array dims. ~tens of MB of relayout vs the GBs of score
-    # traffic the kernel eliminates.
-    pk_t = prefix_k.transpose(1, 0, 2)  # [n_kv, Sp, hd]
-    pv_t = prefix_v.transpose(1, 0, 2)
-
+    """The full kernel over query rows: (o [n_kv, nq, hd] f32, m [n_kv, nq],
+    l [n_kv, nq]), row for row what flash_prefix_attention_parts computes
+    (a row's result does not depend on the rows beside it)."""
+    n_kv, nq, hd = qr.shape
+    Sp = prefix_kt.shape[1]
+    q_block, k_block = _row_blocks(nq, Sp, "flash prefix attention")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_kv, nq // q_block, Sp // k_block),
@@ -370,19 +382,40 @@ def flash_prefix_attention_parts(  # graftlint: ok[unconstrained-sharding] — s
             jax.ShapeDtypeStruct((n_kv, nq, 128), jnp.float32),
         ),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(
         jnp.asarray(prefix_len, dtype=jnp.int32).reshape(1),
-        qr, pk_t, pv_t,
+        qr, prefix_kt, prefix_vt,
     )
-    # [n_kv, nq, ...] -> [n_kv, B, g, S, ...] -> [B, n_kv, g, S, ...]
-    o = o.reshape(n_kv, B, g, S, hd).transpose(1, 0, 2, 3, 4)
-    m = m[:, :, 0].reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
-    l = l[:, :, 0].reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
-    return o, m, l
+    return o, m[:, :, 0], l[:, :, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def flash_prefix_attention_parts(  # graftlint: ok[unconstrained-sharding] — single-device pallas kernel: the engine refuses this path on tp>1 meshes, there is nothing for GSPMD to partition
+    q: jax.Array,  # [B, S, n_heads, hd] post-RoPE queries (UNscaled)
+    prefix_k: jax.Array,  # [Sp, n_kv, hd] shared dense prefix KV
+    prefix_v: jax.Array,
+    prefix_len: jax.Array,  # scalar int32 — valid prefix tokens
+    *,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Flash partials of suffix-queries vs the shared prefix.
+
+    Returns (o, m, l) shaped ([B, n_kv, g, S, hd] f32, [B, n_kv, g, S],
+    [B, n_kv, g, S]) — bit-compatible with
+    ops.attention.attend_part(qg, prefix_k, prefix_v, mask, "bqkgh,skh->bkgqs")
+    for merge_attention_parts. A fully-masked prefix (prefix_len == 0)
+    reports m = NEG_INF, l = 0 (the merge then weights it to zero).
+    """
+    B, S = q.shape[:2]
+    o, m, l = prefix_rows_attention(
+        query_rows(q, prefix_k.shape[1]), head_major(prefix_k), head_major(prefix_v),
+        prefix_len, interpret=interpret,
+    )
+    return parts_of_rows(o, m, l, B, S)
 
 
 # ------------------------------------------------- window over the prefix
@@ -467,50 +500,33 @@ def window_keys_visited(window: int, prefix_cap: int) -> int:
     return window_key_blocks(window, k_block, prefix_cap // k_block) * k_block
 
 
+def row_bounds(key_lo: jax.Array, g: int) -> jax.Array:
+    """key_lo [B, S] -> [B g S] int32: each query row's lowest visible key,
+    in query_rows' order."""
+    B, S = key_lo.shape
+    return jnp.broadcast_to(key_lo.astype(jnp.int32)[:, None, :], (B, g, S)).reshape(B * g * S)
+
+
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def window_prefix_attention(  # graftlint: ok[unconstrained-sharding] — single-device pallas kernel, as flash_prefix_attention_parts
-    q: jax.Array,  # [B, S, n_heads, hd] post-RoPE queries (UNscaled)
-    prefix_k: jax.Array,  # [Sp, n_kv, hd] shared dense prefix KV
-    prefix_v: jax.Array,
+def window_rows_attention(  # graftlint: ok[unconstrained-sharding] — single-device pallas kernel, as flash_prefix_attention_parts
+    qr: jax.Array,  # [n_kv, nq, hd] f32 query rows (query_rows)
+    prefix_kt: jax.Array,  # [n_kv, Sp, hd] head-major prefix KV (head_major)
+    prefix_vt: jax.Array,
     prefix_len: jax.Array,  # scalar int32 — valid prefix tokens
-    key_lo: jax.Array,  # [B, S] int32 — lowest prefix key each query sees
+    row_lo: jax.Array,  # [nq] int32 — each row's lowest visible key (row_bounds)
     *,
     window: int,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """flash_prefix_attention_parts for queries that see a WINDOW of the
-    prefix: query (b, s) sees prefix keys key_lo[b, s] <= j < prefix_len.
-    The queries lie behind the prefix and see at most `window` positions
-    back (key_lo >= prefix_len - window + 1), so a query block's keys lie
-    in at most `window_key_blocks` key blocks: the grid walks those alone,
-    from the first block its lowest row sees, and never visits (nor reads)
-    a key block wholly below the window; the block at the window's edge is
-    masked row by row. Same (o, m, l) as the full kernel.
-
-    Its own name, apart from the full kernel's: the full kernel's readers
-    (benchmark/metrics/prefix_attn_roofline.py) count every prefix key of
-    each of its calls."""
-    B, S, n_heads, hd = q.shape
-    Sp, n_kv, _ = prefix_k.shape
-    g = n_heads // n_kv
-    interpret = pallas_interpret(interpret)
-    nq = B * g * S
-    q_block = _largest_divisor(nq, 1024, 8)
-    k_block = _largest_divisor(Sp, 1024, 128)
-    if q_block is None or k_block is None:
-        raise ValueError(
-            f"unsupported shapes for window prefix attention: nq={nq}, Sp={Sp}"
-        )
+    """The windowed kernel over query rows, as prefix_rows_attention: a row's
+    keys lie in [row_lo, prefix_len), so whatever rows share its query
+    block, the blocks it visits below its own bound hold nothing it sees."""
+    n_kv, nq, hd = qr.shape
+    Sp = prefix_kt.shape[1]
+    q_block, k_block = _row_blocks(nq, Sp, "window prefix attention")
     n_blocks = Sp // k_block
     n_kb = window_key_blocks(window, k_block, n_blocks)
     n_qb = nq // q_block
-
-    qr = q.reshape(B, S, n_kv, g, hd).transpose(2, 0, 3, 1, 4)
-    qr = (qr.astype(jnp.float32) * hd**-0.5).reshape(n_kv, nq, hd)
-    pk_t = prefix_k.transpose(1, 0, 2)
-    pv_t = prefix_v.transpose(1, 0, 2)
-    # rows in the kernel's order (b, g, s), as qr's
-    row_lo = jnp.broadcast_to(key_lo.astype(jnp.int32)[:, None, :], (B, g, S)).reshape(nq)
     lo = jnp.min(row_lo.reshape(n_qb, q_block), axis=1)
     first = jnp.clip(lo // k_block, 0, n_blocks - n_kb)
 
@@ -546,18 +562,47 @@ def window_prefix_attention(  # graftlint: ok[unconstrained-sharding] — single
             jax.ShapeDtypeStruct((n_kv, nq, 128), jnp.float32),
         ),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(
         jnp.asarray(prefix_len, dtype=jnp.int32).reshape(1), first, lo,
-        qr, pk_t, pv_t, row_lo[:, None],
+        qr, prefix_kt, prefix_vt, row_lo[:, None],
     )
-    o = o.reshape(n_kv, B, g, S, hd).transpose(1, 0, 2, 3, 4)
-    m = m[:, :, 0].reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
-    l = l[:, :, 0].reshape(n_kv, B, g, S).transpose(1, 0, 2, 3)
-    return o, m, l
+    return o, m[:, :, 0], l[:, :, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def window_prefix_attention(  # graftlint: ok[unconstrained-sharding] — single-device pallas kernel, as flash_prefix_attention_parts
+    q: jax.Array,  # [B, S, n_heads, hd] post-RoPE queries (UNscaled)
+    prefix_k: jax.Array,  # [Sp, n_kv, hd] shared dense prefix KV
+    prefix_v: jax.Array,
+    prefix_len: jax.Array,  # scalar int32 — valid prefix tokens
+    key_lo: jax.Array,  # [B, S] int32 — lowest prefix key each query sees
+    *,
+    window: int,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """flash_prefix_attention_parts for queries that see a WINDOW of the
+    prefix: query (b, s) sees prefix keys key_lo[b, s] <= j < prefix_len.
+    The queries lie behind the prefix and see at most `window` positions
+    back (key_lo >= prefix_len - window + 1), so a query block's keys lie
+    in at most `window_key_blocks` key blocks: the grid walks those alone,
+    from the first block its lowest row sees, and never visits (nor reads)
+    a key block wholly below the window; the block at the window's edge is
+    masked row by row. Same (o, m, l) as the full kernel.
+
+    Its own name, apart from the full kernel's: the full kernel's readers
+    (benchmark/metrics/prefix_attn_roofline.py) count every prefix key of
+    each of its calls."""
+    B, S, n_heads, _ = q.shape
+    n_kv = prefix_k.shape[1]
+    o, m, l = window_rows_attention(
+        query_rows(q, n_kv), head_major(prefix_k), head_major(prefix_v), prefix_len,
+        row_bounds(key_lo, n_heads // n_kv), window=window, interpret=interpret,
+    )
+    return parts_of_rows(o, m, l, B, S)
 
 
 # ------------------------------------------------ tp-sharded (shard_map)

@@ -470,6 +470,7 @@ GRANITE_CELL = "granite4_h_micro-backlog20"
 GRANITE_METRICS = ["ssm_device_ms_per_bind.tput", "ssm_scan_device_ms_per_bind.tput", "ssd_chunk_scan_roofline.tput"]
 COHERE_CELL = "command_a_plus-backlog128"
 COHERE_METRICS = ["swa_attn_device_ms_per_bind.tput", "window_attn_roofline.tput", "window_kv_read_share.tput"]
+WIDTH_METRIC = "block_prefix_width_share.tput"
 SETUP_METRICS = {"setup_build_s": "build_s", "setup_params_s": "params_s",
                  "setup_trace_lower_s": "trace_lower_s", "setup_load_compile_s": "load_compile_s",
                  "setup_programs_compiled": "programs_compiled"}
@@ -495,8 +496,9 @@ def test_the_state_entries_follow_one_another_and_name_their_cells_alone():
         assert m["moves"] == "binds_per_s" and m["layer"] == "model"
     assert [m["source"] for m in entries] == ["device_trace"] * 4 + ["program_counter"]
     assert entries[-1]["unit"] == "%" and entries[-1]["better"] == "higher"
-    # nothing but the set-up entries after them, then the fifth cell's three and the sixth's
-    assert names[first + 5:] == list(SETUP_METRICS) + GRANITE_METRICS + COHERE_METRICS
+    # nothing but the set-up entries after them, then the fifth cell's three, the sixth's and the
+    # block decode's prefix width
+    assert names[first + 5:] == list(SETUP_METRICS) + GRANITE_METRICS + COHERE_METRICS + [WIDTH_METRIC]
 
 
 def test_the_set_up_entries_follow_the_state_entries_and_move_setup_s():
@@ -648,8 +650,8 @@ class TestGraniteThroughTheSeam:
                                           "traffic": "backlog20_pool80", "chips": 1}
         assert bench["configs"][-2]["name"] == "granite-4_0-h-micro"
         listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
-        assert set(GRANITE_METRICS) <= listed and [m["name"] for m in bench["per_layer"][-6:-3]] == GRANITE_METRICS
-        for m in bench["per_layer"][-6:-3]:
+        assert set(GRANITE_METRICS) <= listed and [m["name"] for m in bench["per_layer"][-7:-4]] == GRANITE_METRICS
+        for m in bench["per_layer"][-7:-4]:
             assert m["workloads"] == [cell] and m["moves"] == "binds_per_s"
         assert {"full_attn_device_ms_per_bind.tput", "state_carry_device_ms_per_bind.tput",
                 "state_valid_share.tput", "prefix_attn_roofline.tput", "model_mfu.tput", *SETUP_METRICS} <= listed
@@ -849,8 +851,8 @@ class TestCommandAPlusThroughTheSeam:
                                           "config": "command-a-plus-05-2026", "traffic": "backlog", "chips": 1}
         assert bench["configs"][-1]["name"] == "command-a-plus-05-2026"
         listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
-        assert [m["name"] for m in bench["per_layer"][-3:]] == COHERE_METRICS
-        for m in bench["per_layer"][-3:]:
+        assert [m["name"] for m in bench["per_layer"][-4:-1]] == COHERE_METRICS
+        for m in bench["per_layer"][-4:-1]:
             assert m["workloads"] == [cell] and m["moves"] == "binds_per_s"
         assert {"full_attn_device_ms_per_bind.tput", "moe_experts_device_ms_per_bind.tput",
                 "moe_router_device_ms_per_bind.tput", "moe_shared_device_ms_per_bind.tput",
@@ -961,3 +963,37 @@ def test_the_window_roofline_reader_counts_the_keys_in_the_window():
     assert got == pytest.approx(100.0 * least / 4e-3)
     assert 0 < got <= 100
     assert bench_run.reader_for("prefix_attn_roofline.tput")(ctx) is None
+
+
+# ------------------------------------------------- block decode's prefix width
+def test_the_width_entry_closes_the_list_and_names_the_narrowing_cells():
+    """Appended last: the three cells whose block decode calls the prefix
+    kernels (the dense, Mamba-2 hybrid and window families), each behind
+    the cells before it; a counter of the program, lower where the
+    narrowing engages more."""
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    assert bench["per_layer"][-1] == {
+        "name": WIDTH_METRIC, "unit": "%", "better": "lower", "source": "program_counter", "layer": "kernels",
+        "moves": "binds_per_s", "workloads": [CELLS[0], GRANITE_CELL, COHERE_CELL]}
+
+
+def test_the_width_reader_reads_none_without_the_counters():
+    """A parent, or a family that does not narrow, books no counters: None, not 0."""
+    import run as bench_run
+    from types import SimpleNamespace
+
+    snap = {"sched": {"client": {"engine": {"waves": 3}}}}
+    ctx = bench_run.Ctx(outcome=SimpleNamespace(before=snap, after=snap))
+    assert bench_run.reader_for(WIDTH_METRIC)(ctx) is None
+
+
+def test_the_width_reader_reads_the_counters():
+    import run as bench_run
+    from types import SimpleNamespace
+
+    before = {"sched": {"client": {"engine": {"block_prefix_positions": 40,
+                                              "block_prefix_positions_full": 480}}}}
+    after = {"sched": {"client": {"engine": {"block_prefix_positions": 40 + 8 * 2 * 18 + 8 * 8 * 2,
+                                             "block_prefix_positions_full": 480 + 8 * 24 * 20}}}}
+    ctx = bench_run.Ctx(outcome=SimpleNamespace(before=before, after=after))
+    assert bench_run.reader_for(WIDTH_METRIC)(ctx) == pytest.approx(100 * (288 + 128) / 3840)

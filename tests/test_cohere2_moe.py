@@ -26,6 +26,7 @@ from k8s_llm_scheduler_tpu.models import cohere2_moe, family
 from k8s_llm_scheduler_tpu.models.configs import Cohere2MoeConfig, get_config
 from k8s_llm_scheduler_tpu.ops.attention import (
     attend_part,
+    block_prefix_widths,
     merge_attention_parts,
     prefix_attend_parts,
     window_prefix_keys_read,
@@ -176,9 +177,12 @@ def test_prefill_then_block_decode_is_the_references_full_forward(toy):
     assert (c["window_keys_read"], c["window_keys_causal"]) == (sum(SUFFIX_LENS) * P_BUCKET, sum(SUFFIX_LENS) * P)
     assert c["moe_layer_calls"] == cfg.n_layers and c["moe_bounded_calls"] == cfg.n_layers
     assert 0 < c["moe_assignments"] <= sum(SUFFIX_LENS) * cfg.n_experts_per_tok * cfg.n_layers
-    for (p, ok), counters in zip(wave.positions, wave.decode_counters):
+    assert (c["block_prefix_positions"], c["block_prefix_positions_full"]) == (0, 0)
+    for (p, ok), counters, blk in zip(wave.positions, wave.decode_counters, BLOCKS):
         c = dict(zip(names, counters))
         assert (c["window_keys_read"], c["window_keys_causal"]) == (int(ok.sum()) * P_BUCKET, int(ok.sum()) * P)
+        w = min(w for w in block_prefix_widths(F) if w >= max(blk))
+        assert (c["block_prefix_positions"], c["block_prefix_positions_full"]) == (R * w, R * F)
 
 
 def test_the_windowed_kernel_serves_the_same_wave(toy, monkeypatch):
@@ -544,7 +548,9 @@ def test_the_full_prefix_kernel_serves_the_global_layers_alone():
     `flash_prefix_attention_parts`, in the suffix call and in block decode:
     a reader of the full kernel's events (metrics/prefix_attn_roofline.py,
     every prefix key a call) sees the global layer's calls and nothing of
-    the window's."""
+    the window's. Block decode holds each layer's kernel once a width of
+    its prefix part (ops/attention.py block_prefix_widths), one of which a
+    call runs."""
     cfg = toy_cfg(jnp.bfloat16)
     model = cohere2_moe
     params = jax.eval_shape(lambda k: model.init_params(k, cfg), jax.random.PRNGKey(0))
@@ -566,7 +572,7 @@ def test_the_full_prefix_kernel_serves_the_global_layers_alone():
     decode = jax.make_jaxpr(lambda p, *a: model.forward_block_decode(p, cfg, *a, prefix_impl="pallas"))(
         params, i32(R, F), jax.ShapeDtypeStruct((R, F), jnp.bool_), i32(R), i32(R, F), *cache(R, SS), i32(R),
         *cache(R, CAP + F), i32(R), *cache(256), i32())
-    for jaxpr in (suffix, decode):
+    for jaxpr, rungs in ((suffix, 1), (decode, len(block_prefix_widths(F)))):
         names = kernels(jaxpr.jaxpr)   # the scan's body: one period
-        assert names.count("window_prefix_attention") == 3
-        assert names.count("flash_prefix_attention_parts") == 1
+        assert names.count("window_prefix_attention") == 3 * rungs
+        assert names.count("flash_prefix_attention_parts") == rungs

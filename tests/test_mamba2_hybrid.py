@@ -24,6 +24,7 @@ import pytest
 
 from k8s_llm_scheduler_tpu.models import family, mamba2_hybrid
 from k8s_llm_scheduler_tpu.models.configs import Mamba2HybridConfig, get_config
+from k8s_llm_scheduler_tpu.ops.attention import block_prefix_widths
 
 BENCH = Path(__file__).resolve().parents[1] / "benchmark"
 
@@ -241,12 +242,16 @@ def test_prefill_then_block_decode_is_the_references_full_forward(toy):
     scale = float(np.std(ref))
     assert scale > 0.01  # logits of the table's scale, not zeros: the tolerance means something
     assert _gaps(wave, ref) < TOL * scale
-    # the counters: every valid token once a call, the scan's width with padding
+    # the counters: every valid token once a call, the scan's width with padding; block
+    # decode's prefix positions at the narrowest width that holds the call's longest run
     c = dict(zip(mamba2_hybrid.COUNTERS, wave.suffix_counters))
-    assert c == {"state_tokens_valid": sum(SUFFIX_LENS), "state_tokens_computed": R * SS}
+    assert c == {"state_tokens_valid": sum(SUFFIX_LENS), "state_tokens_computed": R * SS,
+                 "block_prefix_positions": 0, "block_prefix_positions_full": 0}
     for blk, counters in zip(BLOCKS, wave.decode_counters):
+        w = min(w for w in block_prefix_widths(F) if w >= max(blk))
         assert dict(zip(mamba2_hybrid.COUNTERS, counters)) == {
-            "state_tokens_valid": sum(blk), "state_tokens_computed": R * F}
+            "state_tokens_valid": sum(blk), "state_tokens_computed": R * F,
+            "block_prefix_positions": R * w, "block_prefix_positions_full": R * F}
 
 
 def test_a_lost_pin_state_fails_the_tolerance(toy):

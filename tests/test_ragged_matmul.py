@@ -134,12 +134,14 @@ class TestRaggedBlockDecode:
         from k8s_llm_scheduler_tpu.models.llama import forward_block_decode
 
         cfg, params, kw = self._case()
-        logits_d, gk_d, gv_d = forward_block_decode(
+        logits_d, gk_d, gv_d, counts_d = forward_block_decode(
             params, cfg, **kw, ragged=False
         )
-        logits_r, gk_r, gv_r = forward_block_decode(
+        logits_r, gk_r, gv_r, counts_r = forward_block_decode(
             params, cfg, **kw, ragged=True
         )
+        # both bodies narrow the prefix part to the same rung
+        np.testing.assert_array_equal(np.asarray(counts_r), np.asarray(counts_d))
         live = np.asarray(kw["blk_len"]) > 0
         np.testing.assert_allclose(
             np.asarray(logits_r)[live], np.asarray(logits_d)[live],

@@ -199,9 +199,10 @@ def test_the_routed_layer_sorts_nothing_on_the_chip(one_chip, no_compile_cache, 
     assert "router_top_k" in text
 
 
-# (rows, positions): the command-a-plus cell's decode call, suffix call and a
+# (rows, positions): the command-a-plus cell's decode call at each width of
+# its prefix part (ops/attention.py block_prefix_widths), suffix call and a
 # chunk of its prefix prefill, against a 12,288-token prefix buffer
-@pytest.mark.parametrize("rows, positions", [(8, 24), (8, 128), (1, 2048)])
+@pytest.mark.parametrize("rows, positions", [(8, 24), (8, 128), (1, 2048), (8, 2), (8, 8)])
 def test_the_window_prefix_kernel_compiles_for_the_chip(one_chip, no_compile_cache, rows, positions):
     """ops/pallas_prefix_attention.py `window_prefix_attention` at the
     published heads (128 query heads of 128, 8 KV heads) and window (4,096):
@@ -219,6 +220,25 @@ def test_the_window_prefix_kernel_compiles_for_the_chip(one_chip, no_compile_cac
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "window_prefix_attention" in text
     assert "flash_prefix_attention_parts" not in text
+
+
+# (rows, positions, query heads, KV heads, head width, prefix buffer): block
+# decode's global prefix part at the narrowest width (ops/attention.py
+# block_prefix_widths) in the dense cell's two wave widths, the Mamba-2
+# hybrid's attention and the command-a-plus global layer
+@pytest.mark.parametrize("rows, positions, heads, kv, hd, cap", [
+    (8, 2, 16, 8, 128, 2048), (4, 2, 16, 8, 128, 2048), (8, 2, 32, 8, 64, 2048), (8, 2, 128, 8, 128, 12288)])
+def test_the_full_prefix_kernel_compiles_at_the_narrow_widths(one_chip, no_compile_cache, rows, positions, heads,
+                                                              kv, hd, cap):
+    from k8s_llm_scheduler_tpu.ops.pallas_prefix_attention import flash_prefix_attention_parts
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda q, k, v, n: flash_prefix_attention_parts(q, k, v, n, interpret=False)).lower(
+        shape(rows, positions, heads, hd), shape(cap, kv, hd), shape(cap, kv, hd), shape(dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "flash_prefix_attention_parts" in text
 
 
 def test_block_decode_copies_no_weight_out_of_its_stack(one_chip, no_compile_cache, monkeypatch):
